@@ -1,10 +1,12 @@
 // Predictor: cgo binding over the C inference API (reference
 // go/paddle/predictor.go wraps paddle_c_api.h the same way).
 //
-// Build (the shared library embeds CPython, so link python too):
+// Build (the shared library is built from source into csrc/build, and
+// embeds CPython, so link python too):
 //
+//	make -C ${REPO}/csrc build/libpd_infer_capi.so
 //	CGO_CFLAGS="-I${REPO}/csrc" \
-//	CGO_LDFLAGS="-L${REPO}/csrc -lpd_infer_capi -lpython3.12" \
+//	CGO_LDFLAGS="-L${REPO}/csrc/build -lpd_infer_capi -lpython3.12" \
 //	go build ./...
 package paddle
 

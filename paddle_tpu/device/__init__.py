@@ -1,36 +1,32 @@
 """paddle.device namespace (reference `python/paddle/device.py`).
 
-Also owns the persistent XLA compilation-cache wiring: paddle_tpu points
-`jax_compilation_cache_dir` at `FLAGS_xla_compilation_cache_dir`
-(default `~/.cache/paddle_tpu/xla`) so a repeat run of the same model
-skips XLA recompiles entirely — the first-step compile latency
-`bench.py` reports drops to cache-read time. The wiring happens at
-import when JAX_PLATFORMS names the backend, otherwise lazily at the
-first framework compile (`maybe_enable_compilation_cache`) so importing
-paddle_tpu never forces a JAX backend init. Opt out with the
-`FLAGS_xla_compilation_cache=0` environment variable (always works); a
-post-import `set_flags({"FLAGS_xla_compilation_cache": False})` is only
-honored on the deferred first-compile branch — when JAX_PLATFORMS is
-set, the flag is read during import itself.
+Also owns the one decision about JAX's persistent compilation cache,
+made once here at import by a config update that initialises no backend
+— so the trainer, both serving engines and every script are covered
+alike:
 
-The cache is NOT enabled on the CPU backend: XLA:CPU's serialized
-executables drop input/output buffer aliasing, so a cache *hit* on a
-donated train step reads freed buffers and silently corrupts numerics
-(reproduced on jax 0.4.37 with the dp-sharded step — second process
-reading the cache diverges to ~1e18). TPU executables round-trip
-aliasing correctly; CPU callers who accept the risk can pass
-`enable_compilation_cache(force=True)`, which now warns ONCE naming
-that corruption class instead of overriding silently.
+- `JAX_COMPILATION_CACHE_DIR` set: the cache is placed from outside. JAX
+  reads the variable itself and nothing in this repo sets a directory.
+- unset: the cache goes to `COMPILE_CACHE_DIR`, one fixed git-ignored
+  directory inside the checkout. The path is part of every cache key, so
+  it never moves: no `~`, no tempfile, no pid, no time.
 
-The same gate guards the serving program store
-(`serving/program_store.py`, ISSUE 16): both policies call
-`serialization_unsafe_backend()` here, so "is a deserialized
-executable trustworthy on this backend" has exactly one answer — the
-two refusals cannot drift apart. The store's root directory resolves
-through `program_store_dir()` (FLAGS_gen_program_store_dir).
+Every compile is cached, whatever its size or compile time (JAX's
+defaults skip programs that compile in under a second, which makes the
+set of entries depend on how fast the machine happened to be). Re-verified
+on jax 0.9.0 on the CPU backend too: a donated dp-sharded train step and
+the donated `Model.train_batch` step give identical losses from a cold
+and a warm cache, so the cache is no longer refused there.
+
+The serving program store (`serving/program_store.py`) deserializes
+executables by its own route, which has NOT been re-verified on the CPU
+backend; `serialization_unsafe_backend()` keeps refusing it there unless
+forced (ROADMAP Queue 3 item 5 decides the store's fate).
 """
 import os as _os
 import warnings as _warnings
+
+import jax as _jax
 
 from ..framework.place import (CPUPlace, CUDAPlace, TPUPlace, device_count,
                                get_device, is_compiled_with_cuda,
@@ -38,88 +34,56 @@ from ..framework.place import (CPUPlace, CUDAPlace, TPUPlace, device_count,
 
 __all__ = ["set_device", "get_device", "CPUPlace", "CUDAPlace", "TPUPlace",
            "device_count", "is_compiled_with_cuda", "is_compiled_with_tpu",
-           "cuda", "enable_compilation_cache", "maybe_enable_compilation_cache",
-           "compilation_cache_dir", "serialization_unsafe_backend",
-           "warn_forced_serialization", "program_store_dir"]
+           "cuda", "COMPILE_CACHE_DIR", "compilation_cache_dir",
+           "serialization_unsafe_backend", "warn_forced_serialization",
+           "program_store_dir"]
 
-_compile_cache_dir = None  # active dir once enable_compilation_cache ran
-_cache_decision_pending = False  # JAX_PLATFORMS unset: decide at 1st compile
-_force_warned = False  # one warning per process across BOTH policies
+# <checkout>/.jax_cache — derived from this package's location only
+COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__)))), ".jax_cache")
+
+_force_warned = False  # one warning per process
 
 
-def _cpu_backend() -> bool:
-    """True when jax will (or did) resolve to the CPU backend. Prefers the
-    JAX_PLATFORMS env var (no backend init needed); falls back to asking
-    jax, which initializes the default backend — only reached from the
-    lazy first-compile path, never at import."""
-    env = _os.environ.get("JAX_PLATFORMS", "").strip().lower()
-    if env:
-        return env.split(",")[0].strip() == "cpu"
-    try:
-        import jax
-        return jax.default_backend() == "cpu"
-    except Exception:
-        return True  # no backend at all — nothing to cache
+def _place_compilation_cache():
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
+_place_compilation_cache()
+
+
+def compilation_cache_dir():
+    """Directory of JAX's persistent compile cache in this process."""
+    return _jax.config.jax_compilation_cache_dir
 
 
 def serialization_unsafe_backend() -> bool:
-    """THE gate (ISSUE 16): True when executables deserialized on this
-    backend cannot be trusted to keep input/output buffer aliasing —
-    the PR 1 XLA:CPU corruption class, where a donated program read
-    from a serialized artifact silently reads freed buffers. Both the
-    persistent compilation cache (`enable_compilation_cache`) and the
-    serving program store (`serving/program_store.py`) consult this
-    single predicate, so the two refusal policies cannot drift."""
-    return _cpu_backend()
+    """True on the CPU backend, where the program store's deserialized
+    executables have not been shown to keep input/output buffer aliasing
+    (a donated program that lost it reads freed buffers). Initialises
+    the default backend."""
+    return _jax.default_backend() == "cpu"
 
 
 def warn_forced_serialization(context: str) -> None:
     """One warning per process when a caller overrides the CPU gate
-    (`force=True`) — names the PR 1 corruption class so the override
-    is never silent. Shared by the compilation cache and the program
-    store; whichever forces first emits it."""
+    (`force=True`), so the override is never silent."""
     global _force_warned
     if _force_warned:
         return
     _force_warned = True
     _warnings.warn(
         f"{context}: forcing serialized-executable reuse on the CPU "
-        f"backend. XLA:CPU deserialized executables have dropped "
-        f"input/output donation aliasing on this stack (jax 0.4.37, "
-        f"the PR 1 corruption class: a donated program silently reads "
-        f"freed buffers and diverges ~1e18); every load therefore "
-        f"runs the donation-aliasing self-check and a numeric smoke "
-        f"probe, and falls back to live compile on any mismatch.",
+        f"backend, where deserialized executables have not been shown "
+        f"to keep input/output donation aliasing (the corruption class: "
+        f"a donated program silently reads freed buffers); every load "
+        f"therefore runs the donation-aliasing self-check and a numeric "
+        f"smoke probe, and falls back to live compile on any mismatch.",
         RuntimeWarning, stacklevel=3)
-
-
-def enable_compilation_cache(path=None, force=False):
-    """Point JAX's persistent compilation cache at `path` (defaults to
-    FLAGS_xla_compilation_cache_dir). Returns the active directory, or
-    None when the cache config is unsupported — or when the backend is
-    CPU, where deserialized executables lose donation aliasing and give
-    wrong results (see module docstring); `force=True` overrides, with
-    a one-time warning naming that corruption class."""
-    global _compile_cache_dir
-    from ..framework.flags import flag
-    if serialization_unsafe_backend():
-        if not force:
-            return None
-        warn_forced_serialization("enable_compilation_cache(force=True)")
-    d = _os.path.expanduser(path or flag("FLAGS_xla_compilation_cache_dir"))
-    try:
-        import jax
-        _os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-    except Exception:
-        return None
-    _compile_cache_dir = d
-    return d
-
-
-def compilation_cache_dir():
-    """Directory of the active persistent compile cache (None if off)."""
-    return _compile_cache_dir
 
 
 def program_store_dir():
@@ -130,47 +94,6 @@ def program_store_dir():
     from ..framework.flags import flag
     d = str(flag("FLAGS_gen_program_store_dir") or "").strip()
     return _os.path.expanduser(d) if d else None
-
-
-def maybe_enable_compilation_cache():
-    """Resolve a deferred cache decision (JAX_PLATFORMS unset at import).
-
-    Idempotent and cheap after the first call. Invoked from the
-    framework's compile entry points (Model train/eval/predict compile
-    misses, bench.py) — at that moment a backend is about to be
-    initialized anyway, so the CPU-soundness check in `_cpu_backend()`
-    costs nothing extra, whereas running it at import would force
-    backend init (TPU runtime grab / GPU preallocation) on every
-    `import paddle_tpu`."""
-    global _cache_decision_pending
-    if not _cache_decision_pending:
-        return
-    _cache_decision_pending = False
-    try:
-        from ..framework.flags import flag
-        # in this deferred branch the decision happens after import, so a
-        # set_flags() opt-out CAN be honored — re-read the flag here
-        if flag("FLAGS_xla_compilation_cache"):
-            enable_compilation_cache()
-    except Exception:
-        pass
-
-
-def _init_compilation_cache():
-    global _cache_decision_pending
-    from ..framework.flags import flag
-    try:
-        if not flag("FLAGS_xla_compilation_cache"):
-            return
-        if _os.environ.get("JAX_PLATFORMS", "").strip():
-            enable_compilation_cache()  # env decides; no backend init
-        else:
-            _cache_decision_pending = True  # decide lazily at 1st compile
-    except Exception:
-        pass
-
-
-_init_compilation_cache()
 
 
 class cuda:

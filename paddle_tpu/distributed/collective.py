@@ -22,7 +22,6 @@ import contextlib
 import threading
 from typing import List, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -147,7 +146,6 @@ def broadcast(tensor, src=0, group=None, sync_op=True):
     if axis is not None:
         def impl(v):
             # select src's value on every member of the axis
-            sz = lax.axis_size(axis) if hasattr(lax, "axis_size") else None
             full = lax.all_gather(v, axis)
             return full[src]
         out = apply_op("c_broadcast", impl, (tensor,), {})
@@ -221,8 +219,7 @@ def send(tensor, dst=0, group=None, sync_op=True):
     n = get_world_size()
 
     def impl(v):
-        sz = (jax.lax.axis_size(axis) if hasattr(jax.lax, 'axis_size')
-              else jax.lax.psum(1, axis))
+        sz = lax.axis_size(axis)
         perm = [(i, (i + 1) % sz) for i in range(sz)]
         return lax.ppermute(v, axis, perm)
     out = apply_op("send_v2", impl, (tensor,), {})

@@ -14,9 +14,39 @@ from typing import Optional
 import jax
 
 __all__ = ["ParallelEnv", "init_parallel_env", "get_rank", "get_world_size",
-           "is_initialized"]
+           "is_initialized", "refuse_processes_per_chip"]
 
 _initialized = False
+
+
+def refuse_processes_per_chip(nprocs: int, what: str, env=None) -> None:
+    """Raise before `what` starts `nprocs` > 1 local JAX processes on a
+    host with TPU chips.
+
+    A chip belongs to one process. Nothing here divides the host's chips
+    among children, so each child would try to open all of them: the
+    first wins and the rest fail or hang at backend start-up — the same
+    happens to every child of a parent that already touched JAX. One
+    process drives all local chips (`fleet.init` + a mesh). Children whose
+    environment pins `JAX_PLATFORMS=cpu` need no chip and are let
+    through. The chips are counted from PCI (what jax itself does before
+    it picks a backend), so this initialises nothing."""
+    if nprocs <= 1:
+        return
+    env = os.environ if env is None else env
+    if env.get("JAX_PLATFORMS", "").split(",")[0].strip().lower() == "cpu":
+        return
+    from jax._src import hardware_utils
+    chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    if chips:
+        raise RuntimeError(
+            f"{what}: refusing to start {nprocs} processes on a host with "
+            f"{chips} TPU chip(s). A chip belongs to one process and the "
+            f"children are not given separate chips, so they would fail "
+            f"or hang at backend start-up. Drive all local chips from ONE "
+            f"process (fleet.init(is_collective=True) lays a mesh over "
+            f"them), or pin JAX_PLATFORMS=cpu for a CPU-only "
+            f"multi-process run.")
 
 
 class ParallelEnv:

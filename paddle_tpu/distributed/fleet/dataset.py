@@ -12,8 +12,6 @@ multi-host jobs), and batched into dense int64/float32 arrays.
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -27,13 +25,8 @@ def _load():
     global _LIB
     if _LIB is not None:
         return _LIB
-    d = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))), "csrc")
-    so = os.path.join(d, "libdata_feed.so")
-    if not os.path.exists(so):
-        subprocess.run(["make", "-C", d, "libdata_feed.so"], check=True,
-                       capture_output=True)
-    lib = ctypes.CDLL(so)
+    from ...utils.native import native_lib
+    lib = ctypes.CDLL(native_lib("data_feed"))
     lib.data_feed_parse.restype = ctypes.c_void_p
     lib.data_feed_parse.argtypes = [ctypes.c_char_p,
                                     ctypes.POINTER(ctypes.c_int),

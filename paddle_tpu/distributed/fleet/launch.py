@@ -2,8 +2,12 @@
 launch_ps, `launch_utils.py:435,494` start_local_trainers).
 
 TPU model: ONE process per host (SPMD spans local chips), so the launcher
-spawns one worker per node entry — or per requested proc — wiring the same
-PADDLE_* env contract plus JAX coordinator vars. Usage:
+spawns one worker per node entry, wiring the same PADDLE_* env contract
+plus JAX coordinator vars. `--nproc_per_node > 1` is for CPU multi-process
+runs (`JAX_PLATFORMS=cpu`); on a host with TPU chips it is refused, because
+the children would all open the same chips
+(`distributed.env.refuse_processes_per_chip`). The launcher itself never
+touches a JAX backend. Usage:
   python -m paddle_tpu.distributed.fleet.launch --nproc_per_node 1 train.py
 """
 from __future__ import annotations
@@ -47,6 +51,8 @@ def _parse():
 def _spawn_procs(args):
     ips = args.ips.split(",")
     nproc = args.nproc_per_node
+    from ..env import refuse_processes_per_chip
+    refuse_processes_per_chip(nproc, "fleet.launch --nproc_per_node")
     world = len(ips) * nproc
     endpoints = [f"{ip}:{args.started_port + i}"
                  for ip in ips for i in range(nproc)]

@@ -1,11 +1,10 @@
 """ctypes bindings for the native PS table core (csrc/ps_core.cc;
 reference `paddle/fluid/distributed/table/common_{dense,sparse}_table.cc`).
-Auto-builds the shared library on first use if missing."""
+Builds the shared library from source on first use
+(`utils.native.native_lib`)."""
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 from typing import Optional
 
 import numpy as np
@@ -15,21 +14,12 @@ __all__ = ["DenseTable", "SparseTable", "native_available"]
 _LIB: Optional[ctypes.CDLL] = None
 
 
-def _csrc_dir():
-    pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))  # .../paddle_tpu
-    return os.path.join(os.path.dirname(pkg_root), "csrc")
-
-
 def _load():
     global _LIB
     if _LIB is not None:
         return _LIB
-    so = os.path.join(_csrc_dir(), "libps_core.so")
-    if not os.path.exists(so):
-        subprocess.run(["make", "-C", _csrc_dir(), "libps_core.so"],
-                       check=True, capture_output=True)
-    lib = ctypes.CDLL(so)
+    from ...utils.native import native_lib
+    lib = ctypes.CDLL(native_lib("ps_core"))
     lib.dense_table_create.restype = ctypes.c_void_p
     lib.dense_table_create.argtypes = [ctypes.c_int64, ctypes.c_char_p,
                                        ctypes.c_float]

@@ -333,13 +333,6 @@ register_flag("FLAGS_train_tail_bucketing", True,
               "stats will see the padded rows) and a loss that is a "
               "mean/sum over rows (hapi/model.py falls back to the "
               "unpadded step otherwise)")
-register_flag("FLAGS_xla_compilation_cache", True,
-              "persist compiled XLA executables across processes so repeat "
-              "runs skip recompiles (device/__init__.py wires this into "
-              "jax_compilation_cache_dir at import)")
-register_flag("FLAGS_xla_compilation_cache_dir",
-              os.path.join("~", ".cache", "paddle_tpu", "xla"),
-              "directory backing the persistent XLA compilation cache")
 register_flag("FLAGS_serving_max_batch_size", 64,
               "serving.InferenceEngine: most request rows coalesced into "
               "one device batch (also the largest default shape bucket)")

@@ -60,12 +60,6 @@ def functionalize(layer, forward: Callable = None):
     apply_fn(params: dict, buffers: dict, rng, training: bool, *args,
              **kwargs) -> (out_pytree_of_arrays, new_buffers: dict)
     """
-    # every jitted step builder (hapi Model, parallel/spmd|pipeline|
-    # localsgd, bench) passes through here right before its first
-    # compile — the one choke point to resolve the deferred persistent
-    # compile-cache decision (see device.maybe_enable_compilation_cache)
-    from ..device import maybe_enable_compilation_cache
-    maybe_enable_compilation_cache()
     params = get_params(layer)
     buffers = get_buffers(layer)
     fwd = forward or layer.__call__

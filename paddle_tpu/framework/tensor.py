@@ -197,12 +197,8 @@ class Tensor:
 
     def to(self, place):
         if isinstance(place, str):
-            from .place import set_device
-            # parse without mutating global default
-            from . import place as _p
-            saved = _p._state.place
-            pl = set_device(place)
-            _p._state.place = saved
+            from .place import parse_place
+            pl = parse_place(place)
         else:
             pl = place
         return Tensor(jax.device_put(self._value, device_for(pl)),

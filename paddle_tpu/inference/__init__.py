@@ -397,13 +397,7 @@ class Predictor:
                 if self._jit_call is not None:  # two wrappers (= two traces
                     return self._jit_call       # per shape, breaking the
                 import jax                      # exact-compile-count contract)
-                from ..device import maybe_enable_compilation_cache
                 from ..framework import monitor
-                # resolve the deferred persistent-cache decision: a
-                # serving-only process never passes through functionalize(),
-                # so the first predictor compile is its "first framework
-                # compile" (device/__init__.py contract)
-                maybe_enable_compilation_cache()
                 exported = self._translated._exported
 
                 def _call(*args):

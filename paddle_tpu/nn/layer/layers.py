@@ -210,11 +210,8 @@ class Layer:
                 p._value = p._value.astype(dt)
         if device is not None:
             import jax
-            from ...framework.place import device_for, set_device
-            from ...framework import place as _p
-            saved = _p._state.place
-            pl = set_device(device) if isinstance(device, str) else device
-            _p._state.place = saved
+            from ...framework.place import device_for, parse_place
+            pl = parse_place(device) if isinstance(device, str) else device
             dev = device_for(pl)
             for p in self.parameters():
                 p._value = jax.device_put(p._value, dev)

@@ -13,9 +13,10 @@ Two attention implementations over that layout, one math:
 - **TPU** — `jax.experimental.pallas.ops.tpu.paged_attention` (the
   primitive SNIPPETS.md [3] shards along KV heads): reads pages in
   place, `lengths` masks per sequence. Flag-gated by
-  `FLAGS_use_paged_attention`; tile = `FLAGS_paged_compute_block_pages`
+  `FLAGS_use_paged_attention` and shape-gated by
+  `paged_kernel_supported`; tile = `FLAGS_paged_compute_block_pages`
   pages.
-- **reference** (CPU / interpret parity) — gather the page table into a
+- **reference** (every other backend and shape) — gather the page table into a
   dense `[B, H, T, D]` buffer and run `cached_attention`, the EXACT
   masked-softmax expression `GPTModel.generate`'s fixed cache uses, so
   the generation engine's greedy decode is anchored to the same oracle
@@ -38,6 +39,7 @@ from ..framework import monitor
 from ..framework.flags import flag
 
 __all__ = ["cached_attention", "paged_attention", "paged_gather",
+           "paged_kernel_supported",
            "paged_gather_layers", "paged_gather_quantized",
            "paged_prefix_attention", "paged_write",
            "paged_write_quantized", "page_rows_for_positions",
@@ -205,13 +207,40 @@ def paged_write_quantized(pages, scales, layer, page_ids, offsets, values,
     return pages, scales
 
 
-def _use_kernel() -> bool:
-    if not bool(flag("FLAGS_use_paged_attention")):
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend not initialized yet
-        return False
+def _block_pages() -> int:
+    # lint: allow(flag-in-trace): the kernel tile is lowering structure — which program gets built, not a runtime value; the engine keys its program store on this flag for the same reason
+    return int(flag("FLAGS_paged_compute_block_pages"))
+
+
+def paged_kernel_supported(q_shape, pages_shape, table_shape) -> bool:
+    """Static gate, like `flash_supported`: the shapes the installed
+    `jax.experimental.pallas.ops.tpu.paged_attention` kernel lowers and
+    compiles for. q [B, H, D]; pages [Hkv, N, P, D]; table [B, PP].
+
+    - head dim a multiple of 128: the kernel's running-max / running-sum
+      outputs ([.., 1]-shaped) reuse q's block spec, whose last dim must
+      then be a lane multiple. Head dim 64 (GPT-2 small) fails Mosaic
+      lowering on jax 0.9.0, so it takes the gather reference BY THIS RULE.
+    - page size a multiple of 8: one page is a [P, D] VMEM tile.
+    - query heads a multiple of KV heads (grouped query included).
+    - the kernel walks the table FLAGS_paged_compute_block_pages pages at
+      a time and requires PP to divide evenly.
+    Every shape admitted here must compile on the chip. Compiled on the
+    v5e in f32 and bf16 and matching the reference to < 0.01 (PR 21): head
+    dim 128/256/384/512, page 8/16/32/48, 1..16 sequences, 3..32 heads,
+    groups of 1/2/8, tables of 8 and 64 pages. Widen the rule only with a
+    chip run that shows it."""
+    _, H, D = q_shape
+    Hkv, _, P, Dk = pages_shape
+    return (D == Dk and D % 128 == 0 and H % Hkv == 0 and P % 8 == 0
+            and table_shape[1] % _block_pages() == 0)
+
+
+def _use_kernel(q_shape, pages_shape, table_shape) -> bool:
+    # lint: allow(flag-in-trace): kernel-vs-reference is a trace-time choice by design (module docstring); the flag picks which program gets built
+    return (bool(flag("FLAGS_use_paged_attention"))
+            and jax.default_backend() == "tpu"
+            and paged_kernel_supported(q_shape, pages_shape, table_shape))
 
 
 def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
@@ -222,9 +251,11 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
     page_table [B, PP] int32; pos [B] int32 (last valid position, the
     token just written). Returns [B, H, D].
 
-    TPU dispatches the Pallas kernel (pages read in place); everywhere
-    else the reference gathers to dense and reuses `cached_attention` —
-    the generate-anchored math.
+    On a TPU backend, shapes `paged_kernel_supported` admits dispatch the
+    Pallas kernel (pages read in place); every other shape and backend
+    gathers to dense and reuses `cached_attention` — the generate-anchored
+    math. The choice is a shape rule made before the call, never a
+    fallback from a kernel that failed.
 
     int8 pools pass k_scales/v_scales ([H, N] per-page scales): the
     Pallas kernel has no int8+scale-pool input layout, so quantized
@@ -236,19 +267,25 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
         kb = paged_gather_quantized(k_pages, k_scales, page_table, q.dtype)
         vb = paged_gather_quantized(v_pages, v_scales, page_table, q.dtype)
         return cached_attention(q, kb, vb, pos, scale)
-    if _use_kernel():
+    if _use_kernel(q.shape, k_pages.shape, page_table.shape):
         monitor.stat_add("STAT_paged_attn_kernel")  # traces, not calls
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention as _kernel)
         # the kernel takes no softmax-scale argument and applies none
         # internally: fold ours into q before the qk product
-        out = _kernel(
-            q * scale, k_pages, v_pages,
-            lengths=(pos + 1).astype(jnp.int32),
-            page_indices=page_table.astype(jnp.int32),
-            pages_per_compute_block=int(
-                flag("FLAGS_paged_compute_block_pages")))
-        return out
+        # The kernel's dots name no precision, and the framework pins
+        # jax_default_matmul_precision="highest" (framework/__init__.py);
+        # traced under that they lower to fp32 contract precision, which
+        # Mosaic refuses on the v5e ("Bad rhs type", every shape tried).
+        # DEFAULT is what the kernel was written against and what the
+        # repo's own flash kernels ask for: MXU passes on the stored
+        # dtype, f32 accumulation.
+        with jax.default_matmul_precision("default"):
+            return _kernel(
+                q * scale, k_pages, v_pages,
+                lengths=(pos + 1).astype(jnp.int32),
+                page_indices=page_table.astype(jnp.int32),
+                pages_per_compute_block=_block_pages())
     monitor.stat_add("STAT_paged_attn_reference")  # traces, not calls
     kb = paged_gather(k_pages, page_table)
     vb = paged_gather(v_pages, page_table)
@@ -273,7 +310,6 @@ def sharded_paged_attention(mesh, scale, tp_axis="tp", quantized=False):
     yielding [B, H, D] head-sharded like q."""
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.spmd import compat_shard_map
     hs = P(None, tp_axis, None)           # q / out [B, H, D]
     pool = P(tp_axis, None, None, None)   # one layer [H, N, Pg, D]
     spool = P(tp_axis, None)              # scale grid [H, N]
@@ -287,8 +323,8 @@ def sharded_paged_attention(mesh, scale, tp_axis="tp", quantized=False):
         def call(q, kp, vp, pt, pos):
             return paged_attention(q, kp, vp, pt, pos, scale)
         in_specs = (hs, pool, pool, rep, rep)
-    return jax.jit(compat_shard_map(call, mesh=mesh, in_specs=in_specs,
-                                    out_specs=hs, check=False))
+    return jax.jit(jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+                                 out_specs=hs, check_vma=False))
 
 
 def paged_gather_layers(pages, page_table, scales=None,

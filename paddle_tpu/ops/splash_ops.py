@@ -45,13 +45,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .pallas_ops import (_BLOCK_MIN, _NEG_INF, _HAS_PALLAS, _KernelStats,
-                         _dropout_bits, _interpret, _pick_blocks,
-                         _smem_scalar_spec)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-if _HAS_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from .pallas_ops import (_BLOCK_MIN, _NEG_INF, _KernelStats, _dropout_bits,
+                         _interpret, _pick_blocks, _smem_scalar_spec,
+                         vmem_resident_ok)
 
 __all__ = ["splash_attention", "splash_attention_raw", "splash_supported",
            "sdpa_segment_reference", "STATS"]
@@ -138,9 +137,10 @@ def _seg_mask(qseg, kseg, q_offs, k_offs, causal):
 
 def _fwd_kernel(seed_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, qs_ref,
                 ks_ref, o_ref, lse_ref, *, scale, causal, block_k,
-                dropout_p):
+                dropout_p, heads):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
+    lo, hi = lo_ref[bh // heads, qi], hi_ref[bh // heads, qi]
     q = q_ref[:]
     S, D = k_ref.shape
     bq = q_ref.shape[0]
@@ -178,8 +178,7 @@ def _fwd_kernel(seed_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, qs_ref,
     m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
     acc0 = jnp.zeros((bq, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(lo_ref[0, 0], hi_ref[0, 0], body,
-                                  (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, acc0))
     l_safe = jnp.where(l > 0, l, 1.0)
     o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[:] = m + jnp.log(l_safe)
@@ -200,9 +199,10 @@ def _recompute_p(q, k_blk, allowed, lse, scale):
 
 def _dq_kernel(seed_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, qs_ref,
                ks_ref, do_ref, lse_ref, dl_ref, dq_ref, *, scale, causal,
-               block_k, dropout_p):
+               block_k, dropout_p, heads):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
+    lo, hi = lo_ref[bh // heads, qi], hi_ref[bh // heads, qi]
     q = q_ref[:]
     do = do_ref[:]
     lse = lse_ref[:]
@@ -235,15 +235,16 @@ def _dq_kernel(seed_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, qs_ref,
             precision=jax.lax.Precision.DEFAULT)
 
     dq0 = jnp.zeros((bq, D), jnp.float32)
-    dq = jax.lax.fori_loop(lo_ref[0, 0], hi_ref[0, 0], body, dq0)
+    dq = jax.lax.fori_loop(lo, hi, body, dq0)
     dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(seed_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, qs_ref,
                 ks_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref, *, scale,
-                causal, block_q, dropout_p):
+                causal, block_q, dropout_p, heads):
     bh = pl.program_id(0)
     kb = pl.program_id(1)
+    lo, hi = lo_ref[bh // heads, kb], hi_ref[bh // heads, kb]
     k_blk = k_ref[:]                        # [bk, D]
     v_blk = v_ref[:]
     S, D = q_ref.shape
@@ -286,8 +287,7 @@ def _dkv_kernel(seed_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, qs_ref,
 
     dk0 = jnp.zeros((bk, D), jnp.float32)
     dv0 = jnp.zeros((bk, D), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo_ref[0, 0], hi_ref[0, 0], body,
-                               (dk0, dv0))
+    dk, dv = jax.lax.fori_loop(lo, hi, body, (dk0, dv0))
     dk_ref[:] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
@@ -296,11 +296,12 @@ def _dkv_kernel(seed_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, qs_ref,
 # host-side wrappers
 # ---------------------------------------------------------------------------
 
-def _smem_block_spec(H):
-    """One int32 per (batch, block) grid cell, indexed off the fused
-    batch*heads grid axis."""
-    return pl.BlockSpec((1, 1), lambda b, i: (b // H, i),
-                        memory_space=pltpu.SMEM)
+def _smem_bounds_spec():
+    """The whole [batch, n_blocks] int32 bounds array in SMEM; the kernels
+    index it by (batch, block) themselves. A (1, 1) block per grid cell
+    does not lower on jax 0.9.0: Mosaic wants the last two block dims
+    divisible by (8, 128) or equal to the array's."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _prep(q, k, v, q_seg, kv_seg):
@@ -321,15 +322,16 @@ def _splash_call(q, k, v, q_seg, kv_seg, seed, causal, scale, dropout_p,
                                        causal)
     seed_arr = jnp.asarray(seed, jnp.int32).reshape(1, 1)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_k=block_k, dropout_p=dropout_p)
+                               block_k=block_k, dropout_p=dropout_p,
+                               heads=H)
     STATS.bump("splash_fwd")
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, Sq // block_q),
         in_specs=[
             _smem_scalar_spec(),
-            _smem_block_spec(H),
-            _smem_block_spec(H),
+            _smem_bounds_spec(),
+            _smem_bounds_spec(),
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, Sk, D), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((None, Sk, D), lambda b, i: (b, 0, 0)),
@@ -366,12 +368,12 @@ def _splash_bwd_call(q, k, v, q_seg, kv_seg, seed, out, lse, g, causal,
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k, dropout_p=dropout_p),
+                          block_k=block_k, dropout_p=dropout_p, heads=H),
         grid=(B * H, Sq // block_q),
         in_specs=[
             _smem_scalar_spec(),
-            _smem_block_spec(H),
-            _smem_block_spec(H),
+            _smem_bounds_spec(),
+            _smem_bounds_spec(),
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, Sk, D), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((None, Sk, D), lambda b, i: (b, 0, 0)),
@@ -388,12 +390,12 @@ def _splash_bwd_call(q, k, v, q_seg, kv_seg, seed, out, lse, g, causal,
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, dropout_p=dropout_p),
+                          block_q=block_q, dropout_p=dropout_p, heads=H),
         grid=(B * H, Sk // block_k),
         in_specs=[
             _smem_scalar_spec(),
-            _smem_block_spec(H),
-            _smem_block_spec(H),
+            _smem_bounds_spec(),
+            _smem_bounds_spec(),
             pl.BlockSpec((None, Sq, D), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
@@ -470,15 +472,16 @@ def splash_attention_raw(q, k, v, q_seg, kv_seg, seed, causal, scale,
 
 
 def splash_supported(q_shape, k_shape=None, v_shape=None, is_causal=False,
-                     min_seq=None):
+                     min_seq=None, itemsize=4):
     """Static gate: shapes the splash kernels handle AND where they win.
 
     Packing is self-attention over one fixed row shape, so the gate is
     stricter than flash_supported: S_q == S_kv. Below `min_seq`
     (FLAGS_splash_attention_min_seq) the dense segment-masked fallback
-    wins, same crossover story as the flash kernel.
+    wins, same crossover story as the flash kernel — and the same VMEM
+    bound on the resident sequence (`pallas_ops.vmem_resident_ok`).
     """
-    if not _HAS_PALLAS or len(q_shape) != 4:
+    if len(q_shape) != 4:
         return False
     B, H, Sq, D = q_shape
     k_shape = tuple(k_shape) if k_shape is not None else tuple(q_shape)
@@ -487,7 +490,9 @@ def splash_supported(q_shape, k_shape=None, v_shape=None, is_causal=False,
         return False
     if k_shape != (B, H, Sq, D):      # packed rows: strict self-attention
         return False
-    if Sq % _BLOCK_MIN != 0 or D % 8 != 0 or D > 512:
+    if Sq % _BLOCK_MIN != 0 or D % 8 != 0:
+        return False
+    if not vmem_resident_ok(Sq, D, itemsize):
         return False
     if min_seq is None:
         from ..framework.flags import flag

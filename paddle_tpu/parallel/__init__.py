@@ -6,6 +6,6 @@ from .ring_attention import (ring_attention, shard_map_ring_attention,
                              ulysses_attention)
 from .compression import dgc_compress, dgc_init
 from .localsgd import local_write_back, make_local_train_step
-from .spmd import (batch_placement, batch_sharding, compat_shard_map,
-                   make_sharded_train_step, mapped_axis_size, param_sharding,
-                   shard_params, tp_mesh, write_back, zero_sharding)
+from .spmd import (batch_placement, batch_sharding,
+                   make_sharded_train_step, param_sharding, shard_params,
+                   tp_mesh, write_back, zero_sharding)

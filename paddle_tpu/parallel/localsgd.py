@@ -33,7 +33,6 @@ from ..framework.functional import functionalize
 from ..framework.tensor import Tensor
 from .compression import dgc_compress, dgc_init
 from .mesh import get_mesh
-from .spmd import compat_shard_map
 
 __all__ = ["make_local_train_step", "local_write_back"]
 
@@ -155,9 +154,9 @@ def make_local_train_step(layer, optimizer, loss_fn: Callable, mesh=None,
         in_specs = (state_spec,
                     tuple(P(dp_axis) for _ in inputs),
                     tuple(P(dp_axis) for _ in labels), scalar, scalar)
-        fn = compat_shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                              out_specs=(state_spec, scalar),
-                              check=False)
+        fn = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                           out_specs=(state_spec, scalar),
+                           check_vma=False)
         return fn(state_, inputs, labels, lr, rng)
 
     jit_step = jax.jit(sharded, donate_argnums=(0,))

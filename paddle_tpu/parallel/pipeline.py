@@ -24,8 +24,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .spmd import compat_shard_map, mapped_axis_size
-
 __all__ = ["gpipe_spmd", "pipeline_forward", "partition_blocks",
            "make_pipeline_train_step"]
 
@@ -44,7 +42,7 @@ def pipeline_forward(stage_fn: Callable, stage_params, x, *, axis_name="pp",
     t - d (if in range); activations hop d→d+1 each step. Total steps =
     n_micro + pp - 1.
     """
-    pp = mapped_axis_size(axis_name)
+    pp = lax.axis_size(axis_name)
     d = lax.axis_index(axis_name)
     steps = n_micro + pp - 1
     mb_shape = x.shape[1:]
@@ -100,11 +98,11 @@ def gpipe_spmd(stage_fn: Callable, mesh, n_micro: int, axis_name="pp"):
             return inner(params_local, x_rep)
         param_specs = jax.tree_util.tree_map(
             lambda _: P(axis_name), stacked_params)
-        return compat_shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(param_specs, P()),
             out_specs=P(axis_name),
-            check=False)(stacked_params, x)
+            check_vma=False)(stacked_params, x)
     return wrapper
 
 
@@ -178,7 +176,7 @@ def _hetero_pipeline_inner(block_apply, stage_params, x, rng, training,
     replicated to every pp rank via a masked psum (its transpose routes
     the head's cotangents back to the last stage).
     """
-    pp = mapped_axis_size(axis_name)
+    pp = lax.axis_size(axis_name)
     d = lax.axis_index(axis_name)
     steps = n_micro + pp - 1
     mb_shape = x.shape[1:]
@@ -298,11 +296,11 @@ def make_pipeline_train_step(model, optimizer, loss_fn, *, n_micro,
                 n_micro, recompute, schedule)
         x_spec = (P(None, dp_axis) if dp_axis in mesh.axis_names
                   else P())
-        return compat_shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(bp_specs, x_spec, P()),
             out_specs=x_spec,
-            check=False)(bpv_, x, rng)
+            check_vma=False)(bpv_, x, rng)
 
     def loss_of(pv_all_, bv_, rng, inputs, labels):
         from ..framework.autograd import trace_mode
@@ -364,13 +362,13 @@ def make_pipeline_train_step(model, optimizer, loss_fn, *, n_micro,
             g_stage = jax.tree_util.tree_map(lambda g: g[None], g_stage)
             return loss, g_stage, g_outer
 
-        loss, g_stage, g_outer = compat_shard_map(
+        loss, g_stage, g_outer = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(bp_specs, P(), P(),
                       tuple(mb_spec for _ in ids_m),
                       tuple(mb_spec for _ in lab_m), P()),
             out_specs=(P(), {n: P(pp_axis) for n in bpv}, P()),
-            check=False)(bpv_, opv_, bv_, ids_m, lab_m, rng)
+            check_vma=False)(bpv_, opv_, bv_, ids_m, lab_m, rng)
         grads = {**g_outer, **{f"pp::{n}": g_stage[n] for n in g_stage}}
         return loss, grads
 

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .spmd import mapped_axis_size
-
 __all__ = ["ring_attention", "ulysses_attention", "shard_map_ring_attention"]
 
 
@@ -67,7 +65,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     the global sequence is sp * S_loc, laid out contiguously by rank."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    sp = mapped_axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, H, S, D = q.shape
 
@@ -115,7 +113,7 @@ def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     [B, H/sp, S_global, D], attends locally, re-shards back."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    sp = mapped_axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
 
     def to_seq(x):
         # [B,H,S,D] -> split heads, gather sequence
@@ -148,11 +146,10 @@ def shard_map_ring_attention(q, k, v, mesh, causal=False, impl="ring"):
     [B, H, S, D] sequence-sharded on 'sp'."""
     from jax.sharding import PartitionSpec as P
 
-    from .spmd import compat_shard_map
     attn = ring_attention if impl == "ring" else ulysses_attention
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         functools.partial(attn, axis_name="sp", causal=causal),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3,
-        out_specs=P(None, None, "sp", None))
+        out_specs=P(None, None, "sp", None), check_vma=False)
     return fn(q, k, v)

@@ -34,36 +34,7 @@ from .mesh import get_mesh
 
 __all__ = ["param_sharding", "zero_sharding", "batch_sharding",
            "batch_placement", "make_sharded_train_step", "shard_params",
-           "sharded_splash_attention", "compat_shard_map", "tp_mesh"]
-
-# jax moved shard_map twice: old releases ship it only at
-# jax.experimental.shard_map (keyword `check_rep`), new ones only at
-# jax.shard_map (keyword `check_vma`). Resolve ONCE at import so every
-# caller — training builders and the serving engine's sharded program
-# pack alike — stays version-portable.
-try:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SM_CHECK_KW = "check_rep"
-except ImportError:  # pragma: no cover — jax without the experimental alias
-    _shard_map_impl = jax.shard_map
-    _SM_CHECK_KW = "check_vma"
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs, check=False):
-    """shard_map across jax versions (maps `check` onto whichever of
-    check_rep/check_vma this jax accepts). NOT jitted — wrap the result
-    in jax.jit yourself so donation/AOT knobs stay at the call site."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_SM_CHECK_KW: check})
-
-
-def mapped_axis_size(axis):
-    """`lax.axis_size` inside a shard_map/pmap body, on every jax:
-    old releases lack the function but constant-fold psum of a unit
-    literal to the (static, Python int) axis size."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+           "sharded_splash_attention", "tp_mesh"]
 
 
 def tp_mesh(tp, axis="tp", devices=None):
@@ -393,11 +364,11 @@ def sharded_splash_attention(mesh=None, causal=False, scale=None,
         return splash_attention_raw(q, k, v, q_seg, kv_seg, seed, causal,
                                     sc, dropout_p)
 
-    jitted = jax.jit(compat_shard_map(
+    jitted = jax.jit(jax.shard_map(
         call, mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, seg_spec, seg_spec,
                   PartitionSpec()),
-        out_specs=qkv_spec, check=False))
+        out_specs=qkv_spec, check_vma=False))
 
     def f(q, k, v, q_seg, kv_seg, seed=None):
         if seed is None:

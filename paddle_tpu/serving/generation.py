@@ -592,13 +592,20 @@ class GenerationEngine:
         self._cfg.prefill_buckets = tuple(sorted(
             {min(int(b), cap) for b in self._cfg.prefill_buckets}))
         self._device = device
+        if device is not None and pack is None and self._tp == 1:
+            # a one-device lane keeps its own copy of the weights on
+            # ITS device (committed there, so every program runs there);
+            # the pools below are allocated under the same device
+            import jax
+            self._W = jax.device_put(self._W, device)
         dtype = np.asarray(self._W["lnf"][0]).dtype
         kv_dtype = (str(dtype) if self._cfg.kv_cache_dtype == "auto"
                     else self._cfg.kv_cache_dtype)
-        self._cache = PagedKVCache(
-            mcfg.num_layers, self._H, self._D, self._cfg.page_size,
-            self._cfg.num_pages, self._cfg.pages_per_seq, dtype=kv_dtype,
-            mesh=self._mesh)
+        with self._dev_ctx():
+            self._cache = PagedKVCache(
+                mcfg.num_layers, self._H, self._D, self._cfg.page_size,
+                self._cfg.num_pages, self._cfg.pages_per_seq,
+                dtype=kv_dtype, mesh=self._mesh)
         # int8 page mode: quantize-on-append decode/prefill programs
         # thread the parallel scale pools (donated alongside the pages);
         # everything above this line — admission arithmetic, page
@@ -1118,7 +1125,6 @@ class GenerationEngine:
             from jax.sharding import PartitionSpec as PS
 
             from ..models.gpt import decode_weight_specs
-            from ..parallel.spmd import compat_shard_map
             rep = PS()
             wspec = decode_weight_specs(self._W)
             pool5 = PS(None, "tp", None, None, None)   # [L,H,N,Pg,D]
@@ -1132,8 +1138,8 @@ class GenerationEngine:
 
             def shard(fn, extras, outs, with_w=True):
                 ins = ((wspec,) if with_w else ()) + pspecs + extras
-                return compat_shard_map(fn, mesh=mesh, in_specs=ins,
-                                        out_specs=outs, check=False)
+                return jax.shard_map(fn, mesh=mesh, in_specs=ins,
+                                     out_specs=outs, check_vma=False)
 
             prefill_fn = shard(prefill_fn, (rep,) * 3, (*pspecs, rep))
             tail_prefill_fn = shard(tail_prefill_fn, (rep,) * 4,

@@ -119,13 +119,21 @@ class Router:
             if n < 1:
                 raise InvalidArgumentError(
                     f"Router needs >= 1 replica, got {n}")
+            # one-chip replicas spread over the local devices (replica i
+            # on device i, wrapping when there are more replicas than
+            # devices); a tp > 1 replica lays itself over a mesh slice
+            import jax
+            tp = int(config.tp if config is not None
+                     else overrides.get("tp") or flag("FLAGS_gen_tp"))
+            devs = jax.local_devices() if tp == 1 else [None]
             built: List[EngineSupervisor] = []
             try:
                 for i in range(n):
                     import copy
                     cfg = copy.copy(config) if config is not None else None
                     built.append(EngineSupervisor(
-                        model, cfg, name=f"{name}-r{i}", **overrides))
+                        model, cfg, name=f"{name}-r{i}",
+                        device=devs[i % len(devs)], **overrides))
             except Exception:
                 for sup in built:
                     sup.shutdown(drain=False, timeout_s=5)

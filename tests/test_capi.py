@@ -3,25 +3,23 @@ a real C program links libpd_infer_capi.so, loads a jit-saved artifact,
 runs float32 inference, and its output must match the in-process
 predictor.
 
-The environment gate (`_capi_ready`) is deliberate: when the C
-toolchain is absent, the build fails, or the committed .so cannot
-actually be linked into a driver on THIS machine (e.g. an artifact
-built against a different libpython than the image ships), the tests
-skip with the exact reason instead of failing — after first attempting
-one forced rebuild from source, which is the fix whenever the staleness
-is the artifact's and not the toolchain's."""
+The library is built from `csrc/inference_capi.cc` into the git-ignored
+`csrc/build/` (`paddle_tpu.utils.native.native_lib`) — no binary is
+tracked. The environment gate (`_capi_ready`) is deliberate: when the C
+toolchain is absent or the build fails on THIS machine, the tests skip
+with the exact reason instead of failing."""
 import os
 import shutil
 import subprocess
-import tempfile
 import textwrap
 
 import numpy as np
 import pytest
 
+from paddle_tpu.utils.native import BUILD_DIR, CSRC_DIR, native_lib
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CSRC = os.path.join(REPO, "csrc")
-LIB = os.path.join(CSRC, "libpd_infer_capi.so")
+LIB = os.path.join(BUILD_DIR, "libpd_infer_capi.so")
 
 C_DRIVER = r"""
 #include <stdint.h>
@@ -72,29 +70,12 @@ int main(int argc, char** argv) {
 """
 
 
-_READY = None  # cached (ok, reason) — the probe is expensive, run once
-
-
-def _probe_link():
-    """Link a trivial driver against the .so — the step where a stale
-    artifact surfaces (`make` considers a committed .so up to date, but
-    its DT_NEEDED libpython may not exist on this image)."""
-    with tempfile.TemporaryDirectory() as td:
-        c = os.path.join(td, "probe.c")
-        with open(c, "w") as f:
-            f.write("const char* PD_GetLastError(void);\n"
-                    "int main(void) { PD_GetLastError(); return 0; }\n")
-        r = subprocess.run(
-            ["gcc", c, "-o", os.path.join(td, "probe"), f"-L{CSRC}",
-             "-lpd_infer_capi", f"-Wl,-rpath,{CSRC}"],
-            capture_output=True, text=True)
-        return r.returncode == 0, r.stderr
+_READY = None  # cached (ok, reason) — the build is expensive, run once
 
 
 def _capi_ready():
-    """(ok, skip_reason): toolchain present -> `make` -> probe-link;
-    on probe failure force ONE rebuild from source (`make -B`) and
-    re-probe. Cached for the whole session."""
+    """(ok, skip_reason): toolchain present -> build from source.
+    Cached for the whole session."""
     global _READY
     if _READY is not None:
         return _READY
@@ -103,22 +84,12 @@ def _capi_ready():
         _READY = (False, f"C toolchain absent: no {'/'.join(missing)} "
                          f"in this image")
         return _READY
-    r = subprocess.run(["make", "libpd_infer_capi.so"], cwd=CSRC,
-                       capture_output=True, text=True)
-    if r.returncode != 0 or not os.path.exists(LIB):
-        _READY = (False, "C API lib build failed: "
-                         + r.stderr.strip()[-300:])
+    try:
+        native_lib("pd_infer_capi")
+    except RuntimeError as e:
+        _READY = (False, "C API lib build failed: " + str(e)[-300:])
         return _READY
-    ok, err = _probe_link()
-    if not ok:
-        r = subprocess.run(["make", "-B", "libpd_infer_capi.so"],
-                           cwd=CSRC, capture_output=True, text=True)
-        if r.returncode == 0:
-            ok, err = _probe_link()
-    _READY = (True, "") if ok else (
-        False, "C driver cannot link libpd_infer_capi.so "
-               "(stale artifact for this image?): "
-               + err.strip()[-300:])
+    _READY = (True, "")
     return _READY
 
 
@@ -142,8 +113,8 @@ def test_c_program_runs_saved_model(tmp_path):
     cfile.write_text(textwrap.dedent(C_DRIVER))
     exe = str(tmp_path / "driver")
     r = subprocess.run(
-        ["gcc", str(cfile), "-o", exe, f"-L{CSRC}", "-lpd_infer_capi",
-         f"-Wl,-rpath,{CSRC}"], capture_output=True, text=True)
+        ["gcc", str(cfile), "-o", exe, f"-L{BUILD_DIR}", "-lpd_infer_capi",
+         f"-Wl,-rpath,{BUILD_DIR}"], capture_output=True, text=True)
     assert r.returncode == 0, r.stderr
 
     in_file = str(tmp_path / "in.bin")
@@ -195,7 +166,7 @@ class TestLanguageBindings:
         assert not missing, f"cgo references unexported symbols: {missing}"
 
     def test_header_declares_all_symbols(self):
-        with open(os.path.join(CSRC, "pd_c_api.h")) as f:
+        with open(os.path.join(CSRC_DIR, "pd_c_api.h")) as f:
             header = f.read()
         for sym in self._cgo_symbols():
             assert sym in header, f"{sym} missing from pd_c_api.h"

@@ -36,9 +36,8 @@ def test_collective_inside_shard_map():
             t = paddle.Tensor(x)
             C.all_reduce(t)
             return t._value
-    from paddle_tpu.parallel.spmd import compat_shard_map
-    out = compat_shard_map(fn, mesh=mesh, in_specs=P("dp"),
-                           out_specs=P("dp"), check=False)(
+    out = jax.shard_map(fn, mesh=mesh, in_specs=P("dp"),
+                        out_specs=P("dp"), check_vma=False)(
         jnp.arange(8.0))
     np.testing.assert_allclose(np.asarray(out), [28.0] * 8)
 

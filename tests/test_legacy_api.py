@@ -110,7 +110,8 @@ def test_rng_state_roundtrip():
 
 def test_places_and_misc_shims():
     assert repr(paddle.CUDAPinnedPlace()) == "CUDAPinnedPlace"
-    assert paddle.XPUPlace(0).device() is not None
+    with pytest.raises(RuntimeError, match="no tpu/gpu device"):
+        paddle.XPUPlace(0).device()  # an accelerator place is never the CPU
     assert paddle.get_cudnn_version() is None
     assert not paddle.is_compiled_with_xpu()
     assert paddle.VarBase is paddle.Tensor
@@ -121,3 +122,21 @@ def test_places_and_misc_shims():
     assert p.shape == [3, 2]
     g = paddle.create_global_var([2], 1.0, "float32", persistable=True)
     assert g.persistable
+
+
+def test_print_op_prints_traced_values_at_run_time(capsys):
+    """Under jit the Print op prints VALUES through a host callback, once
+    per execution, and first_n counts those prints."""
+    import jax
+
+    @jax.jit
+    def f(v):
+        return paddle.Print(paddle.Tensor(v) * 2, message="traced",
+                            first_n=2)._value
+
+    for i in range(3):
+        f(np.full((2,), float(i), "float32")).block_until_ready()
+    jax.effects_barrier()
+    out = capsys.readouterr().out.strip().splitlines()
+    assert out == ["traced shape=(2,) dtype=float32 value=[0. 0.]",
+                   "traced shape=(2,) dtype=float32 value=[2. 2.]"]

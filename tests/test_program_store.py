@@ -10,9 +10,9 @@ The load-bearing anchors:
   tampered alias spec fails the self-check (counter + flight dump) and
   falls back to live compile; both paths still produce the store-off
   tokens.
-- **The PR 1 gate** — on XLA:CPU the store refuses without
-  `force=True`, the same `device.serialization_unsafe_backend()` gate
-  `enable_compilation_cache` uses, with one shared one-time warning.
+- **The CPU gate** — on XLA:CPU the store refuses without
+  `force=True` (`device.serialization_unsafe_backend()`), and a forced
+  store warns once per process.
 """
 import json
 import os
@@ -279,31 +279,17 @@ def test_cpu_refusal_without_force(model, tmp_path):
 
 
 def test_forced_serialization_warns_once(model, tmp_path, monkeypatch):
-    """Both force paths share ONE per-process warning naming the PR 1
-    corruption class — the policies cannot drift apart silently."""
-    import jax
+    """Every force path shares ONE per-process warning naming the
+    corruption class — an override of the CPU gate is never silent."""
     monkeypatch.setattr(pdevice, "_force_warned", False)
-    assert pdevice.enable_compilation_cache(
-        path=str(tmp_path / "cc")) is None      # unforced: gate refuses
-    try:
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            ProgramStore(str(tmp_path / "s1"), {"k": 1}, force=True)
-            ProgramStore(str(tmp_path / "s2"), {"k": 2}, force=True)
-            assert pdevice.enable_compilation_cache(
-                path=str(tmp_path / "cc"), force=True) is not None
-    finally:
-        # the forced cache is process-global jax config — turn it back
-        # off so later donated compiles in this process can't hit it
-        # (direct assignment, NOT monkeypatch: teardown would restore
-        # the forced path and leak it into later tests)
-        jax.config.update("jax_compilation_cache_dir", None)
-        pdevice._compile_cache_dir = None
+    with warnings.catch_warnings(record=True) as w:
+        warnings.simplefilter("always")
+        ProgramStore(str(tmp_path / "s1"), {"k": 1}, force=True)
+        ProgramStore(str(tmp_path / "s2"), {"k": 2}, force=True)
     msgs = [str(x.message) for x in w
             if issubclass(x.category, RuntimeWarning)
             and "corruption class" in str(x.message)]
     assert len(msgs) == 1
-    assert "PR 1" in msgs[0]
     assert ProgramStore(str(tmp_path / "s3"), {"k": 3}).refused
 
 

@@ -172,6 +172,13 @@ def test_affinity_off_is_round_robin(model):
         spread = sorted(rep.placements for rep in r._replicas)
         assert spread == [3, 3]
         assert "ROUTE_AFFINITY" not in _reasons(r)
+        # replica i lives on local device i — weights and KV pools both
+        import jax
+        for i, rep in enumerate(r._replicas):
+            eng, dev = rep.sup.engine, jax.local_devices()[i]
+            assert eng._kp.devices() == {dev}
+            assert all(leaf.devices() == {dev}
+                       for leaf in jax.tree_util.tree_leaves(eng._W))
     finally:
         r.shutdown()
 

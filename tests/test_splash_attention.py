@@ -331,10 +331,6 @@ def test_pick_blocks_reads_flags():
 # ---------------------------------------------------------------------------
 
 def test_sharded_splash_attention_parity():
-    try:
-        from jax.experimental.shard_map import shard_map  # noqa: F401
-    except Exception:
-        pytest.skip("no shard_map in this jax")
     from jax.sharding import Mesh
 
     from paddle_tpu.parallel.mesh import set_mesh

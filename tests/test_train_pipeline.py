@@ -252,39 +252,6 @@ def test_train_batch_returns_deferred_scalar():
 
 
 # ---------------------------------------------------------------------------
-# persistent compile cache gating
-# ---------------------------------------------------------------------------
-
-def test_compilation_cache_refused_on_cpu_backend(tmp_path):
-    """XLA:CPU deserialized executables lose donation aliasing (a cache
-    hit corrupts the donated step's numerics), so the persistent cache
-    must stay off on the CPU backend unless forced. Tier-1 runs with
-    JAX_PLATFORMS=cpu, so this pins the soundness of the whole suite."""
-    import jax
-    from paddle_tpu import device
-    if jax.default_backend() != "cpu":
-        pytest.skip("gate only applies to the CPU backend")
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        assert device.enable_compilation_cache(str(tmp_path)) is None
-        assert jax.config.jax_compilation_cache_dir == prev
-        # lazy path (JAX_PLATFORMS unset at import): resolving a pending
-        # decision on a CPU backend must also refuse, and only run once
-        device._cache_decision_pending = True
-        device.maybe_enable_compilation_cache()
-        assert device._cache_decision_pending is False
-        assert device.compilation_cache_dir() is None
-        assert jax.config.jax_compilation_cache_dir == prev
-        # explicit opt-in still works (user accepts the CPU risk)
-        assert device.enable_compilation_cache(
-            str(tmp_path), force=True) == str(tmp_path)
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        device._compile_cache_dir = None
-
-
-# ---------------------------------------------------------------------------
 # compile-count regression
 # ---------------------------------------------------------------------------
 
