@@ -45,12 +45,11 @@ register_flag("FLAGS_flash_attention_interpret", False,
               "test meshes; TPU semantics, interpreter speed)")
 register_flag("FLAGS_flash_attention_min_seq", 512,
               "shortest query length dispatched to the Pallas flash kernel; "
-              "below this XLA's fused dense attention wins (measured "
-              "crossover on v5e; see tools/perf_attr.py)")
+              "below this XLA's fused dense attention wins (crossover "
+              "measured on a v5e before PR 21)")
 register_flag("FLAGS_flash_block_q", 512,
               "preferred q tile for the flash/splash attention kernels "
-              "(multiple of 128; the on-chip sweep — "
-              "tools/perf_flash_sweep.py / perf_splash_sweep.py, v5e, "
+              "(multiple of 128; an on-chip sweep before PR 21 — v5e, "
               "S=2048, bf16 — picked 512). Kernels fall back to the "
               "largest of 128/256/512/this that divides the sequence")
 register_flag("FLAGS_flash_block_kv", 512,
@@ -64,8 +63,8 @@ register_flag("FLAGS_use_splash_attention", True,
 register_flag("FLAGS_splash_attention_min_seq", 512,
               "shortest packed-row length dispatched to the splash kernel; "
               "below this the dense segment-masked attention wins (same "
-              "crossover assumption as FLAGS_flash_attention_min_seq until "
-              "swept on-chip — tools/perf_splash_sweep.py)")
+              "crossover assumption as FLAGS_flash_attention_min_seq: "
+              "not swept on-chip)")
 register_flag("FLAGS_use_paged_attention", True,
               "decode-time cached attention over a paged KV cache: on the "
               "TPU backend dispatch to the Pallas paged_attention kernel "

@@ -434,9 +434,9 @@ def registered_histograms() -> Dict[str, StatHistogram]:
 def stat_time(name: str):
     """Accumulate the wall time (ns) of the enclosed block into `name`.
 
-    Used by the training hot loop (`STAT_train_step_ns`) — note that with
-    async dispatch this measures Python dispatch latency, not device
-    compute; pair with an explicit sync when device time is wanted.
+    Note that with async dispatch this measures Python dispatch
+    latency, not device compute; pair with an explicit sync when device
+    time is wanted.
     """
     t0 = time.perf_counter_ns()
     try:

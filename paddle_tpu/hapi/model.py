@@ -66,9 +66,9 @@ Training hot-loop contract (the zero-copy / async-dispatch design):
   batch is never double-masked.
 
 Monitor counters (framework/monitor.py): STAT_train_steps,
-STAT_train_step_compiles (one per input-shape key), STAT_train_step_ns
-(dispatch wall time), STAT_train_host_syncs (DeferredScalar
-materializations), STAT_sharded_carry_syncs (fleet write-backs),
+STAT_train_step_compiles (one per input-shape key),
+STAT_train_host_syncs (DeferredScalar materializations),
+STAT_sharded_carry_syncs (fleet write-backs),
 STAT_tail_pad_batches / STAT_tail_pad_compiles_avoided (tail bucketing).
 """
 from __future__ import annotations
@@ -86,15 +86,18 @@ from ..framework import random as frandom
 from ..framework.deferred import DeferredScalar, materialize_many
 from ..framework.flags import flag
 from ..framework.functional import functionalize, get_buffers, get_params
-from ..framework.monitor import STAT_ADD, STAT_SUB, stat_get, stat_time
+from ..framework.monitor import STAT_ADD, STAT_SUB, stat_get
 from ..framework.tensor import Tensor
 from ..io import DataLoader, Dataset
 from ..io.device_loader import DeviceFeeder
 from ..metric import Metric
-from ..profiler import RecordEvent, device_telemetry, flight_recorder
+from ..profiler import device_telemetry, flight_recorder, step_log
 from . import callbacks as cbks_mod
 
 __all__ = ["Model"]
+
+
+_NO_BATCH = object()     # the fit loop's end-of-epoch sentinel
 
 
 def _flatten_batch(data):
@@ -324,14 +327,19 @@ class Model:
             def fwd():
                 wrapped_in = [Tensor(x) for x in inputs]
                 wrapped_lb = [Tensor(x) for x in labels]
-                out, new_bufs = apply_fn(pv, bv, rng, True,
-                                         *[w._value for w in wrapped_in])
-                wout = jax.tree_util.tree_map(
-                    lambda x: Tensor(x), out)
-                if mask is None:
-                    lv = self._loss_value(wout, wrapped_lb)
-                else:
-                    lv = self._masked_loss(wout, wrapped_lb, mask)
+                # the step's phases as names on the device (`forward`,
+                # `loss`, `optimizer`; the backward pass reads
+                # `transpose(jvp(forward/...))` by the transform itself)
+                with jax.named_scope("forward"):
+                    out, new_bufs = apply_fn(
+                        pv, bv, rng, True, *[w._value for w in wrapped_in])
+                with jax.named_scope("loss"):
+                    wout = jax.tree_util.tree_map(
+                        lambda x: Tensor(x), out)
+                    if mask is None:
+                        lv = self._loss_value(wout, wrapped_lb)
+                    else:
+                        lv = self._masked_loss(wout, wrapped_lb, mask)
                 return lv, (out, new_bufs)
             if amp_level:
                 from .. import amp as amp_mod
@@ -345,16 +353,18 @@ class Model:
             lv_raw = lv._value if isinstance(lv, Tensor) else lv
             return jnp.mean(lv_raw.astype("float32")), aux
 
-        def step(carry, rng, step_no, lr, inputs, labels, mask=None):
+        # the name is the program's: a trace reads `jit_train_step(...)`
+        def train_step(carry, rng, step_no, lr, inputs, labels, mask=None):
             pv, bv, opt_state = (carry["params"], carry["buffers"],
                                  carry["opt_state"])
             (lv, (out, new_bufs)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(pv, bv, rng, inputs, labels, mask)
-            new_pv, new_state = opt.apply_gradients_pytree(
-                grads, pv, opt_state, lr, step_no)
+            with jax.named_scope("optimizer"):
+                new_pv, new_state = opt.apply_gradients_pytree(
+                    grads, pv, opt_state, lr, step_no)
             return {"params": new_pv, "buffers": new_bufs,
                     "opt_state": new_state}, lv, out
-        return step
+        return train_step
 
     # -- carry management ----------------------------------------------------
     def _ensure_carry(self):
@@ -563,11 +573,10 @@ class Model:
         step_no = getattr(self, "_global_step", 0) + 1
         self._global_step = step_no
         try:
-            with stat_time("STAT_train_step_ns"):
-                new_carry, lv, out = fn(
-                    carry, rng, jnp.asarray(step_no, "int32"),
-                    jnp.asarray(self._optimizer.get_lr(), "float32"),
-                    tuple(inputs), tuple(labels), mask)
+            new_carry, lv, out = fn(
+                carry, rng, jnp.asarray(step_no, "int32"),
+                jnp.asarray(self._optimizer.get_lr(), "float32"),
+                tuple(inputs), tuple(labels), mask)
         except _TailMaskError:
             # trace-time failure: the carry was never dispatched into —
             # rerun the real rows through the plain (unpadded) step. The
@@ -674,9 +683,8 @@ class Model:
         self._sharded_mask_live = loss_mask is not None
         state = self._sharded_state
         try:
-            with stat_time("STAT_train_step_ns"):
-                new_state, lv = self._sharded_step(
-                    state, tuple(ins), tuple(lbs))
+            new_state, lv = self._sharded_step(
+                state, tuple(ins), tuple(lbs))
         except _TailMaskError:
             if self._is_token_mask(loss_mask):
                 raise  # packing: no unpadded shape to fall back to
@@ -715,10 +723,11 @@ class Model:
         if fn is None:
             apply_fn = self._apply_fn
 
-            def estep(pv_, bv_, rng, ins, lbs, mask_=None):
+            def eval_step(pv_, bv_, rng, ins, lbs, mask_=None):
                 from ..framework.autograd import trace_mode
-                out, _ = apply_fn(pv_, bv_, rng, False, *ins)
-                with trace_mode():
+                with jax.named_scope("forward"):
+                    out, _ = apply_fn(pv_, bv_, rng, False, *ins)
+                with trace_mode(), jax.named_scope("loss"):
                     wout = jax.tree_util.tree_map(lambda x: Tensor(x), out)
                     if self._loss is not None and lbs:
                         wlbs = [Tensor(x) for x in lbs]
@@ -731,7 +740,7 @@ class Model:
                           if isinstance(lv, Tensor) else
                           (lv if lv is not None else jnp.zeros(())))
                 return lv_raw, out
-            fn = jax.jit(estep)
+            fn = jax.jit(eval_step)
             self._eval_step_cache[key] = fn
         rng = frandom.get_rng_key()
         try:
@@ -922,6 +931,7 @@ class Model:
         step_count = 0
         logs = {}  # stays bound for on_end even with epochs=0
         feed = self._buffered(loader)
+        clock = step_log.FitClock()
         self._in_fit = True  # keep the carry live; write back at epoch ends
         flight_recorder.touch()  # periodic counter snapshots while training
         device_telemetry.touch()  # HBM/compile/MFU gauges while training
@@ -945,26 +955,37 @@ class Model:
                 # must stay OFF (no row padding, no double-masking).
                 token_masked = self._token_masked(loader)
                 pad_to = None if token_masked else self._tail_target(loader)
-                for step, batch in enumerate(feed):
-                    cbks.on_batch_begin("train", step, logs)
-                    ins, lbs = self._split_batch(batch)
-                    mask, nreal = None, None
-                    if token_masked:
-                        lbs, mask = self._pop_token_mask(lbs)
-                    elif pad_to and self._tail_maskable:
-                        # _tail_maskable re-checked per batch: a
-                        # mid-epoch fallback stops the masked attempts
-                        ins, lbs, mask, nreal = self._pad_tail(
-                            ins, lbs, pad_to)
+                # the step record (profiler/step_log.py FitRecord): each
+                # stretch of the loop goes to a bucket, the waits under a
+                # span on the profiler's clock, and `close` writes one
+                # record a step whose buckets tile its wall exactly
+                clock.restart()
+                batches, step = iter(feed), -1
+                while True:
+                    with clock.span("input_wait_ms", "fit::input_wait"):
+                        batch = next(batches, _NO_BATCH)
+                    if batch is _NO_BATCH:
+                        break
+                    step += 1
+                    with clock.span("callback_ms", "fit::callbacks"):
+                        cbks.on_batch_begin("train", step, logs)
+                    with clock.span("prep_ms"):
+                        ins, lbs = self._split_batch(batch)
+                        mask, nreal = None, None
+                        if token_masked:
+                            lbs, mask = self._pop_token_mask(lbs)
+                        elif pad_to and self._tail_maskable:
+                            # _tail_maskable re-checked per batch: a
+                            # mid-epoch fallback stops the masked attempts
+                            ins, lbs, mask, nreal = self._pad_tail(
+                                ins, lbs, pad_to)
                     padded = not token_masked and mask is not None and \
                         nreal is not None and nreal < len(mask)
                     c0 = (stat_get("STAT_train_step_compiles") if padded
                           else 0)
-                    # the fit loop's own track in the chrome trace: step
-                    # scopes on the main thread next to the feeder/lane
-                    # threads (dispatch wall time; device time is in the
-                    # jax.profiler trace)
-                    with RecordEvent("fit::train_step"):
+                    # argument preparation and the launch (either step
+                    # path); device time is in the jax.profiler trace
+                    with clock.span("dispatch_ms", "fit::train_step"):
                         loss, metrics = self.train_batch(ins, lbs,
                                                          loss_mask=mask)
                     if padded and self._dist_ctx is None and \
@@ -977,10 +998,12 @@ class Model:
                         STAT_ADD("STAT_tail_pad_compiles_avoided")
                     lv = loss[0] if isinstance(loss, (list, tuple)) else loss
                     # deferred host sync: the loss stays a device handle
-                    # except on the log cadence (one sync per log_freq)
+                    # except on the log cadence (one sync per log_freq),
+                    # where the loop waits for the chip
                     if log_freq and step % log_freq == 0 and \
                             isinstance(lv, DeferredScalar):
-                        lv = float(lv)
+                        with clock.span("sync_ms", "fit::sync"):
+                            lv = float(lv)
                     logs = {"loss": lv, "step": step, "batch_size":
                             nreal if nreal is not None else
                             (ins[0].shape[0] if hasattr(ins[0], "shape")
@@ -991,11 +1014,14 @@ class Model:
                         vals = r if isinstance(r, list) else [r]
                         for n, v in zip(names, vals):
                             logs[n] = v
-                    cbks.on_batch_end("train", step, logs)
+                    with clock.span("callback_ms", "fit::callbacks"):
+                        cbks.on_batch_end("train", step, logs)
+                    clock.close(step)
                     step_count += 1
                     if num_iters is not None and step_count >= num_iters:
                         self.stop_training = True
                         break
+                del batches  # a feeder cut short stops its thread now
                 # epoch boundary: params/opt state back into Tensors, loss
                 # to a host float (callbacks may checkpoint / early-stop).
                 # validate: an async step failure from the un-synced tail

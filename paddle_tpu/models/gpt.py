@@ -53,8 +53,11 @@ def _gen_w(w, dtype):
 
 def gpt_logits(W, h):
     """Final LN + tied LM head over hidden states `h` [..., E]."""
+    import jax
+
     lnfw, lnfb = W["lnf"]
-    return _gen_ln(h, lnfw, lnfb) @ W["wte"].T
+    with jax.named_scope("lm_head"):
+        return _gen_ln(h, lnfw, lnfb) @ W["wte"].T
 
 
 def _gen_block_pass(W, h, attend, *, num_heads, reduce=None):
@@ -82,27 +85,31 @@ def _gen_block_pass(W, h, attend, *, num_heads, reduce=None):
     ks, vs = [], []
     for i, (l1w, l1b, wq, bq, wk, bk, wv, bv, wo, bo, l2w, l2b,
             w1, b1, w2, b2) in enumerate(W["blocks"]):
-        x = _gen_ln(h, l1w, l1b)
-
         def heads(t):
             return t.reshape(B, S, H, -1).transpose(0, 2, 1, 3)
-        q = heads(x @ _gen_w(wq, x.dtype) + bq)
-        k = heads(x @ _gen_w(wk, x.dtype) + bk)
-        v = heads(x @ _gen_w(wv, x.dtype) + bv)
-        ks.append(k)
-        vs.append(v)
-        o = attend(i, q, k, v)
-        o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
-        ow = o @ _gen_w(wo, h.dtype)
-        if reduce is not None:
-            ow = reduce(ow)
-        h = h + (ow + bo)
-        x2 = _gen_ln(h, l2w, l2b)
-        mw = jax.nn.gelu(x2 @ _gen_w(w1, h.dtype) + b1,
-                         approximate=False) @ _gen_w(w2, h.dtype)
-        if reduce is not None:
-            mw = reduce(mw)
-        h = h + (mw + b2)
+        # scopes are names on the device (`layer_3/attn/...` in a
+        # profiler trace), nothing else: tools/trace_report.py groups by
+        # them
+        with jax.named_scope(f"layer_{i}/attn"):
+            x = _gen_ln(h, l1w, l1b)
+            q = heads(x @ _gen_w(wq, x.dtype) + bq)
+            k = heads(x @ _gen_w(wk, x.dtype) + bk)
+            v = heads(x @ _gen_w(wv, x.dtype) + bv)
+            ks.append(k)
+            vs.append(v)
+            o = attend(i, q, k, v)
+            o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
+            ow = o @ _gen_w(wo, h.dtype)
+            if reduce is not None:
+                ow = reduce(ow)
+            h = h + (ow + bo)
+        with jax.named_scope(f"layer_{i}/mlp"):
+            x2 = _gen_ln(h, l2w, l2b)
+            mw = jax.nn.gelu(x2 @ _gen_w(w1, h.dtype) + b1,
+                             approximate=False) @ _gen_w(w2, h.dtype)
+            if reduce is not None:
+                mw = reduce(mw)
+            h = h + (mw + b2)
     return h, jnp.stack(ks), jnp.stack(vs)
 
 
@@ -118,7 +125,8 @@ def gpt_prefill(W, ids, *, num_heads, scale, reduce=None):
     import jax
 
     _, S = ids.shape
-    h = W["wte"][ids] + W["wpe"][jnp.arange(S)][None]
+    with jax.named_scope("embed"):
+        h = W["wte"][ids] + W["wpe"][jnp.arange(S)][None]
 
     def attend(layer, q, k, v):
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
@@ -150,8 +158,11 @@ def gpt_prefill_extend(W, ids, positions, ctx_attend, *, num_heads,
     [L,B,H,S_t,D] per-layer tail K/V for the caller's cache writes) —
     both flavors share `_gen_block_pass`, so the block math literally
     cannot diverge from the full-prefill oracle."""
+    import jax
+
     del scale  # the ctx_attend hook owns the scale (kept for symmetry)
-    h = W["wte"][ids] + W["wpe"][positions][None]
+    with jax.named_scope("embed"):
+        h = W["wte"][ids] + W["wpe"][positions][None]
     return _gen_block_pass(W, h, ctx_attend, num_heads=num_heads,
                            reduce=reduce)
 
@@ -176,7 +187,10 @@ def gpt_spec_verify(W, toks, positions, ctx_attend, *, num_heads,
     caller's — acceptance-masked — cache writes). Sharing
     `_gen_block_pass` is what anchors verification to the decode-step
     oracle: the block math literally cannot diverge."""
-    h = W["wte"][toks] + W["wpe"][positions]
+    import jax
+
+    with jax.named_scope("embed"):
+        h = W["wte"][toks] + W["wpe"][positions]
     return _gen_block_pass(W, h, ctx_attend, num_heads=num_heads,
                            reduce=reduce)
 
@@ -199,25 +213,28 @@ def gpt_decode_step(W, tok, pos, cache, write_kv, attend, *, num_heads,
 
     B = tok.shape[0]
     H = num_heads
-    h = W["wte"][tok] + W["wpe"][pos]
+    with jax.named_scope("embed"):
+        h = W["wte"][tok] + W["wpe"][pos]
     for i, (l1w, l1b, wq, bq, wk, bk, wv, bv, wo, bo, l2w, l2b,
             w1, b1, w2, b2) in enumerate(W["blocks"]):
-        x = _gen_ln(h, l1w, l1b)
-        q = (x @ _gen_w(wq, x.dtype) + bq).reshape(B, H, -1)
-        k = (x @ _gen_w(wk, x.dtype) + bk).reshape(B, H, -1)
-        v = (x @ _gen_w(wv, x.dtype) + bv).reshape(B, H, -1)
-        cache = write_kv(cache, i, k, v, pos)
-        o = attend(cache, i, q, pos).reshape(B, -1)
-        ow = o @ _gen_w(wo, h.dtype)
-        if reduce is not None:
-            ow = reduce(ow)
-        h = h + (ow + bo)
-        x2 = _gen_ln(h, l2w, l2b)
-        mw = jax.nn.gelu(x2 @ _gen_w(w1, h.dtype) + b1,
-                         approximate=False) @ _gen_w(w2, h.dtype)
-        if reduce is not None:
-            mw = reduce(mw)
-        h = h + (mw + b2)
+        with jax.named_scope(f"layer_{i}/attn"):
+            x = _gen_ln(h, l1w, l1b)
+            q = (x @ _gen_w(wq, x.dtype) + bq).reshape(B, H, -1)
+            k = (x @ _gen_w(wk, x.dtype) + bk).reshape(B, H, -1)
+            v = (x @ _gen_w(wv, x.dtype) + bv).reshape(B, H, -1)
+            cache = write_kv(cache, i, k, v, pos)
+            o = attend(cache, i, q, pos).reshape(B, -1)
+            ow = o @ _gen_w(wo, h.dtype)
+            if reduce is not None:
+                ow = reduce(ow)
+            h = h + (ow + bo)
+        with jax.named_scope(f"layer_{i}/mlp"):
+            x2 = _gen_ln(h, l2w, l2b)
+            mw = jax.nn.gelu(x2 @ _gen_w(w1, h.dtype) + b1,
+                             approximate=False) @ _gen_w(w2, h.dtype)
+            if reduce is not None:
+                mw = reduce(mw)
+            h = h + (mw + b2)
     return gpt_logits(W, h), cache
 
 

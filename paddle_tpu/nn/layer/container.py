@@ -39,6 +39,8 @@ class Sequential(Layer):
 
 
 class LayerList(Layer):
+    _scope_passthrough = True
+
     def __init__(self, sublayers=None):
         super().__init__()
         if sublayers is not None:
@@ -53,7 +55,7 @@ class LayerList(Layer):
         return self._sub_layers[str(idx)]
 
     def __setitem__(self, idx, layer):
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -70,7 +72,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, layers):
         for layer in layers:
@@ -100,6 +102,8 @@ class ParameterList(Layer):
 
 
 class LayerDict(Layer):
+    _scope_passthrough = True
+
     def __init__(self, sublayers=None):
         super().__init__()
         if sublayers is not None:

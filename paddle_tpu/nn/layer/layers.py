@@ -11,6 +11,7 @@ from __future__ import annotations
 import collections
 from typing import Callable, Iterator, Optional, Tuple
 
+import jax
 import numpy as np
 
 from ...framework.dtype import to_jax_dtype
@@ -34,6 +35,10 @@ class HookRemoveHelper:
 
 
 class Layer:
+    # a container that is iterated and never called (LayerList, LayerDict)
+    # passes its own name on to its items: `layers/3`
+    _scope_passthrough = False
+
     def __init__(self, name_scope: Optional[str] = None, dtype="float32"):
         self.training = True
         self._dtype = dtype
@@ -69,7 +74,24 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[str(name)] = sublayer
+        self._name_sublayer(str(name), sublayer)
         return sublayer
+
+    def _name_sublayer(self, name, sub):
+        """Remember the name under which this layer holds `sub`: the
+        sublayer's `forward` runs under `jax.named_scope` of it, so an
+        operation's name stack on the device reads
+        `ErnieForPretraining/ernie/encoder/layers/3/self_attn/...`
+        (`__call__`). A layer held twice answers to the later name."""
+        if sub is None:
+            return
+        own = self.__dict__.get("_scope_name")
+        if self._scope_passthrough and own:
+            name = f"{own}/{name}"
+        object.__setattr__(sub, "_scope_name", name)
+        if sub._scope_passthrough:
+            for key, item in sub._sub_layers.items():
+                sub._name_sublayer(key, item)
 
     def register_buffer(self, name, tensor, persistable=True):
         if tensor is not None and not isinstance(tensor, Tensor):
@@ -97,6 +119,7 @@ class Layer:
             if layers is None:
                 raise RuntimeError("call Layer.__init__ first")
             layers[name] = value
+            self._name_sublayer(name, value)
             for d in (params, buffers):
                 if d is not None:
                     d.pop(name, None)
@@ -284,7 +307,11 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        outputs = self.forward(*inputs, **kwargs)
+        # a name on the device, nothing else: the parent's name for this
+        # layer, or the class name at the root (`_name_sublayer`)
+        with jax.named_scope(self.__dict__.get("_scope_name")
+                             or type(self).__name__):
+            outputs = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             result = hook(self, inputs, outputs)
             if result is not None:

@@ -46,6 +46,14 @@ __all__ = ["cached_attention", "paged_attention", "paged_gather",
            "sharded_paged_attention"]
 
 
+# Every function below that touches the pools runs under a named scope of
+# its own — `kv_write`, `kv_gather`, `kv_attend`, `paged_attn_kernel` —
+# so a profiler trace tells the pools' relay (writes, gathers and the
+# copies XLA makes for them) from the attention arithmetic
+# (tools/trace_report.py). Names are metadata: the programs are the same.
+
+
+@jax.named_scope("kv_attend")
 def cached_attention(q, kb, vb, pos, scale):
     """Masked attention of one-position queries over a dense cache.
 
@@ -60,6 +68,7 @@ def cached_attention(q, kb, vb, pos, scale):
     return jnp.einsum("bht,bhtd->bhd", p, vb)
 
 
+@jax.named_scope("kv_gather")
 def paged_gather(pages, page_table):
     """Materialize page-table rows as a dense cache view.
 
@@ -92,6 +101,7 @@ def page_rows_for_positions(page_table, positions, page_size):
             positions % page_size)
 
 
+@jax.named_scope("kv_write")
 def paged_write(pages, layer, page_ids, offsets, values):
     """Scatter per-row K/V vectors into one layer of a paged pool.
 
@@ -127,6 +137,7 @@ def _q8(v, s):
     return jnp.clip(jnp.round(q), -127, 127).astype(jnp.int8)
 
 
+@jax.named_scope("kv_gather")
 def paged_gather_quantized(pages, scales, page_table, dtype=jnp.float32):
     """Dequantizing gather: int8 pages [H, N, P, D] + scales [H, N] →
     dense floating [B, H, PP*P, D] (only THIS batch's pages are ever
@@ -140,6 +151,7 @@ def paged_gather_quantized(pages, scales, page_table, dtype=jnp.float32):
     return jnp.moveaxis(kb, 1, 0).reshape(B, H, PP * P, D)
 
 
+@jax.named_scope("kv_write")
 def paged_write_quantized(pages, scales, layer, page_ids, offsets, values,
                           requant=False):
     """Quantize-on-append into int8 pools; returns (pages, scales).
@@ -280,7 +292,9 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
         # DEFAULT is what the kernel was written against and what the
         # repo's own flash kernels ask for: MXU passes on the stored
         # dtype, f32 accumulation.
-        with jax.default_matmul_precision("default"):
+        # JAX's own pallas_call: its name is not ours to give, the scope is
+        with jax.default_matmul_precision("default"), \
+                jax.named_scope("paged_attn_kernel"):
             return _kernel(
                 q * scale, k_pages, v_pages,
                 lengths=(pos + 1).astype(jnp.int32),
@@ -327,6 +341,7 @@ def sharded_paged_attention(mesh, scale, tp_axis="tp", quantized=False):
                                  out_specs=hs, check_vma=False))
 
 
+@jax.named_scope("kv_gather")
 def paged_gather_layers(pages, page_table, scales=None,
                         dtype=jnp.float32):
     """Materialize ONE sequence's page-table row as a dense view across
@@ -345,6 +360,7 @@ def paged_gather_layers(pages, page_table, scales=None,
     return kb.reshape(L, H, PP * P, D)
 
 
+@jax.named_scope("kv_attend")
 def paged_prefix_attention(q, kb, vb, k_tail, v_tail, prefix_len, scale):
     """Tail-prefill attention: multi-position queries over a cached
     prefix (pre-gathered from pages) plus the tail's own in-flight K/V.

@@ -50,10 +50,10 @@ def vmem_resident_ok(seq, head_dim, itemsize):
 
 def _block_pref(flag_name):
     """Preferred tile size from a FLAGS_flash_block_* flag. The defaults
-    (512/512) are the on-chip sweep result (tools/perf_flash_sweep.py,
-    v5e, S=2048, bf16); with native-dtype MXU dots the GPT seq-2048
-    bench runs 1.47x dense (bench.py). The splash path rides the same
-    flags (tools/perf_splash_sweep.py re-runs the sweep for it)."""
+    (512/512) are from an on-chip sweep on an earlier machine (v5e,
+    S=2048, bf16; the sweep script is gone — a profiler trace read with
+    tools/trace_report.py shows `flash_fwd` / `flash_bwd_dq` /
+    `flash_bwd_dkv` by name). The splash path rides the same flags."""
     from ..framework.flags import flag
     # lint: allow(flag-in-trace): this IS the sanctioned snapshot point — flash/splash_attention_raw reads the tile flags once per outer trace and threads them through the custom_vjp as static args, so fwd and bwd can never desync (the PR 6 contract)
     pref = int(flag(flag_name))
@@ -364,6 +364,7 @@ def _flash_call(q, k, v, bias, seed, causal, scale, dropout_p,
             jax.ShapeDtypeStruct((B * H, Sq, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(seed_arr, qr, kr, vr, bias3)
     return out.reshape(B, H, Sq, D), lse
 
@@ -401,6 +402,7 @@ def _flash_bwd_call(q, k, v, bias, seed, out, lse, g, causal, scale,
         out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(seed_arr, qr, kr, vr, bias3, gr, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -426,6 +428,7 @@ def _flash_bwd_call(q, k, v, bias, seed, out, lse, g, causal, scale,
             jax.ShapeDtypeStruct((B * H, Sk, D), q.dtype),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(seed_arr, qr, kr, vr, bias3, gr, lse, delta)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
             dv.reshape(B, H, Sk, D))

@@ -34,8 +34,8 @@ Design:
     the O(S·D) recompute backward are pallas_ops' — see its docstring.
 
 Tile sizes ride the same FLAGS_flash_block_q / FLAGS_flash_block_kv knobs
-as the flash kernel (tools/perf_splash_sweep.py re-runs the sweep for
-this path; the prior 512/512 flash result is the default).
+as the flash kernel (the prior 512/512 flash result is the default; not
+swept for this path).
 """
 from __future__ import annotations
 
@@ -347,6 +347,7 @@ def _splash_call(q, k, v, q_seg, kv_seg, seed, causal, scale, dropout_p,
             jax.ShapeDtypeStruct((B * H, Sq, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="splash_fwd",
     )(seed_arr, kv_lo, kv_hi, qr, kr, vr, qs3, ks3)
     return out.reshape(B, H, Sq, D), lse
 
@@ -386,6 +387,7 @@ def _splash_bwd_call(q, k, v, q_seg, kv_seg, seed, out, lse, g, causal,
         out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         interpret=_interpret(),
+        name="splash_bwd_dq",
     )(seed_arr, kv_lo, kv_hi, qr, kr, vr, qs_col, ks3, gr, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -414,6 +416,7 @@ def _splash_bwd_call(q, k, v, q_seg, kv_seg, seed, out, lse, g, causal,
             jax.ShapeDtypeStruct((B * H, Sk, D), q.dtype),
         ],
         interpret=_interpret(),
+        name="splash_bwd_dkv",
     )(seed_arr, q_lo, q_hi, qr, kr, vr, qs_col, ks3, gr, lse, delta)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
             dv.reshape(B, H, Sk, D))

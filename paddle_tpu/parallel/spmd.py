@@ -228,15 +228,17 @@ def make_sharded_train_step(layer, optimizer, loss_fn: Callable,
 
     def loss_of(pv_, bv_, rng, inputs, labels):
         from ..framework.autograd import trace_mode
-        out, new_bufs = fwd(pv_, bv_, rng, True, *inputs)
-        with trace_mode():
+        # the same phase names as the one-device step (hapi/model.py)
+        with jax.named_scope("forward"):
+            out, new_bufs = fwd(pv_, bv_, rng, True, *inputs)
+        with trace_mode(), jax.named_scope("loss"):
             wout = jax.tree_util.tree_map(lambda x: Tensor(x), out)
             wlab = [Tensor(x) for x in labels]
             lv = loss_fn(wout, wlab)
         lv_raw = lv._value if isinstance(lv, Tensor) else lv
         return jnp.mean(lv_raw.astype("float32")), new_bufs
 
-    def step_fn(state, inputs, labels, lr, rng):
+    def train_step(state, inputs, labels, lr, rng):
         pv_, bv_, opt_state_, step_no = (state["params"], state["buffers"],
                                          state["opt_state"],
                                          state["step_no"])
@@ -257,8 +259,9 @@ def make_sharded_train_step(layer, optimizer, loss_fn: Callable,
             gd = to_jax_dtype(grad_dtype)
             grads = jax.tree_util.tree_map(
                 lambda g, p: g.astype(gd).astype(p.dtype), grads, pv_)
-        new_pv, new_opt = optimizer.apply_gradients_pytree(
-            grads, pv_, opt_state_, lr, step_no + 1)
+        with jax.named_scope("optimizer"):
+            new_pv, new_opt = optimizer.apply_gradients_pytree(
+                grads, pv_, opt_state_, lr, step_no + 1)
         new_state = {"params": new_pv, "buffers": new_bufs,
                      "opt_state": new_opt, "step_no": step_no + 1}
         if new_dgc is not None:
@@ -278,7 +281,7 @@ def make_sharded_train_step(layer, optimizer, loss_fn: Callable,
             lambda v, s: jax.device_put(v, s), dgc_state, dgc_shard)
         state_sharding["dgc"] = dgc_shard
     jit_step = jax.jit(
-        step_fn,
+        train_step,
         out_shardings=(state_sharding, repl),
         donate_argnums=(0,) if donate else ())
 

@@ -182,8 +182,11 @@ def profiler(state="All", tracer_option="Default", profile_path=None,
 
 
 class Profiler:
-    """paddle.profiler.Profiler 2.x-style wrapper; on TPU also drives
-    jax.profiler for a device trace directory consumable by TensorBoard.
+    """paddle.profiler.Profiler 2.x-style wrapper; with `log_dir` it also
+    drives jax.profiler for a device trace: trace with
+    `Profiler(log_dir=d)`, read with `python tools/trace_report.py d`
+    (device time per program and per named scope, idle gaps by the
+    program's spans).
 
     `step()` is a real step marker: it closes a `ProfilerStep#N` scope on
     the calling thread and snapshots the monitor counters, so the chrome
@@ -197,28 +200,34 @@ class Profiler:
         self._step_t0 = None
 
     def start(self):
+        if self.log_dir:
+            # a device trace that cannot start is an error, not a quiet
+            # run without one. The Python tracer is off: it hooks every
+            # call on every thread and would slow the very host code
+            # whose gaps the trace is read for (the benchmark's reason)
+            import jax.profiler
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.log_dir,
+                                     profiler_options=options)
+            self._jax_started = True
         start_profiler()
         self._step_n = 0
         self._step_t0 = time.perf_counter()
-        if self.log_dir:
-            try:
-                import jax.profiler
-                jax.profiler.start_trace(self.log_dir)
-                self._jax_started = True
-            except Exception:
-                pass
         return self
 
     def stop(self):
-        if self._jax_started:
+        try:
+            self.step()  # close the open ProfilerStep scope
+        finally:
+            self._step_t0 = None
             try:
-                import jax.profiler
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-        self.step()  # close the open ProfilerStep scope
-        self._step_t0 = None
-        stop_profiler()
+                if self._jax_started:
+                    self._jax_started = False
+                    import jax.profiler
+                    jax.profiler.stop_trace()
+            finally:
+                stop_profiler()
 
     def __enter__(self):
         return self.start()

@@ -52,6 +52,40 @@ oldest overwritten — the same bounding discipline as the trace rings):
                   step thread's mark-to-mark wall EXACTLY (bookkeeping
                   is the remainder of the rounded siblings), feeding
                   the STAT_gen_step_attr_* histogram family
+    decode_wait_ms / prefill_wait_ms
+                  the part of decode_ms / prefill_ms the step thread
+                  spent blocked in the read-back (`np.asarray` of the
+                  program's host outputs) — waiting for the chip; the
+                  rest is launch and argument upload. SUB-SPLITS (ISSUE
+                  25): the six buckets above are unchanged and these
+                  two are never added to them
+    admit_wait_ms sum, over the requests admitted THIS iteration, of
+                  admitted − queued (GenSpan stamps): with `admitted`
+                  the mean wait from submission to a slot and pages
+
+The fit loop has a record of its own (`FitRecord`, ISSUE 25): one per
+train step into ONE process-wide `FitLog` ring of the same kind, read
+through `fit_records()`:
+
+    fit           ordinal of the `Model.fit` call in the process
+    step          step ordinal inside its epoch
+    t             perf_counter at the record
+    input_wait_ms blocked taking the next batch from the feeder or the
+                  loader (span `fit::input_wait`)
+    prep_ms       batch split, tail padding, masks
+    dispatch_ms   the `train_batch` call: argument preparation and the
+                  launch (span `fit::train_step`). On the v5e the jitted
+                  call returns only when the step BEFORE has finished (its
+                  donated carry is that step's output), so this bucket
+                  holds the wait for the chip on every step but the one
+                  after a sync (PERF.md, PR 25: 183 of 190 ms)
+    sync_ms       blocked in `float(loss)` on the log cadence: waiting
+                  for the chip (span `fit::sync`)
+    callback_ms   on_batch_begin + on_batch_end (span `fit::callbacks`)
+    other_ms      the remainder of the ROUNDED siblings, so the six
+                  buckets sum to wall_ms EXACTLY (the engine's rule)
+    wall_ms       mark to mark: the end of the previous step's record
+                  (or the epoch's start) to the end of this one
 
 The ring is exported three ways: `/steps` JSON
 (`steps_payload()` — per-engine records + audit-log tail, the input of
@@ -70,14 +104,17 @@ Everything is gated by `FLAGS_gen_step_log` (default on; `bench.py
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, List, Optional
 
 from ..framework import monitor
 from ..framework.flags import flag
+from . import RecordEvent
 from ._engine_registry import EngineRegistry
 
-__all__ = ["StepRecord", "StepLog", "enabled", "register", "unregister",
-           "steps_payload", "chrome_counter_events"]
+__all__ = ["StepRecord", "StepLog", "FitRecord", "FitLog", "FitClock",
+           "enabled", "register", "unregister", "steps_payload",
+           "fit_log", "fit_records", "chrome_counter_events"]
 
 _FIELDS = ("it", "step", "t", "live", "admitted", "completed", "expired",
            "poisoned", "aborted", "freed", "queue_depth", "oldest_age_ms",
@@ -107,24 +144,76 @@ _FIELDS = ("it", "step", "t", "live", "admitted", "completed", "expired",
            # marks a record from before this era (or the abort-path
            # flush record, which never owned a full iteration)
            "attr_admit_ms", "attr_promote_ms", "attr_bookkeep_ms",
-           "attr_idle_ms", "attr_wall_ms")
+           "attr_idle_ms", "attr_wall_ms",
+           # ISSUE 25: launch against wait inside decode_ms / prefill_ms
+           # (sub-splits: the six buckets and their exact sum are as
+           # they were), and how long this iteration's admissions had
+           # queued — appended, by the same era rule
+           "decode_wait_ms", "prefill_wait_ms", "admit_wait_ms")
+
+_FIT_FIELDS = ("fit", "step", "t", "input_wait_ms", "prep_ms",
+               "dispatch_ms", "sync_ms", "callback_ms", "other_ms",
+               "wall_ms")
+_FIT_BUCKETS = _FIT_FIELDS[3:8]     # measured; other_ms is the remainder
+_FIT_RING = 4096                    # records; the engine ring's default
 
 
 def enabled() -> bool:
     return bool(flag("FLAGS_gen_step_log"))
 
 
-class StepRecord:
-    """One engine iteration's scheduler state (compact: slots only)."""
+class _Record:
+    """Compact record: slots only, absent fields read 0."""
 
-    __slots__ = _FIELDS
+    __slots__ = ()
 
     def __init__(self, **kw):
-        for f in _FIELDS:
+        for f in self.__slots__:
             setattr(self, f, kw.get(f, 0))
 
     def to_dict(self) -> dict:
-        return {f: getattr(self, f) for f in _FIELDS}
+        return {f: getattr(self, f) for f in self.__slots__}
+
+
+class StepRecord(_Record):
+    """One engine iteration's scheduler state."""
+
+    __slots__ = _FIELDS
+
+
+class FitRecord(_Record):
+    """One train step of a fit loop: where its wall went on the host."""
+
+    __slots__ = _FIT_FIELDS
+
+
+class _Ring:
+    """Bounded record ring, oldest overwritten. `snapshot()` is a
+    GIL-consistent copy in the order of `key` (a record's own monotone
+    counter): the writer can append between the copy and a read of its
+    index, which would rotate the true oldest record to the newest
+    position, so the rotation point is found in the copy itself."""
+
+    def __init__(self, capacity: int):
+        self.cap = max(1, int(capacity))
+        self._buf: list = []
+        self._idx = 0           # oldest slot once full
+        self.recorded = 0       # total records ever appended
+
+    def _append(self, rec) -> None:
+        if len(self._buf) < self.cap:
+            self._buf.append(rec)
+        else:
+            self._buf[self._idx] = rec
+            self._idx = (self._idx + 1) % self.cap
+        self.recorded += 1
+
+    def _snapshot(self, key) -> list:
+        buf = list(self._buf)   # one GIL-atomic copy — consistent
+        if len(buf) < self.cap:
+            return buf
+        lo = min(range(len(buf)), key=lambda i: key(buf[i]))
+        return buf[lo:] + buf[:lo] if lo else buf
 
 
 _hists_lock = threading.Lock()
@@ -159,17 +248,14 @@ def _step_attr_hists():
     return _attr_hists
 
 
-class StepLog:
+class StepLog(_Ring):
     """One engine's bounded step ring. The owning step thread is the
     only writer; `snapshot()`/`tail()` are GIL-consistent copies."""
 
     def __init__(self, engine: str, capacity: Optional[int] = None):
+        super().__init__(flag("FLAGS_gen_step_log_size")
+                         if capacity is None else capacity)
         self.engine = engine
-        self.cap = max(1, int(flag("FLAGS_gen_step_log_size")
-                              if capacity is None else capacity))
-        self._buf: List[StepRecord] = []
-        self._idx = 0           # oldest slot once full
-        self.recorded = 0       # total records ever appended
         register(self)
 
     def record(self, rec: StepRecord) -> None:
@@ -190,28 +276,102 @@ class StepLog:
                              rec.attr_promote_ms, rec.decode_ms,
                              rec.attr_bookkeep_ms, rec.attr_idle_ms)):
                 h.observe(max(0.0, v))
-        if len(self._buf) < self.cap:
-            self._buf.append(rec)
-        else:
-            self._buf[self._idx] = rec
-            self._idx = (self._idx + 1) % self.cap
-        self.recorded += 1
+        self._append(rec)
 
     def snapshot(self) -> List[StepRecord]:
-        buf = list(self._buf)   # one GIL-atomic copy — consistent
-        if len(buf) < self.cap:
-            return buf
-        # _idx may be stale relative to the copy (the step thread can
-        # record() between the copy and the read), which would rotate
-        # the true oldest record to the newest position — rotate on the
-        # records' own monotone iteration counter instead
-        lo = min(range(len(buf)), key=lambda i: buf[i].it)
-        return buf[lo:] + buf[:lo] if lo else buf
+        return self._snapshot(lambda r: r.it)
 
     def tail(self, n: int) -> List[dict]:
         """Last `n` records as dicts, oldest-first (flight dumps,
         `/steps`)."""
         return [r.to_dict() for r in self.snapshot()[-max(0, int(n)):]]
+
+
+# -- the fit loop's ring (ISSUE 25) ------------------------------------------
+
+class FitLog(_Ring):
+    """The process's bounded ring of train-step records. Fit loops on
+    several threads may share it, so an append takes a lock (uncontended
+    in the one-loop case: tens of nanoseconds against a step)."""
+
+    def __init__(self, capacity: int = _FIT_RING):
+        super().__init__(capacity)
+        self._lock = threading.Lock()
+        self._fits = 0
+
+    def begin_fit(self) -> int:
+        """Ordinal of a starting `fit()` call (1-based)."""
+        with self._lock:
+            self._fits += 1
+            return self._fits
+
+    def record(self, rec: FitRecord) -> None:
+        with self._lock:
+            self._append(rec)
+
+    def snapshot(self) -> List[FitRecord]:
+        return self._snapshot(lambda r: r.t)
+
+
+fit_log = FitLog()
+
+
+def fit_records() -> List[dict]:
+    """The fit ring's retained records as dicts, oldest first."""
+    return [r.to_dict() for r in fit_log.snapshot()]
+
+
+class _FitSpan:
+    """`with clock.span(bucket, name)`: the block's wall goes to
+    `bucket`, under a RecordEvent `name` when one is given (the same
+    stretch on the profiler's clock)."""
+
+    __slots__ = ("_acc", "_bucket", "_ev", "_t0")
+
+    def __init__(self, acc, bucket, name):
+        self._acc, self._bucket = acc, bucket
+        self._ev = None if name is None else RecordEvent(name)
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        if self._ev is not None:
+            self._ev.begin()
+
+    def __exit__(self, *exc):
+        if self._ev is not None:
+            self._ev.end()
+        self._acc[self._bucket] += time.perf_counter() - self._t0
+        return False
+
+
+class FitClock:
+    """One fit loop's marks. `span()` charges a stretch to a bucket,
+    `close(step)` writes the step's record: wall is mark to mark, every
+    stored value is rounded first and `other_ms` is the remainder of the
+    rounded siblings, so a record's buckets sum to its wall_ms exactly.
+    `restart()` moves the mark without a record (an epoch's start)."""
+
+    def __init__(self, log: FitLog = fit_log):
+        self._log = log
+        self.fit = log.begin_fit()
+        self.restart()
+
+    def restart(self) -> None:
+        self._acc = dict.fromkeys(_FIT_BUCKETS, 0.0)
+        self._mark = time.perf_counter()
+
+    def span(self, bucket: str, name: Optional[str] = None) -> _FitSpan:
+        return _FitSpan(self._acc, bucket, name)
+
+    def close(self, step: int) -> None:
+        now = time.perf_counter()
+        parts = {k: round(v * 1000.0, 3) for k, v in self._acc.items()}
+        wall = round((now - self._mark) * 1000.0, 3)
+        self._log.record(FitRecord(
+            fit=self.fit, step=step, t=now, wall_ms=wall,
+            other_ms=round(wall - sum(parts.values()), 3), **parts))
+        self._acc = dict.fromkeys(_FIT_BUCKETS, 0.0)
+        self._mark = now
 
 
 # -- registry (the `/steps` surface) ----------------------------------------

@@ -713,7 +713,8 @@ class GenerationEngine:
                     "prefill_ms": 0.0, "decode_ms": 0.0,
                     "promote_ms": 0.0,
                     "attr_idle_ms": 0.0, "attr_sched_ms": 0.0,
-                    "attr_wall_ms": 0.0}
+                    "attr_wall_ms": 0.0, "decode_wait_ms": 0.0,
+                    "prefill_wait_ms": 0.0, "admit_wait_ms": 0.0}
         # published BEFORE the step thread exists so a router polling a
         # freshly built replica reads a truthful empty-engine snapshot
         self._pressure = self._compute_pressure()
@@ -824,6 +825,12 @@ class GenerationEngine:
         ledger = self._ledger
         max_position = self._max_position
 
+        # the programs' names are fixed on purpose (gen_prefill,
+        # gen_prefill_tail, gen_decode, gen_verify, gen_zero_pages,
+        # gen_cow_copy, gen_tier_gather, gen_tier_write): a profiler
+        # trace's `XLA Modules` line reads `jit_gen_decode(...)`, and
+        # tools/trace_report.py sums device time per program by them
+
         def note(key: str):
             # runs at TRACE time only (python side effect under jit),
             # so the pack-owned ledger counts compiles exactly — the
@@ -852,7 +859,7 @@ class GenerationEngine:
                     paged_write(vp, layer, page_ids, offs,
                                 v.astype(vp.dtype)))
 
-        def prefill_fn(W, *rest):
+        def gen_prefill(W, *rest):
             pools, (pt_row, ids, length) = rest[:NP], rest[NP:]
             note(f"prefill[b={ids.shape[1]}]")
             h, ks, vs = gpt_prefill(W, ids, num_heads=H, scale=scale,
@@ -875,7 +882,7 @@ class GenerationEngine:
             idx = jnp.clip(length - 1, 0, S_b - 1)
             return (*pools, gpt_logits(W, h[0, idx]))
 
-        def tail_prefill_fn(W, *rest):
+        def gen_prefill_tail(W, *rest):
             """Prefix-hit prefill: only the prompt TAIL runs the model —
             queries attend the cached prefix pages READ-ONLY plus their
             own in-flight K/V, and the writes land in the tail's pages
@@ -921,7 +928,7 @@ class GenerationEngine:
             idx = jnp.clip(length - 1, 0, S_b - 1)
             return (*pools, gpt_logits(W, h[0, idx]))
 
-        def cow_fn(*rest):
+        def gen_cow_copy(*rest):
             """Copy-on-write page split: clone one page's content across
             every layer/head from `src` to `dst` — including the
             per-(layer, head, page) scale rows in the int8 mode, so the
@@ -954,24 +961,25 @@ class GenerationEngine:
             kp, vp = pools
             return paged_attention(q, kp[layer], vp[layer], pt, pos, scale)
 
-        def decode_fn(W, *rest):
+        def gen_decode(W, *rest):
             pools = rest[:NP]
             pt, tok, pos, active, temps, smask, key = rest[NP:]
             note(f"decode[m={tok.shape[0]}]")
             logits, (pools, _) = gpt_decode_step(
                 W, tok, pos, (pools, pt), write_kv, attend,
                 num_heads=H, scale=scale, reduce=psum)
-            greedy = jnp.argmax(logits, -1).astype(jnp.int32)
-            lg = logits / jnp.maximum(temps[:, None], 1e-6)
-            if top_k:
-                kth = jax.lax.top_k(lg, int(top_k))[0][..., -1:]
-                lg = jnp.where(lg < kth, -1e30, lg)
-            sampled = jax.random.categorical(key, lg).astype(jnp.int32)
-            nxt = jnp.where(smask, sampled, greedy)
-            bad = active & ~jnp.all(jnp.isfinite(logits), axis=-1)
-            return (*pools, jnp.where(active, nxt, 0), bad)
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(logits, -1).astype(jnp.int32)
+                lg = logits / jnp.maximum(temps[:, None], 1e-6)
+                if top_k:
+                    kth = jax.lax.top_k(lg, int(top_k))[0][..., -1:]
+                    lg = jnp.where(lg < kth, -1e30, lg)
+                sampled = jax.random.categorical(key, lg).astype(jnp.int32)
+                nxt = jnp.where(smask, sampled, greedy)
+                bad = active & ~jnp.all(jnp.isfinite(logits), axis=-1)
+                return (*pools, jnp.where(active, nxt, 0), bad)
 
-        def verify_fn(W, *rest):
+        def gen_verify(W, *rest):
             """Speculative verify step (ISSUE 14): score every live
             slot's [current token + k drafts] block — k+1 positions —
             in ONE pass over the paged cache (`gpt_spec_verify` on the
@@ -1058,7 +1066,7 @@ class GenerationEngine:
                                 offs.reshape(-1), ksf, vsf, requant=True)
             return (*pools, n_acc, nxt, bad)
 
-        def zero_fn(*rest):
+        def gen_zero_pages(*rest):
             # trash-padded page rows: the scratch page is re-zeroed with
             # every free, which also scrubs poisoned prefill tails; the
             # int8 mode resets the freed pages' SCALES too, so the next
@@ -1075,7 +1083,7 @@ class GenerationEngine:
             return (kp.at[:, :, pages].set(0.0),
                     vp.at[:, :, pages].set(0.0))
 
-        def tier_gather_fn(*rest):
+        def gen_tier_gather(*rest):
             """Demotion gather (ISSUE 18): copy ONE page's raw blocks —
             and, in the int8 mode, its per-(layer, head) scale rows —
             out of the pools for the host tier. NON-donating by
@@ -1093,7 +1101,7 @@ class GenerationEngine:
             kp, vp = pools
             return (kp[:, :, page], vp[:, :, page])
 
-        def tier_write_fn(*rest):
+        def gen_tier_write(*rest):
             """Promotion scatter (ISSUE 18): write one fixed-width
             chunk of host-tier pages — raw content, raw int8 scale rows
             — into the admission's fresh target pages. Pad rows route
@@ -1141,42 +1149,44 @@ class GenerationEngine:
                 return jax.shard_map(fn, mesh=mesh, in_specs=ins,
                                      out_specs=outs, check_vma=False)
 
-            prefill_fn = shard(prefill_fn, (rep,) * 3, (*pspecs, rep))
-            tail_prefill_fn = shard(tail_prefill_fn, (rep,) * 4,
+            gen_prefill = shard(gen_prefill, (rep,) * 3, (*pspecs, rep))
+            gen_prefill_tail = shard(gen_prefill_tail, (rep,) * 4,
                                     (*pspecs, rep))
-            decode_fn = shard(decode_fn, (rep,) * 7,
+            gen_decode = shard(gen_decode, (rep,) * 7,
                               (*pspecs, rep, rep))
-            verify_fn = shard(verify_fn, (rep,) * 8,
+            gen_verify = shard(gen_verify, (rep,) * 8,
                               (*pspecs, rep, rep, rep))
-            cow_fn = shard(cow_fn, (rep,) * 2, pspecs, with_w=False)
-            zero_fn = shard(zero_fn, (rep,), pspecs, with_w=False)
+            gen_cow_copy = shard(gen_cow_copy, (rep,) * 2, pspecs,
+                                 with_w=False)
+            gen_zero_pages = shard(gen_zero_pages, (rep,), pspecs,
+                                   with_w=False)
             # tier seam (ISSUE 18): the host store keeps FULL pages —
             # the gather's sharded out_specs reassemble every head
             # shard into one host block, and the write's chunk specs
             # split the staged full blocks back across the slice
-            tier_gather_fn = shard(
-                tier_gather_fn, (rep,),
+            gen_tier_gather = shard(
+                gen_tier_gather, (rep,),
                 (page4, page4, page2, page2) if quant
                 else (page4, page4), with_w=False)
-            tier_write_fn = shard(
-                tier_write_fn,
+            gen_tier_write = shard(
+                gen_tier_write,
                 (rep, chunk5, chunk5, chunk3, chunk3) if quant
                 else (rep, chunk5, chunk5),
                 pspecs, with_w=False)
 
         donate = tuple(range(1, 1 + NP))
-        self._prefill_jit = jax.jit(prefill_fn, donate_argnums=donate)
-        self._tail_jit = jax.jit(tail_prefill_fn, donate_argnums=donate)
-        self._decode_jit = jax.jit(decode_fn, donate_argnums=donate)
-        self._verify_jit = (jax.jit(verify_fn, donate_argnums=donate)
+        self._prefill_jit = jax.jit(gen_prefill, donate_argnums=donate)
+        self._tail_jit = jax.jit(gen_prefill_tail, donate_argnums=donate)
+        self._decode_jit = jax.jit(gen_decode, donate_argnums=donate)
+        self._verify_jit = (jax.jit(gen_verify, donate_argnums=donate)
                             if self._spec_k else None)
-        self._zero_jit = jax.jit(zero_fn,
+        self._zero_jit = jax.jit(gen_zero_pages,
                                  donate_argnums=tuple(range(NP)))
-        self._cow_jit = jax.jit(cow_fn, donate_argnums=tuple(range(NP)))
-        self._tier_gather_jit = (jax.jit(tier_gather_fn)
+        self._cow_jit = jax.jit(gen_cow_copy, donate_argnums=tuple(range(NP)))
+        self._tier_gather_jit = (jax.jit(gen_tier_gather)
                                  if self._tier is not None else None)
         self._tier_write_jit = (
-            jax.jit(tier_write_fn, donate_argnums=tuple(range(NP)))
+            jax.jit(gen_tier_write, donate_argnums=tuple(range(NP)))
             if self._tier is not None else None)
         # warm start (ISSUE 16): resolved AOT executables by program
         # name (ledger keys) + the store-load ledger; warmup fills them
@@ -1837,7 +1847,8 @@ class GenerationEngine:
                     while (not self._queue and self._num_active() == 0
                            and not self._closed):
                         t0 = time.perf_counter()
-                        self._cv.wait()
+                        with RecordEvent("generation::idle"):
+                            self._cv.wait()
                         idle_s += time.perf_counter() - t0
                     if self._closed and self._abort:
                         self._evict_all(UnavailableError(
@@ -1853,10 +1864,11 @@ class GenerationEngine:
                             and self._num_active() == 0):
                         return
                 t0 = time.perf_counter()
-                self._admit()
-                self._expire_active()
-                if self._cfg.prefill_chunk:
-                    self._advance_prefills()
+                with RecordEvent("generation::admit"):
+                    self._admit()
+                    self._expire_active()
+                    if self._cfg.prefill_chunk:
+                        self._advance_prefills()
                 sched_s = time.perf_counter() - t0
                 stepped = False
                 if any(r is not None and r.prefill_pos is None
@@ -1869,12 +1881,13 @@ class GenerationEngine:
                 it["attr_sched_ms"] = sched_s * 1000.0
                 it["attr_wall_ms"] = (now - t_mark) * 1000.0
                 t_mark, idle_s = now, 0.0
-                self._record_iteration()
-                # sink before resolutions: a caller woken by result()
-                # may immediately read the JSONL — its own event must
-                # already be on disk (no lock held here)
-                self._audit.flush_sink()
-                self._flush_resolutions()
+                with RecordEvent("generation::record"):
+                    self._record_iteration()
+                    # sink before resolutions: a caller woken by
+                    # result() may immediately read the JSONL — its own
+                    # event must already be on disk (no lock held here)
+                    self._audit.flush_sink()
+                    self._flush_resolutions()
                 if not stepped:
                     with self._cv:
                         if (self._queue and self._num_active() == 0
@@ -1882,7 +1895,8 @@ class GenerationEngine:
                             # unadmittable head (page exhaustion): bounded
                             # wait so queued deadlines still expire
                             t0 = time.perf_counter()
-                            self._cv.wait(0.01)
+                            with RecordEvent("generation::idle"):
+                                self._cv.wait(0.01)
                             idle_s += time.perf_counter() - t0
         except BaseException as e:  # noqa: BLE001 — never hang submitters
             if self._die(e):
@@ -1907,7 +1921,8 @@ class GenerationEngine:
             "prefill_ms": 0.0, "decode_ms": 0.0,
             "promote_ms": 0.0,
             "attr_idle_ms": 0.0, "attr_sched_ms": 0.0,
-            "attr_wall_ms": 0.0}
+            "attr_wall_ms": 0.0, "decode_wait_ms": 0.0,
+            "prefill_wait_ms": 0.0, "admit_wait_ms": 0.0}
         # pressure snapshot (ISSUE 17): republished every iteration on
         # the step thread — the only thread that mutates the allocator —
         # so `pressure()` readers never need the engine lock. Runs even
@@ -1971,7 +1986,13 @@ class GenerationEngine:
             tp=self._tp,
             attr_admit_ms=a_admit, attr_promote_ms=a_promote,
             attr_bookkeep_ms=a_book, attr_idle_ms=a_idle,
-            attr_wall_ms=a_wall)
+            attr_wall_ms=a_wall,
+            # ISSUE 25 sub-splits: the read-back's share of the two
+            # device buckets, clipped to its rounded parent
+            decode_wait_ms=min(a_decode, round(it["decode_wait_ms"], 3)),
+            prefill_wait_ms=min(a_prefill,
+                                round(it["prefill_wait_ms"], 3)),
+            admit_wait_ms=round(it["admit_wait_ms"], 3))
         self._step_log.record(rec)
 
     def _resolve_later(self, req: Optional[_GenRequest], fut,
@@ -2325,10 +2346,14 @@ class GenerationEngine:
                 self._audit.audit(
                     "ADMIT", rid=req.rid, slot=slot, pages=need,
                     queued_ms=round(_now_ms() - req.t_enqueue_ms, 3))
+            t_admitted = time.perf_counter()
+            t_queued = req.t_enqueue_ms / 1000.0
             if req.span is not None:
                 req.span.slot = slot
                 req.span.prefix_tokens = req.prefix_tokens
-                req.span.stamp("admitted")
+                req.span.stamp("admitted", t_admitted)
+                t_queued = req.span.stamps.get("queued", t_queued)
+            self._it["admit_wait_ms"] += (t_admitted - t_queued) * 1000.0
             chunk = self._cfg.prefill_chunk
             if chunk and S - req.prefix_tokens > chunk:
                 # chunked prefill (ISSUE 14): the slot is admitted NOW
@@ -2376,6 +2401,17 @@ class GenerationEngine:
             live.append(req)
         self._queue = live
 
+    def _read_back(self, bucket: str, *outs):
+        """The blocking read of a program's host outputs — the step
+        thread waits for the chip here and nowhere else — timed into
+        this iteration's `bucket` (`decode_wait_ms` / `prefill_wait_ms`:
+        sub-splits of decode_ms / prefill_ms; what is left of those is
+        launch and argument upload)."""
+        t0 = _now_ms()
+        host = [np.asarray(o) for o in outs]
+        self._it[bucket] += _now_ms() - t0
+        return host if len(host) > 1 else host[0]
+
     def _bucket_for(self, S: int) -> int:
         for b in self._cfg.prefill_buckets:
             if b >= S:
@@ -2412,7 +2448,7 @@ class GenerationEngine:
                         self._W, *self._pools(), req.pt_row, ids,
                         np.int32(tail), np.int32(pfx))
                 self._set_pools(out[:-1])
-                lg = np.asarray(out[-1])
+                lg = self._read_back("prefill_wait_ms", out[-1])
         else:
             bucket = self._bucket_for(S)
             ids = np.zeros((1, bucket), np.int32)
@@ -2424,7 +2460,7 @@ class GenerationEngine:
                         self._W, *self._pools(), req.pt_row, ids,
                         np.int32(S))
                 self._set_pools(out[:-1])
-                lg = np.asarray(out[-1])
+                lg = self._read_back("prefill_wait_ms", out[-1])
         self._it["prefill_ms"] += _now_ms() - t0
         if not np.all(np.isfinite(lg)):
             self._poison_prefill(req, bucket)
@@ -2596,7 +2632,7 @@ class GenerationEngine:
                     self._W, *self._pools(), req.pt_row, ids,
                     np.int32(take), np.int32(req.prefill_pos))
             self._set_pools(out[:-1])
-            lg = np.asarray(out[-1])
+            lg = self._read_back("prefill_wait_ms", out[-1])
         self._it["prefill_ms"] += _now_ms() - t0
         self._it["prefill_chunks"] += 1
         self._chunks_total += 1
@@ -2719,38 +2755,39 @@ class GenerationEngine:
         if self._spec_k and not self._degraded_spec_off:
             self._spec_step()
             return
-        args = self._step_arrays()
+        with RecordEvent("generation::prepare"):
+            args = self._step_arrays()
         t0 = _now_ms()
         with RecordEvent(f"generation::step[m={self._cfg.max_slots}]"):
             out = self._decode_call(self._W, *self._pools(), *args)
-            nxt = np.asarray(out[-2])
-            bad = np.asarray(out[-1])
+            nxt, bad = self._read_back("decode_wait_ms", out[-2], out[-1])
         if failpoints.fire("decode_poison_nan") is not None:
             bad = self._inject_poison(bad)
         self._set_pools(out[:-2])
         self._it["decode_ms"] += _now_ms() - t0
         self._steps_total += 1
         monitor.stat_add("STAT_gen_steps")
-        for i, req in enumerate(self._slots):
-            if req is None or req.prefill_pos is not None:
-                continue  # empty, or chunk-prefilling (masked this step)
-            if bad[i]:
-                # poison isolation: only THIS sequence fails; its pages
-                # are zeroed before reuse so the NaN cannot reach the
-                # next owner's masked attention
-                self._poison_decode(req, i)
-                continue
-            tok = int(nxt[i])
-            req.toks.append(tok)
-            req.next_pos += 1
-            self._tokens_total += 1
-            monitor.stat_add("STAT_gen_tokens")
-            self._it["tokens"] += 1
-            self._stage_token(req, tok)
-            if req.span is not None:
-                req.span.stamp("last_token")
-            if self._finished(req, tok):
-                self._complete(req)
+        with RecordEvent("generation::deliver"):
+            for i, req in enumerate(self._slots):
+                if req is None or req.prefill_pos is not None:
+                    continue  # empty, or chunk-prefilling (masked this step)
+                if bad[i]:
+                    # poison isolation: only THIS sequence fails; its pages
+                    # are zeroed before reuse so the NaN cannot reach the
+                    # next owner's masked attention
+                    self._poison_decode(req, i)
+                    continue
+                tok = int(nxt[i])
+                req.toks.append(tok)
+                req.next_pos += 1
+                self._tokens_total += 1
+                monitor.stat_add("STAT_gen_tokens")
+                self._it["tokens"] += 1
+                self._stage_token(req, tok)
+                if req.span is not None:
+                    req.span.stamp("last_token")
+                if self._finished(req, tok):
+                    self._complete(req)
 
     def _spec_step(self):
         """ONE speculative engine step (ISSUE 14): every live sequence
@@ -2763,13 +2800,13 @@ class GenerationEngine:
         agreement, so the token stream is identical to the one the
         plain decode program would have produced, just delivered in
         fewer weight streams."""
-        args, drafted = self._spec_arrays()
+        with RecordEvent("generation::prepare"):
+            args, drafted = self._spec_arrays()
         t0 = _now_ms()
         with RecordEvent(f"generation::verify[k={self._spec_k}]"):
             out = self._verify_call(self._W, *self._pools(), *args)
-            n_acc = np.asarray(out[-3])
-            nxt = np.asarray(out[-2])
-            bad = np.asarray(out[-1])
+            n_acc, nxt, bad = self._read_back("decode_wait_ms", out[-3],
+                                              out[-2], out[-1])
         if failpoints.fire("decode_poison_nan") is not None:
             bad = self._inject_poison(bad)
         self._set_pools(out[:-3])
@@ -2781,36 +2818,37 @@ class GenerationEngine:
             self._it["spec_drafted"] += drafted
             self._spec_drafted_total += drafted
         toks_blk = args[1]
-        for i, req in enumerate(self._slots):
-            if req is None or req.prefill_pos is not None:
-                continue
-            if bad[i]:
-                self._poison_decode(req, i)
-                continue
-            acc = int(n_acc[i])
-            if acc:
-                monitor.stat_add("STAT_spec_accepted", acc)
-                self._it["spec_accepted"] += acc
-                self._spec_accepted_total += acc
-                req.spec_accepted += acc
-            # accepted drafts in order, then the bonus token; EOS (or
-            # the max-new budget) inside the block ends the sequence
-            # there — later committed positions sit past next_pos,
-            # masked from every future attend and zeroed with the free
-            for tok in ([int(t) for t in toks_blk[i, 1:1 + acc]]
-                        + [int(nxt[i])]):
-                req.toks.append(tok)
-                req.next_pos += 1
-                self._tokens_total += 1
-                monitor.stat_add("STAT_gen_tokens")
-                self._it["tokens"] += 1
-                self._stage_token(req, tok)
-                if self._finished(req, tok):
-                    break
-            if req.span is not None:
-                req.span.stamp("last_token")
-            if self._finished(req, req.toks[-1]):
-                self._complete(req)
+        with RecordEvent("generation::deliver"):
+            for i, req in enumerate(self._slots):
+                if req is None or req.prefill_pos is not None:
+                    continue
+                if bad[i]:
+                    self._poison_decode(req, i)
+                    continue
+                acc = int(n_acc[i])
+                if acc:
+                    monitor.stat_add("STAT_spec_accepted", acc)
+                    self._it["spec_accepted"] += acc
+                    self._spec_accepted_total += acc
+                    req.spec_accepted += acc
+                # accepted drafts in order, then the bonus token; EOS (or
+                # the max-new budget) inside the block ends the sequence
+                # there — later committed positions sit past next_pos,
+                # masked from every future attend and zeroed with the free
+                for tok in ([int(t) for t in toks_blk[i, 1:1 + acc]]
+                            + [int(nxt[i])]):
+                    req.toks.append(tok)
+                    req.next_pos += 1
+                    self._tokens_total += 1
+                    monitor.stat_add("STAT_gen_tokens")
+                    self._it["tokens"] += 1
+                    self._stage_token(req, tok)
+                    if self._finished(req, tok):
+                        break
+                if req.span is not None:
+                    req.span.stamp("last_token")
+                if self._finished(req, req.toks[-1]):
+                    self._complete(req)
 
     def _finished(self, req: _GenRequest, tok: int) -> bool:
         return ((req.eos is not None and tok == req.eos)
