@@ -297,8 +297,8 @@ class Smoke:
         lengths = ([3, 9, 14, 20, 27, 31, 9, 20] if self.rehearsal
                    else [5, 24, 40, 72, 100, 120, 24, 72])
         prompts = prompts_for(cfg, lengths, seed=2)
-        k0, r0 = (stat_get("STAT_paged_attn_kernel"),
-                  stat_get("STAT_paged_attn_reference"))
+        k0, p0 = (stat_get("STAT_paged_attn_kernel"),
+                  stat_get("STAT_paged_attn_pool"))
         t = time.perf_counter()
         eng = serving.GenerationEngine(
             net, name="smoke", prefill_buckets=buckets, max_slots=8,
@@ -324,13 +324,21 @@ class Smoke:
         check(stats["pages"]["pages_in_use"] == 0,
               "serve: pages_in_use == 0 after drain")
         kern = stat_get("STAT_paged_attn_kernel") - k0
-        ref = stat_get("STAT_paged_attn_reference") - r0
+        pool = stat_get("STAT_paged_attn_pool") - p0
+        entries = stats["pages"]["pages_per_seq"]
+        # head dim 64 is no kernel shape; pool-dense where the pool is no
+        # larger than the batch's gather (GPT-2 small: 8 x 64 entries; the
+        # rehearsal's 128 positions give 8 x 8, so it gathers)
+        want = "pool" if 128 <= 8 * entries else "reference"
         say(f"serve: attention path at head_dim="
-            f"{cfg.hidden_size // cfg.num_heads}: STAT_paged_attn_kernel="
-            f"{kern} STAT_paged_attn_reference={ref} (traces)")
-        check(kern == 0 and ref > 0,
-              "serve: the decode program took the gather reference, by the "
-              "shape rule (head dim 64)")
+            f"{cfg.hidden_size // cfg.num_heads}, 128 pages against 8 slots "
+            f"x {entries} entries: {stats['decode_attention']}; "
+            f"STAT_paged_attn_kernel={kern} STAT_paged_attn_pool={pool} "
+            f"(traces)")
+        check(stats["decode_attention"] == want and kern == 0
+              and pool == (cfg.num_layers if want == "pool" else 0),
+              f"serve: the decode program's attention is `{want}`, by the "
+              f"shape rules")
 
         exact, near, worst, first = self.near_argmax_rate(net, outs, prompts)
         say(f"serve: vs the eager forward, teacher-forced: {exact:.3f} of "

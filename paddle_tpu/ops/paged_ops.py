@@ -1,4 +1,4 @@
-"""Paged KV-cache attention: TPU Pallas kernel dispatch + dense reference.
+"""Paged KV-cache attention: three implementations, two shape rules.
 
 vLLM's PagedAttention insight, TPU-shaped: decode-time K/V lives in
 fixed-size **pages** inside preallocated per-layer pools
@@ -8,27 +8,51 @@ different lengths share one pool with zero fragmentation beyond the last
 partial page, and admission control is exact page arithmetic
 (`serving/kv_cache.py`).
 
-Two attention implementations over that layout, one math:
+Three decode-attention implementations over that layout, one math:
 
-- **TPU** — `jax.experimental.pallas.ops.tpu.paged_attention` (the
+- **kernel** — `jax.experimental.pallas.ops.tpu.paged_attention` (the
   primitive SNIPPETS.md [3] shards along KV heads): reads pages in
   place, `lengths` masks per sequence. Flag-gated by
-  `FLAGS_use_paged_attention` and shape-gated by
-  `paged_kernel_supported`; tile = `FLAGS_paged_compute_block_pages`
-  pages.
-- **reference** (every other backend and shape) — gather the page table into a
-  dense `[B, H, T, D]` buffer and run `cached_attention`, the EXACT
-  masked-softmax expression `GPTModel.generate`'s fixed cache uses, so
-  the generation engine's greedy decode is anchored to the same oracle
-  as `tests/test_generate.py` (positions beyond `pos` mask to -1e30 →
-  exp underflows to exactly 0.0, so page-tail junk and trash-page reads
+  `FLAGS_use_paged_attention`, TPU only, and shape-gated by
+  `paged_kernel_supported` (head dim a multiple of 128); tile =
+  `FLAGS_paged_compute_block_pages` pages.
+- **pool** (pool-dense, `paged_pool_attention`) — no gather: all B
+  rows' queries are scored against the layer's WHOLE pool in one
+  batched matmul (`[B, D] x [D, N*P]` per head) and a page-ownership
+  mask (`paged_pool_mask`, built once a step from the page table and
+  `pos` alone) says which pool rows each sequence reads. Every pool
+  page is read once per layer, whatever the batch. Shape-gated by
+  `paged_pool_dense_supported`: floating pools, not a kernel shape, and
+  `N <= B*PP` — the pool holds no more pages than the gather would
+  materialize, so it never reads more than the path it replaces.
+- **reference** (every other shape: a pool larger than `B*PP`, int8
+  pools, and the oracle the other two are tested against) — gather the
+  page table into a dense `[B, H, T, D]` buffer and run
+  `cached_attention`, the EXACT masked-softmax expression
+  `GPTModel.generate`'s fixed cache uses, so the generation engine's
+  greedy decode is anchored to the same oracle as
+  `tests/test_generate.py` (positions beyond `pos` mask to -1e30 → exp
+  underflows to exactly 0.0, so page-tail junk and trash-page reads
   contribute +0.0 and numerics match the contiguous cache bit-for-bit
-  within one compiled shape).
+  within one compiled shape). The pool path is the same masked
+  expression over the pool's PHYSICAL order — a permutation of the same
+  sum.
 
-Both paths are trace-time choices (python `if` under `jax.jit`), counted
-by `STAT_paged_attn_kernel` / `STAT_paged_attn_reference` — these count
-**traces**, not calls, mirroring the exact-compile accounting everywhere
-else in the serving stack.
+**Row isolation** is part of the contract of all three: a row's output
+depends only on the positions `t <= pos` of its own pages. The gather
+and the kernel get that by reading nothing else; the pool path reads
+every page for every row, and `0.0 * NaN` is NaN, so it masks K by
+`where` (a NaN score outside the mask is dropped, never multiplied),
+multiplies the probabilities by a V made finite, and sets a row's
+output to NaN where a V row it attends is non-finite — the owner of a
+poisoned page still trips the engine's non-finite-logit flag and nobody
+else does. No "the pools are always finite" assumption.
+
+All choices are trace-time (python `if` under `jax.jit`) by observable
+shape — `paged_attention_path` names the one a call takes — and counted
+by `STAT_paged_attn_kernel` / `STAT_paged_attn_pool` /
+`STAT_paged_attn_reference`: **traces**, not calls, mirroring the
+exact-compile accounting everywhere else in the serving stack.
 """
 from __future__ import annotations
 
@@ -38,8 +62,10 @@ import jax.numpy as jnp
 from ..framework import monitor
 from ..framework.flags import flag
 
-__all__ = ["cached_attention", "paged_attention", "paged_gather",
-           "paged_kernel_supported",
+__all__ = ["cached_attention", "paged_attention", "paged_attention_path",
+           "paged_gather", "paged_kernel_supported",
+           "paged_pool_attention", "paged_pool_dense_supported",
+           "paged_pool_mask",
            "paged_gather_layers", "paged_gather_quantized",
            "paged_prefix_attention", "paged_write",
            "paged_write_quantized", "page_rows_for_positions",
@@ -47,9 +73,9 @@ __all__ = ["cached_attention", "paged_attention", "paged_gather",
 
 
 # Every function below that touches the pools runs under a named scope of
-# its own — `kv_write`, `kv_gather`, `kv_attend`, `paged_attn_kernel` —
-# so a profiler trace tells the pools' relay (writes, gathers and the
-# copies XLA makes for them) from the attention arithmetic
+# its own — `kv_write`, `kv_gather`, `kv_mask`, `kv_attend`,
+# `paged_attn_kernel` — so a profiler trace tells the pools' relay (writes,
+# gathers and the copies XLA makes for them) from the attention arithmetic
 # (tools/trace_report.py). Names are metadata: the programs are the same.
 
 
@@ -79,6 +105,64 @@ def paged_gather(pages, page_table):
     B, PP = page_table.shape
     kb = jnp.take(pages, page_table, axis=1)     # [H, B, PP, P, D]
     return jnp.moveaxis(kb, 1, 0).reshape(B, H, PP * P, D)
+
+
+@jax.named_scope("kv_mask")
+def paged_pool_mask(page_table, pos, num_pages, page_size):
+    """Page-ownership mask of the pool-dense path: which rows of one
+    layer's pool, in PHYSICAL order, each sequence attends.
+
+    page_table [B, PP] int32; pos [B] int32 (last valid position).
+    Returns valid [B, N*P] bool: pool row `n*P + p` is valid for
+    sequence b iff page n is entry j of `page_table[b]` with
+    `j*P + p <= pos[b]` — exactly the positions `cached_attention`
+    leaves unmasked in the gathered view. A page shared by two rows
+    (prefix cache, copy-on-write) is owned by both, each at its own j;
+    table entries past a row's length, free pages and other rows' pages
+    are masked; an inactive slot (every entry `TRASH_PAGE`, pos 0) keeps
+    the trash page's first row, as it does in the gathered view. Built
+    from the table and `pos` alone, so one mask serves every layer of a
+    decode step."""
+    PP = page_table.shape[1]
+    # last valid offset inside table entry j: P-1 for a full page,
+    # pos % P for the page holding `pos`, negative past the row's length
+    last = jnp.minimum(
+        pos[:, None] - jnp.arange(PP, dtype=pos.dtype)[None, :] * page_size,
+        page_size - 1)                                        # [B, PP]
+    own = page_table[:, :, None] == jnp.arange(num_pages)[None, None, :]
+    limit = jnp.max(jnp.where(own, last[:, :, None], -1), axis=1)  # [B, N]
+    offs = jnp.arange(num_pages * page_size) % page_size
+    return offs[None, :] <= jnp.repeat(limit, page_size, axis=1)
+
+
+@jax.named_scope("kv_attend")
+def paged_pool_attention(q, k_pages, v_pages, valid, scale):
+    """Pool-dense decode attention: every row's query against the whole
+    of one layer's pool, `valid` (`paged_pool_mask`) picking each row's
+    own positions. q [B, H, D]; k_pages/v_pages [H, N, P, D]; valid
+    [B, N*P]. Returns [B, H, D].
+
+    The masked softmax is `cached_attention`'s (-1e30 → exactly 0.0)
+    over the pool's physical order. Isolation (module docstring): a
+    non-finite K row outside the mask is dropped by the `where`; V is
+    multiplied as a finite copy, and a (row, head) whose own valid V
+    rows are not all finite reads NaN, as the gather would give it."""
+    H, N, P, D = k_pages.shape
+    k = k_pages.reshape(H, N * P, D)
+    # V has two readers, the product and the is-finite reduction. Left
+    # alone XLA:TPU slices the layer out of the pool once for each; the
+    # barrier makes the slice one value that both read (on the v5e,
+    # gpt2-xl's decode step: 39.2 -> 35.3 ms, PERF.md PR 26; on K, which
+    # has one reader, a barrier changes nothing)
+    v = jax.lax.optimization_barrier(v_pages.reshape(H, N * P, D))
+    s = jnp.einsum("bhd,htd->bht", q, k) * scale
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    finite = jnp.isfinite(v)
+    out = jnp.einsum("bht,htd->bhd", p, jnp.where(finite, v, 0))
+    poisoned = ~jnp.all(finite, axis=-1)                      # [H, N*P]
+    bad = jnp.any(valid[:, None, :] & poisoned[None], axis=-1)    # [B, H]
+    return jnp.where(bad[..., None], jnp.nan, out)
 
 
 def page_rows_for_positions(page_table, positions, page_size):
@@ -248,38 +332,72 @@ def paged_kernel_supported(q_shape, pages_shape, table_shape) -> bool:
             and table_shape[1] % _block_pages() == 0)
 
 
-def _use_kernel(q_shape, pages_shape, table_shape) -> bool:
+def paged_pool_dense_supported(q_shape, pages_shape, table_shape,
+                               pages_dtype=jnp.float32) -> bool:
+    """Static gate of the pool-dense path (`paged_pool_attention`), by
+    observable shape like `paged_kernel_supported`. q [B, H, D]; pages
+    [H, N, P, D]; table [B, PP].
+
+    - floating pools: int8 pools dequantize per page on gather.
+    - not a shape of the Pallas kernel, which reads pages in place.
+    - one K/V head per query head (the reference's own limit).
+    - `N <= B*PP`: the pool holds no more pages than the gather would
+      materialize for this batch, so pool-dense never reads more than
+      the path it replaces. The engine's defaults (512 pages, 8 slots,
+      64 entries) sit exactly on the rule; few slots over a large
+      prefix-cached pool stay on the gather."""
+    B, H, D = q_shape
+    Hkv, N, _, Dk = pages_shape
+    return (jnp.issubdtype(pages_dtype, jnp.floating)
+            and H == Hkv and D == Dk
+            and not paged_kernel_supported(q_shape, pages_shape, table_shape)
+            and N <= B * table_shape[1])
+
+
+def paged_attention_path(q_shape, pages_shape, table_shape,
+                         pages_dtype=jnp.float32) -> str:
+    """Which implementation `paged_attention` traces for these shapes:
+    "kernel", "pool" or "reference" (module docstring)."""
+    if not jnp.issubdtype(pages_dtype, jnp.floating):
+        return "reference"    # int8 pools dequantize page by page on gather
     # lint: allow(flag-in-trace): kernel-vs-reference is a trace-time choice by design (module docstring); the flag picks which program gets built
-    return (bool(flag("FLAGS_use_paged_attention"))
+    if (bool(flag("FLAGS_use_paged_attention"))
             and jax.default_backend() == "tpu"
-            and paged_kernel_supported(q_shape, pages_shape, table_shape))
+            and paged_kernel_supported(q_shape, pages_shape, table_shape)):
+        return "kernel"
+    if paged_pool_dense_supported(q_shape, pages_shape, table_shape,
+                                  pages_dtype):
+        return "pool"
+    return "reference"
 
 
 def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
-                    k_scales=None, v_scales=None):
+                    k_scales=None, v_scales=None, pool_mask=None):
     """One decode position of attention over a paged KV cache.
 
     q [B, H, D]; k_pages/v_pages [H, N, P, D] (ONE layer's pool);
     page_table [B, PP] int32; pos [B] int32 (last valid position, the
     token just written). Returns [B, H, D].
 
-    On a TPU backend, shapes `paged_kernel_supported` admits dispatch the
-    Pallas kernel (pages read in place); every other shape and backend
-    gathers to dense and reuses `cached_attention` — the generate-anchored
-    math. The choice is a shape rule made before the call, never a
-    fallback from a kernel that failed.
+    `paged_attention_path` picks the implementation from the shapes: on
+    a TPU backend, shapes `paged_kernel_supported` admits dispatch the
+    Pallas kernel (pages read in place); shapes
+    `paged_pool_dense_supported` admits score every row against the
+    whole pool under `pool_mask` (the `paged_pool_mask` of this table
+    and `pos`; a caller with many layers builds it once and passes it,
+    else it is built here); every other shape gathers to dense and
+    reuses `cached_attention` — the generate-anchored math. The choice
+    is a shape rule made before the call, never a fallback from a
+    kernel that failed.
 
     int8 pools pass k_scales/v_scales ([H, N] per-page scales): the
     Pallas kernel has no int8+scale-pool input layout, so quantized
     reads always take the dequantizing gather + dense reference (the
     gather materializes only this batch's pages in floating form; the
     pools stay int8 in HBM — on TPU and CPU alike)."""
-    if k_scales is not None:
-        monitor.stat_add("STAT_paged_attn_reference")  # traces, not calls
-        kb = paged_gather_quantized(k_pages, k_scales, page_table, q.dtype)
-        vb = paged_gather_quantized(v_pages, v_scales, page_table, q.dtype)
-        return cached_attention(q, kb, vb, pos, scale)
-    if _use_kernel(q.shape, k_pages.shape, page_table.shape):
+    path = paged_attention_path(q.shape, k_pages.shape, page_table.shape,
+                                k_pages.dtype)
+    if path == "kernel":
         monitor.stat_add("STAT_paged_attn_kernel")  # traces, not calls
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention as _kernel)
@@ -300,9 +418,19 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
                 lengths=(pos + 1).astype(jnp.int32),
                 page_indices=page_table.astype(jnp.int32),
                 pages_per_compute_block=_block_pages())
+    if path == "pool":
+        monitor.stat_add("STAT_paged_attn_pool")  # traces, not calls
+        if pool_mask is None:
+            pool_mask = paged_pool_mask(page_table, pos, k_pages.shape[1],
+                                        k_pages.shape[2])
+        return paged_pool_attention(q, k_pages, v_pages, pool_mask, scale)
     monitor.stat_add("STAT_paged_attn_reference")  # traces, not calls
-    kb = paged_gather(k_pages, page_table)
-    vb = paged_gather(v_pages, page_table)
+    if k_scales is not None:
+        kb = paged_gather_quantized(k_pages, k_scales, page_table, q.dtype)
+        vb = paged_gather_quantized(v_pages, v_scales, page_table, q.dtype)
+    else:
+        kb = paged_gather(k_pages, page_table)
+        vb = paged_gather(v_pages, page_table)
     return cached_attention(q, kb, vb, pos, scale)
 
 

@@ -765,6 +765,16 @@ class GenerationEngine:
             self._kp, self._vp = pools
 
     def _build_programs(self, pack: Optional[_ProgramPack] = None):
+        # which of ops/paged_ops.py's three implementations the decode
+        # program's attention takes — a shape rule, so it is known
+        # before (and whether or not) anything is traced; under a tp
+        # mesh the rule sees the per-shard head count
+        from ..ops.paged_ops import paged_attention_path
+        kp = self._kp
+        self._decode_attention = paged_attention_path(
+            (self._cfg.max_slots, self._H // self._tp, kp.shape[-1]),
+            (self._H // self._tp,) + tuple(kp.shape[2:]),
+            (self._cfg.max_slots, self._cfg.pages_per_seq), kp.dtype)
         if pack is not None:
             # resurrection path (ISSUE 15): adopt the previous
             # incarnation's jit wrappers and SHARE its ledger dict —
@@ -801,6 +811,7 @@ class GenerationEngine:
                                      paged_attention, paged_gather,
                                      paged_gather_layers,
                                      paged_gather_quantized,
+                                     paged_pool_mask,
                                      paged_prefix_attention, paged_write,
                                      paged_write_quantized)
 
@@ -947,26 +958,36 @@ class GenerationEngine:
             return (kp.at[:, :, dst].set(kp[:, :, src]),
                     vp.at[:, :, dst].set(vp[:, :, src]))
 
+        # the decode cache threaded through gpt_decode_step's hooks:
+        # (pools, page table, pool-dense ownership mask or None)
         def write_kv(cache, layer, k, v, pos):
-            pools, pt = cache
+            pools, pt, mask = cache
             page_ids, offs = page_rows_for_positions(pt, pos, P)
-            return (write_pages(pools, layer, page_ids, offs, k, v), pt)
+            return (write_pages(pools, layer, page_ids, offs, k, v), pt,
+                    mask)
 
         def attend(cache, layer, q, pos):
-            pools, pt = cache
+            pools, pt, mask = cache
             if quant:
                 kp, vp, ksc, vsc = pools
                 return paged_attention(q, kp[layer], vp[layer], pt, pos,
                                        scale, ksc[layer], vsc[layer])
             kp, vp = pools
-            return paged_attention(q, kp[layer], vp[layer], pt, pos, scale)
+            return paged_attention(q, kp[layer], vp[layer], pt, pos, scale,
+                                   pool_mask=mask)
+
+        pool_dense = self._decode_attention == "pool"
 
         def gen_decode(W, *rest):
             pools = rest[:NP]
             pt, tok, pos, active, temps, smask, key = rest[NP:]
             note(f"decode[m={tok.shape[0]}]")
-            logits, (pools, _) = gpt_decode_step(
-                W, tok, pos, (pools, pt), write_kv, attend,
+            # pool-dense attention: the mask depends on the table and
+            # `pos` alone, so every layer of the step shares this one
+            mask = (paged_pool_mask(pt, pos, pools[0].shape[2], P)
+                    if pool_dense else None)
+            logits, (pools, _, _) = gpt_decode_step(
+                W, tok, pos, (pools, pt, mask), write_kv, attend,
                 num_heads=H, scale=scale, reduce=psum)
             with jax.named_scope("sample"):
                 greedy = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -1238,6 +1259,7 @@ class GenerationEngine:
                 "kv_tier_chunk_pages": self._cfg.kv_tier_chunk_pages,
                 "spec_k": self._spec_k,
                 "top_k": self._cfg.top_k,
+                "decode_attention": self._decode_attention,
                 # mesh-slice lane (ISSUE 19): tp degree + mesh shape
                 # join the content key — a shard_map program compiled
                 # for one slice layout must never resolve on another
@@ -3048,6 +3070,9 @@ class GenerationEngine:
             "compiles": ledger,
             "loaded": loaded,
             "programs": programs,
+            # "kernel" / "pool" / "reference": the paged attention the
+            # decode program was built with (ops/paged_ops.py)
+            "decode_attention": self._decode_attention,
             "program_store": {
                 "configured": bool(self._cfg.program_store),
                 "active": self._store is not None,

@@ -277,6 +277,39 @@ def test_poisoned_sequence_fails_alone_and_pages_scrub(model):
     assert monitor.stat_get("STAT_gen_poisoned") > p0
 
 
+def test_poisoned_v_pages_fail_their_sequence_alone(model):
+    """The V side of the same contract. On the pool-dense path (64 pages =
+    2 slots x 32 entries) every row's probabilities multiply every page, and
+    0.0 * NaN is NaN: the co-resident sequence must still decode its
+    clean-run tokens, and the owner must still fail."""
+    ids = _prompts()
+    ref_a = model.generate(paddle.to_tensor(ids[0:1]),
+                           max_new_tokens=12).numpy()[0]
+    fired = []
+
+    def hook(eng):
+        req = eng._slots[1] if len(eng._slots) > 1 else None
+        if not fired and req is not None and len(req.toks) >= 2:
+            pages = eng._cache.owned(req.rid)
+            if pages:
+                eng._vp = eng._vp.at[:, :, pages].set(np.nan)
+                fired.append(req.rid)
+
+    with _engine(model, num_pages=64) as eng:
+        assert eng.stats()["decode_attention"] == "pool"
+        eng._pre_step_hook = hook
+        fa = eng.submit(ids[0], max_new_tokens=12)
+        fb = eng.submit(ids[1], max_new_tokens=12)
+        with pytest.raises(FatalError):
+            fb.result(timeout=120)
+        out_a = fa.result(timeout=120)
+        eng._pre_step_hook = None
+        pages_after = eng.stats()["pages"]["pages_in_use"]
+    assert fired, "test hook never found the co-resident sequence"
+    np.testing.assert_array_equal(out_a, ref_a)
+    assert pages_after == 0
+
+
 # -- lifecycle / backpressure / observability -------------------------------
 
 def test_backpressure_rejects_at_queue_depth(model):
@@ -337,6 +370,47 @@ def test_stats_shape_and_counters(model):
     assert s["ttft_ms"]["count"] >= 1
     assert monitor.stat_get("STAT_gen_steps") > s0_steps
     assert monitor.stat_get("STAT_gen_completions") >= 1
+
+
+@pytest.mark.parametrize("prefix_cache", [False, True],
+                         ids=["private-pages", "shared-prefix-pages"])
+def test_greedy_tokens_identical_on_both_sides_of_the_pool_rule(
+        model, prefix_cache):
+    """The decode attention is chosen by shape (`ops/paged_ops.py`): a pool
+    of max_slots * pages_per_seq pages is scored pool-dense, one of 8 pages
+    more takes the gather reference. Same prompts, same greedy tokens — and
+    with the prefix cache on, rows that SHARE physical pages (each owning
+    them at its own table entry) read them through the ownership mask."""
+    rng = np.random.RandomState(5)
+    head = rng.randint(0, 512, size=8)          # two full pages of 4
+    prompts = [np.concatenate([head, rng.randint(0, 512, size=n)])
+               .astype("int64") for n in (3, 6, 1, 5)]
+    M, PP = 2, 128 // 4                         # tiny: 128 positions
+    outs, paths = {}, {}
+    for num_pages in (M * PP, M * PP + 8):
+        p0, r0, h0 = (monitor.stat_get("STAT_paged_attn_pool"),
+                      monitor.stat_get("STAT_paged_attn_reference"),
+                      monitor.stat_get("STAT_prefix_hits"))
+        with _engine(model, num_pages=num_pages, prefill_buckets=(8, 16),
+                     prefix_cache=prefix_cache) as eng:
+            futs = [eng.submit(p, max_new_tokens=9) for p in prompts]
+            outs[num_pages] = [f.result(timeout=120) for f in futs]
+            s = eng.stats()
+        paths[num_pages] = s["decode_attention"]
+        assert s["compiles"]["decode[m=2]"] == 1
+        assert (monitor.stat_get("STAT_prefix_hits") > h0) == prefix_cache
+        traced = (monitor.stat_get("STAT_paged_attn_pool") - p0,
+                  monitor.stat_get("STAT_paged_attn_reference") - r0)
+        # one trace per layer of the ONE decode program (tiny: 2 layers);
+        # the tail-prefill programs count as reference on both sides
+        assert traced[0] == (2 if paths[num_pages] == "pool" else 0)
+        assert traced[1] >= (0 if paths[num_pages] == "pool" else 2)
+    assert paths == {M * PP: "pool", M * PP + 8: "reference"}
+    for a, b, p in zip(outs[M * PP], outs[M * PP + 8], prompts):
+        np.testing.assert_array_equal(a, b)
+        ref = model.generate(paddle.to_tensor(p[None]),
+                             max_new_tokens=9).numpy()[0]
+        np.testing.assert_array_equal(a, ref)
 
 
 def test_latency_report_summarizes_gen_spans(model, tmp_path, capsys):

@@ -286,10 +286,30 @@ def lowered_programs(gpt):
         eng.shutdown()
 
 
+def test_gen_decode_on_the_gather_side_of_the_pool_rule_keeps_its_scopes(gpt):
+    """A pool of more pages than max_slots x pages_per_seq takes the
+    gather reference (`ops/paged_ops.py`): `kv_gather` under each layer,
+    no `kv_mask`."""
+    eng = tiny_engine(gpt, "spans_gather", num_pages=72, warmup=False)
+    try:
+        assert eng.stats()["decode_attention"] == "reference"
+        module, stacks = scopes_of(eng._decode_jit.lower(
+            eng._W, *eng._pools(), *eng._step_arrays()))
+    finally:
+        eng.shutdown()
+    assert module == "jit_gen_decode"
+    for s in ("layer_0/attn/kv_write/", "layer_0/attn/kv_gather/",
+              "layer_1/attn/kv_attend/"):
+        assert has(stacks, f"/{s}"), (s, sorted(stacks)[:40])
+    assert not has(stacks, "kv_mask/")
+
+
 @pytest.mark.parametrize("program, scopes", [
-    ("gen_decode", ("embed/", "layer_0/attn/kv_write/",
-                    "layer_0/attn/kv_gather/", "layer_0/attn/kv_attend/",
-                    "layer_1/mlp/", "lm_head/", "sample/")),
+    # the fixture's engine (64 pages = 2 slots x 32 entries) is pool-dense:
+    # one `kv_mask` at the program's top, no gather
+    ("gen_decode", ("embed/", "layer_0/attn/kv_write/", "kv_mask/",
+                    "layer_0/attn/kv_attend/", "layer_1/mlp/", "lm_head/",
+                    "sample/")),
     ("gen_prefill", ("embed/", "layer_0/attn/", "layer_1/mlp/", "kv_write/",
                      "lm_head/")),
     ("gen_prefill_tail", ("kv_gather/", "layer_0/attn/kv_attend/",
@@ -306,6 +326,9 @@ def test_an_engine_program_carries_its_name_and_scopes(lowered_programs,
                                                        program, scopes):
     module, stacks = lowered_programs[program]
     assert module == "jit_" + program
+    if program == "gen_decode":
+        assert not has(stacks, "kv_gather/") \
+            and not has(stacks, "attn/kv_mask/")
     for s in scopes:
         assert has(stacks, f"jit({program})/{s}") \
             or has(stacks, f"/{s}"), (s, sorted(stacks)[:40])

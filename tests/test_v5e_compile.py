@@ -6,6 +6,7 @@ fixture that skips, never at import, and every such test lives in this one
 file (only one process at a time may load the TPU's library).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,3 +72,71 @@ def test_flash_compiles_for_the_v5e_at_the_train_cells_shapes(
     assert text.count("tpu_custom_call") >= len(kernels)
     for name in kernels:        # the kernels' own names, as a trace shows
         assert name in text, name
+
+
+# -- the serve cell's decode program (gpt2-xl.batch-saturated) --------------
+# 48 layers, 25 heads of 64, vocabulary 50257, float32; 16 slots, pages of
+# 16 tokens, 128 pages, 64 table entries (benchmark/configs/gpt2-xl.json)
+GEN_L, GEN_V, GEN_E, GEN_H = 48, 50257, 1600, 25
+GEN_SLOTS, GEN_PAGE, GEN_PAGES, GEN_PP = 16, 16, 128, 64
+# `temp_size_in_bytes` of the same program at the parent of PR 26 (the
+# per-slot gather), compiled here for the same described chip
+GEN_PARENT_TEMP = 5_175_807_488
+
+
+def test_gen_decode_reads_the_pool_once_at_the_serve_cells_shapes(
+        one_chip, uncached):
+    """The engine's own `gen_decode`, lowered at the cell's shapes: the
+    closure depends on heads, page size and the pool's page count, not on
+    depth or vocabulary, so a one-layer engine builds it and the 48-layer
+    shapes go in as arguments. Pool-dense by the shape rule (128 pages
+    <= 16 x 64): no per-slot gather of the 1,024-position extent, the
+    ownership mask computed once and outside the layers, no more
+    temporaries than the gather path needed."""
+    from paddle_tpu import serving
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    net = GPTForCausalLM(GPTConfig(
+        vocab_size=256, hidden_size=GEN_E, num_heads=GEN_H, num_layers=1,
+        intermediate_size=4 * GEN_E, max_position_embeddings=1024,
+        dropout=0.0))
+    net.eval()
+    eng = serving.GenerationEngine(
+        net, max_slots=GEN_SLOTS, page_size=GEN_PAGE, num_pages=GEN_PAGES,
+        prefill_buckets=(128,), warmup=False, name="v5e_compile_probe")
+    try:
+        assert eng.stats()["decode_attention"] == "pool"
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(tuple(shape), dtype,
+                                        sharding=one_chip)
+
+        def like(a):
+            return sds(a.shape, a.dtype)
+
+        W1 = eng._W
+        W = {"wte": sds((GEN_V, GEN_E), jnp.float32), "wpe": like(W1["wpe"]),
+             "lnf": tuple(like(a) for a in W1["lnf"]),
+             "blocks": [tuple(like(a) for a in W1["blocks"][0])
+                        for _ in range(GEN_L)]}
+        pool = sds((GEN_L,) + tuple(eng._kp.shape[1:]), eng._kp.dtype)
+        key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
+        M = GEN_SLOTS
+        compiled = eng._decode_jit.lower(
+            W, pool, pool, sds((M, GEN_PP), jnp.int32), sds((M,), jnp.int32),
+            sds((M,), jnp.int32), sds((M,), jnp.bool_),
+            sds((M,), jnp.float32), sds((M,), jnp.bool_),
+            sds(key.shape, key.dtype)).compile()
+    finally:
+        eng.shutdown(drain=False)
+    text = compiled.as_text()
+    assert "kv_attend" in text and "kv_mask" in text    # names reach the text
+    assert "kv_gather" not in text
+    assert f"f32[{M},{GEN_H},1024,64]" not in text      # the gathered K / V
+    # one mask: built at the program's top, never under a layer's scope, and
+    # its [slots, entries, pages] compare appears once
+    assert not re.search(r"layer_\d+/attn/kv_mask", text)
+    owns = [ln for ln in text.splitlines()
+            if "kv_mask/eq" in ln and " compare(" in ln]
+    assert len(owns) == 1 and f"pred[{M},{GEN_PP},{GEN_PAGES}]" in owns[0]
+    assert compiled.memory_analysis().temp_size_in_bytes <= GEN_PARENT_TEMP
