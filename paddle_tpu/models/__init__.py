@@ -3,3 +3,4 @@ from .bert import (BertConfig, BertForPretraining,
 from .ernie import (ErnieConfig, ErnieForPretraining,
                     ErnieForSequenceClassification, ErnieModel, tp_annotate)
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel, MoEFeedForward
+from .glm_moe import GlmMoeLiteConfig, GlmMoeLiteForCausalLM

@@ -537,7 +537,12 @@ class GPTForCausalLM(nn.Layer):
         than fp32)."""
         gpt = self.gpt
         if gpt.config.use_moe:
-            raise NotImplementedError("generate() with MoE blocks")
+            raise NotImplementedError(
+                "decode_weights()/generate()/GenerationEngine with "
+                "GPTConfig(use_moe=True): MoEFeedForward is a training "
+                "layer (top-1, dense one-hot dispatch). The served expert "
+                "model is models/glm_moe.py (GlmMoeLiteForCausalLM: "
+                "routed + shared experts, grouped matmul)")
 
         def w(lin):
             leaf = getattr(lin, "quant_decode_leaf", None)
@@ -558,6 +563,12 @@ class GPTForCausalLM(nn.Layer):
                 w(blk.mlp[2]), blk.mlp[2].bias._value)
                 for blk in gpt.blocks],
         }
+
+    def decode_family(self):
+        """What `serving.GenerationEngine` asks a model for
+        (serving/decode_family.py)."""
+        from ..serving.gpt_family import GPTFamily
+        return GPTFamily(self)
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,
                  top_k=None, temperature=1.0, seed=0):

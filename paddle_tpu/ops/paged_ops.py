@@ -67,6 +67,8 @@ __all__ = ["cached_attention", "paged_attention", "paged_attention_path",
            "paged_pool_attention", "paged_pool_dense_supported",
            "paged_pool_mask",
            "paged_gather_layers", "paged_gather_quantized",
+           "latent_pool_width", "paged_latent_attention",
+           "paged_latent_write",
            "paged_prefix_attention", "paged_write",
            "paged_write_quantized", "page_rows_for_positions",
            "sharded_paged_attention"]
@@ -522,3 +524,88 @@ def paged_prefix_attention(q, kb, vb, k_tail, v_tail, prefix_len, scale):
     p = jax.nn.softmax(jnp.concatenate([sp, st], axis=-1), axis=-1)
     return (jnp.einsum("bhst,bhtd->bhsd", p[..., :T], vb)
             + jnp.einsum("bhst,bhtd->bhsd", p[..., T:], v_tail))
+
+
+# -- latent pools (MLA) -----------------------------------------------------
+#
+# A latent-attention model (models/glm_moe.py) caches ONE row per token and
+# layer, with no head axis: `[c_kv after its norm | k_r after RoPE]`,
+# `kv_rank + rope` values (576 for GLM-4.7-Flash). The pool is
+# `[L, N, P, Rp]`; pages, tables, the scratch page and zero-on-free are the
+# head pools'. Decode attends with the up-projection ABSORBED into the
+# query, so all the heads of a slot score against the same rows: one
+# gather of the slot's table a layer, shared by its heads.
+#
+# The pool's rows are `latent_pool_width(R)` wide: R rounded up to whole
+# 128-lane tiles (640 for 576), the extra lanes zero. With a 576-wide minor
+# dimension XLA:TPU lays the pool out with the PAGE axis in the lanes and
+# every program then copies the whole pool to row-major on entry and back on
+# exit (compiled for the v5e, PR 27: `jit_gen_zero_pages` 1.17 GB of
+# temporaries against 0, the decode step two 1.06 GB copies) — the head
+# pools' relay of PERF.md PR 24-26. Writes pad the row, the score product
+# pads the query, and the values read the first `kv_rank` lanes.
+
+
+def latent_pool_width(latent_dim: int) -> int:
+    """Lanes one cached row takes in the pool: whole 128-lane tiles."""
+    return -(-int(latent_dim) // 128) * 128
+
+
+def _pad_lanes(x, width):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+@jax.named_scope("latent_write")
+def paged_latent_write(pool, layer, page_ids, offsets, rows):
+    """Scatter cached rows into a latent pool `[L, N, P, R]`. Decode
+    (`layer` an int): page_ids/offsets [B], rows [B, R]. Prefill
+    (`layer=None`): page_ids/offsets [S], rows [L, S, R]."""
+    rows = _pad_lanes(rows.astype(pool.dtype), pool.shape[-1])
+    if layer is None:
+        # one scatter a layer: a single scatter over the layer axis makes
+        # XLA:TPU relay the whole pool to a layers-minor layout and back
+        # (compiled for the v5e, PR 27: two 1.17 GB copies a prefill)
+        for i in range(pool.shape[0]):
+            pool = pool.at[i, page_ids, offsets, :].set(rows[i])
+        return pool
+    return pool.at[layer, page_ids, offsets, :].set(rows)
+
+
+def paged_latent_attention(q, pool, page_table, pos, scale, kv_rank):
+    """Absorbed-weight decode attention over ONE layer of a latent pool.
+
+    q [B, H, R]: per head `[q_nope . W_UK | q_rope]`; pool [N, P, Rp],
+    Rp = `latent_pool_width(R)`; page_table [B, PP]; pos [B]. Returns
+    [B, H, kv_rank] float32: the probability-weighted sum of the rows'
+    first `kv_rank` values (the caller multiplies by W_UV). Scores and
+    softmax are float32.
+
+    Each slot gathers the rows of its own table, `[B, PP*P, Rp]`, ONCE for
+    all its heads — a latent row has no head axis, so the gather is H times
+    smaller than the head pools' and the slot's H heads score against its
+    own PP*P rows only. Pool-dense under the page-ownership mask (the head
+    pools' `paged_pool_attention`) scores every slot's heads against the
+    WHOLE pool, B times the products; measured end to end in the cell that
+    serves this family (PERF.md PR 27: 32 slots x 20 heads, 8,192 pages =
+    32 x 256 entries) it took 33.7 ms a decode step against the gather's
+    31.0, so it is not built for latent pools. Row isolation: a position
+    past `pos` is dropped by `where`, the values are multiplied as a
+    finite copy, and a slot that attends a non-finite cached row reads NaN
+    — its owner trips the engine's flag, nobody else."""
+    monitor.stat_add("STAT_paged_attn_latent")     # traces, not calls
+    R = pool.shape[-1]
+    with jax.named_scope("latent_attend"):
+        q = _pad_lanes(q, R)
+        rows = jnp.take(pool, page_table, axis=0)           # [B, PP, P, R]
+        rows = rows.reshape(page_table.shape[0], -1, R)
+        s = jnp.einsum("bhr,btr->bht", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        valid = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]  # [B, T]
+        p = jax.nn.softmax(jnp.where(valid[:, None, :], s, -1e30), axis=-1)
+        v = rows[..., :kv_rank]
+        finite = jnp.isfinite(v)
+        out = jnp.einsum("bht,btc->bhc", p.astype(v.dtype),
+                         jnp.where(finite, v, 0),
+                         preferred_element_type=jnp.float32)
+        bad = jnp.any(valid & ~jnp.all(finite, axis=-1), axis=-1)    # [B]
+        return jnp.where(bad[:, None, None], jnp.nan, out)

@@ -62,6 +62,13 @@ oldest overwritten — the same bounding discipline as the trace rings):
     admit_wait_ms sum, over the requests admitted THIS iteration, of
                   admitted − queued (GenSpan stamps): with `admitted`
                   the mean wait from submission to a slot and pages
+    experts_hit / latent_rows
+                  counted ON THE DEVICE by the latent-attention family's
+                  decode program and read back with the step's tokens
+                  (ISSUE 27; 0 for a family that counts nothing):
+                  distinct experts that got at least one live token,
+                  summed over the expert layers, and cached positions the
+                  step attended, summed over the live slots
 
 The fit loop has a record of its own (`FitRecord`, ISSUE 25): one per
 train step into ONE process-wide `FitLog` ring of the same kind, read
@@ -149,7 +156,11 @@ _FIELDS = ("it", "step", "t", "live", "admitted", "completed", "expired",
            # (sub-splits: the six buckets and their exact sum are as
            # they were), and how long this iteration's admissions had
            # queued — appended, by the same era rule
-           "decode_wait_ms", "prefill_wait_ms", "admit_wait_ms")
+           "decode_wait_ms", "prefill_wait_ms", "admit_wait_ms",
+           # ISSUE 27: what a family's decode program counts on the
+           # device and returns with the tokens (a family that counts
+           # nothing leaves them 0) — appended, by the same era rule
+           "experts_hit", "latent_rows")
 
 _FIT_FIELDS = ("fit", "step", "t", "input_wait_ms", "prep_ms",
                "dispatch_ms", "sync_ms", "callback_ms", "other_ms",

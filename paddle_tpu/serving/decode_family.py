@@ -1,0 +1,99 @@
+"""Decode families: what `GenerationEngine` asks a model for.
+
+The engine's scheduler, page allocator, streams, step ring and supervisor
+know pages, slots and tokens, and no model. Everything that depends on what
+a model IS — its weight pytree, what one cached token looks like, the bodies
+of the prefill / decode / zero-pages programs, the largest position it can
+be asked for, and which of the engine's options it can serve — comes from
+the model's family, which the model hands over from `decode_family()`:
+
+    name             "gpt" (serving/gpt_family.py), "latent"
+                     (serving/latent_family.py)
+    max_position     largest number of positions of one sequence
+    step_counters    names of the `StepRecord` fields the decode program
+                     counts on the device: it returns them as ONE int32
+                     vector after the tokens and the poison flags, and the
+                     step thread sums them into the iteration's record
+    weights()        the weight pytree the programs take as an ARGUMENT; it
+                     aliases the parameters' arrays
+    dtype(W)         the dtype the model computes in ("auto" pages take it)
+    check(cfg, tp)   raises a named InvalidArgumentError for an option of
+                     the engine that the family does not build
+    shard_weights(W, mesh)   (a family whose `check` admits tp > 1)
+    make_cache(cfg, kv_dtype, mesh) -> PagedKVCache
+                     the pools are the cache's `pools`, in the order the
+                     programs take and return them
+    decode_attention(cfg, tp, pools) -> str
+                     the name `stats()["decode_attention"]` shows
+    key_material()   what of the model shapes the programs (program store)
+    build(ctx)       the program bodies by name: "prefill", "decode",
+                     "zero_pages" always; "prefill_tail", "cow_copy",
+                     "verify", "tier_gather", "tier_write" where the family
+                     serves the option (else None). Signatures:
+                       prefill(W, *pools, pt_row, ids, length)
+                           -> (*pools, logits of the last real position)
+                       decode(W, *pools, pt, tok, pos, active, temps,
+                              smask, key) -> (*pools, next, bad[, counters])
+                       zero_pages(*pools, pages) -> pools
+"""
+from __future__ import annotations
+
+from ..framework import monitor
+from ..framework.errors import InvalidArgumentError
+
+__all__ = ["ProgramContext", "config_items", "family_of", "sample_next"]
+
+
+class ProgramContext:
+    """What a family's `build` sees of the engine: configuration, mesh,
+    the pools' count and the compile ledger's `note` — scalars and the
+    LEDGER, never the engine object: the program pack outlives any one
+    incarnation, and a closure pinning a dead engine would pin its pools."""
+
+    def __init__(self, cfg, tp, mesh, npool, quant, decode_attention, W,
+                 ledger):
+        self.cfg, self.tp, self.mesh = cfg, tp, mesh
+        self.npool, self.quant = npool, quant
+        self.decode_attention, self.W = decode_attention, W
+
+        def note(key: str):
+            # runs at TRACE time only (python side effect under jit),
+            # so the pack-owned ledger counts compiles exactly — the
+            # same accounting trick as Predictor.compile_count
+            ledger[key] = ledger.get(key, 0) + 1
+            monitor.stat_add("STAT_gen_compiles")
+        self.note = note
+
+
+def config_items(config) -> dict:
+    """A model configuration as sorted JSON-able items: what of the model
+    shapes the programs (the program store's key material)."""
+    return {k: v for k, v in sorted(vars(config).items())}
+
+
+def sample_next(logits, active, temps, smask, key, top_k):
+    """The tail of every family's decode program: greedy or sampled next
+    token per slot (0 for an empty slot) and the per-slot poison flag.
+    logits [M, V]; returns (next [M] int32, bad [M] bool)."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, -1).astype(jnp.int32)
+        lg = logits / jnp.maximum(temps[:, None], 1e-6)
+        if top_k:
+            kth = jax.lax.top_k(lg, int(top_k))[0][..., -1:]
+            lg = jnp.where(lg < kth, -1e30, lg)
+        sampled = jax.random.categorical(key, lg).astype(jnp.int32)
+        nxt = jnp.where(smask, sampled, greedy)
+        bad = active & ~jnp.all(jnp.isfinite(logits), axis=-1)
+        return jnp.where(active, nxt, 0), bad
+
+
+def family_of(model):
+    make = getattr(model, "decode_family", None)
+    if make is None:
+        raise InvalidArgumentError(
+            f"GenerationEngine serves a model that has a decode family "
+            f"(models.GPTForCausalLM, models.GlmMoeLiteForCausalLM); got "
+            f"{type(model).__name__}")
+    return make()

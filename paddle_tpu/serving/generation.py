@@ -109,7 +109,8 @@ from ..profiler import (RecordEvent, audit, device_telemetry, exporter,
                         flight_recorder, slo, spans, step_log,
                         timeseries, trace_context)
 from . import failpoints
-from .kv_cache import TRASH_PAGE, PagedKVCache
+from .decode_family import ProgramContext, family_of
+from .kv_cache import TRASH_PAGE
 from .kv_tier import HostTier
 from .prefix_cache import PrefixCache
 from .spec_decode import NGramProposer
@@ -153,7 +154,8 @@ class GenerationConfig:
                  program_store: Optional[str] = None,
                  program_store_force: Optional[bool] = None,
                  tp: Optional[int] = None,
-                 top_k: int = 0, seed: int = 0, warmup: bool = True):
+                 top_k: int = 0, seed: int = 0, warmup: bool = True,
+                 gc_freeze: bool = False):
         self.max_slots = int(flag("FLAGS_gen_max_slots")
                              if max_slots is None else max_slots)
         if self.max_slots < 1:
@@ -256,6 +258,16 @@ class GenerationConfig:
         self.top_k = int(top_k)
         self.seed = int(seed)
         self.warmup = bool(warmup)
+        # once warmed, collect and `gc.freeze()`: everything alive then
+        # (jax, the programs, the weights' pytrees: some 150k container
+        # objects) leaves the collector's sight, so a full (generation-2)
+        # collection during serving walks the requests' objects and not
+        # the process — unfrozen it takes ~100 ms with every thread of the
+        # process waiting for the GIL, the step thread included (read on
+        # the chip, PERF.md PR 27: two stalls of 90 and 113 ms in a 20 s
+        # window, 118 ms a collection on the CPU). Process-wide, so it is
+        # the deployment's choice and off by default; `shutdown` unfreezes.
+        self.gc_freeze = bool(gc_freeze)
 
 
 class TokenStream:
@@ -479,9 +491,18 @@ class _ProgramPack:
         self.tier_write = tier_write
 
 
+def _pool_view(i):
+    """Property over entry `i` of an engine's pool list."""
+    return property(lambda self: self._pool_arrays[i],
+                    lambda self, v: self._pool_arrays.__setitem__(i, v))
+
+
 class GenerationEngine:
-    """Token-level continuous-batching front-end over a
-    `models.GPTForCausalLM`.
+    """Token-level continuous-batching front-end over a model that has a
+    decode family (`serving/decode_family.py`): the head-pool family
+    builds every option below, the latent-pool family prefill, decode and
+    zero-pages — an option a family does not build is refused by name at
+    construction. Nothing below the family seam knows a model.
 
     `submit(prompt_ids, ...)` returns a `concurrent.futures.Future`
     resolving to the full token sequence (prompt + generated, numpy
@@ -532,21 +553,16 @@ class GenerationEngine:
         self.incarnation = int(incarnation)
         self._on_death = on_death
         carry = _carryover or {}
-        from ..models.gpt import GPTForCausalLM
-        if not isinstance(model, GPTForCausalLM):
-            raise InvalidArgumentError(
-                f"GenerationEngine serves a models.GPTForCausalLM "
-                f"(got {type(model).__name__})")
+        # everything that depends on what the model IS comes from its
+        # decode family (serving/decode_family.py); a model without one
+        # is refused by name
+        self._family = family = family_of(model)
         self._model = model
-        mcfg = model.gpt.config
         pack: Optional[_ProgramPack] = carry.get("pack")
-        # raises for MoE; a resurrection reuses the pack's exact weight
-        # pytree so the rebuilt programs see identical leaves
-        self._W = pack.W if pack is not None else model.decode_weights()
-        self._H = mcfg.num_heads
-        self._D = mcfg.hidden_size // mcfg.num_heads
-        self._scale = 1.0 / self._D ** 0.5
-        self._max_position = mcfg.max_position_embeddings
+        # a resurrection reuses the pack's exact weight pytree so the
+        # rebuilt programs see identical leaves
+        self._W = pack.W if pack is not None else family.weights()
+        self._max_position = family.max_position
         # mesh-slice lane (ISSUE 19): tp > 1 generalizes the lane from
         # one chip to a mesh slice — every program rebuilds as a
         # shard_map program over the 'tp' axis with projections and KV
@@ -569,16 +585,13 @@ class GenerationEngine:
             else:
                 self._mesh = None
         self._cfg.tp = self._tp
-        if self._H % self._tp != 0:
-            raise InvalidArgumentError(
-                f"num_heads={self._H} not divisible by tp={self._tp} — "
-                f"head-sharded lanes need equal slices")
+        # the family refuses, by name, an option it does not build
+        family.check(self._cfg, self._tp)
         if self._tp > 1 and pack is None:
             # one-time placement: head-sharded projection leaves,
             # replicated embeddings/LNs (a resurrection's pack.W is
             # already placed — reuse keeps leaves identical)
-            from ..models.gpt import shard_decode_weights
-            self._W = shard_decode_weights(self._W, self._mesh)
+            self._W = family.shard_weights(self._W, self._mesh)
         if self._cfg.pages_per_seq <= 0:
             self._cfg.pages_per_seq = -(-self._max_position
                                         // self._cfg.page_size)
@@ -598,23 +611,20 @@ class GenerationEngine:
             # the pools below are allocated under the same device
             import jax
             self._W = jax.device_put(self._W, device)
-        dtype = np.asarray(self._W["lnf"][0]).dtype
-        kv_dtype = (str(dtype) if self._cfg.kv_cache_dtype == "auto"
+        kv_dtype = (str(family.dtype(self._W))
+                    if self._cfg.kv_cache_dtype == "auto"
                     else self._cfg.kv_cache_dtype)
         with self._dev_ctx():
-            self._cache = PagedKVCache(
-                mcfg.num_layers, self._H, self._D, self._cfg.page_size,
-                self._cfg.num_pages, self._cfg.pages_per_seq,
-                dtype=kv_dtype, mesh=self._mesh)
+            self._cache = family.make_cache(self._cfg, kv_dtype, self._mesh)
         # int8 page mode: quantize-on-append decode/prefill programs
         # thread the parallel scale pools (donated alongside the pages);
         # everything above this line — admission arithmetic, page
         # tables, zero-on-free, the compile ledger — is dtype-blind
         self._quant_kv = self._cache.quantized
-        self._kp = self._cache.k_pages
-        self._vp = self._cache.v_pages
-        self._ks = self._cache.k_scales
-        self._vs = self._cache.v_scales
+        # the donated device pools, in the order the family's programs
+        # take and return them (head pools: K, V[, K scales, V scales];
+        # a latent pool: the one)
+        self._pool_arrays = list(self._cache.pools)
         # prefix cache (ISSUE 12): content-hash chain index over the
         # refcounted pages; None keeps the PR 8 ownership semantics
         # exactly (every page refcount 1, nothing cached or shared)
@@ -660,6 +670,9 @@ class GenerationEngine:
         # BEFORE the futures, so a stream's final token always precedes
         # its future's resolution (step-thread only)
         self._stream_q: List[tuple] = []
+        # (stream_q, resolve_q) pairs whose iteration's record has landed,
+        # waiting for the next program's read-back (see _release_staged)
+        self._released: List[tuple] = []
         self._warmed = False
         self._steps_total = 0
         self._prefills_total = 0
@@ -714,7 +727,8 @@ class GenerationEngine:
                     "promote_ms": 0.0,
                     "attr_idle_ms": 0.0, "attr_sched_ms": 0.0,
                     "attr_wall_ms": 0.0, "decode_wait_ms": 0.0,
-                    "prefill_wait_ms": 0.0, "admit_wait_ms": 0.0}
+                    "prefill_wait_ms": 0.0, "admit_wait_ms": 0.0,
+                    **dict.fromkeys(family.step_counters, 0)}
         # published BEFORE the step thread exists so a router polling a
         # freshly built replica reads a truthful empty-engine snapshot
         self._pressure = self._compute_pressure()
@@ -733,6 +747,10 @@ class GenerationEngine:
             if self._cfg.warmup:
                 self._warmup()
             self._warmed = True
+            if self._cfg.gc_freeze:
+                import gc
+                gc.collect()
+                gc.freeze()
             self._thread = threading.Thread(
                 target=self._loop, daemon=True, name=f"{name}-genstep")
             self._thread.start()
@@ -751,30 +769,27 @@ class GenerationEngine:
     # -- jitted programs ---------------------------------------------------
 
     def _pools(self):
-        """The donated device-pool tuple the jitted programs thread:
-        (k_pages, v_pages) — plus the parallel scale pools in the int8
-        page mode."""
-        if self._quant_kv:
-            return (self._kp, self._vp, self._ks, self._vs)
-        return (self._kp, self._vp)
+        """The donated device-pool tuple the jitted programs thread, as
+        the family's cache laid it out: (k_pages, v_pages) plus the
+        parallel scale pools in the int8 page mode, or the one latent
+        pool."""
+        return tuple(self._pool_arrays)
 
     def _set_pools(self, pools):
-        if self._quant_kv:
-            self._kp, self._vp, self._ks, self._vs = pools
-        else:
-            self._kp, self._vp = pools
+        pools = list(pools)
+        assert len(pools) == len(self._pool_arrays)
+        self._pool_arrays = pools
+
+    # head-pool views by position (tier staging, tests): K, V and the
+    # int8 mode's two scale pools
+    _kp, _vp, _ks, _vs = map(_pool_view, range(4))
 
     def _build_programs(self, pack: Optional[_ProgramPack] = None):
-        # which of ops/paged_ops.py's three implementations the decode
-        # program's attention takes — a shape rule, so it is known
-        # before (and whether or not) anything is traced; under a tp
-        # mesh the rule sees the per-shard head count
-        from ..ops.paged_ops import paged_attention_path
-        kp = self._kp
-        self._decode_attention = paged_attention_path(
-            (self._cfg.max_slots, self._H // self._tp, kp.shape[-1]),
-            (self._H // self._tp,) + tuple(kp.shape[2:]),
-            (self._cfg.max_slots, self._cfg.pages_per_seq), kp.dtype)
+        # which attention the decode program takes — the family's shape
+        # rule, so it is known before (and whether or not) anything is
+        # traced
+        self._decode_attention = self._family.decode_attention(
+            self._cfg, self._tp, self._pools())
         if pack is not None:
             # resurrection path (ISSUE 15): adopt the previous
             # incarnation's jit wrappers and SHARE its ledger dict —
@@ -802,412 +817,38 @@ class GenerationEngine:
             self._pack = pack
             return
         import jax
-        import jax.numpy as jnp
 
-        from ..models.gpt import (gpt_decode_step, gpt_logits,
-                                  gpt_prefill, gpt_prefill_extend,
-                                  gpt_spec_verify)
-        from ..ops.paged_ops import (page_rows_for_positions,
-                                     paged_attention, paged_gather,
-                                     paged_gather_layers,
-                                     paged_gather_quantized,
-                                     paged_pool_mask,
-                                     paged_prefix_attention, paged_write,
-                                     paged_write_quantized)
-
-        tp, mesh = self._tp, self._mesh
-        # mesh-slice lane (ISSUE 19): under shard_map every closure sees
-        # PER-SHARD tensors, so H is the LOCAL head count (head_dim —
-        # and with it `scale` — is untouched by head sharding) and
-        # `psum` is the once-per-block partial-sum reduction the
-        # row-parallel projections apply before their replicated bias
-        H = self._H // tp
-        P, scale = self._cfg.page_size, self._scale
-        psum = (lambda x: jax.lax.psum(x, "tp")) if tp > 1 else None
-        top_k = self._cfg.top_k
-        quant = self._quant_kv
-        # pools per program signature: (kp, vp) or (kp, vp, ks, vs) —
-        # the int8 mode's scale pools ride (and are donated) alongside
-        # the pages so quantize-on-append updates both in place
-        NP = self._npool = 4 if quant else 2
         # the trace-time closures capture the LEDGER and scalars, never
-        # the engine object: the pack outlives any one incarnation, and
-        # a closure pinning the dead engine would pin its pools too
-        ledger = self._ledger
-        max_position = self._max_position
+        # the engine object (ProgramContext). The programs' names are
+        # fixed on purpose (gen_prefill, gen_prefill_tail, gen_decode,
+        # gen_verify, gen_zero_pages, gen_cow_copy, gen_tier_gather,
+        # gen_tier_write): a profiler trace's `XLA Modules` line reads
+        # `jit_gen_decode(...)`, and tools/trace_report.py sums device
+        # time per program by them
+        NP = self._npool = len(self._pool_arrays)
+        fns = self._family.build(ProgramContext(
+            self._cfg, self._tp, self._mesh, NP, self._quant_kv,
+            self._decode_attention, self._W, self._ledger))
 
-        # the programs' names are fixed on purpose (gen_prefill,
-        # gen_prefill_tail, gen_decode, gen_verify, gen_zero_pages,
-        # gen_cow_copy, gen_tier_gather, gen_tier_write): a profiler
-        # trace's `XLA Modules` line reads `jit_gen_decode(...)`, and
-        # tools/trace_report.py sums device time per program by them
-
-        def note(key: str):
-            # runs at TRACE time only (python side effect under jit),
-            # so the pack-owned ledger counts compiles exactly — the
-            # same accounting trick as Predictor.compile_count
-            ledger[key] = ledger.get(key, 0) + 1
-            monitor.stat_add("STAT_gen_compiles")
-
-        def write_pages(pools, layer, page_ids, offs, k, v,
-                        requant=False):
-            # requant=True only in the tail program: a CoW split page
-            # arrives with content + scale, every other prefill target
-            # is freshly zeroed (trace-time switch — the full-prefill
-            # program carries no whole-page requant traffic)
-            if quant:
-                kp, vp, ksc, vsc = pools
-                kp, ksc = paged_write_quantized(kp, ksc, layer, page_ids,
-                                                offs, k, requant=requant)
-                vp, vsc = paged_write_quantized(vp, vsc, layer, page_ids,
-                                                offs, v, requant=requant)
-                return (kp, vp, ksc, vsc)
-            kp, vp = pools
-            # a forced narrower page dtype (kv_cache_dtype="bfloat16"
-            # under an fp32 model) is a deliberate storage downcast
-            return (paged_write(kp, layer, page_ids, offs,
-                                k.astype(kp.dtype)),
-                    paged_write(vp, layer, page_ids, offs,
-                                v.astype(vp.dtype)))
-
-        def gen_prefill(W, *rest):
-            pools, (pt_row, ids, length) = rest[:NP], rest[NP:]
-            note(f"prefill[b={ids.shape[1]}]")
-            h, ks, vs = gpt_prefill(W, ids, num_heads=H, scale=scale,
-                                    reduce=psum)
-            S_b = ids.shape[1]
-            pos = jnp.arange(S_b)
-            page_ids, offs = page_rows_for_positions(pt_row, pos, P)
-            # bucket-pad tail positions (pos >= length) write to the
-            # reserved scratch page, never the sequence's own pages —
-            # the documented contract, and load-bearing in the int8
-            # mode: the scatter-max page scales must not bake pad-token
-            # K/V magnitudes into a real page's quantization grid (the
-            # grid only ever widens, so the pollution would be
-            # permanent; fp32 merely overwrites the junk later)
-            valid = pos < length
-            page_ids = jnp.where(valid, page_ids, TRASH_PAGE)
-            offs = jnp.where(valid, offs, 0)
-            pools = write_pages(pools, None, page_ids, offs,
-                                ks[:, 0], vs[:, 0])
-            idx = jnp.clip(length - 1, 0, S_b - 1)
-            return (*pools, gpt_logits(W, h[0, idx]))
-
-        def gen_prefill_tail(W, *rest):
-            """Prefix-hit prefill: only the prompt TAIL runs the model —
-            queries attend the cached prefix pages READ-ONLY plus their
-            own in-flight K/V, and the writes land in the tail's pages
-            (bucket-pad positions routed to the scratch page, exactly
-            the full-prefill contract — a shared page never receives a
-            pad write). One compiled program per tail bucket."""
-            pools = rest[:NP]
-            pt_row, ids, length, offset = rest[NP:]
-            note(f"prefill_tail[b={ids.shape[1]}]")
-            S_b = ids.shape[1]
-            ar = jnp.arange(S_b)
-            valid = ar < length
-            # pad positions clamp to 0 so neither the wpe gather nor the
-            # page-index arithmetic ever reads out of range; their
-            # writes go to the scratch page below regardless
-            positions = jnp.where(valid, offset + ar, 0)
-            # gather the sequence's cached pages ONCE across all layers
-            # (dequantizing in the int8 mode) — per-layer pool slices
-            # would copy the whole layer buffer per layer, costing more
-            # than the tail's compute
-            if quant:
-                kp, vp, ksc, vsc = pools
-                kb_all = paged_gather_layers(kp, pt_row, ksc)
-                vb_all = paged_gather_layers(vp, pt_row, vsc)
-            else:
-                kp, vp = pools
-                kb_all = paged_gather_layers(kp, pt_row)
-                vb_all = paged_gather_layers(vp, pt_row)
-
-            def ctx_attend(layer, q, k, v):
-                return paged_prefix_attention(
-                    q, kb_all[layer][None], vb_all[layer][None],
-                    k, v, offset, scale)
-
-            h, ks, vs = gpt_prefill_extend(W, ids, positions, ctx_attend,
-                                           num_heads=H, scale=scale,
-                                           reduce=psum)
-            page_ids, offs = page_rows_for_positions(pt_row, positions, P)
-            page_ids = jnp.where(valid, page_ids, TRASH_PAGE)
-            offs = jnp.where(valid, offs, 0)
-            pools = write_pages(pools, None, page_ids, offs,
-                                ks[:, 0], vs[:, 0], requant=True)
-            idx = jnp.clip(length - 1, 0, S_b - 1)
-            return (*pools, gpt_logits(W, h[0, idx]))
-
-        def gen_cow_copy(*rest):
-            """Copy-on-write page split: clone one page's content across
-            every layer/head from `src` to `dst` — including the
-            per-(layer, head, page) scale rows in the int8 mode, so the
-            private copy dequantizes identically to the shared
-            original."""
-            pools = rest[:NP]
-            src, dst = rest[NP], rest[NP + 1]
-            note("cow_copy")
-            if quant:
-                kp, vp, ksc, vsc = pools
-                return (kp.at[:, :, dst].set(kp[:, :, src]),
-                        vp.at[:, :, dst].set(vp[:, :, src]),
-                        ksc.at[:, :, dst].set(ksc[:, :, src]),
-                        vsc.at[:, :, dst].set(vsc[:, :, src]))
-            kp, vp = pools
-            return (kp.at[:, :, dst].set(kp[:, :, src]),
-                    vp.at[:, :, dst].set(vp[:, :, src]))
-
-        # the decode cache threaded through gpt_decode_step's hooks:
-        # (pools, page table, pool-dense ownership mask or None)
-        def write_kv(cache, layer, k, v, pos):
-            pools, pt, mask = cache
-            page_ids, offs = page_rows_for_positions(pt, pos, P)
-            return (write_pages(pools, layer, page_ids, offs, k, v), pt,
-                    mask)
-
-        def attend(cache, layer, q, pos):
-            pools, pt, mask = cache
-            if quant:
-                kp, vp, ksc, vsc = pools
-                return paged_attention(q, kp[layer], vp[layer], pt, pos,
-                                       scale, ksc[layer], vsc[layer])
-            kp, vp = pools
-            return paged_attention(q, kp[layer], vp[layer], pt, pos, scale,
-                                   pool_mask=mask)
-
-        pool_dense = self._decode_attention == "pool"
-
-        def gen_decode(W, *rest):
-            pools = rest[:NP]
-            pt, tok, pos, active, temps, smask, key = rest[NP:]
-            note(f"decode[m={tok.shape[0]}]")
-            # pool-dense attention: the mask depends on the table and
-            # `pos` alone, so every layer of the step shares this one
-            mask = (paged_pool_mask(pt, pos, pools[0].shape[2], P)
-                    if pool_dense else None)
-            logits, (pools, _, _) = gpt_decode_step(
-                W, tok, pos, (pools, pt, mask), write_kv, attend,
-                num_heads=H, scale=scale, reduce=psum)
-            with jax.named_scope("sample"):
-                greedy = jnp.argmax(logits, -1).astype(jnp.int32)
-                lg = logits / jnp.maximum(temps[:, None], 1e-6)
-                if top_k:
-                    kth = jax.lax.top_k(lg, int(top_k))[0][..., -1:]
-                    lg = jnp.where(lg < kth, -1e30, lg)
-                sampled = jax.random.categorical(key, lg).astype(jnp.int32)
-                nxt = jnp.where(smask, sampled, greedy)
-                bad = active & ~jnp.all(jnp.isfinite(logits), axis=-1)
-                return (*pools, jnp.where(active, nxt, 0), bad)
-
-        def gen_verify(W, *rest):
-            """Speculative verify step (ISSUE 14): score every live
-            slot's [current token + k drafts] block — k+1 positions —
-            in ONE pass over the paged cache (`gpt_spec_verify` on the
-            `_gen_block_pass` seam), accept the longest greedily-
-            agreeing draft prefix IN-GRAPH, and commit only the
-            consumed positions' K/V: rejected draft lanes, inactive
-            slots and clamped pad positions all scrub to the reserved
-            scratch page. That routing IS the rollback — a rejected
-            draft never dirties a real page, so the int8 scale grids
-            never widen from a token that was not kept and the PR 12
-            CoW/sharing invariants hold untouched (writes always land
-            past any shared prefix). Block queries attend the cached
-            pages READ-ONLY (per-slot prefix length = the slot's cache
-            position) plus the block's own in-flight K/V — the
-            `paged_prefix_attention` oracle, so greedy output is
-            token-identical to the plain decode program. Returns
-            (*pools, n_accepted [M], next_token [M], bad [M])."""
-            pools = rest[:NP]
-            pt, toks_blk, dmask, pos0, active, temps, smask, key = \
-                rest[NP:]
-            note(f"verify[k={toks_blk.shape[1] - 1}]")
-            M, K1 = toks_blk.shape
-            # pad/overflow positions clamp into wpe range; their writes
-            # are scratch-routed below regardless (the engine truncates
-            # real drafts to the request's token budget, so every
-            # CONSUMED position is in range by construction)
-            positions = jnp.clip(pos0[:, None] + jnp.arange(K1)[None, :],
-                                 0, max_position - 1)
-
-            def ctx_attend(layer, q, k, v):
-                if quant:
-                    kp, vp, ksc, vsc = pools
-                    kb = paged_gather_quantized(kp[layer], ksc[layer],
-                                                pt, q.dtype)
-                    vb = paged_gather_quantized(vp[layer], vsc[layer],
-                                                pt, q.dtype)
-                else:
-                    kp, vp = pools
-                    kb = paged_gather(kp[layer], pt)
-                    vb = paged_gather(vp[layer], pt)
-                return paged_prefix_attention(q, kb, vb, k, v, pos0,
-                                              scale)
-
-            h, ks, vs = gpt_spec_verify(W, toks_blk, positions,
-                                        ctx_attend, num_heads=H,
-                                        reduce=psum)
-            logits = gpt_logits(W, h)                       # [M, K1, V]
-            greedy = jnp.argmax(logits, -1).astype(jnp.int32)
-            # n_acc = longest prefix of drafts the model agrees with
-            # (greedy[j] is the model's token AFTER position j, so
-            # draft j+1 is accepted iff it equals greedy[j])
-            agree = (greedy[:, :-1] == toks_blk[:, 1:]) & dmask
-            n_acc = jnp.sum(jnp.cumprod(agree.astype(jnp.int32),
-                                        axis=1), axis=1).astype(jnp.int32)
-            # sampled slots take no drafts (greedy acceptance would
-            # bias the distribution); they ride the verify program as
-            # plain one-token decode with the decode program's
-            # temperature/top-k sampling expression
-            n_acc = jnp.where(smask, 0, n_acc)
-            bonus = jnp.take_along_axis(greedy, n_acc[:, None], 1)[:, 0]
-            lg0 = logits[:, 0] / jnp.maximum(temps[:, None], 1e-6)
-            if top_k:
-                kth = jax.lax.top_k(lg0, int(top_k))[0][..., -1:]
-                lg0 = jnp.where(lg0 < kth, -1e30, lg0)
-            sampled = jax.random.categorical(key, lg0).astype(jnp.int32)
-            nxt = jnp.where(smask, sampled, bonus)
-            nxt = jnp.where(active, nxt, 0)
-            consumed = jnp.arange(K1)[None, :] <= n_acc[:, None]
-            finite = jnp.all(jnp.isfinite(logits), axis=-1)  # [M, K1]
-            bad = active & jnp.any(consumed & ~finite, axis=1)
-            commit = consumed & active[:, None]
-            page_ids, offs = page_rows_for_positions(pt, positions, P)
-            page_ids = jnp.where(commit, page_ids, TRASH_PAGE)
-            offs = jnp.where(commit, offs, 0)
-            L, D = ks.shape[0], ks.shape[-1]
-            # [L, M, H, K1, D] -> [L, H, M*K1, D]: the prefill-shaped
-            # all-layers scatter
-            ksf = jnp.moveaxis(ks, 1, 2).reshape(L, H, M * K1, D)
-            vsf = jnp.moveaxis(vs, 1, 2).reshape(L, H, M * K1, D)
-            # requant=True: commits land on the slot's current partial
-            # page, which already holds content (and, int8, a non-zero
-            # scale) — the tail-prefill contract, not the fresh-page one
-            pools = write_pages(pools, None, page_ids.reshape(-1),
-                                offs.reshape(-1), ksf, vsf, requant=True)
-            return (*pools, n_acc, nxt, bad)
-
-        def gen_zero_pages(*rest):
-            # trash-padded page rows: the scratch page is re-zeroed with
-            # every free, which also scrubs poisoned prefill tails; the
-            # int8 mode resets the freed pages' SCALES too, so the next
-            # owner starts from a clean quantization grid and a poisoned
-            # page's scale can't survive its content
-            pools, pages = rest[:NP], rest[NP]
-            if quant:
-                kp, vp, ksc, vsc = pools
-                return (kp.at[:, :, pages].set(0),
-                        vp.at[:, :, pages].set(0),
-                        ksc.at[:, :, pages].set(0.0),
-                        vsc.at[:, :, pages].set(0.0))
-            kp, vp = pools
-            return (kp.at[:, :, pages].set(0.0),
-                    vp.at[:, :, pages].set(0.0))
-
-        def gen_tier_gather(*rest):
-            """Demotion gather (ISSUE 18): copy ONE page's raw blocks —
-            and, in the int8 mode, its per-(layer, head) scale rows —
-            out of the pools for the host tier. NON-donating by
-            contract: the pools are kept (the content is being copied
-            off-device, the page frees through the ordinary eviction
-            path right after), which is also why this program can never
-            ride the program store — `_selfcheck_alias` requires every
-            covered program to donate its pools."""
-            pools, page = rest[:NP], rest[NP]
-            note("tier_gather")
-            if quant:
-                kp, vp, ksc, vsc = pools
-                return (kp[:, :, page], vp[:, :, page],
-                        ksc[:, :, page], vsc[:, :, page])
-            kp, vp = pools
-            return (kp[:, :, page], vp[:, :, page])
-
-        def gen_tier_write(*rest):
-            """Promotion scatter (ISSUE 18): write one fixed-width
-            chunk of host-tier pages — raw content, raw int8 scale rows
-            — into the admission's fresh target pages. Pad rows route
-            to the reserved scratch page with zero content, the
-            standard pad contract, so the ONE compiled width
-            (kv_tier_chunk_pages) covers every promotion length with
-            zero retraces."""
-            pools = rest[:NP]
-            note(f"tier_write[w={rest[NP].shape[0]}]")
-            if quant:
-                pages, kb, vb, ksb, vsb = rest[NP:]
-                kp, vp, ksc, vsc = pools
-                return (kp.at[:, :, pages].set(jnp.moveaxis(kb, 0, 2)),
-                        vp.at[:, :, pages].set(jnp.moveaxis(vb, 0, 2)),
-                        ksc.at[:, :, pages].set(jnp.moveaxis(ksb, 0, 2)),
-                        vsc.at[:, :, pages].set(jnp.moveaxis(vsb, 0, 2)))
-            pages, kb, vb = rest[NP:]
-            kp, vp = pools
-            return (kp.at[:, :, pages].set(jnp.moveaxis(kb, 0, 2)),
-                    vp.at[:, :, pages].set(jnp.moveaxis(vb, 0, 2)))
-
-        if tp > 1:
-            # partition every program over the 'tp' mesh axis: W enters
-            # under the Megatron specs, the pools (and int8 scale
-            # grids) head-sharded, page tables / token ids / scalars /
-            # PRNG keys replicated, and the logits (psum-reduced inside
-            # the blocks) leave replicated — each donated sharded pool
-            # aliases straight into its identically-sharded output
-            from jax.sharding import PartitionSpec as PS
-
-            from ..models.gpt import decode_weight_specs
-            rep = PS()
-            wspec = decode_weight_specs(self._W)
-            pool5 = PS(None, "tp", None, None, None)   # [L,H,N,Pg,D]
-            grid3 = PS(None, "tp", None)               # [L,H,N]
-            pspecs = ((pool5, pool5, grid3, grid3) if quant
-                      else (pool5, pool5))
-            page4 = PS(None, "tp", None, None)         # one page [L,H,Pg,D]
-            page2 = PS(None, "tp")                     # scale row [L,H]
-            chunk5 = PS(None, None, "tp", None, None)  # [W,L,H,Pg,D]
-            chunk3 = PS(None, None, "tp")              # [W,L,H]
-
-            def shard(fn, extras, outs, with_w=True):
-                ins = ((wspec,) if with_w else ()) + pspecs + extras
-                return jax.shard_map(fn, mesh=mesh, in_specs=ins,
-                                     out_specs=outs, check_vma=False)
-
-            gen_prefill = shard(gen_prefill, (rep,) * 3, (*pspecs, rep))
-            gen_prefill_tail = shard(gen_prefill_tail, (rep,) * 4,
-                                    (*pspecs, rep))
-            gen_decode = shard(gen_decode, (rep,) * 7,
-                              (*pspecs, rep, rep))
-            gen_verify = shard(gen_verify, (rep,) * 8,
-                              (*pspecs, rep, rep, rep))
-            gen_cow_copy = shard(gen_cow_copy, (rep,) * 2, pspecs,
-                                 with_w=False)
-            gen_zero_pages = shard(gen_zero_pages, (rep,), pspecs,
-                                   with_w=False)
-            # tier seam (ISSUE 18): the host store keeps FULL pages —
-            # the gather's sharded out_specs reassemble every head
-            # shard into one host block, and the write's chunk specs
-            # split the staged full blocks back across the slice
-            gen_tier_gather = shard(
-                gen_tier_gather, (rep,),
-                (page4, page4, page2, page2) if quant
-                else (page4, page4), with_w=False)
-            gen_tier_write = shard(
-                gen_tier_write,
-                (rep, chunk5, chunk5, chunk3, chunk3) if quant
-                else (rep, chunk5, chunk5),
-                pspecs, with_w=False)
+        def jit(name, **kw):
+            # a program the family does not build (and whose option it
+            # therefore refused at construction) stays None
+            fn = fns.get(name)
+            return jax.jit(fn, **kw) if fn is not None else None
 
         donate = tuple(range(1, 1 + NP))
-        self._prefill_jit = jax.jit(gen_prefill, donate_argnums=donate)
-        self._tail_jit = jax.jit(gen_prefill_tail, donate_argnums=donate)
-        self._decode_jit = jax.jit(gen_decode, donate_argnums=donate)
-        self._verify_jit = (jax.jit(gen_verify, donate_argnums=donate)
+        pools_only = tuple(range(NP))
+        self._prefill_jit = jit("prefill", donate_argnums=donate)
+        self._tail_jit = jit("prefill_tail", donate_argnums=donate)
+        self._decode_jit = jit("decode", donate_argnums=donate)
+        self._verify_jit = (jit("verify", donate_argnums=donate)
                             if self._spec_k else None)
-        self._zero_jit = jax.jit(gen_zero_pages,
-                                 donate_argnums=tuple(range(NP)))
-        self._cow_jit = jax.jit(gen_cow_copy, donate_argnums=tuple(range(NP)))
-        self._tier_gather_jit = (jax.jit(gen_tier_gather)
+        self._zero_jit = jit("zero_pages", donate_argnums=pools_only)
+        self._cow_jit = jit("cow_copy", donate_argnums=pools_only)
+        self._tier_gather_jit = (jit("tier_gather")
                                  if self._tier is not None else None)
         self._tier_write_jit = (
-            jax.jit(gen_tier_write, donate_argnums=tuple(range(NP)))
+            jit("tier_write", donate_argnums=pools_only)
             if self._tier is not None else None)
         # warm start (ISSUE 16): resolved AOT executables by program
         # name (ledger keys) + the store-load ledger; warmup fills them
@@ -1240,10 +881,10 @@ class GenerationEngine:
         import jaxlib
 
         from ..jit import pytree_spec
-        mcfg = self._model.gpt.config
         dev = jax.devices()[0]
         return {
-            "model": {k: v for k, v in sorted(vars(mcfg).items())},
+            "family": self._family.name,
+            "model": self._family.key_material(),
             "weights_spec": pytree_spec(self._W),
             "engine": {
                 "max_slots": self._cfg.max_slots,
@@ -1449,11 +1090,8 @@ class GenerationEngine:
         survives buffer deletion)."""
         import jax.numpy as jnp
         place = self._cache._place  # keeps the tp mesh placement
-        self._kp = place(jnp.zeros(self._kp.shape, self._kp.dtype))
-        self._vp = place(jnp.zeros(self._vp.shape, self._vp.dtype))
-        if self._quant_kv:
-            self._ks = place(jnp.zeros(self._ks.shape, self._ks.dtype))
-            self._vs = place(jnp.zeros(self._vs.shape, self._vs.dtype))
+        self._pool_arrays = [place(jnp.zeros(a.shape, a.dtype))
+                             for a in self._pool_arrays]
 
     def _selfcheck_alias(self, compiled, recorded: str):
         """The PR 1 structural gate on a LOADED executable: its
@@ -1473,8 +1111,7 @@ class GenerationEngine:
                     "PR 1 aliasing-drop corruption class")
         return None
 
-    @staticmethod
-    def _probe_ok(name: str, out) -> bool:
+    def _probe_ok(self, name: str, out) -> bool:
         """Numeric smoke verdict on one warmup execution of a loaded
         executable: prefill-family programs must return finite logits,
         decode/verify must not raise their in-graph poison flag;
@@ -1482,7 +1119,9 @@ class GenerationEngine:
         returns only pools)."""
         if name.startswith("prefill"):
             return bool(np.all(np.isfinite(np.asarray(out[-1]))))
-        if name.startswith(("decode", "verify")):
+        if name.startswith("decode"):   # (*pools, next, bad[, counters])
+            return not bool(np.asarray(out[self._npool + 1]).any())
+        if name.startswith("verify"):
             return not bool(np.asarray(out[-1]).any())
         return True
 
@@ -1623,16 +1262,16 @@ class GenerationEngine:
                         out = self._warm_one(
                             f"decode[m={M}]", self._decode_jit,
                             lambda: (self._W, *self._pools(), *dargs))
-                    np.asarray(out[-2])
-                    self._set_pools(out[:-2])
+                    np.asarray(out[self._npool])
+                    self._set_pools(out[:self._npool])
             else:
                 dargs = self._step_arrays()
                 with self._dev_ctx():
                     out = self._warm_one(
                         f"decode[m={M}]", self._decode_jit,
                         lambda: (self._W, *self._pools(), *dargs))
-                np.asarray(out[-2])
-                self._set_pools(out[:-2])
+                np.asarray(out[self._npool])
+                self._set_pools(out[:self._npool])
             self._zero_pages([])
 
     # -- request intake ----------------------------------------------------
@@ -1909,7 +1548,14 @@ class GenerationEngine:
                     # result() may immediately read the JSONL — its own
                     # event must already be on disk (no lock held here)
                     self._audit.flush_sink()
-                    self._flush_resolutions()
+                    # with sequences decoding, the next iteration launches
+                    # a program at once and `_read_back` hands these out
+                    # while the chip runs it; otherwise nothing is certain
+                    # to follow, so they go out now
+                    self._release_staged()
+                    if not any(r is not None and r.prefill_pos is None
+                               for r in self._slots):
+                        self._flush_released()
                 if not stepped:
                     with self._cv:
                         if (self._queue and self._num_active() == 0
@@ -1944,7 +1590,8 @@ class GenerationEngine:
             "promote_ms": 0.0,
             "attr_idle_ms": 0.0, "attr_sched_ms": 0.0,
             "attr_wall_ms": 0.0, "decode_wait_ms": 0.0,
-            "prefill_wait_ms": 0.0, "admit_wait_ms": 0.0}
+            "prefill_wait_ms": 0.0, "admit_wait_ms": 0.0,
+            **dict.fromkeys(self._family.step_counters, 0)}
         # pressure snapshot (ISSUE 17): republished every iteration on
         # the step thread — the only thread that mutates the allocator —
         # so `pressure()` readers never need the engine lock. Runs even
@@ -2014,7 +1661,9 @@ class GenerationEngine:
             decode_wait_ms=min(a_decode, round(it["decode_wait_ms"], 3)),
             prefill_wait_ms=min(a_prefill,
                                 round(it["prefill_wait_ms"], 3)),
-            admit_wait_ms=round(it["admit_wait_ms"], 3))
+            admit_wait_ms=round(it["admit_wait_ms"], 3),
+            # what the family's decode program counted on the device
+            **{name: it[name] for name in self._family.step_counters})
         self._step_log.record(rec)
 
     def _resolve_later(self, req: Optional[_GenRequest], fut,
@@ -2051,22 +1700,36 @@ class GenerationEngine:
             return
         self._stream_q.append((req.stream, tok))
 
+    def _release_staged(self):
+        """This iteration's record has landed: what it staged may go out.
+        It goes out at the next `_flush_released` — with sequences
+        decoding that is inside the next program's read-back, while the
+        chip runs (32 woken stream readers between two launches cost the
+        chip 4 ms a step, PERF.md PR 27)."""
+        if self._stream_q or self._resolve_q:
+            self._released.append((self._stream_q, self._resolve_q))
+            self._stream_q, self._resolve_q = [], []
+
+    def _flush_released(self):
+        batches, self._released = self._released, []
+        for sq, q in batches:
+            # streams first: a stream's final token / terminal marker
+            # must be readable by the time its future resolves
+            # ("streamed tokens arrive before resolved")
+            for stream, item in sq:
+                stream._put(item)
+            for _req, fut, result, exc in q:
+                try:
+                    if exc is not None:
+                        fut.set_exception(exc)
+                    else:
+                        fut.set_result(result)
+                except Exception:  # lint: allow(except-pass): racing caller-side cancel pre-admission — the future is already settled, there is nothing left to deliver
+                    pass
+
     def _flush_resolutions(self):
-        # streams first: a stream's final token / terminal marker must
-        # be readable by the time its future resolves ("streamed tokens
-        # arrive before resolved")
-        sq, self._stream_q = self._stream_q, []
-        for stream, item in sq:
-            stream._put(item)
-        q, self._resolve_q = self._resolve_q, []
-        for _req, fut, result, exc in q:
-            try:
-                if exc is not None:
-                    fut.set_exception(exc)
-                else:
-                    fut.set_result(result)
-            except Exception:  # lint: allow(except-pass): racing caller-side cancel pre-admission — the future is already settled, there is nothing left to deliver
-                pass
+        self._release_staged()
+        self._flush_released()
 
     def _die(self, e: BaseException):
         # two INDEPENDENT try blocks: a ring-record failure on a
@@ -2083,8 +1746,9 @@ class GenerationEngine:
         # the death error below must never reach them too (a request
         # observing BOTH a result and the death error was the ISSUE 15
         # resolution race)
-        settled = {req.rid for req, _f, _r, _e in self._resolve_q
-                   if req is not None}
+        settled = {req.rid
+                   for _sq, q in self._released + [([], self._resolve_q)]
+                   for req, _f, _r, _e in q if req is not None}
         try:
             self._flush_resolutions()
         except Exception:  # lint: allow(except-pass): best-effort flush on a dying engine — per-future failures are already guarded inside
@@ -2430,6 +2094,11 @@ class GenerationEngine:
         sub-splits of decode_ms / prefill_ms; what is left of those is
         launch and argument upload)."""
         t0 = _now_ms()
+        # the program is launched and the chip busy: the tokens and
+        # outcomes the LAST iteration staged (its record has landed) wake
+        # their readers now, under the device's time, not between two
+        # launches where the chip would wait for the wake-ups
+        self._flush_released()
         host = [np.asarray(o) for o in outs]
         self._it[bucket] += _now_ms() - t0
         return host if len(host) > 1 else host[0]
@@ -2765,6 +2434,7 @@ class GenerationEngine:
         on, 1 to k+1 tokens through the single compiled verify program.
         The np.asarray below is the step's only host sync."""
         if self._pre_step_hook is not None:
+            self._flush_released()      # a hook may wait for a reader
             self._pre_step_hook(self)
         # fault-injection seams (ISSUE 15, serving/failpoints.py): a
         # slow step first (SLO exercises), then the engine-fatal raise
@@ -2772,6 +2442,7 @@ class GenerationEngine:
         # jit exception (the pools-donated contract)
         ms = failpoints.fire("slow_step_ms")
         if ms:
+            self._flush_released()
             time.sleep(ms / 1000.0)
         failpoints.maybe_raise("decode_step_raise")
         if self._spec_k and not self._degraded_spec_off:
@@ -2782,10 +2453,16 @@ class GenerationEngine:
         t0 = _now_ms()
         with RecordEvent(f"generation::step[m={self._cfg.max_slots}]"):
             out = self._decode_call(self._W, *self._pools(), *args)
-            nxt, bad = self._read_back("decode_wait_ms", out[-2], out[-1])
+            NP = self._npool
+            # (*pools, next tokens, poison flags[, the family's counters])
+            nxt, bad, *counted = self._read_back("decode_wait_ms",
+                                                 *out[NP:])
         if failpoints.fire("decode_poison_nan") is not None:
             bad = self._inject_poison(bad)
-        self._set_pools(out[:-2])
+        self._set_pools(out[:NP])
+        for name, n in zip(self._family.step_counters,
+                           counted[0] if counted else ()):
+            self._it[name] += int(n)
         self._it["decode_ms"] += _now_ms() - t0
         self._steps_total += 1
         monitor.stat_add("STAT_gen_steps")
@@ -3070,8 +2747,9 @@ class GenerationEngine:
             "compiles": ledger,
             "loaded": loaded,
             "programs": programs,
-            # "kernel" / "pool" / "reference": the paged attention the
-            # decode program was built with (ops/paged_ops.py)
+            # "kernel" / "pool" / "reference" (head pools), "latent_gather"
+            # (a latent pool): the paged attention the decode program was
+            # built with (ops/paged_ops.py)
             "decode_attention": self._decode_attention,
             "program_store": {
                 "configured": bool(self._cfg.program_store),
@@ -3285,6 +2963,9 @@ class GenerationEngine:
             step_log.unregister(self._step_log)
         self._audit.close()
         slo.forget(self.name)
+        if self._cfg.gc_freeze:
+            import gc
+            gc.unfreeze()
         if getattr(self, "_owns_metrics_server", False) \
                 and self.metrics_server is not None:
             self.metrics_server.close()

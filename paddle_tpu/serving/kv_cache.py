@@ -1,5 +1,14 @@
 """Paged KV cache: block allocator + preallocated per-layer K/V pools.
 
+The pools come from a description of what one cached token IS. Head pools
+(the constructor, the GPT family): a K and a V row per head, two pools
+`[L, H, N, P, D]`, as described below. `PagedKVCache.described(shapes, ...)`
+(the latent-attention family, models/glm_moe.py) builds whatever pools the
+family names by shape and dtype — ONE pool `[L, N, P, row]` with no head
+axis. Pages, tables, the scratch page, refcounts and admission arithmetic
+are the same for both; the engine threads `pools`, whatever they are,
+through its programs.
+
 vLLM's PagedAttention memory model on TPU terms: decode-time K/V for
 every live sequence lives in ONE pair of preallocated pools
 `[L, H, num_pages, page_size, D]`, carved into fixed-size pages handed
@@ -93,17 +102,13 @@ class PagedKVCache:
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  page_size: int, num_pages: int, pages_per_seq: int,
                  dtype="float32", mesh=None, tp_axis: str = "tp"):
-        if page_size < 1 or num_pages < 2 or pages_per_seq < 1:
-            raise InvalidArgumentError(
-                f"PagedKVCache needs page_size>=1, num_pages>=2 (page 0 "
-                f"is reserved scratch), pages_per_seq>=1; got "
-                f"{page_size}/{num_pages}/{pages_per_seq}")
+        """Head pools: K and V `[L, H, N, P, D]`, plus the two scale
+        pools `[L, H, N]` in the int8 page mode, head-sharded on a tp
+        mesh. `described` builds any other pools."""
+        self._init_pages(page_size, num_pages, pages_per_seq)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
-        self.page_size = int(page_size)
-        self.num_pages = int(num_pages)
-        self.pages_per_seq = int(pages_per_seq)
         self.dtype = str(dtype)
         self.quantized = self.dtype == "int8"
         # mesh-sliced pools (ISSUE 19): on a tp mesh the K/V pools (and
@@ -131,8 +136,42 @@ class PagedKVCache:
             sshape = (self.num_layers, self.num_heads, self.num_pages)
             self.k_scales = self._place(jnp.zeros(sshape, "float32"))
             self.v_scales = self._place(jnp.zeros(sshape, "float32"))
+            self.pools = (self.k_pages, self.v_pages, self.k_scales,
+                          self.v_scales)
         else:
             self.k_scales = self.v_scales = None
+            self.pools = (self.k_pages, self.v_pages)
+        self._note_pools()
+
+    @classmethod
+    def described(cls, pool_shapes, page_size: int, num_pages: int,
+                  pages_per_seq: int):
+        """The allocator over pools the FAMILY describes: `pool_shapes`
+        is a sequence of (shape, dtype), one per pool, in the order the
+        family's programs take and return them; each holds `num_pages`
+        pages somewhere in its shape, which only the family's programs
+        know. One device, no head axis to shard (the latent family's one
+        pool `[L, N, P, row]`)."""
+        import jax.numpy as jnp
+        self = cls.__new__(cls)
+        self._init_pages(page_size, num_pages, pages_per_seq)
+        self.pools = tuple(jnp.zeros(tuple(shape), dtype)
+                           for shape, dtype in pool_shapes)
+        self.dtype = str(self.pools[0].dtype)
+        self.quantized = False
+        self.mesh, self.tp = None, 1
+        self._note_pools()
+        return self
+
+    def _init_pages(self, page_size, num_pages, pages_per_seq):
+        if page_size < 1 or num_pages < 2 or pages_per_seq < 1:
+            raise InvalidArgumentError(
+                f"PagedKVCache needs page_size>=1, num_pages>=2 (page 0 "
+                f"is reserved scratch), pages_per_seq>=1; got "
+                f"{page_size}/{num_pages}/{pages_per_seq}")
+        self.page_size = int(page_size)
+        self.num_pages = int(num_pages)
+        self.pages_per_seq = int(pages_per_seq)
         # LIFO free list: the page freed last is reallocated first, so a
         # hot pool keeps touching the same HBM region
         self._free: List[int] = list(range(self.num_pages - 1, 0, -1))
@@ -147,6 +186,9 @@ class PagedKVCache:
         self._free_low_water = len(self._free)
         self._free_high_water = len(self._free)
         monitor.stat_set("STAT_kv_pages_inuse", 0)
+
+    def _note_pools(self):
+        self._pool_bytes = sum(int(p.nbytes) for p in self.pools)
         b = self.hbm_bytes()
         _note_pool_bytes(b)
         weakref.finalize(self, _note_pool_bytes, -b)
@@ -200,9 +242,7 @@ class PagedKVCache:
         the FULL (unsharded) page: the tier gather reassembles every
         head shard into one host block, so host RAM pays tp-invariant
         bytes per page."""
-        return self.page_hbm_bytes(self.num_layers, self.num_heads,
-                                   self.head_dim, self.page_size,
-                                   self.dtype)
+        return self._pool_bytes // self.num_pages
 
     @classmethod
     def pages_for_budget(cls, budget_bytes: int, *, num_layers: int,
@@ -222,10 +262,7 @@ class PagedKVCache:
     def hbm_bytes(self) -> int:
         """Live device bytes of the K/V pools + scale pools (summed
         across every shard on a tp mesh)."""
-        b = int(self.k_pages.nbytes) + int(self.v_pages.nbytes)
-        if self.quantized:
-            b += int(self.k_scales.nbytes) + int(self.v_scales.nbytes)
-        return b
+        return self._pool_bytes
 
     def shard_hbm_bytes(self) -> int:
         """Per-device pool bytes: heads shard evenly over tp, so ONE
@@ -497,6 +534,7 @@ class PagedKVCache:
         return {
             "dtype": self.dtype,
             "quantized": self.quantized,
+            "pools": [list(p.shape) for p in self.pools],
             "hbm_bytes": self.hbm_bytes(),
             # mesh-slice lanes (ISSUE 19): per-device pool bytes — what
             # ONE chip's HBM actually pays (== hbm_bytes when tp == 1)

@@ -465,3 +465,66 @@ def test_generation_soak_many_slots(model):
                        if k.startswith("decode")]
     assert decode_compiles == [1]
     assert s["pages"]["pages_in_use"] == 0
+
+
+# -- tokens go out while the chip runs the next step --------------------------
+
+def test_staged_tokens_go_out_under_the_next_launch_never_later(model):
+    """With a sequence decoding, an iteration's tokens are released after
+    its record and handed to the stream inside the NEXT program's
+    read-back (while the device runs), or before anything that could wait
+    for a reader (the hook seam here); when nothing decodes any more the
+    last ones go out at once. So at every hook call all earlier steps'
+    tokens are readable, nothing is ever held across two launches, and the
+    stream and the future agree."""
+    seen = []
+
+    def hook(eng):
+        req = next(r for r in eng._slots if r is not None)
+        # flushed before the hook ran: every token decoded so far is in
+        # the stream's queue (nobody reads it yet), none is still staged
+        seen.append((len(req.toks), req.stream._q.qsize(),
+                     len(eng._released), len(eng._stream_q)))
+
+    with _engine(model, max_new_tokens=6) as eng:
+        eng._pre_step_hook = hook
+        s = eng.submit_stream(_prompts(1)[0])
+        out = s.result(timeout=120)
+        eng._pre_step_hook = None
+        assert list(s) == list(out[-6:])
+        assert eng._released == [] and eng._stream_q == []
+    assert len(seen) == 5                  # the first token is prefill's
+    # the first step shares its iteration with the prefill, whose token
+    # waits, staged, for that iteration's record
+    assert seen[0] == (1, 0, 0, 1)
+    for decoded, readable, released, staged in seen[1:]:
+        assert readable == decoded and released == 0 and staged == 0
+
+
+def test_a_read_back_delivers_only_what_an_earlier_record_released(model):
+    """What THIS iteration stages (a request that ends in its prefill, an
+    expiry) must wait for this iteration's record: a read-back later in
+    the same iteration hands out released batches only."""
+    with _engine(model) as eng:
+        eng._stream_q.append(("stream", "tok"))     # staged, not released
+        eng._flush_released()
+        assert eng._stream_q == [("stream", "tok")]
+        eng._stream_q.clear()
+
+
+def test_gc_freeze_keeps_the_warmed_process_out_of_full_collections(model):
+    """`gc_freeze=True`: after warm-up everything alive is frozen, so a
+    full collection while serving walks only what came after; shutdown
+    gives it back. Off by default (process-wide state)."""
+    import gc
+    base = gc.get_freeze_count()        # the interpreter's own, if any
+    with _engine(model) as eng:
+        assert gc.get_freeze_count() == base       # default: untouched
+        eng.generate(_prompts(1)[0])
+    with _engine(model, gc_freeze=True) as eng:
+        frozen = gc.get_freeze_count()
+        assert frozen > base + 10_000              # jax, programs, weights
+        out = eng.generate(_prompts(1)[0])
+        assert out.shape[0] == 7 + 5
+        assert gc.get_freeze_count() > base + 10_000   # still frozen
+    assert gc.get_freeze_count() == 0

@@ -111,9 +111,12 @@ def tiny_engine(gpt, name, **kw):
 
 
 def test_new_step_fields_are_appended_after_the_old():
-    assert step_log._FIELDS[-3:] == ("decode_wait_ms", "prefill_wait_ms",
-                                     "admit_wait_ms")
-    assert step_log._FIELDS[-4] == "attr_wall_ms"
+    # PR 27's two device counters after PR 25's three waits, each era
+    # appended to the one before
+    assert step_log._FIELDS[-2:] == ("experts_hit", "latent_rows")
+    assert step_log._FIELDS[-5:-2] == ("decode_wait_ms", "prefill_wait_ms",
+                                       "admit_wait_ms")
+    assert step_log._FIELDS[-6] == "attr_wall_ms"
     assert list(step_log.StepRecord().to_dict()) == list(step_log._FIELDS)
 
 

@@ -174,6 +174,11 @@ def test_tp_tier_demote_promote_token_identity(model):
 # -- capacity / gauges ------------------------------------------------------
 
 def test_tp_shard_bytes_and_gauge(model):
+    # an earlier test's tp cache gives its share back when it is collected
+    # (weakref.finalize): have that happen before the gauge is read, not
+    # between the two readings
+    import gc
+    gc.collect()
     base = monitor.stat_get("STAT_tp_kv_shard_bytes") or 0
     with _engine(model, tp=2, name="tpgauge") as eng:
         s = eng.stats()["pages"]

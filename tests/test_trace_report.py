@@ -25,6 +25,17 @@ MS = 1e6    # the trace's times are nanoseconds
     ("jit(gen_decode)/layer_17/attn/kv_gather/jit(_take)/gather", 6,
      "layer_*/attn/kv_gather/_take"),
     ("jit(gen_decode)/layer_17/attn/kv_gather/gather", 2, "layer_*/attn"),
+    # the latent family's scopes (PR 27): one row a mechanism, whatever
+    # the layer
+    ("jit(gen_decode)/layer_5/mla/latent_attend/dot_general", 7,
+     "layer_*/mla/latent_attend"),
+    ("jit(gen_decode)/layer_5/mla/latent_write/scatter", 7,
+     "layer_*/mla/latent_write"),
+    ("jit(gen_decode)/layer_3/moe/experts/ragged_dot", 7,
+     "layer_*/moe/experts"),
+    ("jit(gen_prefill)/layer_6/moe/dispatch/jit(argsort)/sort", 7,
+     "layer_*/moe/dispatch/argsort"),
+    ("jit(gen_decode)/layer_0/mlp/dot_general", 7, "layer_*/mlp"),
     ("jit(train_step)/optimizer/add", 6, "optimizer"),
     ("jit(train_step)/jit(main)/add", 6, ""),
     ("", 6, ""),

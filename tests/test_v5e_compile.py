@@ -140,3 +140,70 @@ def test_gen_decode_reads_the_pool_once_at_the_serve_cells_shapes(
             if "kv_mask/eq" in ln and " compare(" in ln]
     assert len(owns) == 1 and f"pred[{M},{GEN_PP},{GEN_PAGES}]" in owns[0]
     assert compiled.memory_analysis().temp_size_in_bytes <= GEN_PARENT_TEMP
+
+
+# -- the latent family's decode program (glm-4.7-flash.reasoning-saturated) --
+# published widths, 64 experts, vocabulary 154,880, bfloat16; 32 slots, pages
+# of 16 tokens, 8,192 pages, 256 table entries
+# (benchmark/configs/glm-4.7-flash.json). The closure depends on the widths,
+# not on depth, so one dense + one expert layer compile in seconds.
+LAT_SLOTS, LAT_PAGE, LAT_PAGES, LAT_PP = 32, 16, 8192, 256
+
+
+def test_latent_decode_compiles_for_the_v5e_without_a_pool_copy(
+        one_chip, uncached):
+    """What the chip's compiler would refuse it refuses here: the grouped
+    expert product (`lax.ragged_dot` -> XLA:TPU's own kernel) is traced
+    under "default" precision because Mosaic refuses its bfloat16 operands
+    under the framework's "highest" pin; a cached row takes 640 lanes so
+    that no program copies the whole pool in and out (at 576 each did:
+    1.17 GB of temporaries for `zero_pages` alone); each slot's rows are
+    gathered once a layer for its 20 heads."""
+    import types
+
+    from paddle_tpu.models.glm_moe import (GlmMoeLiteConfig,
+                                           glm_weight_shapes)
+    from paddle_tpu.serving.decode_family import ProgramContext
+    from paddle_tpu.serving.generation import GenerationConfig
+    from paddle_tpu.serving.latent_family import LatentFamily
+
+    cfg = GlmMoeLiteConfig(num_hidden_layers=2)
+    fam = LatentFamily(types.SimpleNamespace(config=cfg))
+    ecfg = GenerationConfig(max_slots=LAT_SLOTS, page_size=LAT_PAGE,
+                            num_pages=LAT_PAGES, pages_per_seq=LAT_PP,
+                            prefill_buckets=(256,), warmup=False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    W = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                               glm_weight_shapes(cfg))
+    pool = sds((2, LAT_PAGES, LAT_PAGE, 640), "bfloat16")
+    path = fam.decode_attention(ecfg, 1, (pool,))
+    assert path == "latent_gather"
+    fns = fam.build(ProgramContext(ecfg, 1, None, 1, False, path, W, {}))
+    key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
+    M = LAT_SLOTS
+    decode = jax.jit(fns["decode"], donate_argnums=(1,)).lower(
+        W, pool, sds((M, LAT_PP), "int32"), sds((M,), "int32"),
+        sds((M,), "int32"), sds((M,), "bool"), sds((M,), "float32"),
+        sds((M,), "bool"), sds(key.shape, key.dtype)).compile()
+    zero = jax.jit(fns["zero_pages"], donate_argnums=(0,)).lower(
+        pool, sds((LAT_PP,), "int32")).compile()
+    text = decode.as_text()
+    for scope in ("layer_1/mla/latent_attend", "layer_1/mla/absorb",
+                  "layer_1/mla/latent_write", "layer_1/moe/experts",
+                  "layer_1/moe/router", "layer_0/mlp", "lm_head", "sample"):
+        assert scope in text, scope
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "kv_mask" not in text            # no pool-dense ownership mask
+    # no copy of the whole pool, in either program
+    whole = f"bf16[2,{LAT_PAGES},{LAT_PAGE},640]"
+    for t in (text, zero.as_text()):
+        assert not [ln for ln in t.splitlines()
+                    if f"= {whole}" in ln and " copy(" in ln]
+    assert zero.memory_analysis().temp_size_in_bytes < 1 << 20
+    # a layer's gathered rows [32, 4096, 640] bfloat16 (168 MB) and their
+    # scores; nothing pool-dense: [640, 131072] float32 would be 335 MB
+    assert decode.memory_analysis().temp_size_in_bytes < 400 << 20
