@@ -363,6 +363,20 @@ class Smoke:
               f"serve: the decode program's attention is `{want}`, by the "
               f"shape rules")
 
+        # what the engine holds, and in which layout (PR 28)
+        pools = stats["pools"]
+        say(f"serve: pools ({stats['pages']['pool_form']}) {pools}")
+        check(all(p["layout"] == p["compiled_for"] == p["preferred"]
+                  for p in pools),
+              "serve: the pools lie in the layout the decode program was "
+              "compiled for, and the compiler, left to choose, chose it "
+              "(no program relays a pool, at its boundary or inside)")
+        if cfg.hidden_size // cfg.num_heads == 64:  # not the rehearsal's 16
+            check(all(p["device_bytes"] <= 1.1 * p["logical_bytes"]
+                      for p in pools),
+                  "serve: 64-wide heads in one dense row: every pool's "
+                  "device bytes within 1.1 times the bytes it stores")
+
         exact, near, worst, first = self.near_argmax_rate(net, outs, prompts)
         say(f"serve: vs the eager forward, teacher-forced: {exact:.3f} of "
             f"generated tokens are the eager argmax, {near:.3f} within "

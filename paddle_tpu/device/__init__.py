@@ -64,8 +64,9 @@ def compilation_cache_dir():
 def serialization_unsafe_backend() -> bool:
     """True on the CPU backend, where the program store's deserialized
     executables have not been shown to keep input/output buffer aliasing
-    (a donated program that lost it reads freed buffers). Initialises
-    the default backend."""
+    (a donated program that lost it reads freed buffers); the generation
+    engine does not ask this backend's compiler which layout it would
+    give the pools either. Initialises the default backend."""
     return _jax.default_backend() == "cpu"
 
 
@@ -94,6 +95,32 @@ def program_store_dir():
     from ..framework.flags import flag
     d = str(flag("FLAGS_gen_program_store_dir") or "").strip()
     return _os.path.expanduser(d) if d else None
+
+
+def layout_name(fmt, shape, dtype) -> str:
+    """How a `jax.experimental.layout.Format` lays `shape` out on its
+    device: `default` where it is the backend's own default for that
+    shape, else the compiler's `major_to_minor` and tiling, as reported.
+    Read from the format; a tiling is never written down here."""
+    from jax.experimental.layout import Layout
+    layout, sharding = fmt.layout, fmt.sharding
+    if layout is None:
+        return "default"
+    dev = min(sharding.device_set, key=lambda d: d.id)
+    default = Layout.from_pjrt_layout(dev.client.get_default_layout(
+        _jax.numpy.dtype(dtype), sharding.shard_shape(tuple(shape)), dev))
+    if layout == default:
+        return "default"
+    tiles = "".join(f"T{tuple(t)}" for t in layout.tiling or ())
+    return f"{layout.major_to_minor}:{tiles}".replace(" ", "")
+
+
+def array_layout(arr):
+    """(`layout_name`, device bytes over all its shards) of an array as
+    the device holds it: a padded tiling counts in the bytes."""
+    return (layout_name(arr.format, arr.shape, arr.dtype),
+            sum(int(s.data.on_device_size_in_bytes())
+                for s in arr.addressable_shards))
 
 
 class cuda:

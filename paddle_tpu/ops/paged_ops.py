@@ -1,9 +1,9 @@
 """Paged KV-cache attention: three implementations, two shape rules.
 
 vLLM's PagedAttention insight, TPU-shaped: decode-time K/V lives in
-fixed-size **pages** inside preallocated per-layer pools
-(`[L, H, num_pages, page_size, D]`), and a per-sequence **page table**
-maps logical token positions to physical pages — so sequences of wildly
+fixed-size **pages** inside preallocated per-layer pools, and a
+per-sequence **page table** maps logical token positions to physical
+pages — so sequences of wildly
 different lengths share one pool with zero fragmentation beyond the last
 partial page, and admission control is exact page arithmetic
 (`serving/kv_cache.py`).
@@ -53,6 +53,28 @@ shape — `paged_attention_path` names the one a call takes — and counted
 by `STAT_paged_attn_kernel` / `STAT_paged_attn_pool` /
 `STAT_paged_attn_reference`: **traces**, not calls, mirroring the
 exact-compile accounting everywhere else in the serving stack.
+
+**Two forms of a head pool, one shape rule** (`HeadPoolForm`,
+`head_pools_fused`). JAX's paged kernel wants one layer as
+`[Hkv, N, P, D]` and reads it in place, and with a head width that fills
+whole 128-lane tiles that shape is dense on the TPU: such heads keep the
+*split* form `[L, H, N, P, D]` (scales `[L, H, N]`). Narrower heads
+(64-wide: GPT-2) would pad every (page, head) tile 2.56-fold there, and
+XLA:TPU relaid the whole pools in and out of every program (PERF.md,
+PR 24-28); they take the *fused* form `[L, N, P, H*D]` (scales
+`[L, N, H]`): a row is a token's heads side by side, the page axis is
+axis 1 as in the latent pools, and the plain row-major layout is dense.
+Like a latent row (`latent_pool_width`) a fused row takes whole 128-lane
+tiles, the lanes past `H*D` zero (gpt2-xl: 1,600 values in 1,664 lanes):
+the backend's DEFAULT layout for `f32[48,128,16,1600]` puts the 128 pages
+in the lanes, because that pads nothing, and a layout that is not the
+default does not survive JAX's persistent compile cache (PR 28: an
+executable the cache hands back returns default layouts); with a
+lane-exact row the default is the row-major one the decode program
+wants, for any number of pages. Every function below takes either form
+and tells them by rank; what it hands the arithmetic (whole pages, scale
+rows, gathered views) has the split form's axis order in both, so each
+algorithm is written once.
 """
 from __future__ import annotations
 
@@ -62,7 +84,8 @@ import jax.numpy as jnp
 from ..framework import monitor
 from ..framework.flags import flag
 
-__all__ = ["cached_attention", "paged_attention", "paged_attention_path",
+__all__ = ["HeadPoolForm", "head_pools_fused",
+           "cached_attention", "paged_attention", "paged_attention_path",
            "paged_gather", "paged_kernel_supported",
            "paged_pool_attention", "paged_pool_dense_supported",
            "paged_pool_mask",
@@ -81,6 +104,187 @@ __all__ = ["cached_attention", "paged_attention", "paged_attention_path",
 # (tools/trace_report.py). Names are metadata: the programs are the same.
 
 
+# -- where the page axis and the head axis are --------------------------------
+
+
+def head_pools_fused(head_dim: int) -> bool:
+    """The shape rule of the head pools (module docstring): a head width
+    that is a whole number of 128-lane tiles keeps the split form the
+    Pallas kernel reads in place (`paged_kernel_supported` asks for the
+    same widths); any other width takes the fused form, whose row-major
+    layout is dense. By observable shape, like `paged_attention_path`."""
+    return int(head_dim) % 128 != 0
+
+
+class HeadPoolForm:
+    """Where the page axis and the head axis of a head pool are: the one
+    place the cache, the engine and the family's programs ask.
+
+    split  K/V `[L, H, N, P, D]`, scales `[L, H, N]`: page axis 2, heads 1
+    fused  K/V `[L, N, P, row]`, scales `[L, N, H]`: page axis 1, heads last
+
+    A fused `row` is the heads' `H*D` values in whole 128-lane tiles
+    (`latent_pool_width`). `heads` is the number the pools hold in all;
+    on a tp mesh of `shards` devices each shard's own heads lie in a row
+    of their own whole tiles, so that the last axis splits evenly and a
+    shard sees the pool of a `HeadPoolForm(heads // shards, head_dim)`
+    (the rule reads the head width, which sharding leaves alone)."""
+
+    def __init__(self, heads: int, head_dim: int, shards: int = 1):
+        self.heads, self.head_dim = int(heads), int(head_dim)
+        self.shards = int(shards)
+        self.fused = head_pools_fused(head_dim)
+        self.page_axis = 1 if self.fused else 2
+        self.pool_rank = 4 if self.fused else 5
+        self.name = "[L,N,P,H*D]" if self.fused else "[L,H,N,P,D]"
+        # the lanes one token takes in a K or V pool, and those it fills
+        self.used = self.heads * self.head_dim
+        self.row = (self.shards * latent_pool_width(self.used // self.shards)
+                    if self.fused else self.used)
+
+    def pool_shape(self, layers, num_pages, page_size):
+        if self.fused:
+            return (layers, num_pages, page_size, self.row)
+        return (layers, self.heads, num_pages, page_size, self.head_dim)
+
+    def scale_shape(self, layers, num_pages):
+        return ((layers, num_pages, self.heads) if self.fused
+                else (layers, self.heads, num_pages))
+
+    def layer_shape(self, pool_shape):
+        """One layer of a pool as the shape rules read it, `(H, N, P, D)`
+        (`paged_attention_path`), whichever form the pool has."""
+        N, P = pool_shape[self.page_axis:self.page_axis + 2]
+        return (self.heads, N, P, self.head_dim)
+
+    def head_axis(self, ndim: int, lead: int = 0) -> int:
+        """The axis a tp mesh shards, of a pool, a scale pool, one page
+        cut out of either, or (`lead=1`) a chunk of such pages."""
+        return ndim - 1 if self.fused else 1 + lead
+
+    def spec(self, ndim: int, axis_name: str = "tp", lead: int = 0):
+        from jax.sharding import PartitionSpec
+        spec = [None] * ndim
+        spec[self.head_axis(ndim, lead)] = axis_name
+        return PartitionSpec(*spec)
+
+    def _index(self, pages):
+        return (slice(None),) * self.page_axis + (pages,)
+
+    def pages(self, pool, pages):
+        """Whole pages of a K/V or scale pool by id, in the pool's own
+        order: a scalar id cuts the page axis out, a vector [W] leaves W
+        in its place."""
+        return pool[self._index(pages)]
+
+    def at_pages(self, pool, pages):
+        """`pool.at[...]` over the same pages (zero, copy-on-write, tier
+        write): `.set` takes what `pages` returns."""
+        return pool.at[self._index(pages)]
+
+    def zero_pages(self, pool, pages, scratch):
+        """Zero the pages a scratch-padded row names (`PagedKVCache.
+        zero_rows`: the freed pages first, the scratch page's id after
+        them) — one page at a time and in place, the freed pages and the
+        scratch page once. One scatter of the whole row first builds a
+        row of zero pages and then writes every padding entry too: for
+        gpt2-xl's 64 entries 314 MB a pool where a request frees 18
+        pages of 4.9 MB (compiled for the v5e, PR 28)."""
+        ax = self.page_axis
+        n = jnp.minimum(jnp.sum(pages != scratch) + 1, pages.shape[0])
+        zero = jnp.zeros(pool.shape[:ax] + (1,) + pool.shape[ax + 1:],
+                         pool.dtype)
+        return jax.lax.fori_loop(
+            0, n, lambda i, pool: jax.lax.dynamic_update_slice_in_dim(
+                pool, zero, pages[i], axis=ax), pool)
+
+    def from_chunk(self, blocks):
+        """A host-tier chunk `[W, *page]` (pages stacked in front) as
+        `at_pages(pool, ids [W]).set` takes it."""
+        return jnp.moveaxis(blocks, 0, self.page_axis)
+
+
+def _split_heads(x, hd):
+    """A fused row's heads: [..., row] -> [..., H, D], `hd` = (H, D); the
+    lanes past H*D are the row's padding."""
+    H, D = hd
+    return x[..., :H * D].reshape(x.shape[:-1] + (H, D))
+
+
+def _merge_heads(x, row):
+    """[..., H, D] -> a fused row [..., row], zero past H*D."""
+    return _pad_lanes(x.reshape(x.shape[:-2] + (-1,)), row)
+
+
+# Whole pages and their scale rows, handed over in the SPLIT form's axis
+# order whichever form the pool has (a fused pool: rank 4, one layer rank
+# 3). `layer=None`: every layer, ids [S] -> pages [L, H, S, P, D], scales
+# [L, H, S]. `layer` an int: ids [B] -> pages [H, B, P, D], scales [H, B].
+
+
+def _take_pages(pages, layer, ids, hd):
+    if layer is None:
+        if pages.ndim == 5:
+            return pages[:, :, ids]
+        return jnp.moveaxis(_split_heads(pages[:, ids], hd), 3, 1)
+    if pages.ndim == 5:
+        return pages[layer][:, ids]
+    return jnp.moveaxis(_split_heads(pages[layer, ids], hd), 2, 0)
+
+
+def _put_pages(pages, layer, ids, blocks):
+    if layer is None:
+        if pages.ndim == 5:
+            return pages.at[:, :, ids].set(blocks)
+        return pages.at[:, ids].set(
+            _merge_heads(jnp.moveaxis(blocks, 1, 3), pages.shape[-1]))
+    if pages.ndim == 5:
+        # the scalar layer index joins the advanced block, which is then
+        # non-contiguous, so the batch dim lands in FRONT (same subtlety
+        # as paged_write's docstring) — move it there
+        return pages.at[layer, :, ids, :, :].set(jnp.moveaxis(blocks, 1, 0))
+    return pages.at[layer, ids].set(
+        _merge_heads(jnp.moveaxis(blocks, 0, 2), pages.shape[-1]))
+
+
+def _gather_rows(pages, table, axis, hd, scales=None, dtype=None):
+    """The gathers over a fused pool: the table's pages taken along the
+    page `axis` (0: one layer `[N, P, row]`, table [B, PP]; 1: all layers
+    `[L, N, P, row]`, table [PP]), dequantized by their `scales`
+    (`[.., N, H]`) where given, as `[.., H, PP*P, D]`."""
+    kb = _split_heads(jnp.take(pages, table, axis=axis), hd)  # [..,PP,P,H,D]
+    if scales is not None:
+        sc = jnp.take(scales, table, axis=axis)               # [.., PP, H]
+        kb = kb.astype(dtype) * sc[..., None, :, None].astype(dtype)
+    kb = kb.reshape(kb.shape[:-4] + (-1,) + kb.shape[-2:])    # [.., T, H, D]
+    return jnp.moveaxis(kb, -3, -2)
+
+
+def _take_scales(scales, layer, ids, fused):
+    if layer is None:
+        return (jnp.swapaxes(scales[:, ids], 1, 2) if fused
+                else scales[:, :, ids])
+    return scales[layer, ids].T if fused else scales[layer][:, ids]
+
+
+def _at_scales(scales, layer, ids, fused):
+    """`.at[...]` over the same rows; its `.set` / `.max` take them as
+    `_pool_order` lays them (a scalar layer index beside the ids puts the
+    batch dim in FRONT: [B, H] for one layer of either form)."""
+    if layer is None:
+        return scales.at[:, ids] if fused else scales.at[:, :, ids]
+    return scales.at[layer, ids] if fused else scales.at[layer, :, ids]
+
+
+def _pool_order(rows, layer, fused):
+    """Scale rows in the split order ([L, H, S] / [H, B]) as `_at_scales`
+    takes them: [L, S, H] for a fused pool, [B, H] for one layer of
+    either (the scalar layer index puts the batch dim in front)."""
+    if layer is None:
+        return jnp.swapaxes(rows, 1, 2) if fused else rows
+    return rows.T
+
+
 @jax.named_scope("kv_attend")
 def cached_attention(q, kb, vb, pos, scale):
     """Masked attention of one-position queries over a dense cache.
@@ -97,14 +301,16 @@ def cached_attention(q, kb, vb, pos, scale):
 
 
 @jax.named_scope("kv_gather")
-def paged_gather(pages, page_table):
+def paged_gather(pages, page_table, heads=None):
     """Materialize page-table rows as a dense cache view.
 
-    pages [H, N, P, D] (one layer's pool); page_table [B, PP] int32.
-    Returns [B, H, PP*P, D] — logical token order regardless of physical
-    page placement."""
-    H, _, P, D = pages.shape
+    pages [H, N, P, D] (one layer's pool) or fused [N, P, row] with
+    `heads` = (H, D) given; page_table [B, PP] int32. Returns [B, H, PP*P, D] —
+    logical token order regardless of physical page placement."""
     B, PP = page_table.shape
+    if pages.ndim == 3:
+        return _gather_rows(pages, page_table, 0, heads)
+    H, _, P, D = pages.shape
     kb = jnp.take(pages, page_table, axis=1)     # [H, B, PP, P, D]
     return jnp.moveaxis(kb, 1, 0).reshape(B, H, PP * P, D)
 
@@ -148,7 +354,13 @@ def paged_pool_attention(q, k_pages, v_pages, valid, scale):
     over the pool's physical order. Isolation (module docstring): a
     non-finite K row outside the mask is dropped by the `where`; V is
     multiplied as a finite copy, and a (row, head) whose own valid V
-    rows are not all finite reads NaN, as the gather would give it."""
+    rows are not all finite reads NaN, as the gather would give it.
+
+    A fused layer `[N, P, row]` is read as `[N*P, H, D]`, rows outermost
+    as they lie: the same products and sums with the pool's row axis in
+    front of the head axis."""
+    if k_pages.ndim == 3:
+        return _pool_attention_rows(q, k_pages, v_pages, valid, scale)
     H, N, P, D = k_pages.shape
     k = k_pages.reshape(H, N * P, D)
     # V has two readers, the product and the is-finite reduction. Left
@@ -164,6 +376,22 @@ def paged_pool_attention(q, k_pages, v_pages, valid, scale):
     out = jnp.einsum("bht,htd->bhd", p, jnp.where(finite, v, 0))
     poisoned = ~jnp.all(finite, axis=-1)                      # [H, N*P]
     bad = jnp.any(valid[:, None, :] & poisoned[None], axis=-1)    # [B, H]
+    return jnp.where(bad[..., None], jnp.nan, out)
+
+
+def _pool_attention_rows(q, k_pages, v_pages, valid, scale):
+    """`paged_pool_attention` over one fused layer `[N, P, row]`."""
+    hd = q.shape[1:]
+    k = _split_heads(k_pages.reshape(-1, k_pages.shape[-1]), hd)  # [T,H,D]
+    v = jax.lax.optimization_barrier(
+        _split_heads(v_pages.reshape(-1, v_pages.shape[-1]), hd))
+    s = jnp.einsum("bhd,thd->bht", q, k) * scale
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    finite = jnp.isfinite(v)
+    out = jnp.einsum("bht,thd->bhd", p, jnp.where(finite, v, 0))
+    poisoned = ~jnp.all(finite, axis=-1)                      # [T, H]
+    bad = jnp.any(valid[:, :, None] & poisoned[None], axis=1)     # [B, H]
     return jnp.where(bad[..., None], jnp.nan, out)
 
 
@@ -187,6 +415,26 @@ def page_rows_for_positions(page_table, positions, page_size):
             positions % page_size)
 
 
+def _write_rows(pages, layer, page_ids, offsets, values):
+    """`paged_write`, outside its scope."""
+    if pages.ndim == 4:
+        if layer is not None:
+            return pages.at[layer, page_ids, offsets, :].set(
+                _merge_heads(values, pages.shape[-1]))
+        rows = _merge_heads(jnp.moveaxis(values, 1, 2), pages.shape[-1])
+        # one scatter of L x S whole rows, the layer an INDEX like page
+        # and offset: with the layer axis left as a window (`[:, ids,
+        # offs]`) XLA:TPU relays the whole pool to a layers-minor layout
+        # and back (compiled for the v5e, PR 27 and PR 28), and one
+        # scatter a layer traces and lowers 48 of them a pool
+        layers = jnp.arange(pages.shape[0])[:, None]
+        return pages.at[layers, page_ids[None], offsets[None], :].set(rows)
+    if layer is None:
+        return pages.at[:, :, page_ids, offsets, :].set(values)
+    # the scalar layer puts the batch dim in FRONT: values are [B, H, D]
+    return pages.at[layer, :, page_ids, offsets, :].set(values)
+
+
 @jax.named_scope("kv_write")
 def paged_write(pages, layer, page_ids, offsets, values):
     """Scatter per-row K/V vectors into one layer of a paged pool.
@@ -196,10 +444,10 @@ def paged_write(pages, layer, page_ids, offsets, values):
     non-contiguous, so numpy indexing moves the batch dim to the
     front). `layer=None` writes all layers at once (prefill):
     page_ids/offsets [S], values [L, H, S, D] (adjacent advanced block
-    stays in place)."""
-    if layer is None:
-        return pages.at[:, :, page_ids, offsets, :].set(values)
-    return pages.at[layer, :, page_ids, offsets, :].set(values)
+    stays in place). A fused pool [L, N, P, row] takes the same values
+    and stores each token's heads side by side in one row, zero past
+    them."""
+    return _write_rows(pages, layer, page_ids, offsets, values)
 
 
 # -- int8 page mode ---------------------------------------------------------
@@ -224,13 +472,17 @@ def _q8(v, s):
 
 
 @jax.named_scope("kv_gather")
-def paged_gather_quantized(pages, scales, page_table, dtype=jnp.float32):
-    """Dequantizing gather: int8 pages [H, N, P, D] + scales [H, N] →
-    dense floating [B, H, PP*P, D] (only THIS batch's pages are ever
-    materialized in floating form — the pools stay int8 in HBM)."""
+def paged_gather_quantized(pages, scales, page_table, dtype=jnp.float32,
+                           heads=None):
+    """Dequantizing gather: int8 pages [H, N, P, D] + scales [H, N] (or
+    fused [N, P, row] + [N, H], `heads` = (H, D)) → dense floating
+    [B, H, PP*P, D] (only THIS batch's pages are ever materialized in
+    floating form — the pools stay int8 in HBM)."""
     monitor.stat_add("STAT_kv_quant_reads")  # traces, not calls
-    H, _, P, D = pages.shape
     B, PP = page_table.shape
+    if pages.ndim == 3:
+        return _gather_rows(pages, page_table, 0, heads, scales, dtype)
+    H, _, P, D = pages.shape
     kb = jnp.take(pages, page_table, axis=1)        # [H, B, PP, P, D]
     sc = jnp.take(scales, page_table, axis=1)       # [H, B, PP]
     kb = kb.astype(dtype) * sc[..., None, None].astype(dtype)
@@ -266,42 +518,43 @@ def paged_write_quantized(pages, scales, layer, page_ids, offsets, values,
     between frees, which dequantizes finite and is masked out, same as
     the fp32 contract."""
     monitor.stat_add("STAT_kv_quant_writes")  # traces, not calls
+    # whole pages and scale rows come and go in the split form's order,
+    # whichever form the pools have
+    fused, hd = pages.ndim == 4, (values.shape[1], values.shape[-1])
     if layer is None:
         a = jnp.max(jnp.abs(values), axis=-1) / 127.0        # [L, H, S]
-        s_old = scales[:, :, page_ids]                       # [L, H, S]
-        scales = scales.at[:, :, page_ids].max(a)            # dup-safe
-        s_tok = scales[:, :, page_ids]                       # [L, H, S]
+        s_old = _take_scales(scales, None, page_ids, fused)  # [L, H, S]
+        scales = _at_scales(scales, None, page_ids, fused).max(
+            _pool_order(a, None, fused))                     # dup-safe
+        s_tok = _take_scales(scales, None, page_ids, fused)  # [L, H, S]
         if requant:
             # duplicate page ids are safe — s_old/s_tok are per-page,
             # so duplicates compute identical requantized pages and the
             # scatter's last-writer-wins is a no-op
             fdt = values.dtype
-            pk = pages[:, :, page_ids]                       # [L,H,S,P,D]
+            pk = _take_pages(pages, None, page_ids, hd)      # [L,H,S,P,D]
             ratio = jnp.where(
                 s_tok > 0, s_old / jnp.where(s_tok > 0, s_tok, 1.0), 1.0)
             pk = jnp.round(pk.astype(fdt) * ratio[..., None, None]) \
                 .astype(jnp.int8)
-            pages = pages.at[:, :, page_ids].set(pk)
+            pages = _put_pages(pages, None, page_ids, pk)
         q = _q8(values, s_tok[..., None])
-        return pages.at[:, :, page_ids, offsets, :].set(q), scales
+        return _write_rows(pages, None, page_ids, offsets, q), scales
     B = page_ids.shape[0]
     fdt = values.dtype
     a = jnp.max(jnp.abs(values), axis=-1) / 127.0            # [B, H]
-    s_old = scales[layer][:, page_ids]                       # [H, B]
+    s_old = _take_scales(scales, layer, page_ids, fused)     # [H, B]
     s_new = jnp.maximum(s_old, a.T)                          # [H, B]
-    pk = pages[layer][:, page_ids]                           # [H, B, P, D]
+    pk = _take_pages(pages, layer, page_ids, hd)             # [H, B, P, D]
     ratio = jnp.where(s_new > 0,
                       s_old / jnp.where(s_new > 0, s_new, 1.0), 1.0)
     pk = jnp.round(pk.astype(fdt) * ratio[..., None, None]) \
         .astype(jnp.int8)
     q = _q8(values, jnp.moveaxis(s_new, 1, 0)[..., None])    # [B, H, D]
     pk = pk.at[:, jnp.arange(B), offsets, :].set(jnp.moveaxis(q, 0, 1))
-    # scatter target: the scalar layer index joins the advanced block,
-    # which is then non-contiguous, so the batch dim lands in FRONT
-    # (same subtlety as paged_write's docstring) — move it there
-    pages = pages.at[layer, :, page_ids, :, :].set(
-        jnp.moveaxis(pk, 1, 0))                              # [B, H, P, D]
-    scales = scales.at[layer, :, page_ids].set(s_new.T)      # [B, H]
+    pages = _put_pages(pages, layer, page_ids, pk)
+    scales = _at_scales(scales, layer, page_ids, fused).set(
+        _pool_order(s_new, layer, fused))                    # [B, H]
     return pages, scales
 
 
@@ -377,9 +630,9 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
                     k_scales=None, v_scales=None, pool_mask=None):
     """One decode position of attention over a paged KV cache.
 
-    q [B, H, D]; k_pages/v_pages [H, N, P, D] (ONE layer's pool);
-    page_table [B, PP] int32; pos [B] int32 (last valid position, the
-    token just written). Returns [B, H, D].
+    q [B, H, D]; k_pages/v_pages [H, N, P, D] (ONE layer's pool, or
+    fused [N, P, row]); page_table [B, PP] int32; pos [B] int32 (last
+    valid position, the token just written). Returns [B, H, D].
 
     `paged_attention_path` picks the implementation from the shapes: on
     a TPU backend, shapes `paged_kernel_supported` admits dispatch the
@@ -397,7 +650,12 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
     reads always take the dequantizing gather + dense reference (the
     gather materializes only this batch's pages in floating form; the
     pools stay int8 in HBM — on TPU and CPU alike)."""
-    path = paged_attention_path(q.shape, k_pages.shape, page_table.shape,
+    H, D = hd = q.shape[1:]
+    if k_pages.ndim == 3:       # a fused layer [N, P, row]: one K/V head
+        layer_shape = (H,) + k_pages.shape[:2] + (D,)       # a query head
+    else:
+        layer_shape = k_pages.shape
+    path = paged_attention_path(q.shape, layer_shape, page_table.shape,
                                 k_pages.dtype)
     if path == "kernel":
         monitor.stat_add("STAT_paged_attn_kernel")  # traces, not calls
@@ -423,16 +681,17 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
     if path == "pool":
         monitor.stat_add("STAT_paged_attn_pool")  # traces, not calls
         if pool_mask is None:
-            pool_mask = paged_pool_mask(page_table, pos, k_pages.shape[1],
-                                        k_pages.shape[2])
+            pool_mask = paged_pool_mask(page_table, pos, *layer_shape[1:3])
         return paged_pool_attention(q, k_pages, v_pages, pool_mask, scale)
     monitor.stat_add("STAT_paged_attn_reference")  # traces, not calls
     if k_scales is not None:
-        kb = paged_gather_quantized(k_pages, k_scales, page_table, q.dtype)
-        vb = paged_gather_quantized(v_pages, v_scales, page_table, q.dtype)
+        kb = paged_gather_quantized(k_pages, k_scales, page_table, q.dtype,
+                                    hd)
+        vb = paged_gather_quantized(v_pages, v_scales, page_table, q.dtype,
+                                    hd)
     else:
-        kb = paged_gather(k_pages, page_table)
-        vb = paged_gather(v_pages, page_table)
+        kb = paged_gather(k_pages, page_table, hd)
+        vb = paged_gather(v_pages, page_table, hd)
     return cached_attention(q, kb, vb, pos, scale)
 
 
@@ -473,14 +732,17 @@ def sharded_paged_attention(mesh, scale, tp_axis="tp", quantized=False):
 
 @jax.named_scope("kv_gather")
 def paged_gather_layers(pages, page_table, scales=None,
-                        dtype=jnp.float32):
+                        dtype=jnp.float32, heads=None):
     """Materialize ONE sequence's page-table row as a dense view across
     ALL layers at once: pages [L, H, N, P, D] + page_table [PP] →
     [L, H, PP*P, D] (dequantized via per-page `scales` [L, H, N] in the
-    int8 mode). One gather from the whole pool instead of a per-layer
+    int8 mode; a fused pool [L, N, P, row] with `heads` = (H, D) given,
+    scales [L, N, H]). One gather from the whole pool instead of a per-layer
     `pages[layer]` slice — slicing the [L, ...] pool per layer copies
     the full layer buffer each time, which dwarfs the tail prefill's
     actual compute; gathering first touches only this row's pages."""
+    if pages.ndim == 4:        # fused [L, N, P, row], scales [L, N, H]
+        return _gather_rows(pages, page_table, 1, heads, scales, dtype)
     L, H, _, P, D = pages.shape
     PP = page_table.shape[0]
     kb = jnp.take(pages, page_table, axis=2)       # [L, H, PP, P, D]

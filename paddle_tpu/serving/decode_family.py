@@ -22,7 +22,11 @@ the model's family, which the model hands over from `decode_family()`:
     shard_weights(W, mesh)   (a family whose `check` admits tp > 1)
     make_cache(cfg, kv_dtype, mesh) -> PagedKVCache
                      the pools are the cache's `pools`, in the order the
-                     programs take and return them
+                     programs take and return them; the engine lays
+                     them out on the device as its step program was
+                     compiled to take them and holds every program to
+                     that layout (`generation.jit_program`), whatever
+                     the family
     decode_attention(cfg, tp, pools) -> str
                      the name `stats()["decode_attention"]` shows
     key_material()   what of the model shapes the programs (program store)

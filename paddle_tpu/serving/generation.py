@@ -469,11 +469,11 @@ class _ProgramPack:
 
     __slots__ = ("ledger", "loaded", "execs", "prefill", "tail",
                  "decode", "verify", "zero", "cow", "npool", "W",
-                 "tier_gather", "tier_write")
+                 "tier_gather", "tier_write", "formats", "preferred")
 
     def __init__(self, ledger, prefill, tail, decode, verify, zero, cow,
                  npool, W, loaded=None, execs=None, tier_gather=None,
-                 tier_write=None):
+                 tier_write=None, formats=None, preferred=None):
         self.ledger = ledger
         self.loaded = {} if loaded is None else loaded
         self.execs = {} if execs is None else execs
@@ -489,6 +489,38 @@ class _ProgramPack:
         # wrapper, or a supervised restart would retrace them
         self.tier_gather = tier_gather
         self.tier_write = tier_write
+        # the pools' layout the programs were compiled for, and the one
+        # the compiler chose when asked (PR 28)
+        self.formats = formats
+        self.preferred = preferred
+
+
+def jit_program(fn, name, fmts, counters=False, with_w=True, donates=True):
+    """A family's program body `fn` behind the engine's jit boundary: the
+    pools donated, and taken and returned as `fmts` says, one
+    `jax.experimental.layout.Format` a pool — the layout left to the
+    compiler where the step program is asked, the pools' own for every
+    program that serves (`GenerationEngine._build_programs`). `name` is the body's key in the
+    family's `build` (its signature: serving/decode_family.py);
+    `counters`: the decode program also returns the family's
+    `step_counters`."""
+    import jax
+    fmts = tuple(fmts)
+    # what the program takes after the pools and returns after them
+    # (None: it returns no pool)
+    n_in, n_out = {
+        "prefill": (3, 1), "prefill_tail": (4, 1),
+        "decode": (7, 2 + bool(counters)), "verify": (8, 3),
+        "zero_pages": (1, 0), "cow_copy": (2, 0),
+        "tier_gather": (1, None), "tier_write": (1 + len(fmts), 0)}[name]
+    lead = int(with_w)
+    return jax.jit(
+        fn,
+        donate_argnums=(tuple(range(lead, lead + len(fmts)))
+                        if donates else ()),
+        in_shardings=(None,) * lead + fmts + (None,) * n_in,
+        out_shardings=(None if n_out is None
+                       else fmts + (None,) * n_out))
 
 
 def _pool_view(i):
@@ -815,8 +847,12 @@ class GenerationEngine:
             self._loaded = pack.loaded
             self._store = None
             self._pack = pack
+            self._pool_formats = pack.formats
+            self._preferred = pack.preferred
+            self._note_pool_layout()
             return
         import jax
+        from jax.experimental.layout import Format, Layout
 
         # the trace-time closures capture the LEDGER and scalars, never
         # the engine object (ProgramContext). The programs' names are
@@ -826,30 +862,10 @@ class GenerationEngine:
         # `jit_gen_decode(...)`, and tools/trace_report.py sums device
         # time per program by them
         NP = self._npool = len(self._pool_arrays)
-        fns = self._family.build(ProgramContext(
+        self._fns = self._family.build(ProgramContext(
             self._cfg, self._tp, self._mesh, NP, self._quant_kv,
             self._decode_attention, self._W, self._ledger))
 
-        def jit(name, **kw):
-            # a program the family does not build (and whose option it
-            # therefore refused at construction) stays None
-            fn = fns.get(name)
-            return jax.jit(fn, **kw) if fn is not None else None
-
-        donate = tuple(range(1, 1 + NP))
-        pools_only = tuple(range(NP))
-        self._prefill_jit = jit("prefill", donate_argnums=donate)
-        self._tail_jit = jit("prefill_tail", donate_argnums=donate)
-        self._decode_jit = jit("decode", donate_argnums=donate)
-        self._verify_jit = (jit("verify", donate_argnums=donate)
-                            if self._spec_k else None)
-        self._zero_jit = jit("zero_pages", donate_argnums=pools_only)
-        self._cow_jit = jit("cow_copy", donate_argnums=pools_only)
-        self._tier_gather_jit = (jit("tier_gather")
-                                 if self._tier is not None else None)
-        self._tier_write_jit = (
-            jit("tier_write", donate_argnums=pools_only)
-            if self._tier is not None else None)
         # warm start (ISSUE 16): resolved AOT executables by program
         # name (ledger keys) + the store-load ledger; warmup fills them
         self._execs = {}
@@ -862,6 +878,72 @@ class GenerationEngine:
                 force=self._cfg.program_store_force)
             if self._store.refused:
                 self._store = None
+
+        # THE LAYOUT CONTRACT (PR 28). Every program takes and returns
+        # the pools in ONE layout, the one they lie in on the device, so
+        # a donated pool aliases straight into its output and no program
+        # relays a pool at its boundary — provided that layout is the one
+        # the step program (decode; verify where speculation replaces
+        # it) reads and writes in place. That is ASKED, not assumed: the
+        # step program is compiled with the pools' layout left to the
+        # compiler and its choice read back from that compile, the one
+        # that serves (`_execs`). Where the choice is how the pools lie —
+        # what the head pools' form (ops/paged_ops.HeadPoolForm) and the
+        # latent row's width exist to make true — nothing more happens.
+        # Where it is not, the pools can NOT be moved to it: an
+        # executable that JAX's persistent compile cache hands back
+        # returns its results in the default layout whatever it was
+        # compiled for (read on the v5e and on the CPU, PR 28), so a
+        # layout that is not the pools' own holds only until the next
+        # process. The step program is then compiled once more, held to
+        # the layout the pools have, and `stats()["pools"]` shows what
+        # the compiler `preferred`: a shape to repair, not a fault. No
+        # layout is written down here: each is read from an array or
+        # from a compile. A backend whose serialized executables are not
+        # to be trusted at all (`device.serialization_unsafe_backend`)
+        # is not asked.
+        from .. import device as _device
+        choice = (None if _device.serialization_unsafe_backend()
+                  else Layout.AUTO)
+        jit = self._jit_program
+        step = "verify" if self._spec_k else "decode"
+        step_name = (f"verify[k={self._spec_k}]" if self._spec_k
+                     else f"decode[m={self._cfg.max_slots}]")
+        step_args = (self._spec_arrays()[0] if self._spec_k
+                     else self._step_arrays())
+        fmts = tuple(a.format for a in self._pool_arrays)
+        abstract = [jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                         sharding=a.sharding)
+                    for a in self._pool_arrays]
+        args = (self._W, *abstract, *step_args)
+        with self._dev_ctx():
+            compiled = self._resolve_step(
+                step_name,
+                jit(step, [Format(choice, f.sharding) for f in fmts]), args)
+            chosen = tuple(compiled.input_formats[0][1:1 + NP])
+            if not (chosen == tuple(compiled.output_formats[:NP]) == fmts):
+                # (traced once all the same: the body's trace is reused)
+                compiled = jit(step, fmts).lower(*args).compile()
+        self._execs[step_name] = compiled
+        self._pool_formats = fmts
+        self._preferred = [_device.layout_name(f, a.shape, a.dtype)
+                           for a, f in zip(self._pool_arrays, chosen)]
+        self._note_pool_layout()
+
+        self._prefill_jit = jit("prefill", fmts)
+        self._tail_jit = jit("prefill_tail", fmts)
+        # `.lower()` of these two gives the step program's text; the
+        # engine itself runs `_execs[step_name]`, compiled above
+        self._decode_jit = jit("decode", fmts)
+        self._verify_jit = jit("verify", fmts) if self._spec_k else None
+        self._zero_jit = jit("zero_pages", fmts, with_w=False)
+        self._cow_jit = jit("cow_copy", fmts, with_w=False)
+        self._tier_gather_jit = (
+            jit("tier_gather", fmts, with_w=False, donates=False)
+            if self._tier is not None else None)
+        self._tier_write_jit = (
+            jit("tier_write", fmts, with_w=False)
+            if self._tier is not None else None)
         self._pack = _ProgramPack(
             ledger=self._ledger, prefill=self._prefill_jit,
             tail=self._tail_jit, decode=self._decode_jit,
@@ -869,7 +951,59 @@ class GenerationEngine:
             cow=self._cow_jit, npool=self._npool, W=self._W,
             loaded=self._loaded, execs=self._execs,
             tier_gather=self._tier_gather_jit,
-            tier_write=self._tier_write_jit)
+            tier_write=self._tier_write_jit, formats=self._pool_formats,
+            preferred=self._preferred)
+
+    def _jit_program(self, name, fmts, with_w=True, donates=True):
+        """`jit_program` over one of this engine's program bodies; a
+        program the family does not build (and whose option it therefore
+        refused at construction) stays None."""
+        fn = self._fns.get(name)
+        if fn is None:
+            return None
+        return jit_program(fn, name, fmts, bool(self._family.step_counters),
+                           with_w, donates)
+
+    def _resolve_step(self, name, auto_jit, args):
+        """The step program's executable, compiled with the pools' layout
+        left to the compiler (`args` holds the pools as shapes: an array
+        would bring a layout of its own). With a program store a
+        key-matched entry whose aliasing survived is taken instead, and
+        a live compile is written back, as `_warm_one` does for the
+        other programs."""
+        if self._store is not None:
+            hit = self._store.load(name)
+            if hit is not None:
+                compiled, recorded = hit
+                if self._selfcheck_alias(compiled, recorded) is None:
+                    self._loaded[name] = self._loaded.get(name, 0) + 1
+                    return compiled
+                monitor.stat_add("STAT_pack_selfcheck_failures")
+        compiled = auto_jit.lower(*args).compile()
+        if self._store is not None:
+            self._store.store(name, compiled)
+        return compiled
+
+    def _note_pool_layout(self):
+        """The pools lie as every program was compiled to take them (a
+        fresh pool: its default layout): checked, and told to the cache,
+        which reports it."""
+        from ..device import layout_name
+        self._check_pool_layout("built")
+        self._cache.note_layout(self._pool_arrays)
+        self._compiled_for = [
+            layout_name(f, a.shape, a.dtype)
+            for a, f in zip(self._pool_arrays, self._pool_formats)]
+
+    def _check_pool_layout(self, when: str):
+        """The pools the engine holds lie as its programs were compiled to
+        take them — or a program relays a pool at its boundary on every
+        call, which is what the contract exists to prevent: refuse."""
+        for a, f in zip(self._pool_arrays, self._pool_formats):
+            if a.format != f:
+                raise FatalError(
+                    f"{self.name}: {when}, a pool compiled for as {f} "
+                    f"lies as {a.format}")
 
     def _store_key_material(self) -> dict:
         """Everything that shapes the traced programs, JSON-able — the
@@ -1040,16 +1174,16 @@ class GenerationEngine:
             with self._dev_ctx():
                 if self._tp == 1:
                     return [jax.device_put(a) for a in [row] + blocks]
-                # stage straight onto the slice: each block is a FULL
-                # host page [C, L, H, ...] — split its head axis across
+                # stage straight onto the slice: each block is a chunk of
+                # FULL host pages — split its head axis across
                 # the mesh here so the donating tier_write dispatch
                 # pays no reshard (the overlap this path exists for)
-                from jax.sharding import NamedSharding, PartitionSpec
+                from jax.sharding import NamedSharding
+                form = self._cache.form
 
                 def ns(a):
-                    spec = [None] * a.ndim
-                    spec[2] = "tp"
-                    return NamedSharding(self._mesh, PartitionSpec(*spec))
+                    return NamedSharding(self._mesh,
+                                         form.spec(a.ndim, lead=1))
                 return [jax.device_put(row)] + [
                     jax.device_put(a, ns(a)) for a in blocks]
 
@@ -1273,6 +1407,9 @@ class GenerationEngine:
                 np.asarray(out[self._npool])
                 self._set_pools(out[:self._npool])
             self._zero_pages([])
+        # every program has run once: each returned the pools as it took
+        # them (an executable handed back by a compile cache included)
+        self._check_pool_layout("warmed")
 
     # -- request intake ----------------------------------------------------
 
@@ -2739,10 +2876,20 @@ class GenerationEngine:
         programs = {name: ("loaded" if loaded.get(name)
                            and not ledger.get(name) else "compiled")
                     for name in set(ledger) | set(loaded)}
+        pages = self._cache.stats()
         return {
             "slots": slots,
             "queue_depth": depth,
-            "pages": self._cache.stats(),
+            "pages": pages,
+            # what the engine holds (PR 28): per pool its logical shape,
+            # the layout the device holds it in, the one the programs
+            # were compiled for (the same), the one the compiler
+            # `preferred` for the step program when left to choose
+            # (another: that program relays the pool inside itself),
+            # device and logical bytes
+            "pools": [dict(p, compiled_for=c, preferred=w) for p, c, w in
+                      zip(pages["pools"], self._compiled_for,
+                          self._preferred)],
             "kv": self._kv_introspection(slot_of),
             "compiles": ledger,
             "loaded": loaded,

@@ -1,10 +1,13 @@
 """The GPT decode family: what `serving.GenerationEngine` asks a
 `models.GPTForCausalLM` for (`serving/decode_family.py`).
 
-A cached token is one K and one V row per head and layer: two head pools
-`[L, H, N, P, D]` (int8 pages add two scale pools), `paged_attention` over
-them by its shape rules, learned positions (so the largest position is the
-table's length). Every option of the engine is built for this family: the
+A cached token is one K and one V row per head and layer: two head pools in
+the form the head width takes (`ops/paged_ops.HeadPoolForm`: `[L, N, P, H*D]`, a
+row in whole lane tiles, for GPT-2's 64-wide heads, `[L, H, N, P, D]` for 128-wide ones; int8 pages add
+two scale pools), `paged_attention` over them by its shape rules, learned
+positions (so the largest position is the table's length). Nothing below
+writes a page or a head index out: whole pages, the tp specs and the shape
+rules' view of a layer all come from the form. Every option of the engine is built for this family: the
 prefix cache's tail prefill and copy-on-write, speculative verify, the host
 tier, int8 pages, tensor parallelism.
 
@@ -66,10 +69,11 @@ class GPTFamily:
         program's attention takes — a shape rule, so it is known before
         (and whether or not) anything is traced; under a tp mesh the rule
         sees the per-shard head count."""
-        from ..ops.paged_ops import paged_attention_path
+        from ..ops.paged_ops import HeadPoolForm, paged_attention_path
         kp, H = pools[0], self.num_heads // tp
         return paged_attention_path(
-            (cfg.max_slots, H, kp.shape[-1]), (H,) + tuple(kp.shape[2:]),
+            (cfg.max_slots, H, self.head_dim),
+            HeadPoolForm(H, self.head_dim).layer_shape(kp.shape),
             (cfg.max_slots, cfg.pages_per_seq), kp.dtype)
 
     def key_material(self):
@@ -84,7 +88,7 @@ class GPTFamily:
         from ..models.gpt import (gpt_decode_step, gpt_logits,
                                   gpt_prefill, gpt_prefill_extend,
                                   gpt_spec_verify)
-        from ..ops.paged_ops import (page_rows_for_positions,
+        from ..ops.paged_ops import (HeadPoolForm, page_rows_for_positions,
                                      paged_attention, paged_gather,
                                      paged_gather_layers,
                                      paged_gather_quantized,
@@ -99,6 +103,9 @@ class GPTFamily:
         # `psum` is the once-per-block partial-sum reduction the
         # row-parallel projections apply before their replicated bias
         H = self.num_heads // tp
+        # where the page axis and the head axis of these pools are
+        form = HeadPoolForm(H, self.head_dim)
+        hd = (H, self.head_dim)
         P, scale = ctx.cfg.page_size, self.scale
         psum = (lambda x: jax.lax.psum(x, "tp")) if tp > 1 else None
         top_k = ctx.cfg.top_k
@@ -184,12 +191,12 @@ class GPTFamily:
             # than the tail's compute
             if quant:
                 kp, vp, ksc, vsc = pools
-                kb_all = paged_gather_layers(kp, pt_row, ksc)
-                vb_all = paged_gather_layers(vp, pt_row, vsc)
+                kb_all = paged_gather_layers(kp, pt_row, ksc, heads=hd)
+                vb_all = paged_gather_layers(vp, pt_row, vsc, heads=hd)
             else:
                 kp, vp = pools
-                kb_all = paged_gather_layers(kp, pt_row)
-                vb_all = paged_gather_layers(vp, pt_row)
+                kb_all = paged_gather_layers(kp, pt_row, heads=hd)
+                vb_all = paged_gather_layers(vp, pt_row, heads=hd)
 
             def ctx_attend(layer, q, k, v):
                 return paged_prefix_attention(
@@ -216,15 +223,8 @@ class GPTFamily:
             pools = rest[:NP]
             src, dst = rest[NP], rest[NP + 1]
             note("cow_copy")
-            if quant:
-                kp, vp, ksc, vsc = pools
-                return (kp.at[:, :, dst].set(kp[:, :, src]),
-                        vp.at[:, :, dst].set(vp[:, :, src]),
-                        ksc.at[:, :, dst].set(ksc[:, :, src]),
-                        vsc.at[:, :, dst].set(vsc[:, :, src]))
-            kp, vp = pools
-            return (kp.at[:, :, dst].set(kp[:, :, src]),
-                    vp.at[:, :, dst].set(vp[:, :, src]))
+            return tuple(form.at_pages(p, dst).set(form.pages(p, src))
+                         for p in pools)
 
         # the decode cache threaded through gpt_decode_step's hooks:
         # (pools, page table, pool-dense ownership mask or None)
@@ -252,7 +252,8 @@ class GPTFamily:
             note(f"decode[m={tok.shape[0]}]")
             # pool-dense attention: the mask depends on the table and
             # `pos` alone, so every layer of the step shares this one
-            mask = (paged_pool_mask(pt, pos, pools[0].shape[2], P)
+            mask = (paged_pool_mask(pt, pos,
+                                    pools[0].shape[form.page_axis], P)
                     if pool_dense else None)
             logits, (pools, _, _) = gpt_decode_step(
                 W, tok, pos, (pools, pt, mask), write_kv, attend,
@@ -294,13 +295,13 @@ class GPTFamily:
                 if quant:
                     kp, vp, ksc, vsc = pools
                     kb = paged_gather_quantized(kp[layer], ksc[layer],
-                                                pt, q.dtype)
+                                                pt, q.dtype, hd)
                     vb = paged_gather_quantized(vp[layer], vsc[layer],
-                                                pt, q.dtype)
+                                                pt, q.dtype, hd)
                 else:
                     kp, vp = pools
-                    kb = paged_gather(kp[layer], pt)
-                    vb = paged_gather(vp[layer], pt)
+                    kb = paged_gather(kp[layer], pt, hd)
+                    vb = paged_gather(vp[layer], pt, hd)
                 return paged_prefix_attention(q, kb, vb, k, v, pos0,
                                               scale)
 
@@ -354,15 +355,8 @@ class GPTFamily:
             # owner starts from a clean quantization grid and a poisoned
             # page's scale can't survive its content
             pools, pages = rest[:NP], rest[NP]
-            if quant:
-                kp, vp, ksc, vsc = pools
-                return (kp.at[:, :, pages].set(0),
-                        vp.at[:, :, pages].set(0),
-                        ksc.at[:, :, pages].set(0.0),
-                        vsc.at[:, :, pages].set(0.0))
-            kp, vp = pools
-            return (kp.at[:, :, pages].set(0.0),
-                    vp.at[:, :, pages].set(0.0))
+            return tuple(form.zero_pages(p, pages, TRASH_PAGE)
+                         for p in pools)
 
         def gen_tier_gather(*rest):
             """Demotion gather (ISSUE 18): copy ONE page's raw blocks —
@@ -375,12 +369,7 @@ class GPTFamily:
             covered program to donate its pools."""
             pools, page = rest[:NP], rest[NP]
             note("tier_gather")
-            if quant:
-                kp, vp, ksc, vsc = pools
-                return (kp[:, :, page], vp[:, :, page],
-                        ksc[:, :, page], vsc[:, :, page])
-            kp, vp = pools
-            return (kp[:, :, page], vp[:, :, page])
+            return tuple(form.pages(p, page) for p in pools)
 
         def gen_tier_write(*rest):
             """Promotion scatter (ISSUE 18): write one fixed-width
@@ -390,19 +379,10 @@ class GPTFamily:
             standard pad contract, so the ONE compiled width
             (kv_tier_chunk_pages) covers every promotion length with
             zero retraces."""
-            pools = rest[:NP]
-            note(f"tier_write[w={rest[NP].shape[0]}]")
-            if quant:
-                pages, kb, vb, ksb, vsb = rest[NP:]
-                kp, vp, ksc, vsc = pools
-                return (kp.at[:, :, pages].set(jnp.moveaxis(kb, 0, 2)),
-                        vp.at[:, :, pages].set(jnp.moveaxis(vb, 0, 2)),
-                        ksc.at[:, :, pages].set(jnp.moveaxis(ksb, 0, 2)),
-                        vsc.at[:, :, pages].set(jnp.moveaxis(vsb, 0, 2)))
-            pages, kb, vb = rest[NP:]
-            kp, vp = pools
-            return (kp.at[:, :, pages].set(jnp.moveaxis(kb, 0, 2)),
-                    vp.at[:, :, pages].set(jnp.moveaxis(vb, 0, 2)))
+            pools, pages, blocks = rest[:NP], rest[NP], rest[NP + 1:]
+            note(f"tier_write[w={pages.shape[0]}]")
+            return tuple(form.at_pages(p, pages).set(form.from_chunk(b))
+                         for p, b in zip(pools, blocks))
 
         if tp > 1:
             # partition every program over the 'tp' mesh axis: W enters
@@ -416,14 +396,13 @@ class GPTFamily:
             from ..models.gpt import decode_weight_specs
             rep = PS()
             wspec = decode_weight_specs(ctx.W)
-            pool5 = PS(None, "tp", None, None, None)   # [L,H,N,Pg,D]
-            grid3 = PS(None, "tp", None)               # [L,H,N]
-            pspecs = ((pool5, pool5, grid3, grid3) if quant
-                      else (pool5, pool5))
-            page4 = PS(None, "tp", None, None)         # one page [L,H,Pg,D]
-            page2 = PS(None, "tp")                     # scale row [L,H]
-            chunk5 = PS(None, None, "tp", None, None)  # [W,L,H,Pg,D]
-            chunk3 = PS(None, None, "tp")              # [W,L,H]
+            # the head axis of a pool, of one page cut out of it, and of
+            # a chunk of such pages stacked in front: the form's
+            ranks = (form.pool_rank, form.pool_rank, 3, 3)[:NP]  # K, V[,
+            #                                                their scales]
+            pspecs = tuple(form.spec(r) for r in ranks)
+            page_specs = tuple(form.spec(r - 1) for r in ranks)
+            chunk_specs = tuple(form.spec(r, lead=1) for r in ranks)
 
             def shard(fn, extras, outs, with_w=True):
                 ins = ((wspec,) if with_w else ()) + pspecs + extras
@@ -445,15 +424,10 @@ class GPTFamily:
             # the gather's sharded out_specs reassemble every head
             # shard into one host block, and the write's chunk specs
             # split the staged full blocks back across the slice
-            gen_tier_gather = shard(
-                gen_tier_gather, (rep,),
-                (page4, page4, page2, page2) if quant
-                else (page4, page4), with_w=False)
-            gen_tier_write = shard(
-                gen_tier_write,
-                (rep, chunk5, chunk5, chunk3, chunk3) if quant
-                else (rep, chunk5, chunk5),
-                pspecs, with_w=False)
+            gen_tier_gather = shard(gen_tier_gather, (rep,), page_specs,
+                                    with_w=False)
+            gen_tier_write = shard(gen_tier_write, (rep, *chunk_specs),
+                                   pspecs, with_w=False)
 
         return {"prefill": gen_prefill, "prefill_tail": gen_prefill_tail,
                 "decode": gen_decode, "verify": gen_verify,

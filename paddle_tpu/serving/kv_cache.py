@@ -2,17 +2,21 @@
 
 The pools come from a description of what one cached token IS. Head pools
 (the constructor, the GPT family): a K and a V row per head, two pools
-`[L, H, N, P, D]`, as described below. `PagedKVCache.described(shapes, ...)`
-(the latent-attention family, models/glm_moe.py) builds whatever pools the
-family names by shape and dtype — ONE pool `[L, N, P, row]` with no head
-axis. Pages, tables, the scratch page, refcounts and admission arithmetic
-are the same for both; the engine threads `pools`, whatever they are,
-through its programs.
+whose form follows the head width (`ops/paged_ops.HeadPoolForm`, the one
+place that knows where the page axis and the head axis are): 64-wide heads
+take `[L, N, P, H*D]`, a token's heads side by side in one dense row of
+whole 128-lane tiles (so that the device's default layout is row-major);
+128-wide heads keep `[L, H, N, P, D]`, which JAX's paged kernel reads in
+place. `PagedKVCache.described(shapes, ...)` (the latent-attention family,
+models/glm_moe.py) builds whatever pools the family names by shape and
+dtype — ONE pool `[L, N, P, row]` with no head axis. Pages, tables, the
+scratch page, refcounts and admission arithmetic are the same for all; the
+engine threads `pools`, whatever they are, through its programs, and tells
+the cache which layout they took on the device (`note_layout`).
 
 vLLM's PagedAttention memory model on TPU terms: decode-time K/V for
-every live sequence lives in ONE pair of preallocated pools
-`[L, H, num_pages, page_size, D]`, carved into fixed-size pages handed
-out by a free-list allocator. A sequence owns `ceil(tokens / page_size)`
+every live sequence lives in ONE pair of preallocated pools, carved into
+fixed-size pages handed out by a free-list allocator. A sequence owns `ceil(tokens / page_size)`
 pages recorded in a fixed-width page-table row (trash-padded), so the
 device-side shapes never depend on how many sequences are live or how
 long they are — the prerequisite for the generation engine's single
@@ -92,6 +96,11 @@ def _note_shard_bytes(delta: int) -> None:
     monitor.stat_gauge_add("STAT_tp_kv_shard_bytes", delta)
 
 
+def _ungauge(gauged) -> None:
+    _note_pool_bytes(-gauged[0])
+    _note_shard_bytes(-gauged[1])
+
+
 class PagedKVCache:
     """Block allocator over per-layer paged K/V pools.
 
@@ -102,9 +111,9 @@ class PagedKVCache:
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  page_size: int, num_pages: int, pages_per_seq: int,
                  dtype="float32", mesh=None, tp_axis: str = "tp"):
-        """Head pools: K and V `[L, H, N, P, D]`, plus the two scale
-        pools `[L, H, N]` in the int8 page mode, head-sharded on a tp
-        mesh. `described` builds any other pools."""
+        """Head pools: K and V in the form the head width takes
+        (`self.form`), plus the two scale pools in the int8 page mode,
+        head-sharded on a tp mesh. `described` builds any other pools."""
         self._init_pages(page_size, num_pages, pages_per_seq)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
@@ -113,9 +122,10 @@ class PagedKVCache:
         self.quantized = self.dtype == "int8"
         # mesh-sliced pools (ISSUE 19): on a tp mesh the K/V pools (and
         # the int8 scale grids) are laid out head-sharded with
-        # NamedSharding — each device holds [L, H/tp, N, P, D], so one
-        # chip's HBM pays total/tp and the page axis stays FULL on every
-        # shard (page ids, tables and the allocator are tp-invariant)
+        # NamedSharding — each device holds heads/tp of every page, so
+        # one chip's HBM pays total/tp and the page axis stays FULL on
+        # every shard (page ids, tables and the allocator are
+        # tp-invariant)
         self.mesh = mesh
         self.tp_axis = str(tp_axis)
         self.tp = int(mesh.shape[tp_axis]) if mesh is not None else 1
@@ -124,8 +134,12 @@ class PagedKVCache:
                 f"num_heads={self.num_heads} not divisible by "
                 f"tp={self.tp} — head-sharded pools need equal slices")
         import jax.numpy as jnp
-        shape = (self.num_layers, self.num_heads, self.num_pages,
-                 self.page_size, self.head_dim)
+
+        from ..ops.paged_ops import HeadPoolForm
+        # the shape rule (ops/paged_ops.head_pools_fused): no flag
+        self.form = HeadPoolForm(self.num_heads, self.head_dim, self.tp)
+        shape = self.form.pool_shape(self.num_layers, self.num_pages,
+                                     self.page_size)
         self.k_pages = self._place(jnp.zeros(shape, self.dtype))
         self.v_pages = self._place(jnp.zeros(shape, self.dtype))
         # int8 page mode: per-(layer, head, page) symmetric abs-max
@@ -133,7 +147,7 @@ class PagedKVCache:
         # "page empty" — zero-on-free resets both pools, so a freed
         # page's next owner starts from a clean quantization grid)
         if self.quantized:
-            sshape = (self.num_layers, self.num_heads, self.num_pages)
+            sshape = self.form.scale_shape(self.num_layers, self.num_pages)
             self.k_scales = self._place(jnp.zeros(sshape, "float32"))
             self.v_scales = self._place(jnp.zeros(sshape, "float32"))
             self.pools = (self.k_pages, self.v_pages, self.k_scales,
@@ -160,6 +174,7 @@ class PagedKVCache:
         self.dtype = str(self.pools[0].dtype)
         self.quantized = False
         self.mesh, self.tp = None, 1
+        self.form = None            # no head axis: the family's own rows
         self._note_pools()
         return self
 
@@ -189,25 +204,60 @@ class PagedKVCache:
 
     def _note_pools(self):
         self._pool_bytes = sum(int(p.nbytes) for p in self.pools)
+        # until the engine says otherwise (`note_layout`) the pools lie
+        # as allocated: the default layout, device bytes = logical bytes
+        self._pool_info = [
+            {"shape": list(p.shape), "dtype": str(p.dtype),
+             "layout": "default", "device_bytes": int(p.nbytes),
+             "logical_bytes": self._logical_bytes(p)} for p in self.pools]
+        # the gauges count DEVICE bytes; one mutable cell so the
+        # finalizer takes back whatever `note_layout` made of them
+        self._gauged = [0, 0]
+        weakref.finalize(self, _ungauge, self._gauged)
+        self._gauge()
+
+    def _logical_bytes(self, pool) -> int:
+        """The bytes of what a pool stores: a fused head pool's rows are
+        whole lane tiles, of which the heads fill `form.used` lanes."""
+        form = self.form
+        if form is not None and form.fused and pool.ndim == 4:
+            return int(pool.nbytes) * form.used // form.row
+        return int(pool.nbytes)
+
+    def _gauge(self):
         b = self.hbm_bytes()
-        _note_pool_bytes(b)
-        weakref.finalize(self, _note_pool_bytes, -b)
+        _note_pool_bytes(b - self._gauged[0])
+        self._gauged[0] = b
         if self.tp > 1:
             s = self.shard_hbm_bytes()
-            _note_shard_bytes(s)
-            weakref.finalize(self, _note_shard_bytes, -s)
+            _note_shard_bytes(s - self._gauged[1])
+            self._gauged[1] = s
+
+    def note_layout(self, pools):
+        """The engine laid the pools out as its decode program was
+        compiled to take them: record, per pool, the layout the device
+        holds (`default`, or `major_to_minor` + tiling as the compiler
+        reported it) and the bytes it takes there, and let the gauges
+        count those. Nothing else in the cache depends on the layout."""
+        from ..device import array_layout
+        self._pool_info = []
+        for p in pools:
+            name, device_bytes = array_layout(p)
+            self._pool_info.append(
+                {"shape": list(p.shape), "dtype": str(p.dtype),
+                 "layout": name, "device_bytes": device_bytes,
+                 "logical_bytes": self._logical_bytes(p)})
+        self._gauge()
 
     def _place(self, arr):
-        """Lay one pool onto the tp mesh head-sharded (axis 1); a
-        mesh-less cache keeps the single-device default placement."""
+        """Lay one pool onto the tp mesh head-sharded; a mesh-less cache
+        keeps the single-device default placement."""
         if self.mesh is None:
             return arr
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec
-        spec = [None] * arr.ndim
-        spec[1] = self.tp_axis
-        return jax.device_put(
-            arr, NamedSharding(self.mesh, PartitionSpec(*spec)))
+        from jax.sharding import NamedSharding
+        return jax.device_put(arr, NamedSharding(
+            self.mesh, self.form.spec(arr.ndim, self.tp_axis)))
 
     # -- capacity arithmetic ----------------------------------------------
 
@@ -225,11 +275,14 @@ class PagedKVCache:
         if tp < 1 or num_heads % tp != 0:
             raise InvalidArgumentError(
                 f"num_heads={num_heads} not divisible by tp={tp}")
+        from ..ops.paged_ops import HeadPoolForm
         item = np.dtype(dtype).itemsize
-        hl = num_heads // tp
-        b = 2 * num_layers * hl * page_size * head_dim * item
+        # the lanes a token takes in a pool: the form's (a fused row is
+        # whole 128-lane tiles a shard)
+        row = HeadPoolForm(num_heads, head_dim, tp).row // tp
+        b = 2 * num_layers * page_size * row * item
         if str(dtype) == "int8":
-            b += 2 * num_layers * hl * 4  # fp32 scale per (L, H/tp)
+            b += 2 * num_layers * (num_heads // tp) * 4  # fp32 scale per (L, H/tp)
         return b
 
     def page_host_bytes(self) -> int:
@@ -261,8 +314,9 @@ class PagedKVCache:
 
     def hbm_bytes(self) -> int:
         """Live device bytes of the K/V pools + scale pools (summed
-        across every shard on a tp mesh)."""
-        return self._pool_bytes
+        across every shard on a tp mesh), in the layout the device holds
+        them: a padded tiling counts."""
+        return sum(i["device_bytes"] for i in self._pool_info)
 
     def shard_hbm_bytes(self) -> int:
         """Per-device pool bytes: heads shard evenly over tp, so ONE
@@ -534,7 +588,12 @@ class PagedKVCache:
         return {
             "dtype": self.dtype,
             "quantized": self.quantized,
-            "pools": [list(p.shape) for p in self.pools],
+            # per pool: logical shape, the layout the device holds it in,
+            # device and logical bytes (`note_layout`)
+            "pools": [dict(i) for i in self._pool_info],
+            # the head pools' form by the head-width rule (None: the
+            # family described its own pools)
+            "pool_form": self.form.name if self.form is not None else None,
             "hbm_bytes": self.hbm_bytes(),
             # mesh-slice lanes (ISSUE 19): per-device pool bytes — what
             # ONE chip's HBM actually pays (== hbm_bytes when tp == 1)

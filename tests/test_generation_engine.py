@@ -252,7 +252,7 @@ def test_poisoned_sequence_fails_alone_and_pages_scrub(model):
         if not fired and req is not None and len(req.toks) >= 2:
             pages = eng._cache.owned(req.rid)
             if pages:
-                eng._kp = eng._kp.at[:, :, pages].set(np.nan)
+                eng._kp = eng._cache.form.at_pages(eng._kp, pages).set(np.nan)
                 fired.append(req.rid)
 
     with _engine(model, num_pages=64) as eng:
@@ -292,7 +292,7 @@ def test_poisoned_v_pages_fail_their_sequence_alone(model):
         if not fired and req is not None and len(req.toks) >= 2:
             pages = eng._cache.owned(req.rid)
             if pages:
-                eng._vp = eng._vp.at[:, :, pages].set(np.nan)
+                eng._vp = eng._cache.form.at_pages(eng._vp, pages).set(np.nan)
                 fired.append(req.rid)
 
     with _engine(model, num_pages=64) as eng:

@@ -137,13 +137,16 @@ def test_a_poisoned_latent_page_reaches_its_owner_only(owner):
 def test_a_latent_cache_is_one_pool_without_a_head_axis():
     c = PagedKVCache.described([((3, 8, 16, 640), "bfloat16")], 16, 8, 4)
     assert len(c.pools) == 1 and c.pools[0].shape == (3, 8, 16, 640)
-    assert c.stats()["pools"] == [[3, 8, 16, 640]] and not c.quantized
+    assert [p["shape"] for p in c.stats()["pools"]] == [[3, 8, 16, 640]]
+    assert c.stats()["pool_form"] is None and not c.quantized
     assert c.hbm_bytes() == 3 * 8 * 16 * 640 * 2
     assert c.page_host_bytes() == 3 * 16 * 640 * 2
     c.alloc(7, 40)                                     # same allocator
     assert c.pages_in_use == 3 and c.free_pages == 4
     heads = PagedKVCache(3, 2, 8, 16, 8, 4)
-    assert heads.stats()["pools"] == [[3, 2, 8, 16, 8]] * 2
+    # 8-wide heads: a token's two heads side by side in one row of a
+    # whole lane tile
+    assert [p["shape"] for p in heads.stats()["pools"]] == [[3, 8, 16, 128]] * 2
     assert heads.page_host_bytes() == PagedKVCache.page_hbm_bytes(
         3, 2, 8, 16)
     q = PagedKVCache(3, 2, 8, 16, 8, 4, dtype="int8")
@@ -240,7 +243,7 @@ def test_the_engine_serves_the_model_token_for_token(tiny):
         assert st["decode_attention"] == "latent_gather"
         assert st["compiles"] == {"prefill[b=16]": 1, "prefill[b=32]": 1,
                                   "decode[m=4]": 1}
-        assert st["pages"]["pools"] == [[3, 64, 4, 128]]
+        assert [p["shape"] for p in st["pools"]] == [[3, 64, 4, 128]]
         streams = [eng.submit_stream(p, max_new_tokens=9) for p in prompts]
         outs = [np.asarray(s.result(120)) for s in streams]
         assert eng.stats()["compiles"] == st["compiles"]    # none after

@@ -215,12 +215,13 @@ def test_int8_cow_clones_scales_and_free_never_zeroes_sharer(model):
         a = eng.generate(p8, max_new_tokens=4)   # registers the chain
         chain = sorted(eng._cache.cached_pages())
         assert len(chain) == 2
-        scales_before = np.asarray(eng._ks)[:, :, chain].copy()
+        form = eng._cache.form
+        scales_before = form.pages(np.asarray(eng._ks), chain).copy()
         assert float(np.abs(scales_before).max()) > 0
         b = eng.generate(p8, max_new_tokens=4)   # CoW split + decode
         # the sharer completed and freed; the cached chain's pages and
         # scale rows must be untouched (zero-on-free deferred)
-        scales_after = np.asarray(eng._ks)[:, :, chain]
+        scales_after = form.pages(np.asarray(eng._ks), chain)
         np.testing.assert_array_equal(scales_before, scales_after)
         cw = eng.stats()["kv"]["prefix"]
         assert cw["hits"] >= 1

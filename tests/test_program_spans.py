@@ -262,9 +262,11 @@ def lowered_programs(gpt):
                       kv_tier=True)
     try:
         W, pools = eng._W, eng._pools()
-        L, H, _, P, D = pools[0].shape
         C = eng._cfg.kv_tier_chunk_pages
-        chunk = np.zeros((C, L, H, P, D), pools[0].dtype)
+        # a chunk of C whole pages stacked in front, as the host tier
+        # holds them: a page is the pool with its page axis cut out
+        page = eng._cache.form.pages(pools[0], 0).shape
+        chunk = np.zeros((C,) + page, pools[0].dtype)
         ids = np.zeros((1, 16), np.int32)
         row = np.zeros((eng._cfg.pages_per_seq,), np.int32)
         five, four = np.int32(5), np.int32(4)

@@ -232,9 +232,12 @@ def test_unsliceable_output_verdict_under_quantized_artifact(tmp_path):
 def test_kv_cache_int8_scale_pools_and_budget_arithmetic():
     c = PagedKVCache(2, 3, 8, 4, 16, 4, dtype="int8")
     assert c.quantized and str(c.k_pages.dtype) == "int8"
-    assert c.k_scales.shape == (2, 3, 16)
-    assert c.v_scales.shape == (2, 3, 16)
-    assert c.hbm_bytes() == (2 * 2 * 3 * 16 * 4 * 8      # int8 pools
+    # 8-wide heads take the fused form: 3 x 8 values in a row of one
+    # whole lane tile, a scale per (layer, page, head)
+    assert c.form.fused and c.k_pages.shape == (2, 16, 4, 128)
+    assert c.k_scales.shape == (2, 16, 3)
+    assert c.v_scales.shape == (2, 16, 3)
+    assert c.hbm_bytes() == (2 * 2 * 16 * 4 * 128        # int8 pools
                              + 2 * 2 * 3 * 16 * 4)       # fp32 scales
     dims = dict(num_layers=2, num_heads=3, head_dim=8, page_size=4)
     per_fp = PagedKVCache.page_hbm_bytes(dtype="float32", **dims)
@@ -401,7 +404,9 @@ def test_prefill_pad_tail_never_touches_real_page_scales(gpt_model):
             ks = np.asarray(eng._ks)
             # prompt 12, page_size 4: pages[0:3] hold real tokens,
             # pages[3:] are decode-reserve — untouched by prefill
-            seen.append((ks[:, :, pages[:3]], ks[:, :, pages[3:]]))
+            form = eng._cache.form
+            seen.append((form.pages(ks, pages[:3]),
+                         form.pages(ks, pages[3:])))
 
     eng = serving.GenerationEngine(
         gpt_model, max_slots=2, page_size=4, num_pages=32,
@@ -444,8 +449,9 @@ def test_int8_kv_poison_isolated_and_scale_pool_scrubbed(gpt_model):
         if not fired and req is not None and len(req.toks) >= 2:
             pages = eng._cache.owned(req.rid)
             if pages:
-                eng._kp = eng._kp.at[:, :, pages].set(127)
-                eng._ks = eng._ks.at[:, :, pages].set(np.nan)
+                form = eng._cache.form
+                eng._kp = form.at_pages(eng._kp, pages).set(127)
+                eng._ks = form.at_pages(eng._ks, pages).set(np.nan)
                 poisoned_pages.extend(pages)
                 fired.append(req.rid)
 
@@ -464,8 +470,9 @@ def test_int8_kv_poison_isolated_and_scale_pool_scrubbed(gpt_model):
         # the victim's pages AND scales were zeroed on free
         ks = np.asarray(eng._ks)
         kp = np.asarray(eng._kp)
-        assert np.all(ks[:, :, poisoned_pages] == 0.0)
-        assert np.all(kp[:, :, poisoned_pages] == 0)
+        form = eng._cache.form
+        assert np.all(form.pages(ks, poisoned_pages) == 0.0)
+        assert np.all(form.pages(kp, poisoned_pages) == 0)
         # a follow-up request reusing those pages decodes cleanly
         out_c = eng.generate(prompts[0], max_new_tokens=12)
         np.testing.assert_array_equal(out_c, ref[0])

@@ -197,7 +197,9 @@ def test_tp_page_arithmetic_per_shard():
     slice: the same per-chip budget admits tp× the pages — the
     serve-larger-models unlock, and the admission arithmetic stays in
     tp-invariant page units (the page axis is full on every shard)."""
-    kw = dict(num_layers=2, num_heads=4, head_dim=16, page_size=4)
+    # (64-wide heads: a shard's two heads fill a 128-lane tile exactly;
+    # narrower toy heads would pad each shard's row to a whole tile)
+    kw = dict(num_layers=2, num_heads=4, head_dim=64, page_size=4)
     full = PagedKVCache.page_hbm_bytes(**kw)
     half = PagedKVCache.page_hbm_bytes(**kw, tp=2)
     assert half * 2 == full
