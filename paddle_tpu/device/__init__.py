@@ -18,13 +18,10 @@ on jax 0.9.0 on the CPU backend too: a donated dp-sharded train step and
 the donated `Model.train_batch` step give identical losses from a cold
 and a warm cache, so the cache is no longer refused there.
 
-The serving program store (`serving/program_store.py`) deserializes
-executables by its own route, which has NOT been re-verified on the CPU
-backend; `serialization_unsafe_backend()` keeps refusing it there unless
-forced (ROADMAP Queue 3 item 5 decides the store's fate).
+This cache is the system's one warm start: a process that finds its
+programs there compiles nothing anew.
 """
 import os as _os
-import warnings as _warnings
 
 import jax as _jax
 
@@ -34,16 +31,12 @@ from ..framework.place import (CPUPlace, CUDAPlace, TPUPlace, device_count,
 
 __all__ = ["set_device", "get_device", "CPUPlace", "CUDAPlace", "TPUPlace",
            "device_count", "is_compiled_with_cuda", "is_compiled_with_tpu",
-           "cuda", "COMPILE_CACHE_DIR", "compilation_cache_dir",
-           "serialization_unsafe_backend", "warn_forced_serialization",
-           "program_store_dir"]
+           "cuda", "COMPILE_CACHE_DIR", "compilation_cache_dir"]
 
 # <checkout>/.jax_cache — derived from this package's location only
 COMPILE_CACHE_DIR = _os.path.join(
     _os.path.dirname(_os.path.dirname(_os.path.dirname(
         _os.path.abspath(__file__)))), ".jax_cache")
-
-_force_warned = False  # one warning per process
 
 
 def _place_compilation_cache():
@@ -59,42 +52,6 @@ _place_compilation_cache()
 def compilation_cache_dir():
     """Directory of JAX's persistent compile cache in this process."""
     return _jax.config.jax_compilation_cache_dir
-
-
-def serialization_unsafe_backend() -> bool:
-    """True on the CPU backend, where the program store's deserialized
-    executables have not been shown to keep input/output buffer aliasing
-    (a donated program that lost it reads freed buffers); the generation
-    engine does not ask this backend's compiler which layout it would
-    give the pools either. Initialises the default backend."""
-    return _jax.default_backend() == "cpu"
-
-
-def warn_forced_serialization(context: str) -> None:
-    """One warning per process when a caller overrides the CPU gate
-    (`force=True`), so the override is never silent."""
-    global _force_warned
-    if _force_warned:
-        return
-    _force_warned = True
-    _warnings.warn(
-        f"{context}: forcing serialized-executable reuse on the CPU "
-        f"backend, where deserialized executables have not been shown "
-        f"to keep input/output donation aliasing (the corruption class: "
-        f"a donated program silently reads freed buffers); every load "
-        f"therefore runs the donation-aliasing self-check and a numeric "
-        f"smoke probe, and falls back to live compile on any mismatch.",
-        RuntimeWarning, stacklevel=3)
-
-
-def program_store_dir():
-    """Root directory configured for the serving program store
-    (FLAGS_gen_program_store_dir, expanded; None when unset = store
-    off). Resolution only — the CPU-soundness decision lives in
-    `serialization_unsafe_backend()`, applied by the store itself."""
-    from ..framework.flags import flag
-    d = str(flag("FLAGS_gen_program_store_dir") or "").strip()
-    return _os.path.expanduser(d) if d else None
 
 
 def layout_name(fmt, shape, dtype) -> str:

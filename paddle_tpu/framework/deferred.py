@@ -9,8 +9,8 @@ device so the fit loop can run ahead of the accelerator and only pay one
 sync per `log_freq` steps (same overlap trick as jax.block_until_ready
 placement in Bradbury et al.'s async dispatch model).
 
-Every materialization bumps `STAT_train_host_syncs` so tests and `bench.py`
-can assert the sync budget of a training loop.
+Every materialization bumps `STAT_train_host_syncs` so tests can assert the
+sync budget of a training loop.
 """
 from __future__ import annotations
 

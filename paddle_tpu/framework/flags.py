@@ -80,7 +80,8 @@ register_flag("FLAGS_kv_cache_dtype", "auto",
               "fp32 scale pools, quantize-on-append / dequantize-on-"
               "read (ops/paged_ops.py), ~4x pages per HBM byte so the "
               "same pool budget admits ~4x the concurrent sequences "
-              "(bench.py --mode quant gates >=1.9x at equal bytes); "
+              "(tests/test_quantized_serving.py holds >=1.9x at equal "
+              "bytes); "
               "'float32'/'bfloat16' force an unquantized page dtype")
 register_flag("FLAGS_paged_page_size", 16,
               "tokens per KV-cache page (serving.PagedKVCache); the TPU "
@@ -188,32 +189,13 @@ register_flag("FLAGS_kv_tier_chunk_pages", 4,
               "(serving/kv_tier.py): the engine device_put-stages chunk "
               "i+1 while chunk i's jitted scatter is in flight — the "
               "double-buffer depth knob, and the fixed width of the ONE "
-              "compiled tier_write program (trace-shaping: part of the "
-              "program-store content key)")
-register_flag("FLAGS_gen_program_store_dir", "",
-              "serving.GenerationEngine: root directory of the on-disk "
-              "AOT executable store (serving/program_store.py) — warmup "
-              "loads serialized prefill/tail/decode/verify/cow programs "
-              "under a content key instead of tracing when the key "
-              "matches (miss compiles as today, then writes back), so a "
-              "fresh PROCESS warm-starts in seconds. Empty = off. "
-              "Refused on the CPU backend (the PR 1 aliasing-drop "
-              "corruption class, device.serialization_unsafe_backend) "
-              "unless FLAGS_gen_program_store_force")
-register_flag("FLAGS_gen_program_store_force", False,
-              "serving.GenerationEngine: use the program store even on "
-              "a backend where device.serialization_unsafe_backend() "
-              "is True (XLA:CPU) — emits the one-time PR 1 corruption-"
-              "class warning; every load still runs the donation-"
-              "aliasing self-check + numeric smoke probe and falls "
-              "back to live compile on any mismatch")
+              "compiled tier_write program")
 register_flag("FLAGS_gen_step_log", True,
               "serving.GenerationEngine: record one compact scheduler "
               "record per engine iteration into the bounded per-engine "
               "step ring (profiler/step_log.py; /steps, chrome counter "
               "tracks, engine_step_ms/gen_queue_age_ms histograms); off "
-              "removes the per-iteration accounting entirely "
-              "(bench.py --mode generation A/Bs it, <2% gate)")
+              "removes the per-iteration accounting entirely")
 register_flag("FLAGS_gen_step_log_size", 4096,
               "per-engine step-ring capacity in records; the oldest "
               "record is overwritten (same bounding discipline as "
@@ -304,8 +286,7 @@ register_flag("FLAGS_router_affinity", True,
               "prefix-affinity placement (serving/router.py): steer a "
               "request to the replica whose sketch holds the longest "
               "blake2b chain over the prompt's leading full pages; "
-              "False = pure round-robin over undrained replicas (the "
-              "bench.py --mode router A/B arm)")
+              "False = pure round-robin over undrained replicas")
 register_flag("FLAGS_router_sketch_digests", 8192,
               "per-replica LRU sketch capacity, in chain digests, the "
               "router's affinity placement matches against — bounds "

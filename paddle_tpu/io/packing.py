@@ -38,8 +38,8 @@ a sequence no row can host is DROPPED (counted, warned once) — size
 `rows` for your length distribution (`suggest_rows`) so drops stay rare.
 
 `policy="pad"` is the one-sequence-per-row baseline (classic pad-to-max
-with the same tensor layout) — the control arm of `bench.py --mode
-packing` and of parity tests.
+with the same tensor layout) — the control arm of the parity tests
+(`tests/test_packing.py`).
 
 Counters (framework/monitor.py): STAT_packing_packs,
 STAT_packing_sequences, STAT_packing_tokens (real), STAT_packing_slots

@@ -27,8 +27,7 @@ from ..framework.tensor import Tensor
 
 __all__ = ["to_static", "declarative", "save", "load", "TranslatedLayer",
            "not_to_static", "ProgramTranslator", "enable_to_static",
-           "dy2static", "serialize_compiled", "deserialize_compiled",
-           "compiled_alias_spec", "pytree_spec", "key_material_digest"]
+           "dy2static"]
 
 from .dy2static import ProgramTranslator, ast_transform, enable_to_static
 
@@ -421,81 +420,3 @@ def load(path, **configs):
             state = pickle.load(f)
     return TranslatedLayer(exported, state,
                            quant=load_meta(path).get("quant"))
-
-
-# -- AOT executable serialization (ISSUE 16) --------------------------------
-#
-# The serving program store (`serving/program_store.py`) persists the
-# engine's compiled programs across PROCESSES; these are the shared
-# primitives it and `tools/pack_inspect.py` build on. They ride
-# `jax.experimental.serialize_executable` — a different artifact path
-# than the persistent compilation cache, but the PR 1 lesson applies to
-# both: a deserialized donated program is only trustworthy if its
-# input/output aliasing survived the round trip, so the alias spec is
-# introspectable here and checked on every load.
-
-def serialize_compiled(compiled) -> bytes:
-    """One opaque blob for a `jax.stages.Compiled`: the XLA executable
-    payload plus the input/output pytree defs its caller signature
-    needs (all three pickle cleanly on this stack)."""
-    from jax.experimental import serialize_executable as _se
-    payload, in_tree, out_tree = _se.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree),
-                        protocol=pickle.HIGHEST_PROTOCOL)
-
-
-def deserialize_compiled(blob: bytes):
-    """Inverse of `serialize_compiled` → a callable
-    `jax.stages.Compiled` loaded onto the current backend."""
-    from jax.experimental import serialize_executable as _se
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return _se.deserialize_and_load(payload, in_tree, out_tree)
-
-
-def compiled_alias_spec(compiled) -> str:
-    """The executable's input/output donation-aliasing spec as a
-    canonical string ("" when the program aliases nothing). Extracted
-    from the optimized HLO module header — the one place XLA states
-    what the RUNTIME will actually alias, which is exactly what the
-    PR 1 incident showed can silently differ from what jit was asked
-    to donate."""
-    import re
-    mods = compiled.runtime_executable().hlo_modules()
-    specs = []
-    for m in mods:
-        head = m.to_string()[:4000]
-        got = re.search(r"input_output_alias=\{(.*?)\}, entry", head)
-        if got:
-            spec = " ".join(got.group(1).split())
-            if spec:
-                specs.append(spec)
-    return "; ".join(specs)
-
-
-def pytree_spec(tree) -> list:
-    """Structural fingerprint of a pytree of arrays: sorted
-    [path, shape, dtype] triples. For a quantized decode-weight tree
-    the (int8 value, fp32 scale) leaf pairs land here with their own
-    dtypes/shapes, so this doubles as the quant-manifest digest input
-    the program-store key needs — same weights file, different
-    quantization, different key."""
-    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    out = []
-    for path, leaf in flat:
-        arr = np.asarray(leaf) if not hasattr(leaf, "dtype") else leaf
-        out.append([jax.tree_util.keystr(path),
-                    list(getattr(arr, "shape", [])),
-                    str(getattr(arr, "dtype", type(arr).__name__))])
-    return sorted(out)
-
-
-def key_material_digest(material) -> str:
-    """Stable content key over JSON-able key material (the program
-    store's directory name): canonical JSON → blake2b-128 hex. Any
-    non-JSON leaf falls back to str() — good enough because every
-    field the store keys on is scalars/lists/dicts by construction."""
-    import hashlib
-    import json
-    blob = json.dumps(material, sort_keys=True, separators=(",", ":"),
-                      default=str).encode()
-    return hashlib.blake2b(blob, digest_size=16).hexdigest()

@@ -17,8 +17,7 @@ that never serves or trains never pays for the thread):
   compiles; `note_compile()` adds the measured dispatch wall of that
   call to a cumulative per-(device, bucket) ledger, exported as
   `STAT_compile_ms_<key>` counters plus the full ledger in
-  `snapshot()` → `/stats`. Warmup-vs-live compile cost is the number a
-  restarting fleet's AOT-cache work (ROADMAP) will be judged against.
+  `snapshot()` → `/stats`.
 - **FLOPs / MFU** — `hapi.Model` / the sharded pjit step call
   `note_train_step_lowering()` once per newly-compiled step; an XLA
   HLO cost analysis on the *lowered* module (no second backend

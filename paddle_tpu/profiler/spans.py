@@ -39,8 +39,7 @@ appear in flight-recorder dumps as the dying lane's in-flight spans.
 
 Everything is gated by `FLAGS_serving_spans` (default on); the cost per
 request is a handful of `perf_counter()` calls, dict stores and bounded
-ring appends — `bench.py --mode serving` A/Bs the flag and gates the
-overhead at <2% qps.
+ring appends.
 """
 from __future__ import annotations
 

@@ -105,8 +105,7 @@ observed every iteration the queue is non-empty).
 
 Recording is single-writer (the engine's step thread owns every
 append); readers take GIL-consistent list copies like the tracer rings.
-Everything is gated by `FLAGS_gen_step_log` (default on; `bench.py
---mode generation` A/Bs the flag and gates the overhead <2%).
+Everything is gated by `FLAGS_gen_step_log` (default on).
 """
 from __future__ import annotations
 

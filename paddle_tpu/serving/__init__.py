@@ -39,12 +39,6 @@ pre-compiled bucketed shapes).
   stickiness with zero router session state), least-pressure fallback
   on cached `pressure()` snapshots, drain on SLO burn / breaker-open,
   placement-time re-route under typed-failure semantics.
-- **warm start (ISSUE 16)** — `ProgramStore`: a keyed on-disk AOT
-  executable store; `GenerationEngine` warmup loads serialized
-  prefill/tail/decode/verify/cow programs under a content key instead
-  of tracing (miss → compile + write back), every load gated by a
-  donation-aliasing self-check + numeric smoke probe, refused on
-  XLA:CPU (the PR 1 corruption class) unless forced.
 """
 from __future__ import annotations
 
@@ -62,7 +56,6 @@ from .generation import (CrashManifest, GenerationConfig,  # noqa: E402
                          GenerationEngine, ReplayEntry, TokenStream)
 from .kv_cache import PagedKVCache  # noqa: E402
 from .prefix_cache import PrefixCache, chain_digests  # noqa: E402
-from .program_store import ProgramStore  # noqa: E402
 from .restart import CrashBreaker, RestartBackoff  # noqa: E402
 from .router import Router  # noqa: E402
 from .spec_decode import NGramProposer  # noqa: E402
@@ -71,6 +64,6 @@ from .supervisor import EngineSupervisor  # noqa: E402
 __all__ = ["InferenceEngine", "EngineConfig", "EngineOverloaded",
            "EngineSupervisor", "CrashBreaker", "CrashManifest",
            "GenerationEngine", "GenerationConfig", "NGramProposer",
-           "PagedKVCache", "PrefixCache", "ProgramStore", "ReplayEntry",
+           "PagedKVCache", "PrefixCache", "ReplayEntry",
            "RestartBackoff", "Router", "TokenStream", "chain_digests",
            "failpoints"]

@@ -29,7 +29,6 @@ the model's family, which the model hands over from `decode_family()`:
                      the family
     decode_attention(cfg, tp, pools) -> str
                      the name `stats()["decode_attention"]` shows
-    key_material()   what of the model shapes the programs (program store)
     build(ctx)       the program bodies by name: "prefill", "decode",
                      "zero_pages" always; "prefill_tail", "cow_copy",
                      "verify", "tier_gather", "tier_write" where the family
@@ -45,7 +44,7 @@ from __future__ import annotations
 from ..framework import monitor
 from ..framework.errors import InvalidArgumentError
 
-__all__ = ["ProgramContext", "config_items", "family_of", "sample_next"]
+__all__ = ["ProgramContext", "family_of", "sample_next"]
 
 
 class ProgramContext:
@@ -67,12 +66,6 @@ class ProgramContext:
             ledger[key] = ledger.get(key, 0) + 1
             monitor.stat_add("STAT_gen_compiles")
         self.note = note
-
-
-def config_items(config) -> dict:
-    """A model configuration as sorted JSON-able items: what of the model
-    shapes the programs (the program store's key material)."""
-    return {k: v for k, v in sorted(vars(config).items())}
 
 
 def sample_next(logits, active, temps, smask, key, top_k):

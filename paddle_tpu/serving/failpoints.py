@@ -5,9 +5,9 @@ Every hardened failure path in the generation engine — decode-step
 exceptions, prefill exceptions, poisoned (non-finite) logits, allocator
 exhaustion, slow steps — used to be testable only through hand-crafted
 monkeypatching of private seams. This registry names those seams as
-**failpoints** and arms them from one flag, so the supervisor, the
-chaos soak, and `bench.py --mode recovery` can inject the exact fault
-class they exercise, deterministically, with zero code changes:
+**failpoints** and arms them from one flag, so the supervisor's tests and
+the chaos soak (`tests/test_engine_resurrection.py`) can inject the exact
+fault class they exercise, deterministically, with zero code changes:
 
     FLAGS_failpoints = "decode_step_raise@3"            # 3rd hit only
     FLAGS_failpoints = "decode_poison_nan@every:5"      # every 5th hit

@@ -151,8 +151,6 @@ class GenerationConfig:
                  kv_tier: Optional[bool] = None,
                  kv_tier_host_bytes: Optional[int] = None,
                  kv_tier_chunk_pages: Optional[int] = None,
-                 program_store: Optional[str] = None,
-                 program_store_force: Optional[bool] = None,
                  tp: Optional[int] = None,
                  top_k: int = 0, seed: int = 0, warmup: bool = True,
                  gc_freeze: bool = False):
@@ -235,19 +233,6 @@ class GenerationConfig:
         if self.kv_tier and self.kv_tier_chunk_pages < 1:
             raise InvalidArgumentError(
                 "kv_tier_chunk_pages must be >= 1 when kv_tier is on")
-        # warm start (ISSUE 16): root of the on-disk AOT executable
-        # store; None/"" = off (device.program_store_dir resolves the
-        # flag default). force engages the store even where
-        # device.serialization_unsafe_backend() refuses it (XLA:CPU —
-        # the PR 1 aliasing-drop corruption class, warned once)
-        if program_store is None:
-            from .. import device as _device
-            self.program_store = _device.program_store_dir()
-        else:
-            self.program_store = str(program_store) or None
-        self.program_store_force = bool(
-            flag("FLAGS_gen_program_store_force")
-            if program_store_force is None else program_store_force)
         # mesh-slice lane (ISSUE 19): tensor-parallel degree — the
         # engine builds its whole program pack sharded over a 'tp'
         # mesh axis when > 1 (or when an explicit mesh is handed to
@@ -459,23 +444,19 @@ class _ProgramPack:
     cache: zero new in-process traces, and because the ledger dict is
     owned here — not by any one engine — the shared count proves it.
 
-    ISSUE 16 adds the cross-PROCESS half: `execs` maps program name
-    (the ledger's own keys) → the AOT `jax.stages.Compiled` the engine
-    resolved at warmup — store-loaded OR live-compiled-and-written-back
-    — and `loaded` counts the store loads the way `ledger` counts
-    traces. A resurrection adopts both, so a supervised rebuild of a
-    store-started engine still performs zero traces AND zero disk
-    loads."""
+    `execs` maps the step program's name (the ledger's own key) to the
+    AOT `jax.stages.Compiled` that `_build_programs` compiled with the
+    pools' layout asked of the compiler (PR 28); a resurrection adopts
+    it, so the rebuilt engine runs the same executable."""
 
-    __slots__ = ("ledger", "loaded", "execs", "prefill", "tail",
+    __slots__ = ("ledger", "execs", "prefill", "tail",
                  "decode", "verify", "zero", "cow", "npool", "W",
                  "tier_gather", "tier_write", "formats", "preferred")
 
     def __init__(self, ledger, prefill, tail, decode, verify, zero, cow,
-                 npool, W, loaded=None, execs=None, tier_gather=None,
+                 npool, W, execs=None, tier_gather=None,
                  tier_write=None, formats=None, preferred=None):
         self.ledger = ledger
-        self.loaded = {} if loaded is None else loaded
         self.execs = {} if execs is None else execs
         self.prefill = prefill
         self.tail = tail
@@ -838,14 +819,7 @@ class GenerationEngine:
             self._cow_jit = pack.cow
             self._tier_gather_jit = pack.tier_gather
             self._tier_write_jit = pack.tier_write
-            # ISSUE 16: adopt the resolved AOT executables + the load
-            # ledger too — a resurrection of a store-started engine
-            # re-warms through `execs` directly: zero traces AND zero
-            # disk loads (rebuilds prefer the pack, the pack prefers
-            # the store)
             self._execs = pack.execs
-            self._loaded = pack.loaded
-            self._store = None
             self._pack = pack
             self._pool_formats = pack.formats
             self._preferred = pack.preferred
@@ -866,18 +840,8 @@ class GenerationEngine:
             self._cfg, self._tp, self._mesh, NP, self._quant_kv,
             self._decode_attention, self._W, self._ledger))
 
-        # warm start (ISSUE 16): resolved AOT executables by program
-        # name (ledger keys) + the store-load ledger; warmup fills them
+        # the step program's AOT executable, by its ledger key
         self._execs = {}
-        self._loaded = {}
-        self._store = None
-        if self._cfg.program_store:
-            from .program_store import ProgramStore
-            self._store = ProgramStore(
-                self._cfg.program_store, self._store_key_material(),
-                force=self._cfg.program_store_force)
-            if self._store.refused:
-                self._store = None
 
         # THE LAYOUT CONTRACT (PR 28). Every program takes and returns
         # the pools in ONE layout, the one they lie in on the device, so
@@ -899,12 +863,8 @@ class GenerationEngine:
         # the layout the pools have, and `stats()["pools"]` shows what
         # the compiler `preferred`: a shape to repair, not a fault. No
         # layout is written down here: each is read from an array or
-        # from a compile. A backend whose serialized executables are not
-        # to be trusted at all (`device.serialization_unsafe_backend`)
-        # is not asked.
-        from .. import device as _device
-        choice = (None if _device.serialization_unsafe_backend()
-                  else Layout.AUTO)
+        # from a compile.
+        from ..device import layout_name
         jit = self._jit_program
         step = "verify" if self._spec_k else "decode"
         step_name = (f"verify[k={self._spec_k}]" if self._spec_k
@@ -917,16 +877,17 @@ class GenerationEngine:
                     for a in self._pool_arrays]
         args = (self._W, *abstract, *step_args)
         with self._dev_ctx():
-            compiled = self._resolve_step(
-                step_name,
-                jit(step, [Format(choice, f.sharding) for f in fmts]), args)
+            # (`args` holds the pools as shapes: an array would bring a
+            # layout of its own)
+            compiled = jit(step, [Format(Layout.AUTO, f.sharding)
+                                  for f in fmts]).lower(*args).compile()
             chosen = tuple(compiled.input_formats[0][1:1 + NP])
             if not (chosen == tuple(compiled.output_formats[:NP]) == fmts):
                 # (traced once all the same: the body's trace is reused)
                 compiled = jit(step, fmts).lower(*args).compile()
         self._execs[step_name] = compiled
         self._pool_formats = fmts
-        self._preferred = [_device.layout_name(f, a.shape, a.dtype)
+        self._preferred = [layout_name(f, a.shape, a.dtype)
                            for a, f in zip(self._pool_arrays, chosen)]
         self._note_pool_layout()
 
@@ -949,7 +910,7 @@ class GenerationEngine:
             tail=self._tail_jit, decode=self._decode_jit,
             verify=self._verify_jit, zero=self._zero_jit,
             cow=self._cow_jit, npool=self._npool, W=self._W,
-            loaded=self._loaded, execs=self._execs,
+            execs=self._execs,
             tier_gather=self._tier_gather_jit,
             tier_write=self._tier_write_jit, formats=self._pool_formats,
             preferred=self._preferred)
@@ -963,26 +924,6 @@ class GenerationEngine:
             return None
         return jit_program(fn, name, fmts, bool(self._family.step_counters),
                            with_w, donates)
-
-    def _resolve_step(self, name, auto_jit, args):
-        """The step program's executable, compiled with the pools' layout
-        left to the compiler (`args` holds the pools as shapes: an array
-        would bring a layout of its own). With a program store a
-        key-matched entry whose aliasing survived is taken instead, and
-        a live compile is written back, as `_warm_one` does for the
-        other programs."""
-        if self._store is not None:
-            hit = self._store.load(name)
-            if hit is not None:
-                compiled, recorded = hit
-                if self._selfcheck_alias(compiled, recorded) is None:
-                    self._loaded[name] = self._loaded.get(name, 0) + 1
-                    return compiled
-                monitor.stat_add("STAT_pack_selfcheck_failures")
-        compiled = auto_jit.lower(*args).compile()
-        if self._store is not None:
-            self._store.store(name, compiled)
-        return compiled
 
     def _note_pool_layout(self):
         """The pools lie as every program was compiled to take them (a
@@ -1005,85 +946,25 @@ class GenerationEngine:
                     f"{self.name}: {when}, a pool compiled for as {f} "
                     f"lies as {a.format}")
 
-    def _store_key_material(self) -> dict:
-        """Everything that shapes the traced programs, JSON-able — the
-        content key the store directories hang off. The decode-weight
-        pytree spec doubles as the quant-manifest digest (int8 leaves
-        + scale rows have their own dtypes/shapes); the FLAGS listed
-        are the kernel selections the compiled programs bake in."""
-        import jax
-        import jaxlib
-
-        from ..jit import pytree_spec
-        dev = jax.devices()[0]
-        return {
-            "family": self._family.name,
-            "model": self._family.key_material(),
-            "weights_spec": pytree_spec(self._W),
-            "engine": {
-                "max_slots": self._cfg.max_slots,
-                "page_size": self._cfg.page_size,
-                "num_pages": self._cfg.num_pages,
-                "pages_per_seq": self._cfg.pages_per_seq,
-                "prefill_buckets": list(self._cfg.prefill_buckets),
-                "kv_dtype": self._cache.dtype,
-                "quant_kv": bool(self._quant_kv),
-                "use_tail": bool(self._use_tail),
-                "prefix_cache": self._prefix is not None,
-                "kv_tier": self._tier is not None,
-                "kv_tier_chunk_pages": self._cfg.kv_tier_chunk_pages,
-                "spec_k": self._spec_k,
-                "top_k": self._cfg.top_k,
-                "decode_attention": self._decode_attention,
-                # mesh-slice lane (ISSUE 19): tp degree + mesh shape
-                # join the content key — a shard_map program compiled
-                # for one slice layout must never resolve on another
-                "tp": self._tp,
-                "mesh_shape": (dict(self._mesh.shape)
-                               if self._mesh is not None else None),
-            },
-            "jax": jax.__version__,
-            "jaxlib": jaxlib.__version__,
-            "backend": jax.default_backend(),
-            "device_kind": getattr(dev, "device_kind", "unknown"),
-            "device": str(self._device) if self._device is not None
-            else None,
-            "flags": {
-                "FLAGS_use_paged_attention":
-                    bool(flag("FLAGS_use_paged_attention")),
-                "FLAGS_paged_compute_block_pages":
-                    int(flag("FLAGS_paged_compute_block_pages")),
-                "FLAGS_flash_attention_interpret":
-                    bool(flag("FLAGS_flash_attention_interpret")),
-            },
-        }
-
     def _dev_ctx(self):
         import jax
         import contextlib
         return (jax.default_device(self._device)
                 if self._device is not None else contextlib.nullcontext())
 
-    def _prog(self, name, jit_fn):
-        """The program to run for `name`: the AOT executable warmup
-        resolved (store-loaded or live-compiled-and-written-back) when
-        present, else the jax.jit wrapper — the store-off path,
-        behaviorally identical (ISSUE 16)."""
-        return self._execs.get(name, jit_fn)
-
     def _decode_call(self, *args):
-        """One jitted decode dispatch (seam: tests wrap this to inject
-        per-slot failures)."""
+        """One decode dispatch: the executable `_build_programs` compiled
+        where decode is the step program, else (pre-warmed under
+        speculation, for DEGRADED_SPEC_OFF) the jit wrapper."""
         with self._dev_ctx():
-            return self._prog(f"decode[m={self._cfg.max_slots}]",
-                              self._decode_jit)(*args)
+            return self._execs.get(f"decode[m={self._cfg.max_slots}]",
+                                   self._decode_jit)(*args)
 
     def _verify_call(self, *args):
-        """One jitted speculative-verify dispatch (same test seam
-        discipline as `_decode_call`)."""
+        """One speculative-verify dispatch: the step program's
+        executable."""
         with self._dev_ctx():
-            return self._prog(f"verify[k={self._spec_k}]",
-                              self._verify_jit)(*args)
+            return self._execs[f"verify[k={self._spec_k}]"](*args)
 
     def _zero_pages(self, pages):
         # chunked to the fixed zero-scatter width: one sequence's free
@@ -1098,9 +979,8 @@ class GenerationEngine:
     def _cow_copy(self, src: int, dst: int):
         """Device-side CoW clone of one page (content + int8 scale row)."""
         with self._dev_ctx():
-            fn = self._prog("cow_copy", self._cow_jit)
-            self._set_pools(fn(*self._pools(), np.int32(src),
-                               np.int32(dst)))
+            self._set_pools(self._cow_jit(*self._pools(), np.int32(src),
+                                          np.int32(dst)))
 
     # -- host tier (ISSUE 18) ----------------------------------------------
 
@@ -1214,111 +1094,12 @@ class GenerationEngine:
         self._it["promote_ms"] += _now_ms() - t0
         return True
 
-    # -- program-store warmup seam (ISSUE 16) ------------------------------
-
-    def _reset_pools(self):
-        """Rebuild zeroed device pools after a failed store probe
-        DONATED the live ones into a broken executable. Warmup-time
-        only: at that point the pools hold nothing but scratch-page
-        writes, so zeros are the correct state (shape/dtype metadata
-        survives buffer deletion)."""
-        import jax.numpy as jnp
-        place = self._cache._place  # keeps the tp mesh placement
-        self._pool_arrays = [place(jnp.zeros(a.shape, a.dtype))
-                             for a in self._pool_arrays]
-
-    def _selfcheck_alias(self, compiled, recorded: str):
-        """The PR 1 structural gate on a LOADED executable: its
-        input/output aliasing must match the spec the live compile
-        recorded at write time, and must not be empty — every covered
-        program donates its pools, so an executable that aliases
-        nothing is exactly the aliasing-drop corruption class (it
-        would read freed buffers at the second call). Returns an error
-        string, or None when the check passes."""
-        from ..jit import compiled_alias_spec
-        live = compiled_alias_spec(compiled)
-        if live != recorded:
-            return (f"alias spec mismatch: loaded={live!r} vs "
-                    f"recorded={recorded!r}")
-        if not live.strip():
-            return ("empty alias spec on a donating program — the "
-                    "PR 1 aliasing-drop corruption class")
-        return None
-
-    def _probe_ok(self, name: str, out) -> bool:
-        """Numeric smoke verdict on one warmup execution of a loaded
-        executable: prefill-family programs must return finite logits,
-        decode/verify must not raise their in-graph poison flag;
-        cow_copy completing `block_until_ready` is the probe (it
-        returns only pools)."""
-        if name.startswith("prefill"):
-            return bool(np.all(np.isfinite(np.asarray(out[-1]))))
-        if name.startswith("decode"):   # (*pools, next, bad[, counters])
-            return not bool(np.asarray(out[self._npool + 1]).any())
-        if name.startswith("verify"):
-            return not bool(np.asarray(out[-1]).any())
-        return True
-
-    def _warm_one(self, name: str, jit_fn, args_fn):
-        """Resolve + execute one warmup program, preferring the store.
-
-        Hit → deserialize, run the donation-aliasing self-check, then
-        the numeric smoke probe (ONE scratch execution — the warmup
-        call itself); only then does the executable enter the pack and
-        `loaded[name]` count it. Any failure bumps
-        STAT_pack_selfcheck_failures, dumps a flight record, rebuilds
-        the (possibly donated-away) pools, and falls through to live
-        compile — a corrupt or stale entry costs a compile, never a
-        wrong answer. Miss with a store → AOT lower+compile (note()
-        fires at trace time, so the compile ledger counts it exactly
-        as before), execute, write back. No store → the jax.jit
-        wrapper traces on call: the pre-ISSUE-16 path, untouched."""
-        ex = self._execs.get(name)
-        if ex is not None:     # resurrection: the pack already resolved it
-            return ex(*args_fn())
-        if self._store is None:
-            return jit_fn(*args_fn())
-        hit = self._store.load(name)
-        if hit is not None:
-            import jax
-            compiled, recorded = hit
-            err = self._selfcheck_alias(compiled, recorded)
-            out = None
-            if err is None:
-                try:
-                    out = compiled(*args_fn())
-                    jax.block_until_ready(out)
-                    if not self._probe_ok(name, out):
-                        err = "numeric smoke probe failed"
-                except Exception as e:  # noqa: BLE001
-                    err = f"smoke probe raised: {e!r}"
-            if err is None:
-                self._execs[name] = compiled
-                self._loaded[name] = self._loaded.get(name, 0) + 1
-                return out
-            monitor.stat_add("STAT_pack_selfcheck_failures")
-            flight_recorder.dump(
-                "program_store_selfcheck",
-                extra={"engine": self.name, "program": name,
-                       "key": self._store.key, "error": err})
-            self._reset_pools()
-        compiled = jit_fn.lower(*args_fn()).compile()
-        self._execs[name] = compiled
-        self._store.store(name, compiled)
-        return compiled(*args_fn())
-
     def _warmup(self):
         """Compile every prefill bucket + the decode step (or, with
         speculation on, the ONE verify[k] program that replaces it) +
         the zeroing scatter up front: no live request pays a compile,
         and the ledger's exactly-once invariant is observable from step
-        one. Warmup writes land only in the reserved scratch page.
-
-        With a program store (ISSUE 16), every covered program resolves
-        through `_warm_one` instead: a key-matched store entry
-        deserializes (self-check + smoke probe gated) and the compile
-        ledger does not move — `loaded` counts it instead. A miss
-        AOT-compiles and writes back, so the NEXT process warm-starts."""
+        one. Warmup writes land only in the reserved scratch page."""
         M, PP = self._cfg.max_slots, self._cfg.pages_per_seq
         trash = np.zeros((PP,), np.int32)
         with RecordEvent("generation::warmup"):
@@ -1326,10 +1107,8 @@ class GenerationEngine:
                 ids = np.zeros((1, b), np.int32)
                 with self._dev_ctx():
                     # lint: allow(use-after-donate): donate_argnums covers only the NP pool args riding in the *splat; trash sits AFTER them (position NP+1) and is never donated — reused read-only across warmup prefills
-                    out = self._warm_one(
-                        f"prefill[b={b}]", self._prefill_jit,
-                        lambda: (self._W, *self._pools(), trash, ids,
-                                 np.int32(1)))
+                    out = self._prefill_jit(self._W, *self._pools(), trash,
+                                            ids, np.int32(1))
                 self._set_pools(out[:-1])
                 np.asarray(out[-1])
                 if self._use_tail:
@@ -1340,28 +1119,17 @@ class GenerationEngine:
                     # from step one
                     with self._dev_ctx():
                         # lint: allow(use-after-donate): donate covers only the NP pool args in the *splat; trash/ids ride AFTER them (positions NP+1/NP+2), read-only across warmup prefills
-                        out = self._warm_one(
-                            f"prefill_tail[b={b}]", self._tail_jit,
-                            lambda: (self._W, *self._pools(), trash, ids,  # lint: allow(use-after-donate): same — non-donated arg positions, reused read-only
-                                     np.int32(1), np.int32(0)))
+                        out = self._tail_jit(self._W, *self._pools(), trash,
+                                             ids, np.int32(1), np.int32(0))
                     self._set_pools(out[:-1])
                     np.asarray(out[-1])
             if self._prefix is not None:
                 with self._dev_ctx():
-                    out = self._warm_one(
-                        "cow_copy", self._cow_jit,
-                        lambda: (*self._pools(), np.int32(TRASH_PAGE),
-                                 np.int32(TRASH_PAGE)))
+                    out = self._cow_jit(*self._pools(),
+                                        np.int32(TRASH_PAGE),
+                                        np.int32(TRASH_PAGE))
                 self._set_pools(out)
             if self._tier is not None:
-                # tier programs (ISSUE 18) warm OUTSIDE the program
-                # store: tier_gather keeps its pools (non-donating —
-                # it copies a page out), so it can never satisfy the
-                # store's every-covered-program-donates aliasing
-                # self-check; both compile live against the jit
-                # wrappers instead (the wrappers ride the pack, so a
-                # supervised restart still re-warms from cache with
-                # zero new traces)
                 with self._dev_ctx():
                     g = self._tier_gather_jit(*self._pools(),
                                               np.int32(TRASH_PAGE))
@@ -1378,32 +1146,19 @@ class GenerationEngine:
                 # speculation replaces the decode program outright: the
                 # engine's ledger shows ONE verify[k] trace and no
                 # decode entry at all (the acceptance-criteria shape)
-                vargs = self._spec_arrays()[0]
-                with self._dev_ctx():
-                    out = self._warm_one(
-                        f"verify[k={self._spec_k}]", self._verify_jit,
-                        lambda: (self._W, *self._pools(), *vargs))
+                out = self._verify_call(self._W, *self._pools(),
+                                        *self._spec_arrays()[0])
                 np.asarray(out[-2])
                 self._set_pools(out[:-3])
-                if self._poison_degrade_k or self._degraded_spec_off:
-                    # the poison-storm detector (ISSUE 15) may flip this
-                    # engine to the plain decode program mid-flight —
-                    # pre-warm it so the DEGRADED_SPEC_OFF flip mints no
-                    # runtime compile (the ledger then shows BOTH
-                    # verify[k] and decode[m], each exactly once)
-                    dargs = self._step_arrays()
-                    with self._dev_ctx():
-                        out = self._warm_one(
-                            f"decode[m={M}]", self._decode_jit,
-                            lambda: (self._W, *self._pools(), *dargs))
-                    np.asarray(out[self._npool])
-                    self._set_pools(out[:self._npool])
-            else:
-                dargs = self._step_arrays()
-                with self._dev_ctx():
-                    out = self._warm_one(
-                        f"decode[m={M}]", self._decode_jit,
-                        lambda: (self._W, *self._pools(), *dargs))
+            if (not self._spec_k or self._poison_degrade_k
+                    or self._degraded_spec_off):
+                # (under speculation: the poison-storm detector (ISSUE 15)
+                # may flip this engine to the plain decode program
+                # mid-flight — pre-warm it so the DEGRADED_SPEC_OFF flip
+                # mints no runtime compile; the ledger then shows BOTH
+                # verify[k] and decode[m], each exactly once)
+                out = self._decode_call(self._W, *self._pools(),
+                                        *self._step_arrays())
                 np.asarray(out[self._npool])
                 self._set_pools(out[:self._npool])
             self._zero_pages([])
@@ -2271,8 +2026,7 @@ class GenerationEngine:
             ids[0, :tail] = req.prompt[pfx:]
             with RecordEvent(f"generation::prefill_tail[b={bucket}]"):
                 with self._dev_ctx():
-                    out = self._prog(f"prefill_tail[b={bucket}]",
-                                     self._tail_jit)(
+                    out = self._tail_jit(
                         self._W, *self._pools(), req.pt_row, ids,
                         np.int32(tail), np.int32(pfx))
                 self._set_pools(out[:-1])
@@ -2283,8 +2037,7 @@ class GenerationEngine:
             ids[0, :S] = req.prompt
             with RecordEvent(f"generation::prefill[b={bucket}]"):
                 with self._dev_ctx():
-                    out = self._prog(f"prefill[b={bucket}]",
-                                     self._prefill_jit)(
+                    out = self._prefill_jit(
                         self._W, *self._pools(), req.pt_row, ids,
                         np.int32(S))
                 self._set_pools(out[:-1])
@@ -2455,8 +2208,7 @@ class GenerationEngine:
         t0 = _now_ms()
         with RecordEvent(f"generation::prefill_chunk[b={bucket}]"):
             with self._dev_ctx():
-                out = self._prog(f"prefill_tail[b={bucket}]",
-                                 self._tail_jit)(
+                out = self._tail_jit(
                     self._W, *self._pools(), req.pt_row, ids,
                     np.int32(take), np.int32(req.prefill_pos))
             self._set_pools(out[:-1])
@@ -2865,17 +2617,9 @@ class GenerationEngine:
             slot_of = {r.rid: i for i, r in enumerate(self._slots)
                        if r is not None}
             ledger = dict(self._ledger)
-            loaded = dict(self._loaded)
             steps, prefills, tokens = (self._steps_total,
                                        self._prefills_total,
                                        self._tokens_total)
-        # warm start (ISSUE 16): per-program provenance — a store-
-        # covered program that deserialized reports "loaded" (its
-        # ledger entry never moved), a traced one reports "compiled";
-        # the acceptance criterion reads this mapping directly
-        programs = {name: ("loaded" if loaded.get(name)
-                           and not ledger.get(name) else "compiled")
-                    for name in set(ledger) | set(loaded)}
         pages = self._cache.stats()
         return {
             "slots": slots,
@@ -2892,20 +2636,10 @@ class GenerationEngine:
                           self._preferred)],
             "kv": self._kv_introspection(slot_of),
             "compiles": ledger,
-            "loaded": loaded,
-            "programs": programs,
             # "kernel" / "pool" / "reference" (head pools), "latent_gather"
             # (a latent pool): the paged attention the decode program was
             # built with (ops/paged_ops.py)
             "decode_attention": self._decode_attention,
-            "program_store": {
-                "configured": bool(self._cfg.program_store),
-                "active": self._store is not None,
-                "key": self._store.key if self._store is not None
-                else None,
-                "dir": self._store.key_dir if self._store is not None
-                else None,
-            },
             "steps": steps,
             "prefills": prefills,
             "tokens": tokens,

@@ -20,7 +20,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..framework.errors import InvalidArgumentError
-from .decode_family import config_items, sample_next
+from .decode_family import sample_next
 from .kv_cache import TRASH_PAGE, PagedKVCache
 
 __all__ = ["GPTFamily"]
@@ -75,9 +75,6 @@ class GPTFamily:
             (cfg.max_slots, H, self.head_dim),
             HeadPoolForm(H, self.head_dim).layer_shape(kp.shape),
             (cfg.max_slots, cfg.pages_per_seq), kp.dtype)
-
-    def key_material(self):
-        return config_items(self.config)
 
     def build(self, ctx):
         """The family's program bodies (python callables; the engine jits,
@@ -364,9 +361,7 @@ class GPTFamily:
             out of the pools for the host tier. NON-donating by
             contract: the pools are kept (the content is being copied
             off-device, the page frees through the ordinary eviction
-            path right after), which is also why this program can never
-            ride the program store — `_selfcheck_alias` requires every
-            covered program to donate its pools."""
+            path right after)."""
             pools, page = rest[:NP], rest[NP]
             note("tier_gather")
             return tuple(form.pages(p, page) for p in pools)

@@ -304,7 +304,7 @@ class PagedKVCache:
         """Most pages (incl. the reserved scratch page) an HBM budget
         admits: int8 pages are ~4x denser than fp32 — the serving-
         capacity multiplier the quantized KV mode exists for, and how
-        bench.py builds equal-byte fp32/int8 pools. `budget_bytes` is
+        the tests build equal-byte fp32/int8 pools. `budget_bytes` is
         PER-CHIP HBM; with tp > 1 each chip stores only heads/tp of
         every page, so the same per-chip budget admits tp× the pages —
         the mesh-slice capacity unlock (ISSUE 19)."""

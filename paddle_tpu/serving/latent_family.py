@@ -26,7 +26,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..framework.errors import InvalidArgumentError
-from .decode_family import config_items, sample_next
+from .decode_family import sample_next
 from .kv_cache import TRASH_PAGE, PagedKVCache
 
 __all__ = ["LatentFamily", "latent_decode"]
@@ -103,9 +103,6 @@ class LatentFamily:
 
     def decode_attention(self, cfg, tp, pools):
         return "latent_gather"      # the one path: ops/paged_ops.py, why
-
-    def key_material(self):
-        return config_items(self.config)
 
     def build(self, ctx):
         import jax.numpy as jnp

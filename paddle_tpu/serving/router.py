@@ -4,7 +4,7 @@
 Orca's split, fleet-scale: the engine decides per-STEP (continuous
 batching), the router decides per-REQUEST. Each replica is an
 `EngineSupervisor`-wrapped `GenerationEngine` — already self-healing
-(PR 14), already warm-startable (PR 15), already exposing drain and
+(PR 14), already exposing drain and
 pressure surfaces (PR 11) — so the router stays thin: placement policy
 plus the same `submit()`/`submit_stream()`/`generate()` surface, and
 everything below it keeps its existing exactly-once semantics.

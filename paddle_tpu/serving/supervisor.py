@@ -19,14 +19,9 @@ designs the engine is built on make that cheap:
   ledger, lifted into `_ProgramPack`) means a rebuilt engine reuses
   the dead one's jit wrappers and re-warms from XLA's in-process
   caches: *zero new traces*, ledger-proven, so recovery is pool-rebuild
-  + replay-prefill, not minutes of compilation. Rebuilds prefer the
-  store (ISSUE 16): the carried pack's `execs` map holds the AOT
-  executables the dead engine resolved — store-loaded or live-compiled
-  — so a resurrection re-warms through them with zero traces AND zero
-  disk loads; and because the supervisor rebuilds with the SAME config,
-  a first build (or a pack-less rebuild) that names
-  `program_store` loads from disk instead of tracing, which shrinks
-  the recovery wall from compile-bound to deserialize-bound.
+  + replay-prefill, not minutes of compilation. The carried pack's
+  `execs` holds the step program's AOT executable, which the rebuilt
+  engine runs as it stands.
 - **The prefix cache** (PR 12) makes replay prefill near-free for
   shared-prefix traffic: the first replayed prompt re-registers its
   chain and every later replay walks it.
@@ -424,9 +419,6 @@ class EngineSupervisor:
                                  else None),
             "replay_ms_total": round(self._replay_ms_total, 3),
             "breaker": self._breaker.state(),
-            # warm start (ISSUE 16): whether a pack-less rebuild would
-            # load from the on-disk store instead of recompiling
-            "program_store": self._cfg.program_store,
         }
 
     def stats(self) -> dict:

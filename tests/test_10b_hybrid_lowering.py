@@ -1,6 +1,6 @@
-"""10B-parameter hybrid-parallel lowering proof (the ERNIE-3.0-scale
-configuration BASELINE.md names; reference trains it with sharding +
-pipeline meta-optimizers).
+"""10B-parameter hybrid-parallel lowering proof (an ERNIE-3.0-scale
+configuration; the reference trains it with sharding + pipeline
+meta-optimizers).
 
 No weights are materialized: parameters enter as sharded
 ShapeDtypeStructs and `jit(...).lower()` runs GSPMD partitioning on the

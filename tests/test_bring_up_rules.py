@@ -235,7 +235,7 @@ def test_compile_cache_dir_defaults_to_one_path_in_the_checkout():
     # nothing else in the program sets a cache directory
     hits = subprocess.run(
         ["grep", "-rln", "jax_compilation_cache_dir", "paddle_tpu",
-         "bench.py", "chip_smoke.py"], cwd=REPO, capture_output=True,
+         "chip_smoke.py"], cwd=REPO, capture_output=True,
         text=True).stdout.split()
     assert hits == ["paddle_tpu/device/__init__.py"]
 

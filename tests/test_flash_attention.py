@@ -130,8 +130,9 @@ def test_flash_bf16_forward_close():
 # ---------------------------------------------------------------------------
 
 def test_gate_min_seq_default():
-    # at the bench shape (seq 128) the dense path must win the dispatch:
-    # flash was measured ~25% slower there (VERDICT r3) — regression guard
+    # at seq 128 the dense path must win the dispatch: a flash kernel
+    # has one 128-row block a head there and nothing to stream, so it only
+    # adds its launch and softmax bookkeeping — regression guard
     assert not po.flash_supported((8, 12, 128, 64), min_seq=512)
     assert po.flash_supported((8, 12, 512, 64), min_seq=512)
 
@@ -184,7 +185,7 @@ def test_fallback_causal_decode_bottom_right_aligned():
 
 def test_functional_cross_attention_no_crash():
     """Regression: maskless cross-attention S_q != S_kv used to pass the
-    gate and die inside _flash_call's reshape (VERDICT r3 weak #3)."""
+    gate and die inside _flash_call's reshape."""
     import paddle_tpu.nn.functional as F
     from paddle_tpu.framework.tensor import Tensor
     q = Tensor(_mk((1, 2, 256, 32), 10))

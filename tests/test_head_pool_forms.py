@@ -326,7 +326,8 @@ def test_stats_report_each_pools_shape_layout_and_bytes(net):
     """`stats()["pools"]`: per pool the logical shape, the layout the
     device holds it in and the one the step program was compiled for,
     device and logical bytes; `STAT_kv_cache_hbm_bytes` counts the device
-    bytes. On the CPU the layout is the default one (`_build_programs`)."""
+    bytes. The pools lie in their default layout, whatever the compiler
+    `preferred` (`_build_programs`)."""
     from paddle_tpu.framework import monitor
     g0 = monitor.stat_get("STAT_kv_cache_hbm_bytes")
     with serving.GenerationEngine(
@@ -346,7 +347,6 @@ def test_stats_report_each_pools_shape_layout_and_bytes(net):
                                                "float32"]
         for p, a in zip(pools, eng._pools()):
             assert p["layout"] == p["compiled_for"] == "default"
-            assert p["preferred"] == "default"      # not asked on the CPU
             assert p["device_bytes"] == a.on_device_size_in_bytes()
         # a fused row is whole lane tiles, of which the heads fill a part
         used = form.used / form.row if form.fused else 1
@@ -365,20 +365,15 @@ def test_stats_report_each_pools_shape_layout_and_bytes(net):
         assert [a.format for a in eng._pools()] == list(eng._pool_formats)
 
 
-def test_a_choice_that_is_not_the_pools_own_layout_is_reported_not_taken(
-        monkeypatch):
+def test_a_choice_that_is_not_the_pools_own_layout_is_reported_not_taken():
     """The contract where the compiler, asked, would rather have a pool
-    otherwise than it lies. The CPU's compiler is never asked by the
-    engine (`device.serialization_unsafe_backend`); asked here for once,
-    it picks for the int8 scale pools under the verify program a layout
-    that is not their default. The pools cannot be moved to it (an
-    executable that the compile cache hands back returns default layouts),
-    so the step program is compiled once more held to the layout the
-    pools have, every other program pins that, `stats()["pools"]` says
-    what was `preferred`, and the tokens are the plain int8 engine's."""
-    from paddle_tpu import device
-    monkeypatch.setattr(device, "serialization_unsafe_backend",
-                        lambda: False)
+    otherwise than it lies: the CPU's picks for the int8 scale pools under
+    the verify program a layout that is not their default. The pools
+    cannot be moved to it (an executable that the compile cache hands back
+    returns default layouts), so the step program is compiled once more
+    held to the layout the pools have, every other program pins that,
+    `stats()["pools"]` says what was `preferred`, and the tokens are the
+    plain int8 engine's."""
     paddle.seed(1)
     model = GPTForCausalLM(GPTConfig.tiny(dropout=0.0))
     model.eval()

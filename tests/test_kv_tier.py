@@ -171,6 +171,8 @@ def test_demote_promote_token_identical_fp32(model):
         s = eng.stats()
         reasons = [ev["reason"] for ev in eng._audit.tail(256)]
         tier = eng._tier.stats()
+        # neither tier leaks on the clean path either
+        assert _tier_consistent(eng._tier) and _pool_reconciles(eng)
     for o, r in zip(flood, ref):
         np.testing.assert_array_equal(o, r)
     np.testing.assert_array_equal(again, ref[0])

@@ -163,6 +163,25 @@ def test_suggest_rows():
     assert suggest_rows([100], batch_size=1, max_tokens=16) == 2
 
 
+def test_suggest_rows_fills_a_long_tail_corpus_without_drops():
+    """Packs sized by `suggest_rows` over a clipped-lognormal length
+    distribution (most sequences short, a heavy tail near max_tokens)
+    fill >= 0.8 of their slots and drop nothing."""
+    T, B = 128, 32
+    rng = np.random.RandomState(7)
+    lengths = np.clip(np.round(np.exp(rng.normal(
+        np.log(T / 6.0), 0.9, 320))).astype(int), 4, T)
+    coll = PackingCollator(T, suggest_rows(lengths, B, T))
+    t0, s0 = stat_get("STAT_packing_tokens"), stat_get("STAT_packing_slots")
+    d0 = stat_get("STAT_packing_dropped_seqs")
+    for i in range(0, len(lengths), B):
+        coll([np.zeros(L, np.int64) for L in lengths[i:i + B]])
+    assert stat_get("STAT_packing_dropped_seqs") == d0
+    assert stat_get("STAT_packing_tokens") - t0 == lengths.sum()
+    fill = lengths.sum() / (stat_get("STAT_packing_slots") - s0)
+    assert fill >= 0.8, fill
+
+
 def test_collator_counters_cumulative_fill():
     p0 = stat_get("STAT_packing_packs")
     f0 = stat_get("STAT_packing_fill_ratio_pct")

@@ -256,8 +256,7 @@ def test_kv_cache_int8_scale_pools_and_budget_arithmetic():
 
 def test_can_admit_capacity_multiplies_at_equal_bytes():
     """Same HBM budget, ~4x the pages, ~4x the admitted sequences —
-    the can_admit arithmetic IS the capacity multiplier (gated >=1.9x
-    in bench.py --mode quant)."""
+    the can_admit arithmetic IS the capacity multiplier."""
     dims = dict(num_layers=2, num_heads=2, head_dim=8, page_size=4)
     budget = PagedKVCache.page_hbm_bytes(dtype="float32", **dims) * 9
     n_fp = PagedKVCache.pages_for_budget(budget, dtype="float32", **dims)
@@ -277,6 +276,40 @@ def test_can_admit_capacity_multiplies_at_equal_bytes():
     cap_fp, cap_q = capacity(fp), capacity(q)
     assert cap_fp == 4              # (9 - trash) // 2
     assert cap_q >= 1.9 * cap_fp
+
+
+def test_live_peak_multiplies_at_equal_pool_bytes(gpt_model):
+    """The same multiplier read off live engines: at an equal pool-byte
+    budget sized so fp32 pages hold a quarter of the slots, the int8
+    engine's peak of co-resident sequences (the step ring's `live`) is
+    >= 1.9x the fp32 engine's. Admission could regress (admitted then
+    starved) without moving `can_admit`'s arithmetic."""
+    from paddle_tpu.profiler import step_log
+    cfg = gpt_model.gpt.config
+    dims = dict(num_layers=cfg.num_layers, num_heads=cfg.num_heads,
+                head_dim=cfg.hidden_size // cfg.num_heads, page_size=4)
+    slots, prompt, new = 8, 12, 8
+    per_req = -(-(prompt + new) // 4)
+    fp_pages = (slots // 4) * per_req + 1
+    budget = fp_pages * PagedKVCache.page_hbm_bytes(dtype="float32", **dims)
+    q_pages = PagedKVCache.pages_for_budget(budget, dtype="int8", **dims)
+    prompts = _gen_prompts(n=16, S=prompt)
+
+    def peak(kv, pages):
+        name = f"qpeak_{kv}"
+        with serving.GenerationEngine(
+                gpt_model, max_slots=slots, page_size=4, num_pages=pages,
+                prefill_buckets=(16,), max_new_tokens=new,
+                kv_cache_dtype=kv, request_timeout_ms=0, name=name) as eng:
+            for f in [eng.submit(p, max_new_tokens=new) for p in prompts]:
+                f.result(timeout=300)
+            records = step_log.steps_payload()["engines"][name]["records"]
+            assert eng.stats()["pages"]["pages_in_use"] == 0
+        return max(r["live"] for r in records)
+
+    peak_fp, peak_q = peak("float32", fp_pages), peak("int8", q_pages)
+    assert peak_fp == slots // 4
+    assert peak_q >= 1.9 * peak_fp
 
 
 def test_paged_write_quantized_parity_and_requant_on_grow():

@@ -78,6 +78,16 @@ def _prompts_shared_prefix(n, prefix_pages=2, page=4, tail=4, seed=3,
             .astype("int64") for _ in range(n)]
 
 
+def _drained_clean(rep) -> bool:
+    """No live sequence on the replica: every allocated page is
+    cache-held, one reference each."""
+    cache = rep.sup.engine._cache
+    refs, cached = cache.refcounts(), set(cache.cached_pages())
+    return (cache.owners() == {} and set(refs) == cached
+            and sum(refs.values()) == len(cached)
+            and cache.pages_in_use == len(cached))
+
+
 def _reasons(router):
     return [e["reason"]
             for e in router.stats()["router"]["audit_tail"]]
@@ -151,8 +161,7 @@ def test_affinity_steers_to_warm_replica(model):
         assert first.placements == len(prompts)
         assert cold.placements == 0
         # ... and the warmth is real, not just stickiness: the engine's
-        # prefix cache served every follow-up's leading pages (the
-        # TTFT-visible half, benched in bench.py --mode router)
+        # prefix cache served every follow-up's leading pages
         assert first.sup.engine._prefix.hits == len(prompts) - 1
         assert cold.sup.engine._prefix.hits == 0
         reasons = _reasons(r)
@@ -179,6 +188,32 @@ def test_affinity_off_is_round_robin(model):
             assert eng._kp.devices() == {dev}
             assert all(leaf.devices() == {dev}
                        for leaf in jax.tree_util.tree_leaves(eng._W))
+    finally:
+        r.shutdown()
+
+
+@pytest.mark.parametrize("affinity", [True, False],
+                         ids=["affinity", "round_robin"])
+def test_placement_changes_no_token_no_ledger_and_leaks_no_page(
+        model, affinity):
+    """Placement decides the cache's temperature, never the math: either
+    policy serves `generate`'s tokens, routed traffic rides the warmed
+    programs (no replica's compile ledger moves), and once drained every
+    page still allocated is one the prefix cache holds."""
+    prompts = _prompts_shared_prefix(6, seed=9)
+    ref = [model.generate(paddle.to_tensor(q[None]),
+                          max_new_tokens=5).numpy()[0] for q in prompts]
+    r = _router(model, f"rtr_same_{int(affinity)}", affinity=affinity)
+    try:
+        ledgers = [dict(rep.sup.engine._ledger) for rep in r._replicas]
+        outs = [r.submit(q, max_new_tokens=5).result(timeout=60)
+                for q in prompts]
+        for a, b in zip(outs, ref):
+            np.testing.assert_array_equal(a, b)
+        assert [dict(rep.sup.engine._ledger)
+                for rep in r._replicas] == ledgers
+        assert all(v == 1 for led in ledgers for v in led.values())
+        assert all(_drained_clean(rep) for rep in r._replicas)
     finally:
         r.shutdown()
 
@@ -320,6 +355,8 @@ def test_replica_kill_mid_load_success_or_typed_token_identical(model):
             # no replica's compile ledger moved
             assert [dict(rep.sup.engine._ledger)
                     for rep in r._replicas] == ledgers
+            # and the replay path leaked no page anywhere in the fleet
+            assert all(_drained_clean(rep) for rep in r._replicas)
         finally:
             r.shutdown()
     finally:

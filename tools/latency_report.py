@@ -8,8 +8,7 @@ self-contained `reqspan:` instant into the trace per resolved request:
 
 with the four phase durations (queue / pad / device / resolve) and the
 end-to-end latency in milliseconds. This tool reads a trace written by
-`profiler.export_chrome_tracing`, `/trace`, or `bench.py --trace`, and
-prints:
+`profiler.export_chrome_tracing` or `/trace`, and prints:
 
 - per-phase p50 / p99 / mean / max over every request in the trace,
 - the top-N slowest requests with their full phase breakdown — the

@@ -10,7 +10,7 @@ Heuristics (mirroring the reference's file→UT mapping):
   * a changed `paddle_tpu/<pkg>/...` module selects every test whose
     source mentions the package or any changed module's basename
   * csrc/ or build files select the native-backed tests
-  * anything unmapped (bench.py, docs touching nothing) selects nothing;
+  * anything unmapped (docs touching nothing) selects nothing;
     `--fallback-all` selects the whole suite instead
 """
 from __future__ import annotations
