@@ -687,19 +687,23 @@ class Smoke:
             f"{mcfg.n_routed_experts} top-{mcfg.num_experts_per_tok} vocab="
             f"{mcfg.vocab_size} {mcfg.dtype}: {n_par} parameters built in "
             f"{time.perf_counter() - t:.1f}s wall")
-        bucket, page, steps = (32, 4, 6) if self.rehearsal else (2048, 16, 16)
+        bucket, page, steps = (32, 16, 6) if self.rehearsal else (2048, 16, 16)
         # a prompt that fills its bucket and ends ON a page boundary, two
         # that end inside a page, one short: different lengths in one step
         lengths = ([32, 27, 14, 5] if self.rehearsal
                    else [2048, 1777, 1021, 300])
-        pps = -(-(bucket + steps) // page)
+        # the table's width a power of two (256 entries, the benchmark's):
+        # whole rounds of the decode kernel's page copies, whatever their
+        # size (`paged_ops.paged_latent_kernel_supported`)
+        pps = 1 << (-(-(bucket + steps) // page) - 1).bit_length()
         eng = serving.GenerationEngine(
             net, name="smoke_latent", max_slots=4, page_size=page,
             num_pages=4 * pps, pages_per_seq=pps,
             prefill_buckets=(bucket,), max_new_tokens=steps, warmup=False)
-        check(eng.stats()["decode_attention"] == "latent_gather",
-              "latent: the decode attention is `latent_gather` (each slot's "
-              "576-wide rows gathered once for its 20 heads)")
+        check(eng.stats()["decode_attention"] == "latent_kernel",
+              "latent: the decode attention is `latent_kernel` (one Pallas "
+              "kernel walks each slot's own pages for its 20 heads: no "
+              "gather of the slot's whole table)")
         W = eng._W
         prompts = prompts_for(mcfg, lengths, seed=27)
         pt = np.stack([eng._cache.alloc(i, n + steps)

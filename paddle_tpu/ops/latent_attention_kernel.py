@@ -1,0 +1,209 @@
+"""Decode attention over a latent (MLA) pool as ONE Pallas kernel: each slot's
+own pages, walked in place, the softmax carried across blocks.
+
+The plain form (`ops/paged_ops.paged_latent_attention`, the gather) takes
+every slot's whole table out of the pool — `[B, PP*P, Rp]`, written once and
+read twice a layer whatever the slots hold — and masks what lies past `pos`.
+This kernel follows what the slots HOLD: for each slot it copies that slot's
+pages from the pool in HBM into VMEM a block of pages at a time, up to the
+page that holds `pos` and no further, and keeps a running maximum, sum and
+weighted sum of the rows' first `kv_rank` lanes (flash style), so nothing of
+size `B x PP x P` exists anywhere. A page is read once for all the heads of
+its slot (a latent row has no head axis) and once for both products (the
+values are the row's leading lanes).
+
+Modelled on `jax.experimental.pallas.ops.tpu.paged_attention`
+(`paged_flash_attention_kernel_inline_seq_dim`: a loop over the slot's
+blocks up to its length, page copies double-buffered, the next block — the
+next SLOT's first block at a slot's end — in flight while this one is
+computed), and not that kernel, for three reasons:
+
+- it drops a masked position by ADDING a large negative number to its score
+  and multiplies the values unmasked, so a non-finite row in a page it reads
+  and does not attend (the trash page that fills a table's tail and takes the
+  dead slots' writes, the rows past `pos` in a slot's last page) poisons a
+  slot that does not own it. Here a masked position is dropped by SELECTION
+  in both products: the score by `where`, the value row by `where`. What a
+  slot does attend reaches it unfiltered: a non-finite row it owns gives it
+  NaN (its score is non-finite), as the gather does;
+- it wants K and V as two arrays and would copy every page twice;
+- it has no `interpret` argument; this one runs under `pallas_ops._interpret`
+  like the flash kernels, so the CPU tests run the same body.
+
+One program, not a grid over slots: the loops over slots, a slot's blocks
+and a block's pages live inside the kernel, so the buffer that holds the
+block in flight is a loop value and no state crosses grid steps. The query
+of every slot (1.3 MB at 32 x 32 x 640 bfloat16) and the result (2.1 MB
+float32) sit in VMEM whole.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_ops import _NEG_INF, _interpret
+
+__all__ = ["latent_block_pages", "latent_decode_attention"]
+
+# One of the two VMEM buffers a block of pages is copied into: 32 pages of
+# 16 rows x 640 lanes bfloat16 (the benchmark's pool), 512 rows a round. Both
+# buffers, the queries and the result stay far inside Mosaic's 16 MiB scoped
+# limit. On the v5e, all 7 layers' attention for 32 slots (my chip run,
+# PR 30), at 4 / 8 / 16 / 32 / 64 pages a round: holding 32,480 rows as the
+# benchmark's mix does 1.83 / 1.25 / 0.98 / 0.83 / 0.84 ms (the gather
+# 15.65); 4,000 rows each 6.94 / 4.69 / 3.51 / 2.87 / 2.65; ONE row each
+# 0.24 / 0.23 / 0.23 / 0.24 / 0.28.
+_BLOCK_BYTES = 640 * 1024
+_HEAD_TILE = 16     # query heads are padded to whole sublane tiles (bf16: 16)
+
+
+def latent_block_pages(page_size, row_width, itemsize, table_width) -> int:
+    """Pages one round of copies moves: what fits `_BLOCK_BYTES`, rounded
+    down to a power of two, and no more than the table holds. Derived from
+    what the code can see; `paged_ops.paged_latent_kernel_supported` asks
+    that it divide the table's width."""
+    fit = max(1, _BLOCK_BYTES // (page_size * row_width * itemsize))
+    return min(1 << (fit.bit_length() - 1), int(table_width))
+
+
+def _kernel(len_ref, table_ref, layer_ref, q_ref, pool_ref, o_ref, buf, sems,
+            *, table_width, kv_rank, scale, precision):
+    B = q_ref.shape[0]
+    _, bp, P, Rp = buf.shape
+    bk = bp * P
+    pool = pool_ref.at[layer_ref[0]]
+
+    def copies(b, i, slot, go):
+        """Start (`go`) or wait for the copies of block `i` of slot `b`
+        into buffer `slot`: the slot's pages `i*bp ..`, as far as the page
+        that holds its last position. What lies past it in the buffer is
+        whatever an earlier block left there: never attended."""
+        first = b * table_width + i * bp
+
+        def one(j, _):
+            dma = pltpu.make_async_copy(pool.at[table_ref[first + j]],
+                                        buf.at[slot, j], sems.at[slot])
+            if go:
+                dma.start()
+            else:
+                dma.wait()
+            return 0
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(pl.cdiv(len_ref[b], P) - i * bp, bp), one, 0)
+
+    copies(0, 0, 0, True)
+
+    def per_slot(b, slot):
+        length = len_ref[b]
+        blocks = pl.cdiv(length, bk)
+        q = q_ref[b]                                           # [H, Rp]
+        H = q.shape[0]
+
+        def per_block(i, carry):
+            m, l, acc, slot = carry
+            # the next block is in flight while this one is computed: this
+            # slot's next, or at its end the next slot's first (every slot
+            # has one: a length is at least 1)
+            last = i + 1 == blocks
+            nb = jnp.where(last, b + 1, b)
+            ni = jnp.where(last, 0, i + 1)
+
+            @pl.when(nb < B)
+            def _():
+                copies(nb, ni, 1 - slot, True)
+
+            copies(b, i, slot, False)
+            rows = buf[slot].reshape(bk, Rp)
+            s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32,
+                                    precision=precision) * scale   # [H, bk]
+            t = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            s = jnp.where(t < length, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_new = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            tv = i * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            v = jnp.where(tv < length, rows[:, :kv_rank], 0)
+            acc_new = alpha * acc + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision)
+            return m_new, l_new, acc_new, 1 - slot
+
+        m0 = jnp.full((H, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((H, 1), jnp.float32)
+        acc0 = jnp.zeros((H, kv_rank), jnp.float32)
+        _, l, acc, slot = jax.lax.fori_loop(0, blocks, per_block,
+                                            (m0, l0, acc0, slot))
+        o_ref[b] = acc / l
+        return slot
+
+    jax.lax.fori_loop(0, B, per_slot, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "kv_rank",
+                                             "block_pages", "interpret"))
+def _call(q, pool, page_table, lengths, layer, *, scale, kv_rank,
+          block_pages, interpret):
+    B, H, Rp = q.shape
+    P, PP = pool.shape[2], page_table.shape[1]
+    Hp = -(-H // _HEAD_TILE) * _HEAD_TILE
+    q = jnp.pad(q.astype(pool.dtype), ((0, 0), (0, Hp - H), (0, 0)))
+    # float32 pools (the tiny CPU models; no cell) keep true-float32
+    # products, as the gather has them under the framework's "highest" pin
+    precision = (jax.lax.Precision.HIGHEST if pool.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    kernel = functools.partial(_kernel, table_width=PP, kv_rank=kv_rank,
+                               scale=scale, precision=precision)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,      # lengths, the page table, the layer
+            grid=(1,),
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, block_pages, P, Rp), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, kv_rank), jnp.float32),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32).reshape(-1),
+      layer, q, pool)
+    return out[:, :H]
+
+
+def latent_decode_attention(q, pool, page_table, lengths, scale, kv_rank,
+                            layer=None, block_pages=None):
+    """q [B, H, Rp] (padded to the pool's row); pool ONE layer `[N, P, Rp]`,
+    or with `layer` the whole `[L, N, P, Rp]` pool, of which that layer is
+    read in place — no layer is cut out of the pool first (handed
+    `pool[layer]` XLA:TPU copies the layer for the custom call: 167 MB a
+    layer at the benchmark's shapes); page_table [B, PP]; lengths [B] >= 1,
+    the positions each slot attends. Returns float32 [B, H, kv_rank].
+    Scores, running maximum, sum and rescaling are float32; the two products
+    take the pool's dtype and accumulate in float32.
+
+    The layer reaches the kernel as a scalar, and the call is a `jax.jit` of
+    its own: a decode program's layers share ONE traced and lowered kernel
+    (set-up time is an end-to-end metric: with a kernel traced for every
+    layer, and every page's copy unrolled in it, the decode program took
+    2.6 s to trace where the gather's took 0.3 — 22 s on the chip's host,
+    in every process; PERF.md PR 30)."""
+    if layer is None:
+        pool, layer = pool[None], 0
+    P, Rp = pool.shape[2:]
+    if block_pages is None:
+        block_pages = latent_block_pages(P, Rp, pool.dtype.itemsize,
+                                         page_table.shape[1])
+    return _call(q, pool, page_table, lengths,
+                 jnp.full((1,), layer, jnp.int32), scale=float(scale),
+                 kv_rank=int(kv_rank), block_pages=int(block_pages),
+                 interpret=_interpret())

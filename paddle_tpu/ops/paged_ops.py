@@ -91,6 +91,7 @@ __all__ = ["HeadPoolForm", "head_pools_fused",
            "paged_pool_mask",
            "paged_gather_layers", "paged_gather_quantized",
            "latent_pool_width", "paged_latent_attention",
+           "paged_latent_kernel_supported", "paged_latent_path",
            "paged_latent_write",
            "paged_prefix_attention", "paged_write",
            "paged_write_quantized", "page_rows_for_positions",
@@ -795,8 +796,15 @@ def paged_prefix_attention(q, kb, vb, k_tail, v_tail, prefix_len, scale):
 # `kv_rank + rope` values (576 for GLM-4.7-Flash). The pool is
 # `[L, N, P, Rp]`; pages, tables, the scratch page and zero-on-free are the
 # head pools'. Decode attends with the up-projection ABSORBED into the
-# query, so all the heads of a slot score against the same rows: one
-# gather of the slot's table a layer, shared by its heads.
+# query, so all the heads of a slot score against the same rows: a slot's
+# pages are read once a layer, shared by its heads.
+#
+# Two implementations, one shape-and-backend rule (`paged_latent_path`):
+# the Pallas kernel of `ops/latent_attention_kernel.py`, which walks each
+# slot's own pages in place as far as `pos` with the softmax fused, and the
+# per-slot gather of the slot's whole table, which is what every other
+# backend and shape takes and the plain form the kernel is tested against.
+# There is no pool-dense third: `paged_latent_attention`'s docstring, why.
 #
 # The pool's rows are `latent_pool_width(R)` wide: R rounded up to whole
 # 128-lane tiles (640 for 576), the extra lanes zero. With a 576-wide minor
@@ -833,31 +841,112 @@ def paged_latent_write(pool, layer, page_ids, offsets, rows):
     return pool.at[layer, page_ids, offsets, :].set(rows)
 
 
-def paged_latent_attention(q, pool, page_table, pos, scale, kv_rank):
+def paged_latent_kernel_supported(q_shape, pool_shape, table_shape,
+                                  pool_dtype=jnp.bfloat16) -> bool:
+    """Static gate of the latent decode kernel
+    (`ops/latent_attention_kernel.py`), by observable shape like
+    `paged_kernel_supported`. q [B, H, R]; pool ONE layer [N, P, Rp];
+    table [B, PP].
+
+    - floating rows of 2 or 4 bytes (bfloat16 as served, float32).
+    - a page is whole sublane tiles of its dtype (8 rows of float32, 16 of
+      bfloat16): one page is one copy into a `[P, Rp]` VMEM tile.
+    - the row whole 128-lane tiles (`latent_pool_width`), the query no
+      wider than it.
+    - the kernel walks a slot's table `latent_block_pages` entries at a
+      time (derived from the page's bytes and the table's width) and asks
+      that the block divide the table.
+    Every shape admitted here must compile on the chip: compiled for the
+    described v5e at the benchmark's shapes in bfloat16 and float32
+    (`tests/test_v5e_compile.py`) and run there (PERF.md PR 30). Widen the
+    rule only with a chip run that shows it."""
+    from .latent_attention_kernel import latent_block_pages
+    _, P, Rp = pool_shape
+    dtype = jnp.dtype(pool_dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize not in (2, 4):
+        return False
+    if P % (32 // dtype.itemsize) or Rp % 128 or q_shape[-1] > Rp:
+        return False
+    PP = table_shape[1]
+    return PP % latent_block_pages(P, Rp, dtype.itemsize, PP) == 0
+
+
+def paged_latent_path(q_shape, pool_shape, table_shape,
+                      pool_dtype=jnp.bfloat16) -> str:
+    """Which implementation `paged_latent_attention` traces for these
+    shapes: "latent_kernel" where a Pallas kernel can run (compiled on a
+    TPU backend, or interpreted under FLAGS_flash_attention_interpret: the
+    flash kernels' rule, `nn/functional/attention._kernel_runs`) and
+    `paged_latent_kernel_supported` admits the shapes, else
+    "latent_gather". Made before the call, never a fallback from a kernel
+    that failed."""
+    if _pallas_runs() and paged_latent_kernel_supported(
+            q_shape, pool_shape, table_shape, pool_dtype):
+        return "latent_kernel"
+    return "latent_gather"
+
+
+def _pallas_runs() -> bool:
+    # lint: allow(flag-in-trace): interpret mode is lowering structure (pallas_ops._interpret); the choice of path is made at trace time by design
+    return (bool(flag("FLAGS_flash_attention_interpret"))
+            or jax.default_backend() == "tpu")
+
+
+def paged_latent_attention(q, pool, page_table, pos, scale, kv_rank,
+                           layer=None):
     """Absorbed-weight decode attention over ONE layer of a latent pool.
 
     q [B, H, R]: per head `[q_nope . W_UK | q_rope]`; pool [N, P, Rp],
-    Rp = `latent_pool_width(R)`; page_table [B, PP]; pos [B]. Returns
-    [B, H, kv_rank] float32: the probability-weighted sum of the rows'
-    first `kv_rank` values (the caller multiplies by W_UV). Scores and
-    softmax are float32.
+    Rp = `latent_pool_width(R)` — or, with `layer` (a Python int), the
+    whole pool [L, N, P, Rp], of which that layer is read; page_table
+    [B, PP]; pos [B]. Returns [B, H, kv_rank] float32: the
+    probability-weighted sum of the rows' first `kv_rank` values (the
+    caller multiplies by W_UV). Scores and softmax are float32.
 
-    Each slot gathers the rows of its own table, `[B, PP*P, Rp]`, ONCE for
-    all its heads — a latent row has no head axis, so the gather is H times
-    smaller than the head pools' and the slot's H heads score against its
-    own PP*P rows only. Pool-dense under the page-ownership mask (the head
-    pools' `paged_pool_attention`) scores every slot's heads against the
-    WHOLE pool, B times the products; measured end to end in the cell that
-    serves this family (PERF.md PR 27: 32 slots x 20 heads, 8,192 pages =
-    32 x 256 entries) it took 33.7 ms a decode step against the gather's
-    31.0, so it is not built for latent pools. Row isolation: a position
-    past `pos` is dropped by `where`, the values are multiplied as a
-    finite copy, and a slot that attends a non-finite cached row reads NaN
-    — its owner trips the engine's flag, nobody else."""
-    monitor.stat_add("STAT_paged_attn_latent")     # traces, not calls
+    `paged_latent_path` picks the implementation from backend and shapes.
+    **latent_kernel**: one Pallas kernel copies each slot's own pages out
+    of the pool a block at a time, as far as the page that holds `pos`,
+    and carries the softmax across blocks; a page is read once for the
+    slot's H heads and both products, and nothing of size `B x PP x P` is
+    written. With `layer` it reads the whole pool in place: no layer is cut
+    out for it. **latent_gather** (every other backend and shape, and the
+    plain form the kernel is tested against): each slot gathers the rows of
+    its own table, `[B, PP*P, Rp]`, ONCE for all its heads, whatever it
+    holds — on the v5e 7.8 of the decode step's 27.6 ms in the cell that
+    serves this family, and 12.0 ms with its products and softmax, for
+    0.3 ms of bytes (PERF.md PR 27, PR 30).
+
+    There is no pool-dense third path. Pool-dense under the page-ownership
+    mask (the head pools' `paged_pool_attention`) scores every slot's heads
+    against the WHOLE pool, B times the products; measured end to end in
+    that cell (PERF.md PR 27: 32 slots x 20 heads, 8,192 pages = 32 x 256
+    entries) it took 33.7 ms a decode step against the gather's 31.0, so it
+    is not built for latent pools.
+
+    Row isolation, on both paths: a non-finite row a slot does not attend
+    (another slot's page, the trash page, the rest of its last page) cannot
+    reach it, and a slot that attends a non-finite cached row reads NaN —
+    its owner trips the engine's flag, nobody else. The gather drops a
+    score past `pos` by `where`, multiplies the values as a finite copy and
+    marks the slots whose own rows are not finite; the kernel drops a
+    position past `pos` by `where` in the scores AND in the values, and
+    what a slot does attend reaches it unfiltered."""
+    shape = pool.shape if layer is None else pool.shape[1:]
+    path = paged_latent_path(q.shape, shape, page_table.shape, pool.dtype)
     R = pool.shape[-1]
     with jax.named_scope("latent_attend"):
         q = _pad_lanes(q, R)
+        if path == "latent_kernel":
+            monitor.stat_add("STAT_paged_attn_latent_kernel")  # traces
+            from .latent_attention_kernel import latent_decode_attention
+            # (a length is at least 1, a dead slot's too: the kernel starts
+            # every slot's first round of copies behind the slot before)
+            return latent_decode_attention(
+                q, pool, page_table, jnp.maximum(pos + 1, 1), scale, kv_rank,
+                layer=layer)
+        monitor.stat_add("STAT_paged_attn_latent")     # traces, not calls
+        if layer is not None:
+            pool = pool[layer]
         rows = jnp.take(pool, page_table, axis=0)           # [B, PP, P, R]
         rows = rows.reshape(page_table.shape[0], -1, R)
         s = jnp.einsum("bhr,btr->bht", q, rows,

@@ -2636,9 +2636,9 @@ class GenerationEngine:
                           self._preferred)],
             "kv": self._kv_introspection(slot_of),
             "compiles": ledger,
-            # "kernel" / "pool" / "reference" (head pools), "latent_gather"
-            # (a latent pool): the paged attention the decode program was
-            # built with (ops/paged_ops.py)
+            # "kernel" / "pool" / "reference" (head pools), "latent_kernel"
+            # / "latent_gather" (a latent pool): the paged attention the
+            # decode program was built with (ops/paged_ops.py)
             "decode_attention": self._decode_attention,
             "steps": steps,
             "prefills": prefills,

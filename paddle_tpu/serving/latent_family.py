@@ -6,8 +6,11 @@ A cached token is ONE row per layer with no head axis, `[c_kv | k_r]`
 model's dtype, Rp the row rounded up to whole 128-lane tiles (640; why:
 `ops/paged_ops.py`). Prefill runs the expanded attention over the prompt and
 writes its rows; decode writes the new row, absorbs the up-projection into
-the query and attends the slot's own rows, gathered once for all its heads
-(`ops/paged_ops.paged_latent_attention`). Positions are rotary, so
+the query and attends the slot's own rows, read once for all its heads
+(`ops/paged_ops.paged_latent_attention`: on a TPU one Pallas kernel that
+walks the slot's own pages in the pool as far as `pos`, elsewhere and for
+the shapes its rule refuses a gather of the slot's whole table; which one,
+`stats()["decode_attention"]` says). Positions are rotary, so
 the largest position is the configuration's `max_position_embeddings`; the
 table's width (`pages_per_seq`) is what bounds a sequence in practice.
 
@@ -51,8 +54,10 @@ def latent_decode(W, pool, pt, tok, pos, active, cfg, page_size):
         return paged_latent_write(pool, layer, page_ids, offs, row)
 
     def attend_rows(pool, layer, q, pos):
-        return paged_latent_attention(q, pool[layer], pt, pos, scale,
-                                      cfg.kv_lora_rank)
+        # the whole pool and the layer's index: the kernel reads that
+        # layer's pages in place, with no layer cut out of the pool first
+        return paged_latent_attention(q, pool, pt, pos, scale,
+                                      cfg.kv_lora_rank, layer)
 
     logits, pool, hit = glm_decode_step(W, tok, pos, pool, write_row,
                                         attend_rows, cfg, live=active)
@@ -102,7 +107,14 @@ class LatentFamily:
                                       cfg.num_pages, cfg.pages_per_seq)
 
     def decode_attention(self, cfg, tp, pools):
-        return "latent_gather"      # the one path: ops/paged_ops.py, why
+        """`latent_kernel` or `latent_gather`: the shape-and-backend rule
+        of ops/paged_ops.py (`paged_latent_path`), known before anything
+        is traced."""
+        from ..ops.paged_ops import paged_latent_path
+        m, pool = self.config, pools[0]
+        return paged_latent_path(
+            (cfg.max_slots, m.num_heads, m.latent_dim), pool.shape[1:],
+            (cfg.max_slots, cfg.pages_per_seq), pool.dtype)
 
     def build(self, ctx):
         import jax.numpy as jnp

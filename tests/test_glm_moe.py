@@ -3,6 +3,8 @@ grouped experts, latent pools, the latent decode family behind
 `serving.GenerationEngine`. CPU, tiny sizes, seeded weights. The comparisons
 with the plain float32 reference are in tests/benchmark/test_glm_reference.py.
 """
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -92,46 +94,173 @@ def test_a_dead_row_reads_no_expert_and_counts_for_none():
 
 
 # -- latent pools --------------------------------------------------------------
+# Two paths, one rule (`paged_ops.paged_latent_path`): the per-slot gather,
+# which this backend takes, and the Pallas kernel, which a TPU backend takes
+# and which the interpreter runs here under the flash kernels' flag.
 
-def latent_setup(B=3, PP=4, P=4, N=12, R=40, rank=32, seed=0):
+@contextlib.contextmanager
+def interpreter(on):
+    """The flag that lets a Pallas kernel run on this backend, held to `on`."""
+    from paddle_tpu.framework.flags import get_flags, set_flags
+    old = get_flags(["FLAGS_flash_attention_interpret"])
+    set_flags({"FLAGS_flash_attention_interpret": on})
+    try:
+        yield
+    finally:
+        set_flags(old)
+
+
+@pytest.fixture(params=["gather", "kernel"])
+def path(request):
+    """The same case on both paths. A page of 8 float32 rows is a shape of
+    the kernel's rule; the flag is what lets a Pallas kernel run here."""
+    with interpreter(request.param == "kernel"):
+        yield "latent_" + request.param
+
+
+def latent_setup(B=4, PP=4, P=8, N=20, R=40, rank=32, seed=0,
+                 pos=(5, 16, 0, 31)):
+    """Slot 0 ends inside its first page, slot 1 on the first row of its
+    third page (17 rows: a page boundary and one), slot 2 is DEAD (every
+    entry the trash page 0, pos 0, as the engine parks it), slot 3 fills
+    its table. `pos=(0, 15, ...)` gives lengths 1 and 16."""
     rs = np.random.RandomState(seed)
     width = paged_ops.latent_pool_width(R)
     pool = jnp.zeros((N, P, width), jnp.float32)
     pool = pool.at[:, :, :R].set(rs.randn(N, P, R).astype("float32"))
-    pt = jnp.asarray(rs.permutation(np.arange(1, N))[:B * PP - 1].tolist()
-                     + [0], jnp.int32)[:B * PP].reshape(B, PP)
-    pos = jnp.asarray([5, 15, 0], jnp.int32)[:B]
+    pt = np.asarray(rs.permutation(np.arange(1, N))[:B * PP], np.int32
+                    ).reshape(B, PP)
+    pos = np.asarray(pos, np.int32)
+    for b_ in range(B):             # past a slot's pages: the trash page
+        pt[b_, pos[b_] // P + 1:] = 0
+    pt[2] = 0
     q = jnp.asarray(rs.randn(B, 2, R), jnp.float32)
-    return q, pool, pt, pos, rank, P, N
+    return q, pool, jnp.asarray(pt), jnp.asarray(pos), rank, P, N
 
 
-def test_latent_attention_is_plain_attention_over_each_slots_own_rows():
-    q, pool, pt, pos, rank, P, N = latent_setup()
-    a = paged_ops.paged_latent_attention(q, pool, pt, pos, 0.3, rank)
-    assert a.shape == (3, 2, rank) and a.dtype == jnp.float32
+def attend(q, pool, pt, pos, rank, path, **kw):
+    assert paged_ops.paged_latent_path(q.shape, pool.shape[-3:], pt.shape,
+                                       pool.dtype) == path
+    return paged_ops.paged_latent_attention(q, pool, pt, pos, 0.3, rank, **kw)
+
+
+def plain_attention(q, pool, pt, pos, rank, b_):
+    """Slot `b_`'s cached rows in position order, by hand."""
     pool_np, q_np = np.asarray(pool), np.asarray(q)
-    for b_ in range(3):
-        # the slot's cached rows in position order, by hand
-        rows = np.concatenate([pool_np[int(pg)] for pg in pt[b_]])
-        rows = rows[:int(pos[b_]) + 1]
-        s = (q_np[b_] @ rows[:, :q_np.shape[-1]].T) * 0.3
-        p = np.exp(s - s.max(-1, keepdims=True))
-        want = (p / p.sum(-1, keepdims=True)) @ rows[:, :rank]
-        np.testing.assert_allclose(a[b_], want, atol=1e-5, rtol=1e-5)
+    rows = np.concatenate([pool_np[int(pg)] for pg in pt[b_]])
+    rows = rows[:int(pos[b_]) + 1]
+    s = (q_np[b_] @ rows[:, :q_np.shape[-1]].T) * 0.3
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return (p / p.sum(-1, keepdims=True)) @ rows[:, :rank]
+
+
+@pytest.mark.parametrize("pos", [(5, 16, 0, 31), (0, 15, 0, 8)],
+                         ids=["6-17-dead-full", "1-16-dead-9"])
+def test_latent_attention_is_plain_attention_over_each_slots_own_rows(
+        path, pos):
+    q, pool, pt, pos, rank, P, N = latent_setup(pos=pos)
+    a = attend(q, pool, pt, pos, rank, path)
+    assert a.shape == (4, 2, rank) and a.dtype == jnp.float32
+    for b_ in range(4):     # the dead slot attends the trash page's row 0
+        np.testing.assert_allclose(
+            a[b_], plain_attention(q, pool, pt, pos, rank, b_),
+            atol=1e-5, rtol=1e-5)
     assert paged_ops.latent_pool_width(576) == 640
     assert paged_ops.latent_pool_width(640) == 640
 
 
+def test_the_whole_pool_and_a_layers_index_read_that_layer_in_place(path):
+    """`layer` given: the pool is `[L, N, P, Rp]` and no layer is cut out
+    for the kernel (compiled for the v5e a slice would be copied, 167 MB a
+    layer: tests/test_v5e_compile.py)."""
+    q, pool, pt, pos, rank, P, N = latent_setup()
+    whole = jnp.stack([pool * 0 + 7.0, pool, pool[::-1]])
+    np.testing.assert_array_equal(
+        attend(q, whole, pt, pos, rank, path, layer=1),
+        attend(q, pool, pt, pos, rank, path))
+
+
+@pytest.mark.parametrize("block_pages", [1, 2, 4])
+def test_the_kernel_carries_the_softmax_across_blocks_of_pages(block_pages):
+    """One, two and four pages a round of copies: a slot's rows come in
+    4, 2 and 1 rounds, the next slot's first round in flight behind the
+    last; a poisoned page nobody holds changes nothing."""
+    from paddle_tpu.ops.latent_attention_kernel import latent_decode_attention
+    q, pool, pt, pos, rank, P, N = latent_setup()
+    free = sorted(set(range(1, N)) - set(np.asarray(pt).ravel().tolist()))
+    pool = pool.at[free[0]].set(jnp.nan)
+    with interpreter(True):
+        a = latent_decode_attention(
+            paged_ops._pad_lanes(q, pool.shape[-1]), pool, pt, pos + 1, 0.3,
+            rank, block_pages=block_pages)
+    for b_ in range(4):
+        np.testing.assert_allclose(
+            a[b_], plain_attention(q, pool, pt, pos, rank, b_),
+            atol=1e-5, rtol=1e-5)
+
+
 @pytest.mark.parametrize("owner", [1, 0])
-def test_a_poisoned_latent_page_reaches_its_owner_only(owner):
+def test_a_poisoned_latent_page_reaches_its_owner_only(path, owner):
     q, pool, pt, pos, rank, P, N = latent_setup()
     page = int(pt[owner, 0])               # its owner attends it, others not
     pool = pool.at[page].set(jnp.nan)
-    out = np.asarray(paged_ops.paged_latent_attention(
-        q, pool, pt, pos, 0.3, rank))
+    out = np.asarray(attend(q, pool, pt, pos, rank, path))
     assert np.isnan(out[owner]).all()
-    for other in {0, 1, 2} - {owner}:
+    for other in {0, 1, 3} - {owner}:
         assert np.isfinite(out[other]).all()
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf])
+def test_a_poisoned_trash_page_and_rows_past_pos_reach_nobody(path, poison):
+    """What a slot's pages hold past `pos`, and the trash page that fills a
+    table's tail and takes the dead slots' writes, are READ by a path that
+    moves whole pages and attended by nobody: dropped by selection in both
+    products, never multiplied by a zero probability."""
+    q, pool, pt, pos, rank, P, N = latent_setup()
+    clean = np.asarray(attend(q, pool, pt, pos, rank, path))
+    bad = pool.at[0, 1:].set(poison)    # row 0 is the dead slot's own row
+    for b_ in (0, 1, 3):                # the rest of each live slot's last page
+        last, off = int(pt[b_, int(pos[b_]) // P]), int(pos[b_]) % P + 1
+        bad = bad.at[last, off:].set(poison)
+    out = np.asarray(attend(q, bad, pt, pos, rank, path))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, clean)
+    # the trash page's first row is the one row a dead slot attends: it
+    # reaches the dead slot, whose result nobody reads, and no live one
+    out = np.asarray(attend(q, bad.at[0, 0].set(poison), pt, pos, rank, path))
+    assert np.isnan(out[2]).all()
+    np.testing.assert_array_equal(out[[0, 1, 3]], clean[[0, 1, 3]])
+
+
+@pytest.mark.parametrize("shapes, why", [
+    (dict(P=4), "a page of 4 rows is no whole sublane tile"),
+    (dict(P=8, dtype="bfloat16"), "bfloat16 rows pack 16 to a tile"),
+    (dict(Rp=576), "a row of 576 lanes is no whole lane tile"),
+    (dict(PP=192), "128 pages a round do not divide a table of 192"),
+    (dict(dtype="int8"), "int8 rows"),
+    (dict(R=700), "a query wider than the row"),
+])
+def test_the_kernels_rule_refuses(shapes, why):
+    from paddle_tpu.ops.latent_attention_kernel import latent_block_pages
+    args = dict(B=4, H=2, R=40, N=64, P=8, Rp=128, PP=16, dtype="float32")
+
+    def rule(a):
+        return ((a["B"], a["H"], a["R"]), (a["N"], a["P"], a["Rp"]),
+                (a["B"], a["PP"]), jnp.dtype(a["dtype"]))
+    assert paged_ops.paged_latent_kernel_supported(*rule(args))
+    assert not paged_ops.paged_latent_kernel_supported(
+        *rule(dict(args, **shapes))), why
+    # the benchmark's shapes: 32 pages = 512 rows = 640 KiB a round
+    assert latent_block_pages(16, 640, 2, 256) == 32
+    assert paged_ops.paged_latent_kernel_supported(
+        (32, 20, 576), (8192, 16, 640), (32, 256), jnp.bfloat16)
+    # and the backend's half of the rule: no Pallas kernel runs here
+    # unless the interpreter's flag is on
+    for on, want in ((False, "latent_gather"), (True, "latent_kernel")):
+        with interpreter(on):
+            assert paged_ops.paged_latent_path(*rule(args)) == want
+            assert paged_ops.paged_latent_path(
+                *rule(dict(args, **shapes))) == "latent_gather"
 
 
 def test_a_latent_cache_is_one_pool_without_a_head_axis():
@@ -228,22 +357,37 @@ def greedy(net, prompt, n, width=48):
     return np.asarray(seq, np.int32)
 
 
-def test_the_engine_serves_the_model_token_for_token(tiny):
+@pytest.mark.parametrize("page_size", [4, 8], ids=["page4", "page8"])
+def test_the_engine_serves_the_model_token_for_token(tiny, path, page_size):
+    """Pages of 8 rows are a shape of the kernel's rule and pages of 4 are
+    not: with the interpreter's flag on the first engine takes the kernel
+    and the second the gather, with it off both take the gather; all four
+    serve the Layer's own greedy tokens."""
+    from paddle_tpu.framework import monitor
     cfg, net = tiny
     rs = np.random.RandomState(3)
     # prompts that end inside a page, on a page boundary (8, 16), fill a
     # bucket (16, 32) and spill into the second bucket
     prompts = [rs.randint(0, cfg.vocab_size, n).astype("int32")
                for n in (5, 8, 13, 16, 3, 20, 32)]
+    took = path if page_size == 8 else "latent_gather"
+    counters = ("STAT_paged_attn_latent_kernel", "STAT_paged_attn_latent")
+    before = [monitor.stat_get(c) for c in counters]
     eng = serving.GenerationEngine(
-        net, name="glm_e2e", max_slots=4, page_size=4, num_pages=64,
-        pages_per_seq=16, prefill_buckets=(16, 32), max_new_tokens=12)
+        net, name="glm_e2e", max_slots=4, page_size=page_size,
+        num_pages=256 // page_size, pages_per_seq=64 // page_size,
+        prefill_buckets=(16, 32), max_new_tokens=12)
     try:
         st = eng.stats()
-        assert st["decode_attention"] == "latent_gather"
+        assert st["decode_attention"] == took
         assert st["compiles"] == {"prefill[b=16]": 1, "prefill[b=32]": 1,
                                   "decode[m=4]": 1}
-        assert [p["shape"] for p in st["pools"]] == [[3, 64, 4, 128]]
+        # one trace of the decode program: its three layers' attention, all
+        # on the path the engine names, none on the other
+        traced = [monitor.stat_get(c) - b for c, b in zip(counters, before)]
+        assert traced == ([3, 0] if took == "latent_kernel" else [0, 3])
+        assert [p["shape"] for p in st["pools"]] == [
+            [3, 256 // page_size, page_size, 128]]
         streams = [eng.submit_stream(p, max_new_tokens=9) for p in prompts]
         outs = [np.asarray(s.result(120)) for s in streams]
         assert eng.stats()["compiles"] == st["compiles"]    # none after
@@ -267,6 +411,24 @@ def test_a_pool_larger_than_the_tables_gives_the_same_tokens(tiny):
     eng = serving.GenerationEngine(
         net, name="glm_gather", max_slots=2, page_size=4, num_pages=64,
         pages_per_seq=8, prefill_buckets=(16,), max_new_tokens=8)
+    try:
+        assert eng.stats()["decode_attention"] == "latent_gather"
+        out = eng.generate(p, max_new_tokens=8)
+    finally:
+        eng.shutdown()
+    np.testing.assert_array_equal(out, greedy(net, p, 8))
+
+
+def test_a_table_the_block_does_not_divide_takes_the_gather(tiny, path):
+    """192 entries of 8 rows: a round of copies would be 128 pages, which
+    does not divide the table, so the rule refuses and the engine gathers,
+    whether or not a Pallas kernel could run; same tokens."""
+    cfg, net = tiny
+    p = np.random.RandomState(6).randint(0, cfg.vocab_size, 11).astype("int32")
+    eng = serving.GenerationEngine(
+        net, name="glm_refused_shape", max_slots=2, page_size=8,
+        num_pages=400, pages_per_seq=192, prefill_buckets=(16,),
+        max_new_tokens=8)
     try:
         assert eng.stats()["decode_attention"] == "latent_gather"
         out = eng.generate(p, max_new_tokens=8)

@@ -226,21 +226,29 @@ LAT_SLOTS, LAT_PAGE, LAT_PAGES, LAT_PP = 32, 16, 8192, 256
 
 
 def test_latent_decode_compiles_for_the_v5e_without_a_pool_copy(
-        one_chip, uncached):
+        one_chip, uncached, monkeypatch):
     """What the chip's compiler would refuse it refuses here: the grouped
     expert product (`lax.ragged_dot` -> XLA:TPU's own kernel) is traced
     under "default" precision because Mosaic refuses its bfloat16 operands
     under the framework's "highest" pin; a cached row takes 640 lanes so
     that no program copies the whole pool in and out (at 576 each did:
-    1.17 GB of temporaries for `zero_pages` alone); each slot's rows are
-    gathered once a layer for its 20 heads. Behind the engine's jit
-    boundary (`jit_program`, PR 28) the compiler, left to choose, keeps
-    the DEFAULT layout for this pool: the contract changes nothing here."""
+    1.17 GB of temporaries for `zero_pages` alone). Decode attention is the
+    Pallas kernel of `ops/latent_attention_kernel.py` (PR 30), which walks
+    each slot's own pages in the pool: one custom call a layer, no gather
+    of the slots' tables (a layer's `[32, 4096, 640]` rows were 168 MB and
+    most of the program's 0.35 GB of temporaries), and no layer cut out of
+    the pool for it either (handed `pool[layer]` the compiler copied the
+    layer, 167 MB: the kernel takes the whole pool and the layer's index).
+    Behind the engine's jit boundary (`jit_program`, PR 28) the compiler,
+    left to choose, keeps the DEFAULT layout for this pool. The rule asks
+    the backend, which is the CPU here: the test answers for it."""
     import types
 
     from jax.experimental.layout import Format, Layout
 
     from paddle_tpu.device import layout_name
+    from paddle_tpu.ops import latent_attention_kernel as lk
+    from paddle_tpu.ops import paged_ops
     from paddle_tpu.serving.generation import jit_program
 
     from paddle_tpu.models.glm_moe import (GlmMoeLiteConfig,
@@ -249,6 +257,8 @@ def test_latent_decode_compiles_for_the_v5e_without_a_pool_copy(
     from paddle_tpu.serving.generation import GenerationConfig
     from paddle_tpu.serving.latent_family import LatentFamily
 
+    monkeypatch.setattr(paged_ops, "_pallas_runs", lambda: True)
+    monkeypatch.setattr(lk, "_interpret", lambda: False)
     cfg = GlmMoeLiteConfig(num_hidden_layers=2)
     fam = LatentFamily(types.SimpleNamespace(config=cfg))
     ecfg = GenerationConfig(max_slots=LAT_SLOTS, page_size=LAT_PAGE,
@@ -263,7 +273,7 @@ def test_latent_decode_compiles_for_the_v5e_without_a_pool_copy(
                                glm_weight_shapes(cfg))
     pool = sds((2, LAT_PAGES, LAT_PAGE, 640), "bfloat16")
     path = fam.decode_attention(ecfg, 1, (pool,))
-    assert path == "latent_gather"
+    assert path == "latent_kernel"
     fns = fam.build(ProgramContext(ecfg, 1, None, 1, False, path, W, {}))
     key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
     M = LAT_SLOTS
@@ -284,14 +294,59 @@ def test_latent_decode_compiles_for_the_v5e_without_a_pool_copy(
                   "layer_1/mla/latent_write", "layer_1/moe/experts",
                   "layer_1/moe/router", "layer_0/mlp", "lm_head", "sample"):
         assert scope in text, scope
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "ragged-dot" in text
     assert "kv_mask" not in text            # no pool-dense ownership mask
+    # the kernel by its own name, as a trace shows it: one custom call a
+    # layer, under the layer's `latent_attend` scope
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "latent_decode_attention" in ln]
+    assert len(calls) == 2
+    assert all("/mla/latent_attend" in ln for ln in calls)
     # no copy of the whole pool, in either program
     whole = f"bf16[2,{LAT_PAGES},{LAT_PAGE},640]"
     for t in (text, zero.as_text()):
         assert not [ln for ln in t.splitlines()
                     if f"= {whole}" in ln and " copy(" in ln]
     assert zero.memory_analysis().temp_size_in_bytes < 1 << 20
-    # a layer's gathered rows [32, 4096, 640] bfloat16 (168 MB) and their
-    # scores; nothing pool-dense: [640, 131072] float32 would be 335 MB
-    assert decode.memory_analysis().temp_size_in_bytes < 400 << 20
+    # no buffer of slots x entries x page x 640 elements, in any shape: the
+    # gathered rows, and (32 x 256 entries = 8,192 pages) one layer of the
+    # pool cut out for the kernel
+    gathered = LAT_SLOTS * LAT_PP * LAT_PAGE * 640
+    shapes = set(re.findall(r"\b(?:bf16|f32)\[[\d,]+\]", text))
+    assert whole in shapes
+    assert not [s for s in shapes if int(np.prod(
+        [int(d) for d in s[s.index("[") + 1:-1].split(",")])) == gathered]
+    # 0.35 GB with the gather (PR 27-29); compiled here for the kernel:
+    # 4.9 MB
+    assert decode.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("dtype, page", [("bfloat16", 16), ("float32", 8),
+                                         ("float32", 16)])
+def test_the_latent_kernel_compiles_for_the_v5e_at_the_shapes_its_rule_admits(
+        one_chip, uncached, monkeypatch, dtype, page):
+    """The kernel alone at the cell's widths (32 slots, 20 heads, rows of
+    640 lanes, values 512, a table of 256): bfloat16 as served, and the
+    float32 pools the rule also admits (true-float32 products, "highest"),
+    with the smallest page it admits for each; reading one layer of a whole
+    pool in place."""
+    from paddle_tpu.ops import latent_attention_kernel as lk
+    from paddle_tpu.ops import paged_ops
+
+    monkeypatch.setattr(lk, "_interpret", lambda: False)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    q, table = sds((LAT_SLOTS, 20, 640), dtype), sds((LAT_SLOTS, LAT_PP),
+                                                     "int32")
+    pool = sds((7, 4096, page, 640), dtype)
+    assert paged_ops.paged_latent_kernel_supported(
+        q.shape, pool.shape[1:], table.shape, pool.dtype)
+    compiled = jax.jit(lambda q, pool, pt, n: lk.latent_decode_attention(
+        q, pool, pt, n, 0.0625, 512, layer=3)).lower(
+        q, pool, table, sds((LAT_SLOTS,), "int32")).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "latent_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
