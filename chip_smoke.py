@@ -31,6 +31,16 @@ line of stdout is one JSON object:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
+The engine keeps one decode step in flight ahead of the host (ISSUE 34): it
+launches step n+1 from the tokens the device still holds and only then reads
+step n, so what a step teaches the host — an EOS, a poison flag — is learned
+one step late (the token computed meanwhile is dropped), and whatever needs
+a step's tokens on the host first (speculation, a test hook, an armed step
+failpoint) settles the step in flight before it launches. `serve` and
+`latent` each end a loaded run through the loop and fail unless
+`stats()["lookahead"]["ahead"]` is over 0.9 of that run's decode steps with
+no token dropped, and the tokens still match the phase's reference.
+
 `--cpu-rehearsal` runs the same control flow at tiny sizes on the CPU with
 the kernels in the Pallas interpreter, to debug the script itself. It says
 platform=cpu in every line, is never the default, and proves nothing about
@@ -275,6 +285,12 @@ class Smoke:
         net.eval()
         return cfg, net
 
+    @property
+    def serve_new(self):
+        """New tokens a request of the serve and multi phases: enough decode
+        steps for the share launched ahead of the host to mean something."""
+        return 24 if self.rehearsal else 32
+
     def serve_requests(self, eng, prompts, new):
         """Half the requests through submit (futures), half through
         submit_stream; returns the full sequences, in prompt order."""
@@ -292,6 +308,19 @@ class Smoke:
                     f"but resolved to {list(full[len(prompts[i]):])}")
             outs.append(full)
         return outs
+
+    def check_ahead(self, phase, stats):
+        """The loaded run kept one decode step in flight ahead of the host
+        (ISSUE 34): all but a few of its steps were launched before the
+        step before them was read, and no token was dropped."""
+        look, steps = stats["lookahead"], stats["steps"]
+        self.say(f"{phase}: lookahead {look} over {steps} decode steps")
+        self.check(look["ahead"] > 0.9 * steps and not look["settled"]
+                   and look["dropped_tokens"] == 0,
+                   f"{phase}: over 0.9 of the {steps} decode steps were "
+                   f"launched ahead of the last one's read-back "
+                   f"({look['ahead']}), none settled first, no token "
+                   f"dropped")
 
     def near_argmax_rate(self, net, outs, prompts):
         """Teacher-forced agreement with the eager forward: every sequence
@@ -316,7 +345,7 @@ class Smoke:
         say, check = self.say, self.check
         cfg, net = self.gpt2_small()
         buckets = (16, 32) if self.rehearsal else (32, 128)
-        new = 8 if self.rehearsal else 32
+        new = self.serve_new
         lengths = ([3, 9, 14, 20, 27, 31, 9, 20] if self.rehearsal
                    else [5, 24, 40, 72, 100, 120, 24, 72])
         prompts = prompts_for(cfg, lengths, seed=2)
@@ -346,6 +375,7 @@ class Smoke:
               "serve: zero compiles after warm-up")
         check(stats["pages"]["pages_in_use"] == 0,
               "serve: pages_in_use == 0 after drain")
+        self.check_ahead("serve", stats)
         kern = stat_get("STAT_paged_attn_kernel") - k0
         pool = stat_get("STAT_paged_attn_pool") - p0
         entries = stats["pages"]["pages_per_seq"]
@@ -579,7 +609,7 @@ class Smoke:
 
         # (b) GenerationEngine(tp=n) on GPT-2 small
         cfg, net = self.gpt2_small()
-        new = 8 if self.rehearsal else 32
+        new = self.serve_new    # (its tokens are compared with `serve`'s)
         buckets = (16, 32) if self.rehearsal else (32, 128)
         if served is None:
             lengths = [3, 9, 14, 20] if self.rehearsal else [5, 24, 72, 120]
@@ -816,6 +846,31 @@ class Smoke:
                   f"latent: a cache kept in 8 bits FAILS the limit (median "
                   f"{med8:.4f} > {LATENT_MEDIAN}): the tolerance tells "
                   f"bfloat16 from a lower precision")
+
+        # the same prompts through the engine's own loop, one decode step
+        # in flight ahead of the host (ISSUE 34): its tokens against the
+        # direct steps' above, which the reference has just vouched for
+        freed = [pg for i in range(len(lengths))
+                 for pg in eng._cache.free(i)]
+        eng._zero_pages(freed)
+        new = 24 if self.rehearsal else 32
+        t = time.perf_counter()
+        outs = [np.asarray(f.result(timeout=600)) for f in
+                [eng.submit(pr, max_new_tokens=new) for pr in prompts]]
+        stats = eng.stats()
+        direct = np.stack(toks[:steps + 1], 1)              # [4, steps + 1]
+        looped = np.stack([o[len(pr):len(pr) + steps + 1]
+                           for o, pr in zip(outs, prompts)])
+        same = float((direct == looped).mean())
+        say(f"latent: {len(prompts)} requests of {new} tokens through the "
+            f"loop in {time.perf_counter() - t:.1f}s wall; their first "
+            f"{steps + 1} tokens against the direct steps': {same:.3f} "
+            f"equal")
+        check(all(len(o) == len(pr) + new for o, pr in zip(outs, prompts))
+              and same >= EXACT,
+              f"latent: the loop's tokens are the direct steps' (>= "
+              f"{EXACT})")
+        self.check_ahead("latent", stats)
         eng.shutdown(drain=False)
 
     # -- the run ------------------------------------------------------------

@@ -196,10 +196,12 @@ register_flag("FLAGS_gen_step_log", True,
               "step ring (profiler/step_log.py; /steps, chrome counter "
               "tracks, engine_step_ms/gen_queue_age_ms histograms); off "
               "removes the per-iteration accounting entirely")
-register_flag("FLAGS_gen_step_log_size", 4096,
+register_flag("FLAGS_gen_step_log_size", 16384,
               "per-engine step-ring capacity in records; the oldest "
               "record is overwritten (same bounding discipline as "
-              "FLAGS_trace_ring_size)")
+              "FLAGS_trace_ring_size). 16,384: at 13 ms an iteration a "
+              "50 s window holds up to 3,900 records, and a reader of "
+              "the window wants the drain after it too")
 register_flag("FLAGS_gen_audit_log", "",
               "optional JSONL sink for the generation scheduler's "
               "decision audit log (profiler/audit.py): every "

@@ -39,19 +39,33 @@ oldest overwritten — the same bounding discipline as the trace rings):
                   ring consumers — which read by name with defaults —
                   parse records from both eras unchanged
     prefill_ms / decode_ms
-                  wall spent in prefill jit calls vs the decode step
-                  this iteration — the "is one long prompt spiking
-                  everyone's TPOT" signal
+                  the time of the prefill programs this iteration ran
+                  and of the decode step it READ — the "is one long
+                  prompt spiking everyone's TPOT" signal. With one
+                  decode step in flight ahead of the host (ISSUE 34) a
+                  program's time runs from the later of (its launch,
+                  the observed end of the program before it) to its own
+                  observed end: its device time or more (short only by
+                  the host's lag in seeing the program before it end,
+                  which that program's time holds), and no stretch
+                  counted twice. A record's tokens, device
+                  counters and decode_ms belong to the same step
     tier_demotions / tier_promotions
                   prefix-cache pages demoted to / promoted back from
                   the host-RAM tier THIS iteration (ISSUE 18 — the
                   cross-tier traffic signal)
     attr_admit_ms / attr_promote_ms / attr_bookkeep_ms / attr_idle_ms /
     attr_wall_ms  per-iteration goodput attribution (ISSUE 20): with
-                  prefill_ms and decode_ms these six buckets tile the
-                  step thread's mark-to-mark wall EXACTLY (bookkeeping
-                  is the remainder of the rounded siblings), feeding
-                  the STAT_gen_step_attr_* histogram family
+                  prefill_ms and decode_ms these six buckets sum to
+                  attr_wall_ms EXACTLY (bookkeeping is the remainder of
+                  the rounded siblings), feeding the
+                  STAT_gen_step_attr_* histogram family. Since ISSUE 34
+                  the wall is the sum of the stretches of the step
+                  thread's timeline charged to the iteration (every
+                  stretch is charged once, so the records' walls sum to
+                  the thread's elapsed time), and the three host
+                  buckets hold only time during which NO program was in
+                  flight: the time the chip waited for the host
     decode_wait_ms / prefill_wait_ms
                   the part of decode_ms / prefill_ms the step thread
                   spent blocked in the read-back (`np.asarray` of the
@@ -69,6 +83,10 @@ oldest overwritten — the same bounding discipline as the trace rings):
                   distinct experts that got at least one live token,
                   summed over the expert layers, and cached positions the
                   step attended, summed over the live slots
+    ahead         1 where the iteration's decode step was launched while
+                  the step before it was still unread (ISSUE 34): its
+                  tokens went from the device's output straight into
+                  this step's input, and the host's work ran under it
 
 The fit loop has a record of its own (`FitRecord`, ISSUE 25): one per
 train step into ONE process-wide `FitLog` ring of the same kind, read
@@ -145,10 +163,9 @@ _FIELDS = ("it", "step", "t", "live", "admitted", "completed", "expired",
            # decode_ms (above), attr_bookkeep_ms (host bookkeeping:
            # record/flush/slice — computed as the remainder of the
            # ROUNDED siblings, so the stored buckets sum EXACTLY to
-           # attr_wall_ms), attr_idle_ms (cv waits) — tile the step
-           # thread's mark-to-mark iteration wall. attr_wall_ms == 0
-           # marks a record from before this era (or the abort-path
-           # flush record, which never owned a full iteration)
+           # attr_wall_ms), attr_idle_ms (cv waits) — tile the
+           # iteration's wall. attr_wall_ms == 0 marks a record from
+           # before this era
            "attr_admit_ms", "attr_promote_ms", "attr_bookkeep_ms",
            "attr_idle_ms", "attr_wall_ms",
            # ISSUE 25: launch against wait inside decode_ms / prefill_ms
@@ -159,13 +176,17 @@ _FIELDS = ("it", "step", "t", "live", "admitted", "completed", "expired",
            # ISSUE 27: what a family's decode program counts on the
            # device and returns with the tokens (a family that counts
            # nothing leaves them 0) — appended, by the same era rule
-           "experts_hit", "latent_rows")
+           "experts_hit", "latent_rows",
+           # ISSUE 34: 1 where the iteration's decode step was launched
+           # while the step before it was still unread (its tokens went
+           # from the device's output straight into this step's input)
+           "ahead")
 
 _FIT_FIELDS = ("fit", "step", "t", "input_wait_ms", "prep_ms",
                "dispatch_ms", "sync_ms", "callback_ms", "other_ms",
                "wall_ms")
 _FIT_BUCKETS = _FIT_FIELDS[3:8]     # measured; other_ms is the remainder
-_FIT_RING = 4096                    # records; the engine ring's default
+_FIT_RING = 4096                    # records
 
 
 def enabled() -> bool:

@@ -57,8 +57,8 @@ from ..framework import monitor
 from ..framework.errors import InvalidArgumentError
 from ..framework.flags import flag
 
-__all__ = ["SITES", "InjectedFault", "fire", "maybe_raise", "reset",
-           "snapshot"]
+__all__ = ["SITES", "InjectedFault", "armed", "fire", "maybe_raise",
+           "reset", "snapshot"]
 
 SITES = ("decode_step_raise", "prefill_raise", "decode_poison_nan",
          "alloc_exhaust", "slow_step_ms", "kv_tier.promote_upload",
@@ -138,12 +138,7 @@ class _Registry:
         if not spec.strip():
             return None
         with self._lock:
-            if spec != self._src:
-                # re-arming does NOT reset hit counters: a one-shot
-                # spent before a flag rewrite stays spent (reset() is
-                # the explicit way to start a fresh schedule)
-                self._armed = _parse(spec)
-                self._src = spec
+            self._arm(spec)
             trig = self._armed.get(site)
             if trig is None:
                 return None
@@ -155,6 +150,25 @@ class _Registry:
             self._fired[site] = self._fired.get(site, 0) + 1
         monitor.stat_add("STAT_failpoints_fired")
         return 0.0 if arg is None else arg
+
+    def _arm(self, spec: str) -> None:
+        # (lock held) re-arming does NOT reset hit counters: a one-shot
+        # spent before a flag rewrite stays spent (reset() is the
+        # explicit way to start a fresh schedule)
+        if spec != self._src:
+            self._armed = _parse(spec)
+            self._src = spec
+
+    def armed(self, sites) -> bool:
+        """Whether the flag names any of `sites` — a spent one-shot
+        included: no hit is counted. The fast path — flag unset — is a
+        dict read + strip."""
+        spec = str(flag("FLAGS_failpoints"))
+        if not spec.strip():
+            return False
+        with self._lock:
+            self._arm(spec)
+            return any(site in self._armed for site in sites)
 
     def reset(self) -> None:
         with self._lock:
@@ -177,6 +191,14 @@ def fire(site: str) -> Optional[float]:
     """Count one hit at `site`; non-None (the trigger arg) iff this
     hit fires. Never raises on the hot path when the flag is unset."""
     return _REG.fire(site)
+
+
+def armed(*sites: str) -> bool:
+    """True while FLAGS_failpoints names one of `sites`, whether or not
+    its trigger has fired: the engine asks before it launches a decode
+    step ahead of the last one's read-back (the step sites act between
+    the two)."""
+    return _REG.armed(sites)
 
 
 def maybe_raise(site: str) -> None:

@@ -69,6 +69,22 @@ future's resolution. Stream deadlines split: `ttft_timeout_ms` is HARD
 and resolves with what was delivered — tokens already left the engine
 and cannot be retracted).
 
+**One decode step in flight (ISSUE 34)**: the step thread launches decode
+step n+1 BEFORE it reads step n. Step n's next-token output is step n+1's
+token input as a device array that no one reads in between (a request
+that joined since brings its first token from the host; the program takes
+both and a per-slot mask), positions, activity and the page table come
+from the host's own count, and the PRNG key is folded inside the program
+from the base key and the step number. Then the thread reads step n,
+delivers, completes, records and admits while the chip runs step n+1. What
+is learned a step late — an EOS, a poison flag — costs one step: the
+token step n+1 computed for that slot is dropped (never staged, never
+counted; its K/V write fell inside the request's own pages, zeroed on
+release as ever). What needs a step's tokens on the host before the next
+launch — speculation, a `_pre_step_hook`, an armed step failpoint — finds
+no step in flight: the one loop settles first, decided from the engine's
+own state (`stats()["lookahead"]`).
+
 Hardening carries over from the one-shot engine, re-expressed at token
 granularity: bounded intake (`EngineOverloaded`), worst-case page
 admission control (a request is only admitted when the allocator can
@@ -334,7 +350,9 @@ class _GenRequest:
         self.slot: Optional[int] = None
         self.pt_row = None              # np.int32 [pages_per_seq]
         self.toks: List[int] = []       # generated tokens (eos included)
-        self.next_pos = 0               # cache position the NEXT step writes
+        self.next_pos = 0               # cache position the NEXT launch
+        #                                 writes (advanced AT the launch:
+        #                                 a step in flight has its own)
         self.ordinal = 0                # engine-local submit ordinal
         self.defer_logged = set()       # audit DEFER_* causes noted once
         self.stream = stream            # TokenStream or None
@@ -491,7 +509,7 @@ def jit_program(fn, name, fmts, counters=False, with_w=True, donates=True):
     # (None: it returns no pool)
     n_in, n_out = {
         "prefill": (3, 1), "prefill_tail": (4, 1),
-        "decode": (7, 2 + bool(counters)), "verify": (8, 3),
+        "decode": (10, 2 + bool(counters)), "verify": (8, 3),
         "zero_pages": (1, 0), "cow_copy": (2, 0),
         "tier_gather": (1, None), "tier_write": (1 + len(fmts), 0)}[name]
     lead = int(with_w)
@@ -502,6 +520,61 @@ def jit_program(fn, name, fmts, counters=False, with_w=True, donates=True):
         in_shardings=(None,) * lead + fmts + (None,) * n_in,
         out_shardings=(None if n_out is None
                        else fmts + (None,) * n_out))
+
+
+def step_key(base_key, step):
+    """The PRNG key of decode step `step` (the count of decode steps
+    launched before it): `fold_in(base, step)`, folded INSIDE the decode
+    program from two arguments, so no eager fold (and its microsecond
+    programs) runs before a launch. Bit-identical to the eager
+    `jax.random.fold_in(PRNGKey(seed), step)`."""
+    import jax
+    return jax.random.fold_in(base_key, step)
+
+
+def with_step_inputs(decode):
+    """A family's decode body (serving/decode_family.py) behind the inputs
+    the step thread has when it launches a step AHEAD of the last one's
+    read-back: `prev`, the next-token output of the step before, still on
+    the device; `tok` and `fresh`, the host's token for the slots whose
+    token `prev` does not hold (a request that joined since: its first
+    token was sampled on the host from the prefill's logits) and their
+    mask; the base key and the step's number, folded here. One compiled
+    program as before, the family's body unchanged inside it:
+
+        gen_decode(W, *pools, pt, prev, tok, fresh, pos, active, temps,
+                   smask, base_key, step) -> what the family's body returns
+    """
+    def gen_decode(W, *rest):
+        import jax
+        import jax.numpy as jnp
+        *lead, prev, tok, fresh, pos, active, temps, smask, base_key, \
+            step = rest
+        with jax.named_scope("step_inputs"):
+            tok = jnp.where(fresh, tok, prev)
+            key = step_key(base_key, step)
+        return decode(W, *lead, tok, pos, active, temps, smask, key)
+    return gen_decode
+
+
+class _Flight:
+    """One decode step launched and not yet delivered: which request owned
+    each slot when it was launched (what is learned a step late — an EOS,
+    a poison flag, an expiry — is settled against THIS, not against the
+    slots as they are when the step is read), its outputs on the device,
+    and once its end has been observed the same on the host with the
+    step's own time."""
+
+    __slots__ = ("owners", "outs", "host", "ahead", "decode_ms", "wait_ms")
+
+    def __init__(self, owners, outs, ahead):
+        self.owners = owners        # per slot: the request, or None
+        self.outs = outs            # (next tokens, poison flags[, counters])
+        self.host = None            # the same as numpy, once observed
+        self.ahead = ahead          # launched with the step before unread
+        self.decode_ms = 0.0        # launch (or the end of the program
+        #                             before it) to its own observed end
+        self.wait_ms = 0.0          # of it, blocked in the read-back
 
 
 def _pool_view(i):
@@ -697,6 +770,37 @@ class GenerationEngine:
         self._pre_step_hook = None     # test seam: runs on the step thread
         self._hist = monitor.histogram(f"{name}_request_ms")
         self._base_key = None          # PRNGKey, built lazily on first use
+        # one decode step in flight (ISSUE 34): the step launched and not
+        # yet delivered; the device's next tokens of the LAST decode launch
+        # with the requests they belong to (the next launch's token input
+        # for the slots that still hold the same request); the page table
+        # and the per-slot arrays, built once and patched where a slot's
+        # request changed (`_rows`: whose row each line holds)
+        M, PP = self._cfg.max_slots, self._cfg.pages_per_seq
+        self._flight: Optional[_Flight] = None
+        self._prev = None              # (device next tokens, owners)
+        self._table = np.zeros((M, PP), np.int32)
+        self._temps = np.ones((M,), np.float32)
+        self._smask = np.zeros((M,), bool)
+        self._rows: List[Optional[_GenRequest]] = [None] * M
+        self._no_prev = np.zeros((M,), np.int32)
+        import jax
+        # the base key as the decode program takes it (it folds the step's
+        # number in: `step_key`)
+        self._key_host = np.asarray(jax.random.PRNGKey(self._cfg.seed))
+        self._step_span = f"generation::step[m={M}]"
+        self._ahead_total = 0          # steps launched ahead of a read-back
+        self._settled = {}             # steps that settled first, by reason
+        self._dropped_tokens = 0       # computed for a request that had left
+        # attribution (ISSUE 20 / 34): `_cursor` is how far the step
+        # thread's timeline has been charged to a bucket; `_unobserved`
+        # counts the timed programs launched whose end has not been
+        # observed — while it is over 0 the host's work hides under the
+        # chip's and is charged to nothing; `_phase` is the host bucket
+        # that exposed time goes to
+        self._cursor = time.perf_counter()
+        self._unobserved = 0
+        self._phase = "attr_bookkeep_ms"
         # degraded modes (ISSUE 15): detector knobs snapshotted at
         # construction (a runtime flag flip must not flip speculation
         # onto an un-warmed program); the spec-off verdict itself rides
@@ -731,17 +835,7 @@ class GenerationEngine:
         # deltas so the step ring carries per-iteration demote/promote
         # counts without a second bookkeeping path
         self._tier_counts = (0, 0)
-        self._it = {"admitted": 0, "completed": 0, "expired": 0,
-                    "poisoned": 0, "aborted": 0, "freed": 0,
-                    "prefix_tokens": 0, "cow_splits": 0,
-                    "tokens": 0, "spec_drafted": 0, "spec_accepted": 0,
-                    "prefill_chunks": 0,
-                    "prefill_ms": 0.0, "decode_ms": 0.0,
-                    "promote_ms": 0.0,
-                    "attr_idle_ms": 0.0, "attr_sched_ms": 0.0,
-                    "attr_wall_ms": 0.0, "decode_wait_ms": 0.0,
-                    "prefill_wait_ms": 0.0, "admit_wait_ms": 0.0,
-                    **dict.fromkeys(family.step_counters, 0)}
+        self._it = self._new_counts()
         # published BEFORE the step thread exists so a router polling a
         # freshly built replica reads a truthful empty-engine snapshot
         self._pressure = self._compute_pressure()
@@ -839,6 +933,10 @@ class GenerationEngine:
         self._fns = self._family.build(ProgramContext(
             self._cfg, self._tp, self._mesh, NP, self._quant_kv,
             self._decode_attention, self._W, self._ledger))
+        # the family's decode body behind the inputs of a step launched
+        # ahead of the last one's read-back (ISSUE 34): still ONE decode
+        # program, under the same name
+        self._fns["decode"] = with_step_inputs(self._fns["decode"])
 
         # the step program's AOT executable, by its ledger key
         self._execs = {}
@@ -1068,31 +1166,33 @@ class GenerationEngine:
                     jax.device_put(a, ns(a)) for a in blocks]
 
         t0 = _now_ms()
-        written = 0
-        staged = stage(0)
-        while written < n:
-            if failpoints.fire("kv_tier.promote_upload") is not None:
-                self._zero_pages(targets[:written])
-                self._tier.note_abandon()
-                self._audit.audit("KV_PROMOTE_ABANDON", rid=req.rid,
-                                  pages=n, written=written)
-                # abandoned upload time still went somewhere — charge
-                # the promote bucket (ISSUE 20 attribution)
-                self._it["promote_ms"] += _now_ms() - t0
-                return False
-            nxt = stage(written + C) if written + C < n else None
-            with RecordEvent(f"generation::tier_write[w={C}]"):
-                with self._dev_ctx():
-                    self._set_pools(self._tier_write_jit(
-                        *self._pools(), *staged))
-            written = min(written + C, n)
-            staged = nxt
-        self._tier.note_promotion(n)
-        self._audit.audit("KV_PROMOTE", rid=req.rid, pages=n,
-                          tokens=n * self._cfg.page_size,
-                          ms=round(_now_ms() - t0, 3))
-        self._it["promote_ms"] += _now_ms() - t0
-        return True
+        # the uploads' host time is the promote bucket's (ISSUE 20), an
+        # abandoned upload's too — where no program is in flight to hide it
+        was = self._enter_phase("promote_ms")
+        try:
+            written = 0
+            staged = stage(0)
+            while written < n:
+                if failpoints.fire("kv_tier.promote_upload") is not None:
+                    self._zero_pages(targets[:written])
+                    self._tier.note_abandon()
+                    self._audit.audit("KV_PROMOTE_ABANDON", rid=req.rid,
+                                      pages=n, written=written)
+                    return False
+                nxt = stage(written + C) if written + C < n else None
+                with RecordEvent(f"generation::tier_write[w={C}]"):
+                    with self._dev_ctx():
+                        self._set_pools(self._tier_write_jit(
+                            *self._pools(), *staged))
+                written = min(written + C, n)
+                staged = nxt
+            self._tier.note_promotion(n)
+            self._audit.audit("KV_PROMOTE", rid=req.rid, pages=n,
+                              tokens=n * self._cfg.page_size,
+                              ms=round(_now_ms() - t0, 3))
+            return True
+        finally:
+            self._enter_phase(was)
 
     def _warmup(self):
         """Compile every prefill bucket + the decode step (or, with
@@ -1386,24 +1486,90 @@ class GenerationEngine:
     def _num_active(self) -> int:
         return sum(1 for r in self._slots if r is not None)
 
+    def _new_counts(self) -> dict:
+        """One iteration's counters and attribution buckets, zeroed."""
+        return {"admitted": 0, "completed": 0, "expired": 0,
+                "poisoned": 0, "aborted": 0, "freed": 0,
+                "prefix_tokens": 0, "cow_splits": 0,
+                "tokens": 0, "spec_drafted": 0, "spec_accepted": 0,
+                "prefill_chunks": 0, "ahead": 0,
+                "prefill_ms": 0.0, "decode_ms": 0.0,
+                "promote_ms": 0.0,
+                "attr_idle_ms": 0.0, "attr_admit_ms": 0.0,
+                "attr_bookkeep_ms": 0.0, "decode_wait_ms": 0.0,
+                "prefill_wait_ms": 0.0, "admit_wait_ms": 0.0,
+                **dict.fromkeys(self._family.step_counters, 0)}
+
+    # -- where the step thread's time goes (ISSUE 20 / 34) -------------------
+    #
+    # Every stretch of the step thread's timeline is charged to ONE bucket
+    # by moving `_cursor` over it, so the six buckets tile the timeline
+    # exactly whatever overlaps what. While a timed program (a decode or
+    # verify step, a prefill) is launched and its end not yet observed,
+    # the chip is running and the host's own work hides under it: that
+    # stretch belongs to the program and is charged when its end is
+    # observed, from the later of (its launch, the observed end of the
+    # program before it) — so a program's time reads its device time or
+    # more, short only by the host's lag in SEEING the program before it
+    # end (a stalled step thread), which that program's time holds: over
+    # consecutive programs nothing is lost. Host buckets (admission, bookkeeping, promotion)
+    # get only the time during which NO program was in flight: the time
+    # the chip really waited for the host.
+
+    def _charge_host(self):
+        """Charge the thread's time since the cursor to the current host
+        bucket — unless a program is in flight, whose time it is."""
+        if self._unobserved:
+            return
+        now = time.perf_counter()
+        self._it[self._phase] += (now - self._cursor) * 1000.0
+        self._cursor = now
+
+    def _enter_phase(self, bucket: str) -> str:
+        """Exposed host time goes to `bucket` from here; returns the
+        bucket it went to before."""
+        self._charge_host()
+        was, self._phase = self._phase, bucket
+        return was
+
+    def _program_launched(self):
+        """A timed program is about to be launched: with nothing in flight
+        the host's time up to here was exposed, and the program's starts."""
+        self._charge_host()
+        self._unobserved += 1
+
+    def _program_ended(self) -> float:
+        """A timed program's end has just been observed: its time, in ms —
+        from its launch or the observed end of the program before it,
+        whichever is later (that is where the cursor stands)."""
+        now = time.perf_counter()
+        ms = (now - self._cursor) * 1000.0
+        self._cursor = now
+        self._unobserved -= 1
+        return ms
+
+    def _idle_wait(self, timeout: Optional[float]):
+        """Wait on the engine's condition (held) with nothing in flight:
+        the wait is the idle bucket's."""
+        self._charge_host()
+        with RecordEvent("generation::idle"):
+            self._cv.wait(timeout)
+        now = time.perf_counter()
+        self._it["attr_idle_ms"] += (now - self._cursor) * 1000.0
+        self._cursor = now
+
     def _loop(self):
-        # goodput-attribution marks (ISSUE 20): `t_mark` is the previous
-        # iteration's record boundary — wall is mark-to-mark, so the
-        # record/flush bookkeeping AFTER a record lands is charged to
-        # the NEXT iteration's bookkeeping bucket and consecutive
-        # buckets still tile the step thread's timeline exactly
-        t_mark = time.perf_counter()
-        idle_s = 0.0
+        self._cursor = time.perf_counter()
         try:
             while True:
                 with self._cv:
                     while (not self._queue and self._num_active() == 0
-                           and not self._closed):
-                        t0 = time.perf_counter()
-                        with RecordEvent("generation::idle"):
-                            self._cv.wait()
-                        idle_s += time.perf_counter() - t0
+                           and self._flight is None and not self._closed):
+                        self._idle_wait(None)
                     if self._closed and self._abort:
+                        # the step in flight is dropped, not read: nothing
+                        # of it was staged or counted
+                        self._drop_flight()
                         self._evict_all(UnavailableError(
                             f"{self.name}: engine shut down"))
                         # flush the aborted/freed counts: the ring's
@@ -1414,39 +1580,30 @@ class GenerationEngine:
                         self._flush_resolutions()
                         return
                     if (self._closed and not self._queue
-                            and self._num_active() == 0):
+                            and self._num_active() == 0
+                            and self._flight is None):
                         return
-                t0 = time.perf_counter()
+                self._enter_phase("attr_admit_ms")
                 with RecordEvent("generation::admit"):
                     self._admit()
                     self._expire_active()
                     if self._cfg.prefill_chunk:
                         self._advance_prefills()
-                sched_s = time.perf_counter() - t0
-                stepped = False
-                if any(r is not None and r.prefill_pos is None
-                       for r in self._slots):
-                    self._step()
-                    stepped = True
-                now = time.perf_counter()
-                it = self._it
-                it["attr_idle_ms"] = idle_s * 1000.0
-                it["attr_sched_ms"] = sched_s * 1000.0
-                it["attr_wall_ms"] = (now - t_mark) * 1000.0
-                t_mark, idle_s = now, 0.0
+                self._enter_phase("attr_bookkeep_ms")
+                stepped = self._step()
                 with RecordEvent("generation::record"):
                     self._record_iteration()
                     # sink before resolutions: a caller woken by
                     # result() may immediately read the JSONL — its own
                     # event must already be on disk (no lock held here)
                     self._audit.flush_sink()
-                    # with sequences decoding, the next iteration launches
-                    # a program at once and `_read_back` hands these out
-                    # while the chip runs it; otherwise nothing is certain
-                    # to follow, so they go out now
+                    # with a step in flight or sequences decoding, the
+                    # next iteration reads or launches a program at once
+                    # and `_read_back` hands these out while the chip runs;
+                    # otherwise nothing is certain to follow, so they go
+                    # out now
                     self._release_staged()
-                    if not any(r is not None and r.prefill_pos is None
-                               for r in self._slots):
+                    if self._flight is None and not self._decoding():
                         self._flush_released()
                 if not stepped:
                     with self._cv:
@@ -1454,10 +1611,7 @@ class GenerationEngine:
                                 and not self._abort):
                             # unadmittable head (page exhaustion): bounded
                             # wait so queued deadlines still expire
-                            t0 = time.perf_counter()
-                            with RecordEvent("generation::idle"):
-                                self._cv.wait(0.01)
-                            idle_s += time.perf_counter() - t0
+                            self._idle_wait(0.01)
         except BaseException as e:  # noqa: BLE001 — never hang submitters
             if self._die(e):
                 return  # supervised: the death was handed over and
@@ -1473,17 +1627,10 @@ class GenerationEngine:
         what the iteration already did. The per-iteration counter dict
         is zeroed whether or not the ring is on, so an A/B flag flip
         can't leak one arm's counts into the other."""
-        it, self._it = self._it, {
-            "admitted": 0, "completed": 0, "expired": 0, "poisoned": 0,
-            "aborted": 0, "freed": 0, "prefix_tokens": 0,
-            "cow_splits": 0, "tokens": 0, "spec_drafted": 0,
-            "spec_accepted": 0, "prefill_chunks": 0,
-            "prefill_ms": 0.0, "decode_ms": 0.0,
-            "promote_ms": 0.0,
-            "attr_idle_ms": 0.0, "attr_sched_ms": 0.0,
-            "attr_wall_ms": 0.0, "decode_wait_ms": 0.0,
-            "prefill_wait_ms": 0.0, "admit_wait_ms": 0.0,
-            **dict.fromkeys(self._family.step_counters, 0)}
+        # (what the host did since the last charge, up to this record, is
+        # this iteration's; the record's own cost is the next one's)
+        self._charge_host()
+        it, self._it = self._it, self._new_counts()
         # pressure snapshot (ISSUE 17): republished every iteration on
         # the step thread — the only thread that mutates the allocator —
         # so `pressure()` readers never need the engine lock. Runs even
@@ -1506,21 +1653,29 @@ class GenerationEngine:
             ld, lp = self._tier_counts
             tier_dem, tier_pro = d - ld, p - lp
             self._tier_counts = (d, p)
-        # goodput attribution (ISSUE 20): six buckets that reconcile
-        # EXACTLY to the iteration wall. Every stored value is rounded
-        # first and bookkeeping is the remainder OF THE ROUNDED parts,
-        # so `/steps` readers can assert the sum without fp slack from
-        # our side. The admit bucket is the scheduler-gross time minus
-        # the prefill/promote device work nested inside it; bookkeeping
-        # absorbs decode-side host work beyond the device call plus the
-        # previous iteration's record/flush tail (mark-to-mark wall).
-        a_wall = round(it["attr_wall_ms"], 3)
+        # goodput attribution (ISSUE 20 / 34): six buckets that reconcile
+        # EXACTLY to the iteration's wall, which is their sum: the
+        # stretches of the step thread's timeline charged to this
+        # iteration (`_charge_host` and its neighbours). With a step in
+        # flight an iteration's stretches are not one interval — the time
+        # of the step it READ may have begun under the iteration before —
+        # but every stretch is charged once, so the records' walls still
+        # sum to the thread's elapsed time. Every stored value is rounded
+        # first and bookkeeping is the remainder OF THE ROUNDED parts, so
+        # `/steps` readers can assert the sum without fp slack from our
+        # side. Admission and bookkeeping hold only the host time during
+        # which no program was in flight (the chip waited for the host);
+        # decode_ms / prefill_ms run from the later of (the program's
+        # launch, the observed end of the program before it) to its own
+        # observed end.
         a_idle = round(it["attr_idle_ms"], 3)
         a_prefill = round(it["prefill_ms"], 3)
         a_promote = round(it["promote_ms"], 3)
         a_decode = round(it["decode_ms"], 3)
-        a_admit = round(max(0.0, it["attr_sched_ms"]
-                            - it["prefill_ms"] - it["promote_ms"]), 3)
+        a_admit = round(it["attr_admit_ms"], 3)
+        a_wall = round(it["attr_idle_ms"] + it["prefill_ms"]
+                       + it["promote_ms"] + it["decode_ms"]
+                       + it["attr_admit_ms"] + it["attr_bookkeep_ms"], 3)
         a_book = (a_wall - a_idle - a_admit - a_prefill - a_promote
                   - a_decode)
         rec = step_log.StepRecord(
@@ -1554,6 +1709,9 @@ class GenerationEngine:
             prefill_wait_ms=min(a_prefill,
                                 round(it["prefill_wait_ms"], 3)),
             admit_wait_ms=round(it["admit_wait_ms"], 3),
+            # 1 where this iteration's decode step was launched with the
+            # step before it still unread (ISSUE 34)
+            ahead=it["ahead"],
             # what the family's decode program counted on the device
             **{name: it[name] for name in self._family.step_counters})
         self._step_log.record(rec)
@@ -1624,6 +1782,11 @@ class GenerationEngine:
         self._flush_released()
 
     def _die(self, e: BaseException):
+        # a step in flight is dropped, not read (the pools it was launched
+        # on may be what failed): none of its tokens was staged or
+        # counted, so the manifest's `toks` are exactly what was delivered
+        # and a replay derives the dropped token again — once
+        self._drop_flight()
         # two INDEPENDENT try blocks: a ring-record failure on a
         # half-broken engine must not also strand the staged
         # resolutions (they carry real results/errors already decided)
@@ -1979,12 +2142,19 @@ class GenerationEngine:
             live.append(req)
         self._queue = live
 
-    def _read_back(self, bucket: str, *outs):
-        """The blocking read of a program's host outputs — the step
-        thread waits for the chip here and nowhere else — timed into
-        this iteration's `bucket` (`decode_wait_ms` / `prefill_wait_ms`:
-        sub-splits of decode_ms / prefill_ms; what is left of those is
-        launch and argument upload)."""
+    def _read_back(self, kind: str, *outs):
+        """The blocking read of a timed program's host outputs (`kind`:
+        "prefill", or "decode" for the verify step) — with `_observe`,
+        which reads the decode step in flight, the only places the step
+        thread waits for the chip. The blocked time goes to this
+        iteration's `<kind>_wait_ms`, the program's own time (`_program_
+        ended`) to `<kind>_ms`, of which the wait is a sub-split. A
+        program launched BEHIND a decode step in flight ends after it: that
+        step's end is observed first, so each gets its own time and no
+        stretch is counted twice."""
+        fl = self._flight
+        if fl is not None and fl.host is None:
+            self._observe(fl)
         t0 = _now_ms()
         # the program is launched and the chip busy: the tokens and
         # outcomes the LAST iteration staged (its record has landed) wake
@@ -1992,8 +2162,28 @@ class GenerationEngine:
         # launches where the chip would wait for the wake-ups
         self._flush_released()
         host = [np.asarray(o) for o in outs]
-        self._it[bucket] += _now_ms() - t0
+        self._it[f"{kind}_wait_ms"] += _now_ms() - t0
+        self._it[f"{kind}_ms"] += self._program_ended()
         return host if len(host) > 1 else host[0]
+
+    def _observe(self, fl: _Flight):
+        """Block until the decode step in flight has ended and take its
+        outputs to the host; it is delivered by `_settle`. Its own time
+        and wait ride on the flight, for the record of the iteration that
+        delivers it."""
+        t0 = _now_ms()
+        self._flush_released()      # as in `_read_back`: the chip is busy
+        with RecordEvent("generation::read"):
+            fl.host = [np.asarray(o) for o in fl.outs]
+        fl.wait_ms = _now_ms() - t0
+        fl.decode_ms = self._program_ended()
+
+    def _drop_flight(self):
+        """Forget the step in flight unread (abort, death): nothing of it
+        was staged or counted."""
+        fl, self._flight = self._flight, None
+        if fl is not None and fl.host is None:
+            self._unobserved -= 1
 
     def _bucket_for(self, S: int) -> int:
         for b in self._cfg.prefill_buckets:
@@ -2019,43 +2209,48 @@ class GenerationEngine:
         S = int(req.prompt.size)
         pfx = req.prefix_tokens
         tail = S - pfx
-        t0 = _now_ms()
+        # (with a decode step in flight the prefill is launched behind it:
+        # the chip goes from the step straight into the prefill)
         if pfx:
             bucket = self._bucket_for(tail)
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :tail] = req.prompt[pfx:]
             with RecordEvent(f"generation::prefill_tail[b={bucket}]"):
+                self._program_launched()
                 with self._dev_ctx():
                     out = self._tail_jit(
                         self._W, *self._pools(), req.pt_row, ids,
                         np.int32(tail), np.int32(pfx))
                 self._set_pools(out[:-1])
-                lg = self._read_back("prefill_wait_ms", out[-1])
+                lg = self._read_back("prefill", out[-1])
         else:
             bucket = self._bucket_for(S)
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :S] = req.prompt
             with RecordEvent(f"generation::prefill[b={bucket}]"):
+                self._program_launched()
                 with self._dev_ctx():
                     out = self._prefill_jit(
                         self._W, *self._pools(), req.pt_row, ids,
                         np.int32(S))
                 self._set_pools(out[:-1])
-                lg = self._read_back("prefill_wait_ms", out[-1])
-        self._it["prefill_ms"] += _now_ms() - t0
+                lg = self._read_back("prefill", out[-1])
         if not np.all(np.isfinite(lg)):
             self._poison_prefill(req, bucket)
             return
         self._finish_prefill(req, lg, digests)
 
-    def _inject_poison(self, bad: np.ndarray) -> np.ndarray:
+    def _inject_poison(self, bad: np.ndarray, owners=None) -> np.ndarray:
         """`decode_poison_nan` failpoint: mark the first live slot's
         logits non-finite host-side — the exact verdict the decode
         program's in-graph isfinite check would have returned, so the
-        whole poison-isolation path downstream is exercised unchanged."""
+        whole poison-isolation path downstream is exercised unchanged.
+        `owners`: the step's own (a slot it ran whose request is still
+        there); without them, the slots as they are."""
         bad = np.array(bad, copy=True)
         for i, r in enumerate(self._slots):
-            if r is not None and r.prefill_pos is None:
+            if (r is not None and r.prefill_pos is None
+                    and (owners is None or owners[i] is r)):
                 bad[i] = True
                 break
         return bad
@@ -2205,15 +2400,14 @@ class GenerationEngine:
         bucket = self._bucket_for(take)
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :take] = req.prompt[req.prefill_pos:req.prefill_pos + take]
-        t0 = _now_ms()
         with RecordEvent(f"generation::prefill_chunk[b={bucket}]"):
+            self._program_launched()
             with self._dev_ctx():
                 out = self._tail_jit(
                     self._W, *self._pools(), req.pt_row, ids,
                     np.int32(take), np.int32(req.prefill_pos))
             self._set_pools(out[:-1])
-            lg = self._read_back("prefill_wait_ms", out[-1])
-        self._it["prefill_ms"] += _now_ms() - t0
+            lg = self._read_back("prefill", out[-1])
         self._it["prefill_chunks"] += 1
         self._chunks_total += 1
         monitor.stat_add("STAT_gen_prefill_chunks")
@@ -2249,24 +2443,62 @@ class GenerationEngine:
     # -- decode step -------------------------------------------------------
 
     def _step_arrays(self):
-        M, PP = self._cfg.max_slots, self._cfg.pages_per_seq
-        toks = np.zeros((M,), np.int32)
+        """The decode program's arguments after the pools, for the slots
+        as they are (construction, warm-up, `.lower()` in tests)."""
+        return self._step_inputs(None)[0]
+
+    def _step_inputs(self, flight: Optional[_Flight]):
+        """The arguments of the decode step about to be launched, from the
+        host's own count, and who owns each slot it runs. `flight`: the
+        step launched before it and still unread, if any.
+
+        Admission reserved each request's pages for prompt + max_new and
+        `pt_row` is fixed from then on, `next_pos` advances by one a
+        launch, and a request that ends by max_new ends at a step the host
+        knows before it launches it — so no slot runs a step it does not
+        need, except the one step after an EOS the host has not read yet.
+        The table and the per-slot arrays are patched where a slot's
+        request changed, not rebuilt: an inactive slot's row is zero (its
+        write lands in the reserved scratch page)."""
+        M = self._cfg.max_slots
+        tok = np.zeros((M,), np.int32)
+        fresh = np.ones((M,), bool)
         pos = np.zeros((M,), np.int32)
         active = np.zeros((M,), bool)
-        temps = np.ones((M,), np.float32)
-        smask = np.zeros((M,), bool)
-        pt = np.zeros((M, PP), np.int32)
+        owners: List[Optional[_GenRequest]] = [None] * M
+        held = self._prev[1] if self._prev is not None else None
+        rows = self._rows
         for i, req in enumerate(self._slots):
-            if req is None or req.prefill_pos is not None:
-                continue  # empty, or still chunk-prefilling (no toks)
+            if req is not None and (
+                    req.prefill_pos is not None     # still chunk-prefilling
+                    or (len(req.toks)
+                        + (flight is not None and flight.owners[i] is req)
+                        >= req.max_new)):           # ends at the step in
+                req = None                          # flight: max_new
+            if req is not rows[i]:
+                rows[i] = req
+                if req is None:
+                    self._table[i] = 0
+                    self._temps[i], self._smask[i] = 1.0, False
+                else:
+                    self._table[i] = req.pt_row
+                    self._temps[i] = req.temperature
+                    self._smask[i] = req.do_sample
+            if req is None:
+                continue
+            owners[i] = req
             active[i] = True
-            toks[i] = req.toks[-1]
             pos[i] = req.next_pos
-            temps[i] = req.temperature
-            smask[i] = req.do_sample
-            pt[i] = req.pt_row
-        key = self._step_key()
-        return pt, toks, pos, active, temps, smask, key
+            if held is not None and held[i] is req:
+                fresh[i] = False    # its token is the device's own output
+            else:
+                tok[i] = req.toks[-1]
+        prev = self._prev[0] if self._prev is not None else self._no_prev
+        # (copies: the program's arguments must not change under a launch
+        # that has not taken them yet)
+        return (self._table.copy(), prev, tok, fresh, pos, active,
+                self._temps.copy(), self._smask.copy(), self._key_host,
+                np.int32(self._steps_total)), owners
 
     def _spec_arrays(self):
         """Verify-step inputs (ISSUE 14): per-slot [current token + k
@@ -2316,12 +2548,63 @@ class GenerationEngine:
             self._base_key = jax.random.PRNGKey(self._cfg.seed)
         return jax.random.fold_in(self._base_key, self._steps_total)
 
-    def _step(self):
-        """ONE engine step: every live sequence advances one token
-        through the single compiled decode program (inactive slots are
-        masked into the reserved scratch page) — or, with speculation
-        on, 1 to k+1 tokens through the single compiled verify program.
-        The np.asarray below is the step's only host sync."""
+    def _decoding(self) -> bool:
+        """Some slot holds a sequence past its prefill."""
+        return any(r is not None and r.prefill_pos is None
+                   for r in self._slots)
+
+    def _settles_first(self) -> Optional[str]:
+        """Why the next decode step may NOT be launched ahead of the last
+        one's read-back — what needs a step's tokens on the host before
+        the next launch — or None. Decided from the engine's own state:
+        speculation (the proposer reads the token history), a
+        `_pre_step_hook` (it may look at, or wait for, what was
+        delivered), an armed step failpoint (serving/failpoints.py:
+        they act between a read-back and the next launch)."""
+        if self._spec_k and not self._degraded_spec_off:
+            return "speculation"
+        if self._pre_step_hook is not None:
+            return "pre_step_hook"
+        if failpoints.armed("slow_step_ms", "decode_step_raise",
+                            "decode_poison_nan"):
+            return "failpoint"
+        return None
+
+    def _step(self) -> bool:
+        """The decode half of one iteration of the ONE loop, with at most
+        one step in flight; returns whether anything ran.
+
+        With step n in flight: launch step n+1 from what the device holds
+        (`_launch`), THEN read step n and deliver it (`_settle`) while the
+        chip runs n+1 — unless something needs n's tokens on the host
+        first (`_settles_first`), in which case n is read and nothing is
+        launched. With none in flight (the degenerate case: the first
+        step after an idle engine, every step of an engine that settles
+        first): the hook and the failpoints as ever, then a speculative
+        step, or a launch — read at once where a reason to settle
+        stands, left in flight otherwise. Every live sequence advances
+        one token a step through the single compiled decode program
+        (inactive slots are masked into the reserved scratch page), or 1
+        to k+1 through the single compiled verify program.
+
+        Output is the same work as a loop that reads every step before
+        the next: greedy streams are token-identical; step k still draws
+        its samples from `fold_in(base, k)`, so two engines of one seed
+        that see the same arrivals sample identical streams. Against
+        that loop a request joins one step number later (it is admitted
+        while the next step is already launched), and a step that ran for
+        an EOS overshoot alone, with no other slot live, takes a step
+        number that loop would not have spent: sampled streams equal its
+        streams only where neither happened."""
+        fl = self._flight
+        if fl is not None:
+            why = self._settles_first()
+            if why is None:
+                self._launch(fl)
+            self._settle(fl)
+            return True
+        if not self._decoding():
+            return False
         if self._pre_step_hook is not None:
             self._flush_released()      # a hook may wait for a reader
             self._pre_step_hook(self)
@@ -2334,31 +2617,77 @@ class GenerationEngine:
             self._flush_released()
             time.sleep(ms / 1000.0)
         failpoints.maybe_raise("decode_step_raise")
-        if self._spec_k and not self._degraded_spec_off:
+        why = self._settles_first()
+        if why is not None:
+            self._settled[why] = self._settled.get(why, 0) + 1
+        if why == "speculation":
             self._spec_step()
-            return
+            return True
+        fl = self._launch(None)
+        if fl is not None and why is not None:
+            self._settle(fl)
+        return True
+
+    def _launch(self, flight: Optional[_Flight]) -> Optional[_Flight]:
+        """Launch one decode step and leave it in flight. `flight`: the
+        step before it, still unread — this one is then launched AHEAD,
+        its token input the device's own output of that step. Nothing is
+        launched (None) where no slot has a step to run: every sequence
+        ends at the step in flight.
+
+        An exception out of the launched program is engine-fatal: the
+        pools were donated into it (the contract of every program)."""
         with RecordEvent("generation::prepare"):
-            args = self._step_arrays()
-        t0 = _now_ms()
-        with RecordEvent(f"generation::step[m={self._cfg.max_slots}]"):
+            args, owners = self._step_inputs(flight)
+        if not any(r is not None for r in owners):
+            return None
+        self._program_launched()
+        with RecordEvent(self._step_span):
             out = self._decode_call(self._W, *self._pools(), *args)
-            NP = self._npool
-            # (*pools, next tokens, poison flags[, the family's counters])
-            nxt, bad, *counted = self._read_back("decode_wait_ms",
-                                                 *out[NP:])
-        if failpoints.fire("decode_poison_nan") is not None:
-            bad = self._inject_poison(bad)
+        NP = self._npool
+        # (*pools, next tokens, poison flags[, the family's counters])
         self._set_pools(out[:NP])
-        for name, n in zip(self._family.step_counters,
-                           counted[0] if counted else ()):
-            self._it[name] += int(n)
-        self._it["decode_ms"] += _now_ms() - t0
+        for req in owners:
+            if req is not None:
+                req.next_pos += 1
+        self._prev = (out[NP], owners)
+        self._flight = fl = _Flight(owners, out[NP:], flight is not None)
         self._steps_total += 1
         monitor.stat_add("STAT_gen_steps")
+        if fl.ahead:
+            self._ahead_total += 1
+            monitor.stat_add("STAT_gen_steps_ahead")
+        return fl
+
+    def _settle(self, fl: _Flight):
+        """Read a launched step (`_observe`, unless a read-back behind it
+        already did) and deliver it: tokens staged, sequences completed,
+        its counters and its time into this iteration's record. A slot
+        whose request has left since the launch — it ended by EOS or was
+        poisoned at the step before (learned a step late), expired, was
+        evicted — computed a token nobody takes: dropped, never staged,
+        never counted."""
+        if fl.host is None:
+            self._observe(fl)
+        if self._flight is fl:
+            self._flight = None
+        nxt, bad, *counted = fl.host
+        if failpoints.fire("decode_poison_nan") is not None:
+            bad = self._inject_poison(bad, fl.owners)
+        it = self._it
+        for name, n in zip(self._family.step_counters,
+                           counted[0] if counted else ()):
+            it[name] += int(n)
+        it["decode_ms"] += fl.decode_ms
+        it["decode_wait_ms"] += fl.wait_ms
+        it["ahead"] += fl.ahead
         with RecordEvent("generation::deliver"):
-            for i, req in enumerate(self._slots):
-                if req is None or req.prefill_pos is not None:
-                    continue  # empty, or chunk-prefilling (masked this step)
+            for i, req in enumerate(fl.owners):
+                if req is None:
+                    continue
+                if self._slots[i] is not req:
+                    self._dropped_tokens += 1
+                    continue
                 if bad[i]:
                     # poison isolation: only THIS sequence fails; its pages
                     # are zeroed before reuse so the NaN cannot reach the
@@ -2367,10 +2696,9 @@ class GenerationEngine:
                     continue
                 tok = int(nxt[i])
                 req.toks.append(tok)
-                req.next_pos += 1
                 self._tokens_total += 1
                 monitor.stat_add("STAT_gen_tokens")
-                self._it["tokens"] += 1
+                it["tokens"] += 1
                 self._stage_token(req, tok)
                 if req.span is not None:
                     req.span.stamp("last_token")
@@ -2390,15 +2718,17 @@ class GenerationEngine:
         fewer weight streams."""
         with RecordEvent("generation::prepare"):
             args, drafted = self._spec_arrays()
-        t0 = _now_ms()
+        # (never with a step in flight: `_settles_first`; the device's
+        # next tokens of an earlier decode launch are stale after this)
+        self._prev = None
+        self._program_launched()
         with RecordEvent(f"generation::verify[k={self._spec_k}]"):
             out = self._verify_call(self._W, *self._pools(), *args)
-            n_acc, nxt, bad = self._read_back("decode_wait_ms", out[-3],
+            n_acc, nxt, bad = self._read_back("decode", out[-3],
                                               out[-2], out[-1])
         if failpoints.fire("decode_poison_nan") is not None:
             bad = self._inject_poison(bad)
         self._set_pools(out[:-3])
-        self._it["decode_ms"] += _now_ms() - t0
         self._steps_total += 1
         monitor.stat_add("STAT_gen_steps")
         if drafted:
@@ -2530,9 +2860,12 @@ class GenerationEngine:
             # walks the chain end-to-end. Only pages fully covered by
             # WRITTEN positions qualify: the final token's K/V is never
             # written (it was sampled, not stepped), so the chain stops
-            # at next_pos — registering past it would serve zeros
+            # at `written` — registering past it would serve zeros
+            # (counted from the tokens delivered, not from `next_pos`:
+            # after an EOS learned a step late that has moved once more)
+            written = int(req.prompt.size) + len(req.toks) - 1
             self._register_pages(
-                req, self._prefix.digests(out)[:req.next_pos
+                req, self._prefix.digests(out)[:written
                                                // self._cfg.page_size])
         self._release(req)
         t_done = _now_ms()
@@ -2665,6 +2998,17 @@ class GenerationEngine:
                     decode_tokens / max(1, steps), 4),
             },
             "prefill_chunks": self._chunks_total,
+            # one decode step in flight (ISSUE 34): decode steps launched
+            # while the step before was still unread (`steps` has them
+            # all), steps that settled first by what needed their tokens
+            # on the host ("speculation", "pre_step_hook", "failpoint"),
+            # and tokens the chip computed for a request that had left by
+            # the time they were read (the step after an EOS or a poison
+            # flag learned a step late, an expiry, an eviction): dropped,
+            # never staged or counted
+            "lookahead": {"ahead": self._ahead_total,
+                          "settled": dict(self._settled),
+                          "dropped_tokens": self._dropped_tokens},
             # fault tolerance (ISSUE 15): which engine generation this
             # is, and whether a degraded mode is active
             "incarnation": self.incarnation,

@@ -268,6 +268,65 @@ def test_stream_exactly_once_across_restart(model):
         sup.shutdown()
 
 
+def test_death_with_a_step_in_flight_replays_each_token_once(model):
+    """The engine dies (a prefill fault: not a step failpoint, so decode
+    steps are launched ahead all along) while a decode step is launched
+    and unread (ISSUE 34). That step is dropped, not read: nothing of it
+    was staged or counted, so the manifest holds exactly what was
+    delivered, the replay derives the dropped token again, and every
+    stream still delivers each token once; every submitter gets one
+    outcome and the ring's sums reconcile over both incarnations."""
+    prompts = _prompts(3, seed=13)
+    # the third waits for a slot: it is admitted when the short second
+    # ends, and its prefill (the third) kills the engine under the long
+    # first one's step in flight
+    lengths = [100, 8, 8]
+    with _eng(model, name="resurrect_fref", max_new_tokens=100) as eng:
+        ref = [eng.submit(p, max_new_tokens=n).result()
+               for p, n in zip(prompts, lengths)]
+    k0 = monitor.stat_get("STAT_gen_tokens")
+    c0 = monitor.stat_get("STAT_gen_completions")
+    at_death = []
+    with flags(FLAGS_failpoints="prefill_raise@3",
+               FLAGS_gen_restart_backoff_ms=5.0):
+        sup = _sup(model, name="resurrect_f", max_new_tokens=100)
+        first = sup.engine
+        real_die = first._die
+
+        def die(e):     # what the dying engine held when it died
+            at_death.append(first._flight is not None)
+            return real_die(e)
+        first._die = die
+        streams = [sup.submit_stream(p, max_new_tokens=n)
+                   for p, n in zip(prompts, lengths)]
+        collected = [[] for _ in prompts]
+
+        def drain(i):
+            for tok in streams[i]:
+                collected[i].append(tok)
+
+        ts = [threading.Thread(target=drain, args=(i,), daemon=True)
+              for i in range(len(streams))]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(60)
+        assert sup.restarts == 1
+        assert at_death == [True], "the death did not find a step in flight"
+        for i, st in enumerate(streams):
+            out = st.result(timeout=30)
+            assert collected[i] == out[len(prompts[i]):].tolist()
+            assert np.array_equal(out, ref[i])
+        recs = step_log.steps_payload()["engines"]["resurrect_f"]["records"]
+        assert {r["incarnation"] for r in recs} == {0, 1}
+        assert sum(r["completed"] for r in recs) == \
+            monitor.stat_get("STAT_gen_completions") - c0 == 3
+        assert sum(r["tokens"] for r in recs) == \
+            monitor.stat_get("STAT_gen_tokens") - k0
+        assert sup.stats()["pages"]["pages_in_use"] == 0
+        sup.shutdown()
+
+
 def test_prefill_fault_restart(model):
     prompts = _prompts(2, seed=9)
     with _eng(model, name="resurrect_pref") as eng:

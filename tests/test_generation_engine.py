@@ -528,3 +528,265 @@ def test_gc_freeze_keeps_the_warmed_process_out_of_full_collections(model):
         assert out.shape[0] == 7 + 5
         assert gc.get_freeze_count() > base + 10_000   # still frozen
     assert gc.get_freeze_count() == 0
+
+
+# -- one decode step in flight ahead of the host (ISSUE 34) -------------------
+
+def _wait_until(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if cond():
+            return True
+        time.sleep(0.005)
+    return cond()
+
+
+def _alone(model, prompt, n):
+    """The tokens `generate()` gives this prompt alone."""
+    return model.generate(paddle.to_tensor(prompt[None]),
+                          max_new_tokens=n).numpy()[0]
+
+
+def test_a_mixed_batch_ahead_of_the_host_is_token_identical(model):
+    """Different prompt and output lengths, six requests through two slots
+    (so slots are reused by queued requests), one request admitted while
+    the others decode: each gets the tokens it gets alone, with decode
+    steps launched ahead of the last one's read-back all the while."""
+    rng = np.random.RandomState(7)
+    shapes = [(3, 9), (7, 4), (5, 12), (8, 6), (2, 10), (6, 3)]
+    prompts = [rng.randint(0, 512, size=(S,)).astype("int64")
+               for S, _ in shapes]
+    refs = [_alone(model, p, n) for p, (_, n) in zip(prompts, shapes)]
+    late_p = rng.randint(0, 512, size=(4,)).astype("int64")
+    late_ref = _alone(model, late_p, 7)
+    with _engine(model, prefill_buckets=(4, 8), max_new_tokens=12) as eng:
+        first = eng.submit_stream(prompts[0], max_new_tokens=shapes[0][1])
+        futs = [eng.submit(p, max_new_tokens=n)
+                for p, (_, n) in zip(prompts[1:], shapes[1:])]
+        next(iter(first))               # decoding has begun
+        late = eng.submit(late_p, max_new_tokens=7)     # mid-run
+        outs = [first.result(timeout=120)] + [f.result(timeout=120)
+                                              for f in futs]
+        late_out = late.result(timeout=120)
+        s = eng.stats()
+    for out, ref in zip(outs + [late_out], refs + [late_ref]):
+        np.testing.assert_array_equal(out, ref)
+    look = s["lookahead"]
+    assert look["ahead"] > 0 and look["settled"] == {}
+    assert look["dropped_tokens"] == 0          # no EOS, nobody evicted
+    assert look["ahead"] <= s["steps"]
+    assert s["tokens"] == sum(n for _, n in shapes) + 7
+    assert s["compiles"] == {"prefill[b=4]": 1, "prefill[b=8]": 1,
+                             "decode[m=2]": 1}
+    assert s["pages"]["pages_in_use"] == 0
+
+
+def test_an_eos_with_the_next_step_in_flight_drops_exactly_that_token(model):
+    """A request that stops on EOS at step n has run step n+1 as well (it
+    was launched before n was read): that token is never streamed nor
+    counted, `dropped_tokens` says 1, its pages are zeroed behind the
+    overshoot's write, and the next owner of the slot and the pages
+    decodes its own reference tokens."""
+    ids = _prompts(8, seed=1)
+    # a prompt whose greedy stream first shows some token at its 3rd to
+    # 8th place: that token is the EOS, `stop` decode steps in
+    found = [(p, gen, k) for p in ids[:-1]
+             for gen in [_alone(model, p, 10)[7:]] for k in range(2, 8)
+             if int(gen[k]) not in gen[:k].tolist()]
+    assert found, "no prompt's stream has a late first occurrence"
+    prompt, gen, stop = found[0]
+    eos = int(gen[stop])
+    ids = [prompt, ids[-1]]
+    ref_next = _alone(model, ids[1], 12)
+    with _engine(model, max_new_tokens=12) as eng:
+        t0 = eng.stats()["tokens"]
+        s = eng.submit_stream(ids[0], max_new_tokens=10, eos_token_id=eos)
+        streamed = list(s)
+        out = s.result(timeout=120)
+        assert _wait_until(
+            lambda: eng.stats()["lookahead"]["dropped_tokens"] == 1)
+        st = eng.stats()
+        # EOS included, nothing after it: not in the stream, the result
+        # or the counters
+        assert streamed == gen[:stop + 1].tolist() == out[7:].tolist()
+        assert st["tokens"] - t0 == stop + 1
+        # steps launched: one a decoded token (stop of them, the first
+        # token is the prefill's) and the one overshoot
+        assert st["steps"] == stop + 1
+        assert st["pages"]["pages_in_use"] == 0
+        assert _wait_until(lambda: eng._flight is None)
+        for pool in eng._pools():       # the overshoot's K/V went too
+            assert float(np.abs(np.asarray(pool)).max()) == 0.0
+        out_next = eng.generate(ids[1], max_new_tokens=12)
+        assert eng.stats()["lookahead"]["dropped_tokens"] == 1
+    np.testing.assert_array_equal(out_next, ref_next)
+
+
+def test_a_poison_failpoint_fails_its_request_alone_and_settles_first(model):
+    """`decode_poison_nan` armed: the engine reads every step before the
+    next launch (`settled` counts them under "failpoint", none is ahead),
+    the poisoned request alone fails, and the engine goes on — ahead again
+    once the flag is cleared."""
+    from paddle_tpu.serving import failpoints
+    ids = _prompts(3, seed=2)
+    refs = [_alone(model, p, 8) for p in ids]
+    failpoints.reset()
+    paddle.set_flags({"FLAGS_failpoints": "decode_poison_nan@2"})
+    try:
+        with _engine(model, max_new_tokens=8) as eng:
+            fa = eng.submit(ids[0], max_new_tokens=8)
+            fb = eng.submit(ids[1], max_new_tokens=8)
+            outcomes = []
+            for f in (fa, fb):
+                try:
+                    outcomes.append(f.result(timeout=120))
+                except FatalError as e:
+                    outcomes.append(e)
+            armed = eng.stats()
+            paddle.set_flags({"FLAGS_failpoints": ""})
+            out_c = eng.generate(ids[2], max_new_tokens=8)
+            after = eng.stats()
+    finally:
+        paddle.set_flags({"FLAGS_failpoints": ""})
+        failpoints.reset()
+    failed = [o for o in outcomes if isinstance(o, FatalError)]
+    assert len(failed) == 1
+    for o, ref in zip(outcomes, refs):
+        if not isinstance(o, FatalError):
+            np.testing.assert_array_equal(o, ref)
+    np.testing.assert_array_equal(out_c, refs[2])
+    assert armed["lookahead"]["ahead"] == 0
+    assert armed["lookahead"]["settled"] == {"failpoint": armed["steps"]}
+    assert after["lookahead"]["ahead"] > 0
+    assert after["pages"]["pages_in_use"] == 0
+
+
+@pytest.mark.parametrize("reason", ["speculation", "pre_step_hook",
+                                    "failpoint"])
+def test_what_needs_the_tokens_on_the_host_finds_no_step_in_flight(
+        model, reason):
+    """Speculation (the proposer reads the history), a `_pre_step_hook`,
+    an armed `slow_step_ms`: every step is read before the next launch,
+    decided from the engine's own state, and the tokens are the same."""
+    from paddle_tpu.serving import failpoints
+    ids = _prompts(3, seed=4)
+    refs = [_alone(model, p, 6) for p in ids]
+    seen = []
+    failpoints.reset()
+    if reason == "failpoint":
+        paddle.set_flags({"FLAGS_failpoints": "slow_step_ms@every:2:1"})
+    try:
+        kw = {"spec_k": 2} if reason == "speculation" else {}
+        with _engine(model, max_new_tokens=6, **kw) as eng:
+            if reason == "pre_step_hook":
+                eng._pre_step_hook = lambda e: seen.append(
+                    e._flight is None)
+            outs = [f.result(timeout=120) for f in
+                    [eng.submit(p, max_new_tokens=6) for p in ids]]
+            s = eng.stats()
+    finally:
+        paddle.set_flags({"FLAGS_failpoints": ""})
+        failpoints.reset()
+    for out, ref in zip(outs, refs):
+        np.testing.assert_array_equal(out, ref)
+    look = s["lookahead"]
+    assert look["ahead"] == 0 and look["dropped_tokens"] == 0
+    assert look["settled"] == {reason: s["steps"]} and s["steps"] > 0
+    if reason == "pre_step_hook":
+        assert seen and all(seen)       # never a step in flight at a hook
+
+
+def test_the_key_folded_inside_the_program_is_the_eager_fold(model):
+    """Step k draws from `fold_in(PRNGKey(seed), k)`, folded inside the
+    decode program from the base key and the step's number: bit-identical
+    to the eager fold it replaces, so two engines of one seed that see
+    the same arrivals sample identical streams."""
+    import jax
+    from paddle_tpu.serving.generation import step_key
+    for seed in (0, 42, 2 ** 31 - 1):
+        base = jax.random.PRNGKey(seed)
+        folded = jax.jit(step_key)
+        for k in (0, 1, 7, 4095, 2 ** 20):
+            np.testing.assert_array_equal(
+                np.asarray(folded(np.asarray(base), np.int32(k))),
+                np.asarray(jax.random.fold_in(base, k)))
+    ids = _prompts(3, seed=6)
+
+    def run():
+        with _engine(model, seed=42, max_new_tokens=9) as eng:
+            outs = [eng.generate(p, max_new_tokens=9, do_sample=True,
+                                 temperature=0.9) for p in ids]
+            assert eng.stats()["lookahead"]["ahead"] > 0
+            return outs
+    a, b = run(), run()
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x, y)
+    greedy = [_alone(model, p, 9) for p in ids]
+    assert any(not np.array_equal(x, g) for x, g in zip(a, greedy))
+
+
+def test_a_steps_time_is_never_under_its_programs_and_the_buckets_tile(
+        model):
+    """With the decode program made slow on the device's side (its tokens
+    come ready 15 ms after the launch, the launch itself returns at once),
+    5 ms of host work an iteration and steps launched ahead: `decode_ms`
+    reads that device time — in the median and in the sum: a single record
+    may read short by the host's lag in seeing the step BEFORE end (a
+    stalled thread on a loaded machine), which that step's record holds —
+    where timing only the launch and the blocked wait would read 10 ms; the
+    host's 5 ms hide
+    under it instead of landing in the bookkeeping bucket, `decode_wait_ms`
+    is a part of it, and the six buckets sum to the wall exactly."""
+    import jax
+    from paddle_tpu.profiler import step_log
+    SLOW, HOST = 0.015, 0.005
+
+    def nap(x):
+        time.sleep(SLOW)
+        return x
+
+    slow = jax.jit(lambda x: jax.pure_callback(
+        nap, jax.ShapeDtypeStruct(x.shape, x.dtype), x))
+    ids = _prompts(4, seed=8)
+    with _engine(model, name="ahead_attr", max_new_tokens=8) as eng:
+        real, NP = eng._decode_call, eng._npool
+
+        def slowed(*args):
+            out = real(*args)
+            return (*out[:NP], slow(out[NP]), *out[NP + 1:])
+        eng._decode_call = slowed
+        record = eng._record_iteration
+
+        def busy_host():
+            time.sleep(HOST)
+            record()
+        eng._record_iteration = busy_host
+        futs = [eng.submit(p, max_new_tokens=8) for p in ids]
+        for f in futs:
+            f.result(timeout=120)
+        s = eng.stats()
+        recs = step_log.steps_payload()["engines"]["ahead_attr"]["records"]
+    assert s["lookahead"]["ahead"] > 0
+    stepped = [r for r in recs if r["decode_ms"] > 0]
+    assert len(stepped) == s["steps"]
+    assert sum(r["ahead"] for r in recs) == s["lookahead"]["ahead"]
+    times = sorted(r["decode_ms"] for r in stepped)
+    assert times[len(times) // 2] >= SLOW * 1e3 - 1.0, times
+    assert sum(times) >= SLOW * 1e3 * len(times), times
+    # the host's sleep ran under a step in flight wherever one was
+    assert (sum(r["attr_bookkeep_ms"] for r in recs)
+            < 0.5 * HOST * 1e3 * len(recs))
+    for r in recs:
+        assert 0 <= r["decode_wait_ms"] <= r["decode_ms"], r
+        assert 0 <= r["prefill_wait_ms"] <= r["prefill_ms"], r
+        assert r["attr_admit_ms"] >= 0 and r["attr_bookkeep_ms"] > -0.01, r
+        total = (r["attr_admit_ms"] + r["prefill_ms"]
+                 + r["attr_promote_ms"] + r["decode_ms"]
+                 + r["attr_bookkeep_ms"] + r["attr_idle_ms"])
+        assert abs(total - r["attr_wall_ms"]) < 1e-9, r
+    # the host's work hides under the chip's: the steps' own time is most
+    # of the walls of the iterations that read one
+    assert (sum(r["decode_ms"] for r in stepped)
+            > 0.8 * sum(r["attr_wall_ms"] - r["prefill_ms"]
+                        for r in stepped))
+    assert sum(r["tokens"] for r in recs) == s["tokens"]

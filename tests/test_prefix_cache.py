@@ -297,8 +297,11 @@ def test_stream_ttft_deadline_hard_while_blocked(model):
                  max_new_tokens=100, prefill_buckets=(16,),
                  name="pfx_ttft") as eng:
         fa = eng.submit(prompts[0], max_new_tokens=100)
+        # (5 ms: with decode steps launched ahead of the host, ISSUE 34,
+        # the tiny model's 100 tokens take some 50 ms on the CPU, and the
+        # stream must expire while the first request still holds the slot)
         stream = eng.submit_stream(prompts[1], max_new_tokens=5,
-                                   ttft_timeout_ms=50)
+                                   ttft_timeout_ms=5)
         with pytest.raises(ExecutionTimeoutError):
             next(iter(stream))
         with pytest.raises(ExecutionTimeoutError):
@@ -309,15 +312,26 @@ def test_stream_ttft_deadline_hard_while_blocked(model):
 def test_stream_whole_request_deadline_soft_mid_stream(model):
     """Once tokens flow, the whole-request deadline turns soft: expiry
     stops decoding and resolves with the tokens already delivered."""
+    from paddle_tpu.serving import failpoints
     p = _shared_prefix_prompts(n=1, seed=17)[0]
     t0 = monitor.stat_get("STAT_gen_timeouts")
-    with _engine(model, max_new_tokens=100, num_pages=64,
-                 name="pfx_soft") as eng:
-        stream = eng.submit_stream(p, max_new_tokens=100, timeout_ms=60)
-        toks = list(stream)                      # ends at the deadline
-        out = stream.result(timeout=60)
-        reasons = [ev["reason"] for ev in eng._audit.tail(64)]
-        pages_after = eng.stats()["pages"]["pages_in_use"]
+    # every step slowed by 5 ms: the tiny model's 100 tokens cannot beat
+    # the 60 ms deadline however fast the loop is (with decode steps
+    # launched ahead of the host, ISSUE 34, they otherwise do on the CPU)
+    failpoints.reset()
+    paddle.set_flags({"FLAGS_failpoints": "slow_step_ms@every:1:5"})
+    try:
+        with _engine(model, max_new_tokens=100, num_pages=64,
+                     name="pfx_soft") as eng:
+            stream = eng.submit_stream(p, max_new_tokens=100,
+                                       timeout_ms=60)
+            toks = list(stream)                  # ends at the deadline
+            out = stream.result(timeout=60)
+            reasons = [ev["reason"] for ev in eng._audit.tail(64)]
+            pages_after = eng.stats()["pages"]["pages_in_use"]
+    finally:
+        paddle.set_flags({"FLAGS_failpoints": ""})
+        failpoints.reset()
     assert 1 <= len(toks) < 100
     assert toks == list(out[p.size:])
     assert monitor.stat_get("STAT_gen_timeouts") > t0

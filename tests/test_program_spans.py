@@ -111,12 +111,13 @@ def tiny_engine(gpt, name, **kw):
 
 
 def test_new_step_fields_are_appended_after_the_old():
-    # PR 27's two device counters after PR 25's three waits, each era
-    # appended to the one before
-    assert step_log._FIELDS[-2:] == ("experts_hit", "latent_rows")
-    assert step_log._FIELDS[-5:-2] == ("decode_wait_ms", "prefill_wait_ms",
+    # PR 34's `ahead` after PR 27's two device counters after PR 25's
+    # three waits, each era appended to the one before
+    assert step_log._FIELDS[-1] == "ahead"
+    assert step_log._FIELDS[-3:-1] == ("experts_hit", "latent_rows")
+    assert step_log._FIELDS[-6:-3] == ("decode_wait_ms", "prefill_wait_ms",
                                        "admit_wait_ms")
-    assert step_log._FIELDS[-6] == "attr_wall_ms"
+    assert step_log._FIELDS[-7] == "attr_wall_ms"
     assert list(step_log.StepRecord().to_dict()) == list(step_log._FIELDS)
 
 

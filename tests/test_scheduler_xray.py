@@ -237,7 +237,12 @@ def test_abort_shutdown_flushes_final_record(model):
     eng = _engine(model, name="xray_abort")
     futs = [eng.submit(p, max_new_tokens=100)
             for p in _prompts(n=2, seed=23)]
-    time.sleep(0.1)  # let admissions happen
+    # let admissions happen — and no longer: with decode steps launched
+    # ahead of the host (ISSUE 34) the tiny model's 100 tokens take some
+    # 50 ms on the CPU, so a fixed sleep would find them finished
+    deadline = time.monotonic() + 30
+    while eng._num_active() < 2 and time.monotonic() < deadline:
+        time.sleep(0.0005)
     eng.shutdown(drain=False, timeout_s=120)
     for f in futs:
         with pytest.raises(UnavailableError):
@@ -253,6 +258,61 @@ def test_abort_shutdown_flushes_final_record(model):
     # engine, audit tails by name come back empty
     assert "xray_abort" not in step_log.steps_payload()["engines"]
     assert audit.tail_for("xray_abort") == []
+
+
+def _streamed(streams):
+    """Every stream read to its end on a thread of its own; returns the
+    lists (filled as tokens arrive) and the threads."""
+    got = [[] for _ in streams]
+
+    def read(i):
+        try:
+            for tok in streams[i]:
+                got[i].append(tok)
+        except Exception as e:  # noqa: BLE001 — the outcome, kept
+            got[i].append(e)
+
+    threads = [threading.Thread(target=read, args=(i,), daemon=True)
+               for i in range(len(streams))]
+    for t in threads:
+        t.start()
+    return got, threads
+
+
+def test_drain_shutdown_with_a_step_in_flight_delivers_everything(model):
+    """shutdown(drain=True) called while a decode step is launched and
+    unread (ISSUE 34): every submitter gets its one outcome, the streamed
+    tokens are the result's, and the ring's sums reconcile — a step
+    launched ahead is read, never lost, before the loop exits."""
+    c0 = monitor.stat_get("STAT_gen_completions")
+    k0 = monitor.stat_get("STAT_gen_tokens")
+    ids = _prompts(n=4, seed=31)
+    eng = _engine(model, name="xray_drain_ahead", max_new_tokens=40)
+    streams = [eng.submit_stream(p, max_new_tokens=30) for p in ids]
+    got, threads = _streamed(streams)
+    deadline = time.monotonic() + 60
+    while eng._flight is None and time.monotonic() < deadline:
+        time.sleep(0.0005)
+    assert eng._flight is not None, "no step was ever in flight"
+    eng.shutdown(drain=True, timeout_s=120)
+    for t in threads:
+        t.join(60)
+    recs = eng._step_log.tail(10000)
+    look = eng.stats()["lookahead"]
+    for p, s, g in zip(ids, streams, got):
+        out = s.result(timeout=5)
+        assert out.shape == (7 + 30,)
+        assert g == out[7:].tolist()        # each token once, in order
+    assert eng._flight is None
+    assert look["ahead"] > 0 and look["dropped_tokens"] == 0
+    assert sum(r["completed"] for r in recs) == \
+        monitor.stat_get("STAT_gen_completions") - c0 == 4
+    assert sum(r["tokens"] for r in recs) == \
+        monitor.stat_get("STAT_gen_tokens") - k0 == 4 * 30
+    assert sum(r["admitted"] for r in recs) == \
+        sum(r["freed"] for r in recs) == 4
+    assert sum(r["ahead"] for r in recs) == look["ahead"]
+    assert recs[-1]["pages_in_use"] == 0
 
 
 # -- tentpole 2: KV-pool introspection --------------------------------------

@@ -86,6 +86,17 @@ GEN_SLOTS, GEN_PAGE, GEN_PAGES, GEN_PP = 16, 16, 128, 64
 V5E_HBM, GEN_TEMP_LIMIT = 15.75e9, 1 << 30
 
 
+def step_inputs(sds, M, PP, key):
+    """The decode program's arguments after the pools, as shapes (ISSUE 34:
+    page table, the device's tokens of the step before, the host's tokens
+    and their mask, positions, activity, temperatures, sample mask, base
+    key, step number)."""
+    return (sds((M, PP), "int32"), sds((M,), "int32"), sds((M,), "int32"),
+            sds((M,), "bool"), sds((M,), "int32"), sds((M,), "bool"),
+            sds((M,), "float32"), sds((M,), "bool"),
+            sds(key.shape, key.dtype), sds((), "int32"))
+
+
 def xl_programs(one_chip, num_pages, names):
     """The engine's own programs, compiled at the cell's shapes THROUGH THE
     ENGINE'S JIT BOUNDARY (`jit_program`): the closures depend on heads,
@@ -130,10 +141,7 @@ def xl_programs(one_chip, num_pages, names):
         M = GEN_SLOTS
         auto = (Format(Layout.AUTO, one_chip),) * 2
         out = {"decode": eng._jit_program("decode", auto).lower(
-            W, pool, pool, sds((M, GEN_PP), jnp.int32), sds((M,), jnp.int32),
-            sds((M,), jnp.int32), sds((M,), jnp.bool_),
-            sds((M,), jnp.float32), sds((M,), jnp.bool_),
-            sds(key.shape, key.dtype)).compile()}
+            W, pool, pool, *step_inputs(sds, M, GEN_PP, key)).compile()}
         fmts = tuple(out["decode"].input_formats[0][1:3])
         assert fmts == tuple(out["decode"].output_formats[:2])
         # left to choose, the compiler keeps the layout the pools lie in
@@ -249,7 +257,8 @@ def test_latent_decode_compiles_for_the_v5e_without_a_pool_copy(
     from paddle_tpu.device import layout_name
     from paddle_tpu.ops import latent_attention_kernel as lk
     from paddle_tpu.ops import paged_ops
-    from paddle_tpu.serving.generation import jit_program
+    from paddle_tpu.serving.generation import (jit_program,
+                                               with_step_inputs)
 
     from paddle_tpu.models.glm_moe import (GlmMoeLiteConfig,
                                            glm_weight_shapes)
@@ -278,11 +287,9 @@ def test_latent_decode_compiles_for_the_v5e_without_a_pool_copy(
     key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
     M = LAT_SLOTS
     decode = jit_program(
-        fns["decode"], "decode", (Format(Layout.AUTO, one_chip),),
-        counters=True).lower(
-        W, pool, sds((M, LAT_PP), "int32"), sds((M,), "int32"),
-        sds((M,), "int32"), sds((M,), "bool"), sds((M,), "float32"),
-        sds((M,), "bool"), sds(key.shape, key.dtype)).compile()
+        with_step_inputs(fns["decode"]), "decode",
+        (Format(Layout.AUTO, one_chip),), counters=True).lower(
+        W, pool, *step_inputs(sds, M, LAT_PP, key)).compile()
     fmts = (decode.input_formats[0][1],)
     assert fmts == tuple(decode.output_formats[:1])
     assert layout_name(fmts[0], pool.shape, pool.dtype) == "default"
