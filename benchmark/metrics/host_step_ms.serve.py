@@ -1,11 +1,14 @@
-"""The host's serial work per engine iteration, in ms: the median, over the
+"""The host's own work per engine iteration, in ms: the median, over the
 window's StepRecords that ran a decode step, of attr_wall_ms - attr_idle_ms -
 decode_wait_ms - prefill_wait_ms — everything the step thread did except
-wait, for requests or for the chip. The chip waits for it, because step n+1
-is launched only after step n's tokens are read (ROADMAP Queue 1 item 5).
-None where the window ran no decode step; NO_RECORD where the records have
-no wait fields (before PR 25): the busy iteration whole would read as the
-chip's time, not the host's."""
+wait, for requests or for the chip. Since PR 34 the engine launches decode
+step n+1 before it reads step n, so this work runs UNDER the step in flight:
+hidden, not gone. The chip waits only for the host time with no program in
+flight, attr_admit_ms + attr_bookkeep_ms; this metric reads all of the
+host's work, hidden or not, and would hold the chip again if it grew past
+the device's step. None where the window ran no decode step; NO_RECORD where
+the records have no wait fields (before PR 25): the busy iteration whole
+would read as the chip's time, not the host's."""
 import statistics
 
 from benchmark import program_records

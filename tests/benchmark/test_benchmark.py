@@ -10,6 +10,8 @@ import os
 import shutil
 import subprocess
 import sys
+import time
+import types
 
 import numpy as np
 import pytest
@@ -228,6 +230,95 @@ def test_serve_traffic_is_seeded_clipped_and_the_same_work(loop):
         due = [r["due_s"] for r in a]
         assert len(a) == round(mix["rate_per_s"] * 20.0)
         assert due[0] == 0.0 and due == sorted(due) and due[-1] < 20.0
+
+
+CLOSED_MIXES = [
+    name for name in sorted(
+        os.path.splitext(f)[0] for f in os.listdir(
+            os.path.join(ROOT, "benchmark", "traffic")) if f.endswith(".json"))
+    if data("traffic", name).get("loop") == "closed"]
+
+
+@pytest.mark.parametrize("name", CLOSED_MIXES)
+def test_a_closed_loop_mix_drains_what_is_in_flight(name):
+    """A closed loop always ends with its clients' requests in flight, and
+    `serve.window` counts over the readers that are finished when the drain
+    ends: without a drain the count moves by a whole request with which one
+    ends just before the window does (PR 27: 2.9%; PR 35: 2-3%)."""
+    mix = data("traffic", name)
+    assert mix["drain_seconds"] > 0 and len(mix["drain_why"]) > 100
+
+
+def test_the_gpt2_xl_cell_is_a_deployment_in_which_slots_bind():
+    cfg = run.load_json("benchmark/configs/gpt2-xl.json")
+    mix = data("traffic", "batch-saturated")
+    eng = cfg["run"]["engine"]
+    assert eng["gc_freeze"] is True
+    assert (eng["max_slots"], eng["page_size"]) == (16, 16)
+    assert mix["clients"] > eng["max_slots"]          # a queue always waits
+    assert (mix["pool"], mix["pool_seed"]) == (64, 24)    # the ledger's ring
+    prompt, out = trafficgen.size_pool(mix, mix["pool"])
+    reserved = np.ceil((prompt + out) / eng["page_size"])
+    assert reserved.mean() == pytest.approx(16.65625) and reserved.max() == 42
+    # every slot's mean reservation fits, so slots and not pages bind ...
+    assert eng["num_pages"] >= eng["max_slots"] * reserved.mean()
+    # ... and the pool is no larger than the tables, so decode stays
+    # pool-dense (`paged_ops.paged_pool_dense_supported`)
+    pages_per_seq = eng.get("pages_per_seq",
+                            -(-cfg["n_positions"] // eng["page_size"]))
+    assert eng["num_pages"] <= eng["max_slots"] * pages_per_seq
+    # with 24 clients every entry of the ring is still sent
+    sent = {(c + k * mix["clients"]) % mix["pool"]
+            for c in range(mix["clients"]) for k in range(mix["pool"])}
+    assert sent == set(range(mix["pool"]))
+
+
+class PacedEngine:
+    """Stands in for the engine under `serve.window`: a request's tokens
+    come one every `gap` seconds, whatever else is in flight."""
+
+    def __init__(self, gap):
+        self.gap = gap
+
+    def stats(self):
+        return {"compiles": {}}
+
+    def submit_stream(self, prompt, max_new_tokens, timeout_ms):
+        for tok in range(max_new_tokens):
+            time.sleep(self.gap)
+            yield tok
+
+
+@pytest.mark.parametrize("drain, tokens, requests", [
+    (0, 10, 2),      # no drain: the requests in flight at t1 are left out
+    (3, 14, 4),      # drained: what they were given inside the window counts
+])
+def test_a_drain_counts_the_tokens_stamped_in_the_window_whoever_ends_when(
+        monkeypatch, drain, tokens, requests):
+    """Two clients, requests of 5 tokens 0.2 s apart, a window of 1.5 s:
+    each client finishes one request at 1.0 s and has its second stamped at
+    1.2 and 1.4 s inside the window and at 1.6-2.0 s after it."""
+    from benchmark.drivers import serve
+    mix = {"loop": "closed", "clients": 2, "pool": 2, "pool_seed": 1,
+           "prompt_tokens": {"median": 4, "sigma": 0.0, "min": 4, "max": 4},
+           "output_tokens": {"median": 5, "sigma": 0.0, "min": 5, "max": 5},
+           "max_total_tokens": 16, "drain_seconds": drain, "trace_seconds": 1,
+           "request_timeout_s": 30}
+    record = dict.fromkeys((
+        "attr_admit_ms", "prefill_ms", "attr_promote_ms", "decode_ms",
+        "attr_bookkeep_ms", "attr_idle_ms", "attr_wall_ms", "queue_depth"),
+        0.0)
+    monkeypatch.setattr(serve.step_log, "steps_payload", lambda: {
+        "engines": {serve.ENGINE: {
+            "records": [dict(record, t=time.perf_counter() - 0.5)],
+            "recorded_total": 1, "ring_capacity": 8}}})
+    ctx = types.SimpleNamespace(seed=2 ** 31 + 35, say=lambda msg: None,
+                                config={"vocab_size": 512})
+    w = serve.window(ctx, PacedEngine(0.2), mix, 1.5, False)
+    assert w["end_to_end"]["serve_tokens_per_s"] == tokens / 1.5
+    assert (w["attempted"], w["failed"], len(w["done"])) == \
+        (requests, 0, requests)
+    assert not w["compiled"]
 
 
 def test_pretrain_samples_are_seeded_and_masked():
