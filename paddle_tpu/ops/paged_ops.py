@@ -430,10 +430,21 @@ def _write_rows(pages, layer, page_ids, offsets, values):
         # scatter a layer traces and lowers 48 of them a pool
         layers = jnp.arange(pages.shape[0])[:, None]
         return pages.at[layers, page_ids[None], offsets[None], :].set(rows)
+    # the split form: layer and head are INDICES like page and offset, and
+    # the window is one head's D values. With either left as a window
+    # (`[layer, :, ids, offs]`) XLA:TPU wants the heads beside the lanes and
+    # relays the whole pool to `[L, N, P, H, D]` and back in every program
+    # that writes (compiled for the v5e at 4 K/V heads, PR 36: four copies
+    # of a 340 MB pool a decode step), as it did for the fused form's layers
+    heads = jnp.arange(pages.shape[1])
     if layer is None:
-        return pages.at[:, :, page_ids, offsets, :].set(values)
-    # the scalar layer puts the batch dim in FRONT: values are [B, H, D]
-    return pages.at[layer, :, page_ids, offsets, :].set(values)
+        layers = jnp.arange(pages.shape[0])[:, None, None]
+        return pages.at[layers, heads[None, :, None],
+                        page_ids[None, None], offsets[None, None], :].set(
+                            values)
+    # values are [B, H, D]
+    return pages.at[layer, heads[None], page_ids[:, None],
+                    offsets[:, None], :].set(values)
 
 
 @jax.named_scope("kv_write")
@@ -580,8 +591,12 @@ def paged_kernel_supported(q_shape, pages_shape, table_shape) -> bool:
     Every shape admitted here must compile on the chip. Compiled on the
     v5e in f32 and bf16 and matching the reference to < 0.01 (PR 21): head
     dim 128/256/384/512, page 8/16/32/48, 1..16 sequences, 3..32 heads,
-    groups of 1/2/8, tables of 8 and 64 pages. Widen the rule only with a
-    chip run that shows it."""
+    groups of 1/2/8, tables of 8 and 64 pages; and in bf16 a group of 5 (20
+    query heads over 4 K/V heads, 128 wide), page 16, a 96-entry table, 4 and
+    96 sequences, 3,456 pages a layer (PR 36: the first cell that times it,
+    `falcon-h1-34b.chat-saturated`; a group that is no multiple of 8 takes
+    the kernel's float32-query path). Widen the rule only with a chip run
+    that shows it."""
     _, H, D = q_shape
     Hkv, _, P, Dk = pages_shape
     return (D == Dk and D % 128 == 0 and H % Hkv == 0 and P % 8 == 0
@@ -628,12 +643,16 @@ def paged_attention_path(q_shape, pages_shape, table_shape,
 
 
 def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
-                    k_scales=None, v_scales=None, pool_mask=None):
+                    k_scales=None, v_scales=None, pool_mask=None,
+                    kv_heads=None):
     """One decode position of attention over a paged KV cache.
 
     q [B, H, D]; k_pages/v_pages [H, N, P, D] (ONE layer's pool, or
     fused [N, P, row]); page_table [B, PP] int32; pos [B] int32 (last
-    valid position, the token just written). Returns [B, H, D].
+    valid position, the token just written). Returns [B, H, D]. Grouped
+    queries: the pools hold Hkv < H heads and query head i reads K/V head
+    i // (H / Hkv); a split layer says Hkv by its shape, a fused layer's
+    row does not, so its caller gives `kv_heads`.
 
     `paged_attention_path` picks the implementation from the shapes: on
     a TPU backend, shapes `paged_kernel_supported` admits dispatch the
@@ -651,10 +670,11 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
     reads always take the dequantizing gather + dense reference (the
     gather materializes only this batch's pages in floating form; the
     pools stay int8 in HBM — on TPU and CPU alike)."""
-    H, D = hd = q.shape[1:]
+    H, D = q.shape[1:]
+    hd = (kv_heads or H, D)     # the heads a fused row holds
     if k_pages.ndim == 3:       # a fused layer [N, P, row]: one K/V head
-        layer_shape = (H,) + k_pages.shape[:2] + (D,)       # a query head
-    else:
+        layer_shape = hd[:1] + k_pages.shape[:2] + (D,)     # a query head
+    else:                       # unless `kv_heads` says fewer
         layer_shape = k_pages.shape
     path = paged_attention_path(q.shape, layer_shape, page_table.shape,
                                 k_pages.dtype)
@@ -693,6 +713,9 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
     else:
         kb = paged_gather(k_pages, page_table, hd)
         vb = paged_gather(v_pages, page_table, hd)
+    if kb.shape[1] != H:
+        # grouped queries: query head i reads K/V head i // (H / Hkv)
+        kb, vb = (jnp.repeat(x, H // x.shape[1], axis=1) for x in (kb, vb))
     return cached_attention(q, kb, vb, pos, scale)
 
 

@@ -180,7 +180,14 @@ _FIELDS = ("it", "step", "t", "live", "admitted", "completed", "expired",
            # ISSUE 34: 1 where the iteration's decode step was launched
            # while the step before it was still unread (its tokens went
            # from the device's output straight into this step's input)
-           "ahead")
+           "ahead",
+           # ISSUE 36: the hybrid family's device counters (live slots
+           # whose state-space state the step read and wrote; attended
+           # K/V positions summed over the live slots; 0 for the other
+           # families), and the real prompt tokens of the iteration's
+           # prefill programs, counted by the engine for every family —
+           # appended, by the same era rule
+           "state_slots", "kv_rows", "prefill_tokens")
 
 _FIT_FIELDS = ("fit", "step", "t", "input_wait_ms", "prep_ms",
                "dispatch_ms", "sync_ms", "callback_ms", "other_ms",

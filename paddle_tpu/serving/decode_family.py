@@ -2,14 +2,23 @@
 
 The engine's scheduler, page allocator, streams, step ring and supervisor
 know pages, slots and tokens, and no model. Everything that depends on what
-a model IS — its weight pytree, what one cached token looks like, the bodies
+a model IS — its weight pytree, what one cached token (and, for a family with
+state by slot, one sequence's state) looks like, the bodies
 of the prefill / decode / zero-pages programs, the largest position it can
 be asked for, and which of the engine's options it can serve — comes from
 the model's family, which the model hands over from `decode_family()`:
 
     name             "gpt" (serving/gpt_family.py), "latent"
-                     (serving/latent_family.py)
+                     (serving/latent_family.py), "hybrid"
+                     (serving/hybrid_family.py)
     max_position     largest number of positions of one sequence
+    slot_state       (optional, default False) True for a family that keeps
+                     a fixed-size state per SLOT beside its pages (the
+                     hybrid family: a state-space state and a convolution
+                     window). Slot `i` of the decode batch is row `i` of
+                     such a pool; the prefill program is then told the
+                     request's slot (one more argument, below); nothing
+                     zeroes a slot on free, the next prefill overwrites it
     step_counters    names of the `StepRecord` fields the decode program
                      counts on the device: it returns them as ONE int32
                      vector after the tokens and the poison flags, and the
@@ -22,19 +31,27 @@ the model's family, which the model hands over from `decode_family()`:
     shard_weights(W, mesh)   (a family whose `check` admits tp > 1)
     make_cache(cfg, kv_dtype, mesh) -> PagedKVCache
                      the pools are the cache's `pools`, in the order the
-                     programs take and return them; the engine lays
+                     programs take and return them: pools WITH a page axis
+                     (`kind` "pages": the allocator's) and, after them,
+                     pools WITHOUT one, indexed by slot (`kind` "slots":
+                     `PagedKVCache(slot_pools=...)`); the engine lays
                      them out on the device as its step program was
                      compiled to take them and holds every program to
                      that layout (`generation.jit_program`), whatever
                      the family
     decode_attention(cfg, tp, pools) -> str
                      the name `stats()["decode_attention"]` shows
+    describe(cfg, pools) -> dict   (optional) further names of what the
+                     family's programs were built with, merged into
+                     `stats()` (the hybrid family: `ssm_decode_path`)
     build(ctx)       the program bodies by name: "prefill", "decode",
                      "zero_pages" always; "prefill_tail", "cow_copy",
                      "verify", "tier_gather", "tier_write" where the family
                      serves the option (else None). Signatures:
                        prefill(W, *pools, pt_row, ids, length)
                            -> (*pools, logits of the last real position)
+                       prefill(W, *pools, pt_row, ids, length, slot)
+                           the same, of a family with `slot_state`
                        decode(W, *pools, pt, tok, pos, active, temps,
                               smask, key) -> (*pools, next, bad[, counters])
                        zero_pages(*pools, pages) -> pools
@@ -91,6 +108,7 @@ def family_of(model):
     if make is None:
         raise InvalidArgumentError(
             f"GenerationEngine serves a model that has a decode family "
-            f"(models.GPTForCausalLM, models.GlmMoeLiteForCausalLM); got "
+            f"(models.GPTForCausalLM, models.GlmMoeLiteForCausalLM, "
+            f"models.FalconH1ForCausalLM); got "
             f"{type(model).__name__}")
     return make()

@@ -494,7 +494,8 @@ class _ProgramPack:
         self.preferred = preferred
 
 
-def jit_program(fn, name, fmts, counters=False, with_w=True, donates=True):
+def jit_program(fn, name, fmts, counters=False, with_w=True, donates=True,
+                slot_state=False):
     """A family's program body `fn` behind the engine's jit boundary: the
     pools donated, and taken and returned as `fmts` says, one
     `jax.experimental.layout.Format` a pool — the layout left to the
@@ -502,13 +503,14 @@ def jit_program(fn, name, fmts, counters=False, with_w=True, donates=True):
     program that serves (`GenerationEngine._build_programs`). `name` is the body's key in the
     family's `build` (its signature: serving/decode_family.py);
     `counters`: the decode program also returns the family's
-    `step_counters`."""
+    `step_counters`; `slot_state`: the family keeps state by slot, so its
+    prefill is told the slot (one more argument)."""
     import jax
     fmts = tuple(fmts)
     # what the program takes after the pools and returns after them
     # (None: it returns no pool)
     n_in, n_out = {
-        "prefill": (3, 1), "prefill_tail": (4, 1),
+        "prefill": (3 + bool(slot_state), 1), "prefill_tail": (4, 1),
         "decode": (10, 2 + bool(counters)), "verify": (8, 3),
         "zero_pages": (1, 0), "cow_copy": (2, 0),
         "tier_gather": (1, None), "tier_write": (1 + len(fmts), 0)}[name]
@@ -586,9 +588,10 @@ def _pool_view(i):
 class GenerationEngine:
     """Token-level continuous-batching front-end over a model that has a
     decode family (`serving/decode_family.py`): the head-pool family
-    builds every option below, the latent-pool family prefill, decode and
-    zero-pages — an option a family does not build is refused by name at
-    construction. Nothing below the family seam knows a model.
+    builds every option below, the latent-pool family and the hybrid family
+    (pages and a state by slot) prefill, decode and zero-pages — an option
+    a family does not build is refused by name at construction. Nothing
+    below the family seam knows a model.
 
     `submit(prompt_ids, ...)` returns a `concurrent.futures.Future`
     resolving to the full token sequence (prompt + generated, numpy
@@ -643,6 +646,9 @@ class GenerationEngine:
         # decode family (serving/decode_family.py); a model without one
         # is refused by name
         self._family = family = family_of(model)
+        # a family that keeps a fixed-size state per SLOT beside its pages
+        # says so: its prefill program is told the request's slot
+        self._slot_state = bool(getattr(family, "slot_state", False))
         self._model = model
         pack: Optional[_ProgramPack] = carry.get("pack")
         # a resurrection reuses the pack's exact weight pytree so the
@@ -709,7 +715,8 @@ class GenerationEngine:
         self._quant_kv = self._cache.quantized
         # the donated device pools, in the order the family's programs
         # take and return them (head pools: K, V[, K scales, V scales];
-        # a latent pool: the one)
+        # a latent pool: the one; the hybrid family: K, V, then the state
+        # and the window, which have no page axis)
         self._pool_arrays = list(self._cache.pools)
         # prefix cache (ISSUE 12): content-hash chain index over the
         # refcounted pages; None keeps the PR 8 ownership semantics
@@ -897,6 +904,11 @@ class GenerationEngine:
         # traced
         self._decode_attention = self._family.decode_attention(
             self._cfg, self._tp, self._pools())
+        # what else a family says of the programs it builds, by name
+        # (`describe`, optional): `stats()` shows it
+        describe = getattr(self._family, "describe", None)
+        self._family_info = (describe(self._cfg, self._pools())
+                             if describe else {})
         if pack is not None:
             # resurrection path (ISSUE 15): adopt the previous
             # incarnation's jit wrappers and SHARE its ledger dict —
@@ -1021,7 +1033,7 @@ class GenerationEngine:
         if fn is None:
             return None
         return jit_program(fn, name, fmts, bool(self._family.step_counters),
-                           with_w, donates)
+                           with_w, donates, self._slot_state)
 
     def _note_pool_layout(self):
         """The pools lie as every program was compiled to take them (a
@@ -1208,7 +1220,8 @@ class GenerationEngine:
                 with self._dev_ctx():
                     # lint: allow(use-after-donate): donate_argnums covers only the NP pool args riding in the *splat; trash sits AFTER them (position NP+1) and is never donated — reused read-only across warmup prefills
                     out = self._prefill_jit(self._W, *self._pools(), trash,
-                                            ids, np.int32(1))
+                                            ids, np.int32(1),
+                                            *self._slot_arg(0))
                 self._set_pools(out[:-1])
                 np.asarray(out[-1])
                 if self._use_tail:
@@ -1492,7 +1505,7 @@ class GenerationEngine:
                 "poisoned": 0, "aborted": 0, "freed": 0,
                 "prefix_tokens": 0, "cow_splits": 0,
                 "tokens": 0, "spec_drafted": 0, "spec_accepted": 0,
-                "prefill_chunks": 0, "ahead": 0,
+                "prefill_chunks": 0, "ahead": 0, "prefill_tokens": 0,
                 "prefill_ms": 0.0, "decode_ms": 0.0,
                 "promote_ms": 0.0,
                 "attr_idle_ms": 0.0, "attr_admit_ms": 0.0,
@@ -1712,6 +1725,7 @@ class GenerationEngine:
             # 1 where this iteration's decode step was launched with the
             # step before it still unread (ISSUE 34)
             ahead=it["ahead"],
+            prefill_tokens=it["prefill_tokens"],
             # what the family's decode program counted on the device
             **{name: it[name] for name in self._family.step_counters})
         self._step_log.record(rec)
@@ -2185,6 +2199,11 @@ class GenerationEngine:
         if fl is not None and fl.host is None:
             self._unobserved -= 1
 
+    def _slot_arg(self, slot) -> tuple:
+        """The prefill program's last argument for a family that keeps
+        state by slot (its `slot_state`); nothing for the others."""
+        return (np.int32(slot),) if self._slot_state else ()
+
     def _bucket_for(self, S: int) -> int:
         for b in self._cfg.prefill_buckets:
             if b >= S:
@@ -2232,9 +2251,11 @@ class GenerationEngine:
                 with self._dev_ctx():
                     out = self._prefill_jit(
                         self._W, *self._pools(), req.pt_row, ids,
-                        np.int32(S))
+                        np.int32(S), *self._slot_arg(req.slot))
                 self._set_pools(out[:-1])
                 lg = self._read_back("prefill", out[-1])
+        # real prompt tokens through a prefill program, whatever the family
+        self._it["prefill_tokens"] += tail
         if not np.all(np.isfinite(lg)):
             self._poison_prefill(req, bucket)
             return
@@ -2409,6 +2430,7 @@ class GenerationEngine:
             self._set_pools(out[:-1])
             lg = self._read_back("prefill", out[-1])
         self._it["prefill_chunks"] += 1
+        self._it["prefill_tokens"] += take
         self._chunks_total += 1
         monitor.stat_add("STAT_gen_prefill_chunks")
         if not np.all(np.isfinite(lg)):
@@ -2973,6 +2995,9 @@ class GenerationEngine:
             # / "latent_gather" (a latent pool): the paged attention the
             # decode program was built with (ops/paged_ops.py)
             "decode_attention": self._decode_attention,
+            # what else the family names of its programs (the hybrid
+            # family: "ssm_decode_path")
+            **self._family_info,
             "steps": steps,
             "prefills": prefills,
             "tokens": tokens,

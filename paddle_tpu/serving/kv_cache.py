@@ -7,7 +7,13 @@ place that knows where the page axis and the head axis are): 64-wide heads
 take `[L, N, P, H*D]`, a token's heads side by side in one dense row of
 whole 128-lane tiles (so that the device's default layout is row-major);
 128-wide heads keep `[L, H, N, P, D]`, which JAX's paged kernel reads in
-place. `PagedKVCache.described(shapes, ...)` (the latent-attention family,
+place. With grouped-query attention the pools hold the K/V heads
+(`num_kv_heads`), fewer than the query heads that read them. A family with a
+fixed-size state per SLOT beside its pages (the hybrid family,
+models/falcon_h1.py: a state-space state and a convolution window) names
+those pools too (`slot_pools`): they ride after K and V, have no page axis,
+and the allocator never touches them. `PagedKVCache.described(shapes, ...)`
+(the latent-attention family,
 models/glm_moe.py) builds whatever pools the family names by shape and
 dtype — ONE pool `[L, N, P, row]` with no head axis. Pages, tables, the
 scratch page, refcounts and admission arithmetic are the same for all; the
@@ -110,13 +116,28 @@ class PagedKVCache:
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  page_size: int, num_pages: int, pages_per_seq: int,
-                 dtype="float32", mesh=None, tp_axis: str = "tp"):
+                 dtype="float32", mesh=None, tp_axis: str = "tp",
+                 num_kv_heads: Optional[int] = None, slot_pools=()):
         """Head pools: K and V in the form the head width takes
         (`self.form`), plus the two scale pools in the int8 page mode,
-        head-sharded on a tp mesh. `described` builds any other pools."""
+        head-sharded on a tp mesh. `described` builds any other pools.
+
+        `num_kv_heads` (grouped-query attention): the heads the pools
+        HOLD, fewer than the `num_heads` that read them; every shape, the
+        form and the tp split are of the K/V heads. `slot_pools`: (shape,
+        dtype) of pools WITHOUT a page axis that ride after K and V — a
+        family's fixed-size state per slot, indexed by slot and not by
+        page, which the allocator never touches (`kind` "slots" in
+        `stats()["pools"]`; one device, float pages)."""
         self._init_pages(page_size, num_pages, pages_per_seq)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
+        self.num_kv_heads = int(num_heads if num_kv_heads is None
+                                else num_kv_heads)
+        if self.num_heads % self.num_kv_heads != 0:
+            raise InvalidArgumentError(
+                f"num_heads={num_heads} is not a multiple of "
+                f"num_kv_heads={num_kv_heads}")
         self.head_dim = int(head_dim)
         self.dtype = str(dtype)
         self.quantized = self.dtype == "int8"
@@ -129,15 +150,15 @@ class PagedKVCache:
         self.mesh = mesh
         self.tp_axis = str(tp_axis)
         self.tp = int(mesh.shape[tp_axis]) if mesh is not None else 1
-        if self.num_heads % self.tp != 0:
+        if self.num_kv_heads % self.tp != 0:
             raise InvalidArgumentError(
-                f"num_heads={self.num_heads} not divisible by "
+                f"num_heads={self.num_kv_heads} not divisible by "
                 f"tp={self.tp} — head-sharded pools need equal slices")
         import jax.numpy as jnp
 
         from ..ops.paged_ops import HeadPoolForm
         # the shape rule (ops/paged_ops.head_pools_fused): no flag
-        self.form = HeadPoolForm(self.num_heads, self.head_dim, self.tp)
+        self.form = HeadPoolForm(self.num_kv_heads, self.head_dim, self.tp)
         shape = self.form.pool_shape(self.num_layers, self.num_pages,
                                      self.page_size)
         self.k_pages = self._place(jnp.zeros(shape, self.dtype))
@@ -155,6 +176,13 @@ class PagedKVCache:
         else:
             self.k_scales = self.v_scales = None
             self.pools = (self.k_pages, self.v_pages)
+        if slot_pools:
+            if self.quantized or mesh is not None:
+                raise InvalidArgumentError(
+                    "slot pools ride float pages on one device")
+            self._slot_pools = len(slot_pools)
+            self.pools += tuple(jnp.zeros(tuple(shape), dt)
+                                for shape, dt in slot_pools)
         self._note_pools()
 
     @classmethod
@@ -200,27 +228,45 @@ class PagedKVCache:
         # capacity-planning number /stats surfaces (ISSUE 11)
         self._free_low_water = len(self._free)
         self._free_high_water = len(self._free)
+        self._slot_pools = 0        # trailing pools with no page axis
         monitor.stat_set("STAT_kv_pages_inuse", 0)
 
+    def _kind(self, i: int) -> str:
+        """`pages` for a pool the allocator carves, `slots` for one a
+        family indexes by slot (the trailing `slot_pools`)."""
+        return ("slots" if i >= len(self.pools) - self._slot_pools
+                else "pages")
+
     def _note_pools(self):
-        self._pool_bytes = sum(int(p.nbytes) for p in self.pools)
+        # what ONE page's share of the host tier is sized from: the pools
+        # that have pages
+        self._pool_bytes = sum(int(p.nbytes) for i, p in
+                               enumerate(self.pools)
+                               if self._kind(i) == "pages")
         # until the engine says otherwise (`note_layout`) the pools lie
         # as allocated: the default layout, device bytes = logical bytes
-        self._pool_info = [
-            {"shape": list(p.shape), "dtype": str(p.dtype),
-             "layout": "default", "device_bytes": int(p.nbytes),
-             "logical_bytes": self._logical_bytes(p)} for p in self.pools]
+        self._pool_info = [self._describe(i, p, "default", int(p.nbytes))
+                           for i, p in enumerate(self.pools)]
         # the gauges count DEVICE bytes; one mutable cell so the
         # finalizer takes back whatever `note_layout` made of them
         self._gauged = [0, 0]
         weakref.finalize(self, _ungauge, self._gauged)
         self._gauge()
 
-    def _logical_bytes(self, pool) -> int:
+    def _describe(self, i, pool, layout, device_bytes) -> dict:
+        """One entry of `stats()["pools"]`."""
+        kind = self._kind(i)
+        return {"shape": list(pool.shape), "dtype": str(pool.dtype),
+                "kind": kind, "layout": layout,
+                "device_bytes": device_bytes,
+                "logical_bytes": self._logical_bytes(pool, kind)}
+
+    def _logical_bytes(self, pool, kind="pages") -> int:
         """The bytes of what a pool stores: a fused head pool's rows are
         whole lane tiles, of which the heads fill `form.used` lanes."""
         form = self.form
-        if form is not None and form.fused and pool.ndim == 4:
+        if (form is not None and form.fused and pool.ndim == 4
+                and kind == "pages"):
             return int(pool.nbytes) * form.used // form.row
         return int(pool.nbytes)
 
@@ -240,13 +286,8 @@ class PagedKVCache:
         reported it) and the bytes it takes there, and let the gauges
         count those. Nothing else in the cache depends on the layout."""
         from ..device import array_layout
-        self._pool_info = []
-        for p in pools:
-            name, device_bytes = array_layout(p)
-            self._pool_info.append(
-                {"shape": list(p.shape), "dtype": str(p.dtype),
-                 "layout": name, "device_bytes": device_bytes,
-                 "logical_bytes": self._logical_bytes(p)})
+        self._pool_info = [self._describe(i, p, *array_layout(p))
+                           for i, p in enumerate(pools)]
         self._gauge()
 
     def _place(self, arr):

@@ -71,10 +71,24 @@ def restored(monkeypatch):
                         paged_ops.paged_latent_write)
     monkeypatch.setattr(paged_ops, "paged_latent_attention",
                         paged_ops.paged_latent_attention)
+    monkeypatch.setattr(paged_ops, "paged_attention",
+                        paged_ops.paged_attention)
     monkeypatch.setattr(glm_moe, "moe_route", glm_moe.moe_route)
 
 
-@pytest.mark.parametrize("fault", sorted(set(plant_fault.PLANTS) - {"none"}))
+# the latent family's plants (the hybrid family's: tests/test_falcon_h1.py)
+LATENT_PLANTS = ("dropped_expert", "float8_cache", "wrong_page",
+                 "wrong_table")
+
+
+def test_every_plant_has_a_test_that_shows_it_live():
+    from test_falcon_h1 import HYBRID_PLANTS
+    # `wrong_page` and `wrong_table` wrap both families' decode attention
+    assert set(plant_fault.PLANTS) - {"none"} == \
+        set(LATENT_PLANTS) | set(HYBRID_PLANTS)
+
+
+@pytest.mark.parametrize("fault", LATENT_PLANTS)
 def test_a_plant_moves_the_logits(net, restored, fault):
     sound = paged_logits(net)
     plant_fault.PLANTS[fault]()

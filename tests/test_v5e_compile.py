@@ -357,3 +357,103 @@ def test_the_latent_kernel_compiles_for_the_v5e_at_the_shapes_its_rule_admits(
     assert text.count("tpu_custom_call") == 1
     assert "latent_decode_attention" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# -- the hybrid family's programs (falcon-h1-34b.chat-saturated) --------------
+# widths as published (benchmark/configs/falcon-h1-34b.json), 2 of the 6
+# blocks; 96 slots, pages of 16 tokens, 3,456 pages, 96 table entries
+HYB_SLOTS, HYB_PAGE, HYB_PAGES, HYB_PP, HYB_L = 96, 16, 3456, 96, 2
+
+
+def test_hybrid_programs_compile_for_the_v5e_without_a_pool_copy(
+        one_chip, uncached, monkeypatch):
+    """The three programs of the hybrid family at the cell's shapes, through
+    the engine's jit boundary. Left to choose, the compiler keeps the
+    DEFAULT layout for all four pools — what the explicit head index of
+    `paged_ops._write_rows` and the K-major convolution window exist to make
+    true (with the head axis left as a scatter window it chose
+    `[L, N, P, H, D]` for K and V, and held to the default it copied both
+    340 MB pools in and out of every program; PR 36) — so no program copies
+    a pool. The state update is the Pallas kernel `ssm_decode_update`, one
+    custom call a layer over the pool in place; attention is JAX's paged
+    kernel over 4 K/V heads under 20 query heads. The rules ask the backend,
+    which is the CPU here: the test answers for it."""
+    import types
+
+    from jax.experimental.layout import Format, Layout
+
+    from paddle_tpu.device import layout_name
+    from paddle_tpu.models.falcon_h1 import FalconH1Config, fh1_weight_shapes
+    from paddle_tpu.ops import paged_ops, ssm_ops
+    from paddle_tpu.serving.decode_family import ProgramContext
+    from paddle_tpu.serving.generation import (GenerationConfig, jit_program,
+                                               with_step_inputs)
+    from paddle_tpu.serving.hybrid_family import HybridFamily
+
+    monkeypatch.setattr(ssm_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(ssm_ops.jax, "default_backend", lambda: "tpu")
+    cfg = FalconH1Config(num_hidden_layers=HYB_L)
+    fam = HybridFamily(types.SimpleNamespace(config=cfg))
+    ecfg = GenerationConfig(max_slots=HYB_SLOTS, page_size=HYB_PAGE,
+                            num_pages=HYB_PAGES, pages_per_seq=HYB_PP,
+                            prefill_buckets=(1024,), warmup=False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    W = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                               fh1_weight_shapes(cfg))
+    L, M = HYB_L, HYB_SLOTS
+    pools = (sds((L, 4, HYB_PAGES, HYB_PAGE, 128), "bfloat16"),
+             sds((L, 4, HYB_PAGES, HYB_PAGE, 128), "bfloat16"),
+             sds((L, M, 32, 128, 256), "float32"),
+             sds((L, 4, M, 5120), "bfloat16"))
+    path = fam.decode_attention(ecfg, 1, pools)
+    assert path == "kernel"
+    assert fam.describe(ecfg, pools) == {"ssm_decode_path": "kernel"}
+    fns = fam.build(ProgramContext(ecfg, 1, None, 4, False, path, W, {}))
+    key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
+    decode = jit_program(
+        with_step_inputs(fns["decode"]), "decode",
+        tuple(Format(Layout.AUTO, one_chip) for _ in pools),
+        counters=True).lower(
+        W, *pools, *step_inputs(sds, M, HYB_PP, key)).compile()
+    fmts = tuple(decode.input_formats[0][1:5])
+    assert fmts == tuple(decode.output_formats[:4])
+    assert [layout_name(f, p.shape, p.dtype)
+            for f, p in zip(fmts, pools)] == ["default"] * 4
+    prefill = jit_program(fns["prefill"], "prefill", fmts,
+                          slot_state=True).lower(
+        W, *pools, sds((HYB_PP,), "int32"), sds((1, 1024), "int32"),
+        sds((), "int32"), sds((), "int32")).compile()
+    zero = jit_program(fns["zero_pages"], "zero_pages", fmts,
+                       with_w=False).lower(
+        *pools, sds((HYB_PP,), "int32")).compile()
+    text = decode.as_text()
+    for scope in ("layer_1/ssm/in_proj", "layer_1/ssm/conv",
+                  "layer_1/ssm/state_update", "layer_1/ssm/gate_norm",
+                  "layer_1/ssm/out_proj", "layer_1/attn/qkv",
+                  "layer_1/attn/rope", "layer_1/attn/attend",
+                  "layer_1/attn/out", "layer_1/mlp", "kv_write", "lm_head",
+                  "sample"):
+        assert scope in text, scope
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "ssm_decode_update" in ln]
+    assert len(calls) == HYB_L
+    assert all("/ssm/state_update" in ln for ln in calls)
+    assert "paged_attn_kernel" in text
+    ptext = prefill.as_text()
+    for scope in ("layer_1/ssm/scan", "layer_1/ssm/conv", "state_write",
+                  "layer_1/attn/attend"):
+        assert scope in ptext, scope
+    # no copy of a whole pool, in any of the three
+    shapes = [f"bf16[{L},4,{HYB_PAGES},{HYB_PAGE},128]",
+              f"f32[{L},{M},32,128,256]", f"bf16[{L},4,{M},5120]"]
+    for t in (text, ptext, zero.as_text()):
+        assert not [ln for ln in t.splitlines() if " copy(" in ln
+                    and any(f"= {s}" in ln for s in shapes)]
+    assert zero.memory_analysis().temp_size_in_bytes < 1 << 20
+    # 0.21 GB at six layers (the step's activations and a layer's slices)
+    assert decode.memory_analysis().temp_size_in_bytes < 256 << 20
+    assert prefill.memory_analysis().temp_size_in_bytes < 320 << 20
