@@ -551,7 +551,8 @@ class Smoke:
               "bf16 tolerance (out < 0.05, dq < 0.3)")
 
         # (d) paged attention at head dim 128, page 16, against the gather
-        # reference — the shape the rule admits to the Pallas kernel
+        # reference — the shape the rule admits to the repo's head-pool
+        # kernel `head_decode_attention`
         Bq, Hq, Dq, P, N, PP = 8, 8, 128, 16, 72, 8
         rng = np.random.RandomState(6)
         q = jnp.asarray(rng.standard_normal((Bq, Hq, Dq)), jnp.bfloat16)
@@ -581,10 +582,10 @@ class Smoke:
         say(f"kernels: paged attention q[{Bq},{Hq},{Dq}] page {P}: "
             f"STAT_paged_attn_kernel={kern} STAT_paged_attn_reference="
             f"{refc}, max|out-reference| {max_err(out, want):.4f}")
-        check((kern, refc) == ((0, 1) if self.rehearsal else (1, 0)),
-              "kernels: head dim 128 took the "
-              + ("reference (rehearsal: no TPU)" if self.rehearsal
-                 else "Pallas paged-attention kernel"))
+        check((kern, refc) == (1, 0),
+              "kernels: head dim 128 took the head-pool kernel "
+              "head_decode_attention"
+              + (" (interpreted: rehearsal)" if self.rehearsal else ""))
         check(max_err(out, want) < 0.05,
               "kernels: paged attention matches the gather reference at "
               "bf16 tolerance (< 0.05)")
@@ -944,8 +945,9 @@ class Smoke:
         # token past a chunk of the scan (129 in a 256 bucket), one short
         lengths = [32, 27, 9, 5] if self.rehearsal else [1024, 777, 129, 40]
         buckets = (8, 16, 32) if self.rehearsal else (128, 256, 1024)
-        # the table's width in whole blocks of JAX's paged kernel (4 pages)
-        pps = -(-(-(-(max(lengths) + new) // page)) // 4) * 4
+        # the table's width in whole rounds of the head-pool kernel (32
+        # pages of 32 KB: `latent_attention_kernel.head_block_pages`)
+        pps = -(-(-(-(max(lengths) + new) // page)) // 32) * 32
         eng = serving.GenerationEngine(
             net, name="smoke_hybrid", max_slots=4, page_size=page,
             num_pages=4 * pps + 1, pages_per_seq=pps,
@@ -955,7 +957,7 @@ class Smoke:
                                else ("kernel", "kernel"))
         check(st["decode_attention"] == want_attn
               and st["ssm_decode_path"] == want_ssm,
-              f"hybrid: decode attention is `{want_attn}` (JAX's paged "
+              f"hybrid: decode attention is `{want_attn}` (the head-pool "
               f"kernel over 4 K/V heads under 20 query heads on the chip) "
               f"and the state update `{want_ssm}` (one Pallas kernel over "
               f"the slot pool in place); got {st['decode_attention']} / "

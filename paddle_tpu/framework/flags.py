@@ -67,8 +67,9 @@ register_flag("FLAGS_splash_attention_min_seq", 512,
               "not swept on-chip)")
 register_flag("FLAGS_use_paged_attention", True,
               "decode-time cached attention over a paged KV cache: on the "
-              "TPU backend dispatch to the Pallas paged_attention kernel "
-              "(pages stay in place, sequential reads per page); off — or "
+              "TPU backend dispatch to the repo's Pallas head-pool kernel "
+              "(ops/latent_attention_kernel.head_decode_attention: pages "
+              "stay in place, one copy a page for all K/V heads); off — or "
               "any non-TPU backend — gathers the page table into a dense "
               "[B,H,T,D] buffer and runs the same masked attention as "
               "GPTModel.generate's fixed cache (the CPU/interpret parity "
@@ -85,7 +86,8 @@ register_flag("FLAGS_kv_cache_dtype", "auto",
               "'float32'/'bfloat16' force an unquantized page dtype")
 register_flag("FLAGS_paged_page_size", 16,
               "tokens per KV-cache page (serving.PagedKVCache); the TPU "
-              "paged_attention kernel wants a multiple of 8")
+              "head-pool kernel wants whole sublane tiles (8 float32, "
+              "16 bfloat16 rows)")
 register_flag("FLAGS_paged_num_pages", 512,
               "total pages in the per-layer K/V pools (page 0 is a "
               "reserved scratch page, so usable pages = this - 1); "
@@ -94,9 +96,6 @@ register_flag("FLAGS_paged_pages_per_seq", 0,
               "page-table width (most pages one sequence may hold); 0 "
               "derives ceil(max_position_embeddings / page_size) from "
               "the served model")
-register_flag("FLAGS_paged_compute_block_pages", 4,
-              "pages_per_compute_block for the TPU paged_attention "
-              "kernel (kv tile = this * page_size)")
 register_flag("FLAGS_gen_max_slots", 8,
               "serving.GenerationEngine: fixed decode-batch slot count — "
               "the ONE compiled decode-step shape; live sequences join "

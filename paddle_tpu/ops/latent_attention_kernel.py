@@ -207,3 +207,190 @@ def latent_decode_attention(q, pool, page_table, lengths, scale, kv_rank,
                  jnp.full((1,), layer, jnp.int32), scale=float(scale),
                  kv_rank=int(kv_rank), block_pages=int(block_pages),
                  interpret=_interpret())
+
+
+# -- grouped-query head pools -------------------------------------------------
+#
+# The same page walk over split head pools `[L, Hkv, N, P, D]` (a token's K
+# and V rows per K/V head, `ops/paged_ops.HeadPoolForm`): the decode
+# attention of `paged_ops.paged_attention` where its rule
+# `paged_kernel_supported` admits the shapes. Written below the latent
+# kernel so that none of that kernel's lines move: a program that holds a
+# Pallas kernel keys its compile cache on the kernel's source lines
+# (PERF.md PR 29), and the latent family's decode program stays as it was.
+#
+# What differs from the latent walk is what one page holds and who reads
+# it. One copy moves a page of K for ALL the K/V heads, `pool.at[layer, :,
+# page]` -> `[Hkv, P, D]` (strided in HBM), and one more that page of V: JAX's
+# paged kernel, which this one replaced (PR 37), copied each head's page of
+# K and of V on its own, four times the copies at 4 K/V heads, from a grid
+# of slots x heads x blocks. Each K/V head's G query heads (query head i
+# reads K/V head i // G), padded to a whole sublane tile, are scored
+# against the round's rows of that head, the softmax state per head float32
+# and flash style. A masked position is dropped by selection in both
+# products, as above: no additive mask, no unmasked value row.
+
+__all__ += ["head_block_pages", "head_decode_attention", "head_query_rows"]
+
+# A round's pages of K plus V, one of the two rounds in VMEM: 32 pages of 4
+# heads x 16 rows x 128 lanes of K and of V in bfloat16 (the falcon cell's
+# pools), 512 rows a head a round. On the v5e, six layers'
+# attention for the cell's 96 slots at 8 / 16 / 32 pages a round (my chip
+# run, PR 37): the ring's contexts (1,985 pages a layer) 1.45 / 1.50 / 1.23
+# ms (JAX's paged kernel at 4 / 8 / 16 / 32 pages a block 8.93 / 5.72 /
+# 4.70 / 4.51); full tables 5.70 / 5.64 / 4.16 (31.3 / 16.1 / 10.8 / 8.27);
+# ONE position each 0.40 / 0.63 / 0.66 (3.41 / 3.34 / 3.68 / 4.26).
+_HEAD_BLOCK_BYTES = 1 << 20
+
+
+def head_block_pages(page_size, kv_heads, head_dim, itemsize,
+                     table_width) -> int:
+    """Pages one round of copies moves: what of a page of K plus V fits
+    `_HEAD_BLOCK_BYTES`, rounded down to a power of two, and no more than
+    the table holds. `paged_ops.paged_kernel_supported` asks that it
+    divide the table's width."""
+    page = 2 * kv_heads * page_size * head_dim * itemsize
+    fit = max(1, _HEAD_BLOCK_BYTES // page)
+    return min(1 << (fit.bit_length() - 1), int(table_width))
+
+
+def _head_kernel(len_ref, table_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+                 kbuf, vbuf, sems, *, table_width, scale, precision):
+    B = q_ref.shape[0]
+    _, bp, Hkv, P, D = kbuf.shape
+    Gp = q_ref.shape[1] // Hkv
+    bk = bp * P
+    pools = ((k_ref.at[layer_ref[0]], kbuf), (v_ref.at[layer_ref[0]], vbuf))
+
+    def copies(b, i, slot, go):
+        """Start (`go`) or wait for the copies of round `i` of slot `b` into
+        buffer `slot`: two a page (K, V), each of all the K/V heads, as far
+        as the page that holds the slot's last position."""
+        first = b * table_width + i * bp
+
+        def one(j, _):
+            page = table_ref[first + j]
+            for pool, buf in pools:
+                dma = pltpu.make_async_copy(pool.at[:, page], buf.at[slot, j],
+                                            sems.at[slot])
+                if go:
+                    dma.start()
+                else:
+                    dma.wait()
+            return 0
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(pl.cdiv(len_ref[b], P) - i * bp, bp), one, 0)
+
+    copies(0, 0, 0, True)
+
+    def per_slot(b, slot):
+        length = len_ref[b]
+        rounds = pl.cdiv(length, bk)
+
+        def per_round(i, carry):
+            state, slot = carry
+            last = i + 1 == rounds
+            nb = jnp.where(last, b + 1, b)
+            ni = jnp.where(last, 0, i + 1)
+
+            @pl.when(nb < B)
+            def _():
+                copies(nb, ni, 1 - slot, True)
+
+            copies(b, i, slot, False)
+            t = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            tv = i * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            new = []
+            for h, (m, l, acc) in enumerate(state):
+                q = q_ref[b, h * Gp:(h + 1) * Gp, :]                # [Gp, D]
+                k = kbuf[slot, :, h].reshape(bk, D)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=precision) * scale                   # [Gp, bk]
+                s = jnp.where(t < length, s, _NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                v = jnp.where(tv < length, vbuf[slot, :, h].reshape(bk, D), 0)
+                new.append((
+                    m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
+                    alpha * acc + jax.lax.dot_general(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                        precision=precision)))
+            return tuple(new), 1 - slot
+
+        state0 = tuple((jnp.full((Gp, 1), _NEG_INF, jnp.float32),
+                        jnp.zeros((Gp, 1), jnp.float32),
+                        jnp.zeros((Gp, D), jnp.float32)) for _ in range(Hkv))
+        state, slot = jax.lax.fori_loop(0, rounds, per_round, (state0, slot))
+        for h, (_, l, acc) in enumerate(state):
+            o_ref[b, h * Gp:(h + 1) * Gp, :] = (acc / l).astype(o_ref.dtype)
+        return slot
+
+    jax.lax.fori_loop(0, B, per_slot, 0)
+
+
+def head_query_rows(heads, kv_heads) -> int:
+    """Rows a K/V head's queries take in the kernel: its group, padded to
+    whole sublane tiles."""
+    return -(-(heads // kv_heads) // _HEAD_TILE) * _HEAD_TILE
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_pages",
+                                             "interpret"))
+def _head_call(q, k_pool, v_pool, page_table, lengths, layer, *, scale,
+               block_pages, interpret):
+    B, H, D = q.shape
+    _, Hkv, _, P, _ = k_pool.shape
+    G, Gp = H // Hkv, head_query_rows(H, Hkv)
+    qg = jnp.pad(q.astype(k_pool.dtype).reshape(B, Hkv, G, D),
+                 ((0, 0), (0, 0), (0, Gp - G), (0, 0))).reshape(B, -1, D)
+    precision = (jax.lax.Precision.HIGHEST if k_pool.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    kernel = functools.partial(_head_kernel, table_width=page_table.shape[1],
+                               scale=scale, precision=precision)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((2, block_pages, Hkv, P, D), k_pool.dtype)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,      # lengths, the page table, the layer
+            grid=(1,),
+            in_specs=[whole, in_place, in_place],
+            out_specs=whole,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv * Gp, D), q.dtype),
+        interpret=interpret,
+        name="head_decode_attention",
+    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32).reshape(-1),
+      layer, qg, k_pool, v_pool)
+    return out.reshape(B, Hkv, Gp, D)[:, :, :G].reshape(B, H, D)
+
+
+def head_decode_attention(q, k_pool, v_pool, page_table, lengths, scale,
+                          layer=None, block_pages=None):
+    """q [B, H, D]; k_pool / v_pool ONE layer `[Hkv, N, P, D]`, or with
+    `layer` the whole `[L, Hkv, N, P, D]` pools, read in place; page_table
+    [B, PP]; lengths [B] >= 1, the positions each slot attends. Query head
+    i reads K/V head i // (H / Hkv). Returns [B, H, D] in q's dtype. Scores,
+    running maximum, sum and rescaling are float32; the two products take
+    the pools' dtype and accumulate in float32.
+
+    A round is `head_block_pages` (32 KB a page of K plus V at 4 heads of
+    128 in bfloat16: 32 pages, which divide the benchmark's 96-entry
+    table). The layer reaches the kernel as a scalar
+    and the call is a `jax.jit` of its own, so a decode program's layers
+    share one traced kernel, as `latent_decode_attention`'s do."""
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    _, Hkv, _, P, D = k_pool.shape
+    if block_pages is None:
+        block_pages = head_block_pages(P, Hkv, D, k_pool.dtype.itemsize,
+                                       page_table.shape[1])
+    return _head_call(q, k_pool, v_pool, page_table, lengths,
+                      jnp.full((1,), layer, jnp.int32), scale=float(scale),
+                      block_pages=int(block_pages), interpret=_interpret())

@@ -10,12 +10,12 @@ partial page, and admission control is exact page arithmetic
 
 Three decode-attention implementations over that layout, one math:
 
-- **kernel** — `jax.experimental.pallas.ops.tpu.paged_attention` (the
-  primitive SNIPPETS.md [3] shards along KV heads): reads pages in
-  place, `lengths` masks per sequence. Flag-gated by
-  `FLAGS_use_paged_attention`, TPU only, and shape-gated by
-  `paged_kernel_supported` (head dim a multiple of 128); tile =
-  `FLAGS_paged_compute_block_pages` pages.
+- **kernel** — `head_decode_attention` (`ops/latent_attention_kernel.py`,
+  PR 37): one Pallas program walks each slot's own pages in place as far
+  as `pos`, one copy a page for all K/V heads. Flag-gated by
+  `FLAGS_use_paged_attention`, run where a Pallas kernel runs, and
+  shape-gated by `paged_kernel_supported` (head dim a multiple of 128;
+  the round derived from the page's bytes and the table's width).
 - **pool** (pool-dense, `paged_pool_attention`) — no gather: all B
   rows' queries are scored against the layer's WHOLE pool in one
   batched matmul (`[B, D] x [D, N*P]` per head) and a page-ownership
@@ -55,8 +55,8 @@ by `STAT_paged_attn_kernel` / `STAT_paged_attn_pool` /
 exact-compile accounting everywhere else in the serving stack.
 
 **Two forms of a head pool, one shape rule** (`HeadPoolForm`,
-`head_pools_fused`). JAX's paged kernel wants one layer as
-`[Hkv, N, P, D]` and reads it in place, and with a head width that fills
+`head_pools_fused`). The paged kernel reads a layer as
+`[Hkv, N, P, D]` in place, and with a head width that fills
 whole 128-lane tiles that shape is dense on the TPU: such heads keep the
 *split* form `[L, H, N, P, D]` (scales `[L, H, N]`). Narrower heads
 (64-wide: GPT-2) would pad every (page, head) tile 2.56-fold there, and
@@ -99,10 +99,10 @@ __all__ = ["HeadPoolForm", "head_pools_fused",
 
 
 # Every function below that touches the pools runs under a named scope of
-# its own — `kv_write`, `kv_gather`, `kv_mask`, `kv_attend`,
-# `paged_attn_kernel` — so a profiler trace tells the pools' relay (writes,
-# gathers and the copies XLA makes for them) from the attention arithmetic
-# (tools/trace_report.py). Names are metadata: the programs are the same.
+# its own — `kv_write`, `kv_gather`, `kv_mask`, `kv_attend` — and a kernel
+# under its own name, so a profiler trace tells the pools' relay from the
+# attention (tools/trace_report.py). Names are metadata: the programs are
+# the same.
 
 
 # -- where the page axis and the head axis are --------------------------------
@@ -570,37 +570,41 @@ def paged_write_quantized(pages, scales, layer, page_ids, offsets, values,
     return pages, scales
 
 
-def _block_pages() -> int:
-    # lint: allow(flag-in-trace): the kernel tile is lowering structure — which program gets built, not a runtime value; the engine keys its program store on this flag for the same reason
-    return int(flag("FLAGS_paged_compute_block_pages"))
+def paged_kernel_supported(q_shape, pages_shape, table_shape,
+                           pages_dtype=jnp.float32) -> bool:
+    """Static gate, like `flash_supported`: the shapes the repo's head-pool
+    decode kernel (`ops/latent_attention_kernel.head_decode_attention`)
+    lowers and compiles for. q [B, H, D]; pages ONE layer [Hkv, N, P, D];
+    table [B, PP].
 
-
-def paged_kernel_supported(q_shape, pages_shape, table_shape) -> bool:
-    """Static gate, like `flash_supported`: the shapes the installed
-    `jax.experimental.pallas.ops.tpu.paged_attention` kernel lowers and
-    compiles for. q [B, H, D]; pages [Hkv, N, P, D]; table [B, PP].
-
-    - head dim a multiple of 128: the kernel's running-max / running-sum
-      outputs ([.., 1]-shaped) reuse q's block spec, whose last dim must
-      then be a lane multiple. Head dim 64 (GPT-2 small) fails Mosaic
-      lowering on jax 0.9.0, so it takes the gather reference BY THIS RULE.
-    - page size a multiple of 8: one page is a [P, D] VMEM tile.
-    - query heads a multiple of KV heads (grouped query included).
-    - the kernel walks the table FLAGS_paged_compute_block_pages pages at
-      a time and requires PP to divide evenly.
-    Every shape admitted here must compile on the chip. Compiled on the
-    v5e in f32 and bf16 and matching the reference to < 0.01 (PR 21): head
-    dim 128/256/384/512, page 8/16/32/48, 1..16 sequences, 3..32 heads,
-    groups of 1/2/8, tables of 8 and 64 pages; and in bf16 a group of 5 (20
-    query heads over 4 K/V heads, 128 wide), page 16, a 96-entry table, 4 and
-    96 sequences, 3,456 pages a layer (PR 36: the first cell that times it,
-    `falcon-h1-34b.chat-saturated`; a group that is no multiple of 8 takes
-    the kernel's float32-query path). Widen the rule only with a chip run
-    that shows it."""
-    _, H, D = q_shape
+    - floating pages of 2 or 4 bytes, head dim a multiple of 128, a page
+      whole sublane tiles (8 rows float32, 16 bfloat16): one copy a page
+      moves all the K/V heads' `[Hkv, P, D]` tiles. Head dim 64 (GPT-2)
+      is fused rows and takes the pool-dense path or the gather BY RULE.
+    - query heads a multiple of K/V heads (grouped query included).
+    - the kernel walks a slot's table `head_block_pages` entries at a time
+      (derived from the bytes of a page of K plus V and the table's width)
+      and asks that the round divide the table.
+    - every slot's queries and results, each K/V head's group padded to
+      whole sublane tiles, sit in VMEM whole: at most 8 MiB together.
+    Every shape admitted here must compile on the chip: the described v5e
+    compiles the falcon cell's (`tests/test_v5e_compile.py`), and the chip
+    ran the shapes of `tests/test_chip_kernels.py` and the cell's 96 slots
+    x 20 query heads over 4 K/V heads of 128, pages of 16, a 96-entry
+    table, 3,456 pages a layer (PERF.md PR 37). Widen the rule only with a
+    chip run that shows it."""
+    from .latent_attention_kernel import head_block_pages, head_query_rows
+    B, H, D = q_shape
     Hkv, _, P, Dk = pages_shape
-    return (D == Dk and D % 128 == 0 and H % Hkv == 0 and P % 8 == 0
-            and table_shape[1] % _block_pages() == 0)
+    dtype = jnp.dtype(pages_dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize not in (2, 4):
+        return False
+    if D != Dk or D % 128 or H % Hkv or P % (32 // dtype.itemsize):
+        return False
+    if 2 * B * Hkv * head_query_rows(H, Hkv) * D * 4 > 8 << 20:
+        return False
+    PP = table_shape[1]
+    return PP % head_block_pages(P, Hkv, D, dtype.itemsize, PP) == 0
 
 
 def paged_pool_dense_supported(q_shape, pages_shape, table_shape,
@@ -621,7 +625,8 @@ def paged_pool_dense_supported(q_shape, pages_shape, table_shape,
     Hkv, N, _, Dk = pages_shape
     return (jnp.issubdtype(pages_dtype, jnp.floating)
             and H == Hkv and D == Dk
-            and not paged_kernel_supported(q_shape, pages_shape, table_shape)
+            and not paged_kernel_supported(q_shape, pages_shape, table_shape,
+                                           pages_dtype)
             and N <= B * table_shape[1])
 
 
@@ -632,9 +637,9 @@ def paged_attention_path(q_shape, pages_shape, table_shape,
     if not jnp.issubdtype(pages_dtype, jnp.floating):
         return "reference"    # int8 pools dequantize page by page on gather
     # lint: allow(flag-in-trace): kernel-vs-reference is a trace-time choice by design (module docstring); the flag picks which program gets built
-    if (bool(flag("FLAGS_use_paged_attention"))
-            and jax.default_backend() == "tpu"
-            and paged_kernel_supported(q_shape, pages_shape, table_shape)):
+    if (bool(flag("FLAGS_use_paged_attention")) and _pallas_runs()
+            and paged_kernel_supported(q_shape, pages_shape, table_shape,
+                                       pages_dtype)):
         return "kernel"
     if paged_pool_dense_supported(q_shape, pages_shape, table_shape,
                                   pages_dtype):
@@ -644,19 +649,22 @@ def paged_attention_path(q_shape, pages_shape, table_shape,
 
 def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
                     k_scales=None, v_scales=None, pool_mask=None,
-                    kv_heads=None):
+                    kv_heads=None, layer=None):
     """One decode position of attention over a paged KV cache.
 
     q [B, H, D]; k_pages/v_pages [H, N, P, D] (ONE layer's pool, or
-    fused [N, P, row]); page_table [B, PP] int32; pos [B] int32 (last
-    valid position, the token just written). Returns [B, H, D]. Grouped
-    queries: the pools hold Hkv < H heads and query head i reads K/V head
-    i // (H / Hkv); a split layer says Hkv by its shape, a fused layer's
-    row does not, so its caller gives `kv_heads`.
+    fused [N, P, row]) — or, with `layer` (a Python int or a scalar), the
+    whole pools [L, ...], of which that layer is read; page_table [B, PP]
+    int32; pos [B] int32 (last valid position, the token just written).
+    Returns [B, H, D]. Grouped queries: the pools hold Hkv < H heads and
+    query head i reads K/V head i // (H / Hkv); a split layer says Hkv by
+    its shape, a fused layer's row does not, so its caller gives
+    `kv_heads`.
 
-    `paged_attention_path` picks the implementation from the shapes: on
-    a TPU backend, shapes `paged_kernel_supported` admits dispatch the
-    Pallas kernel (pages read in place); shapes
+    `paged_attention_path` picks the implementation from the shapes: where
+    a Pallas kernel runs, shapes `paged_kernel_supported` admits dispatch
+    the repo's head-pool kernel, which reads the pages in place (with
+    `layer`, in the whole pools: no layer is cut out for it); shapes
     `paged_pool_dense_supported` admits score every row against the
     whole pool under `pool_mask` (the `paged_pool_mask` of this table
     and `pos`; a caller with many layers builds it once and passes it,
@@ -666,39 +674,31 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
     kernel that failed.
 
     int8 pools pass k_scales/v_scales ([H, N] per-page scales): the
-    Pallas kernel has no int8+scale-pool input layout, so quantized
-    reads always take the dequantizing gather + dense reference (the
+    kernel has no int8+scale-pool input layout, so quantized reads
+    always take the dequantizing gather + dense reference (the
     gather materializes only this batch's pages in floating form; the
     pools stay int8 in HBM — on TPU and CPU alike)."""
     H, D = q.shape[1:]
     hd = (kv_heads or H, D)     # the heads a fused row holds
-    if k_pages.ndim == 3:       # a fused layer [N, P, row]: one K/V head
-        layer_shape = hd[:1] + k_pages.shape[:2] + (D,)     # a query head
+    shape = k_pages.shape if layer is None else k_pages.shape[1:]
+    if len(shape) == 3:         # a fused layer [N, P, row]: one K/V head
+        layer_shape = hd[:1] + shape[:2] + (D,)     # a query head
     else:                       # unless `kv_heads` says fewer
-        layer_shape = k_pages.shape
+        layer_shape = shape
     path = paged_attention_path(q.shape, layer_shape, page_table.shape,
                                 k_pages.dtype)
     if path == "kernel":
         monitor.stat_add("STAT_paged_attn_kernel")  # traces, not calls
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention as _kernel)
-        # the kernel takes no softmax-scale argument and applies none
-        # internally: fold ours into q before the qk product
-        # The kernel's dots name no precision, and the framework pins
-        # jax_default_matmul_precision="highest" (framework/__init__.py);
-        # traced under that they lower to fp32 contract precision, which
-        # Mosaic refuses on the v5e ("Bad rhs type", every shape tried).
-        # DEFAULT is what the kernel was written against and what the
-        # repo's own flash kernels ask for: MXU passes on the stored
-        # dtype, f32 accumulation.
-        # JAX's own pallas_call: its name is not ours to give, the scope is
-        with jax.default_matmul_precision("default"), \
-                jax.named_scope("paged_attn_kernel"):
-            return _kernel(
-                q * scale, k_pages, v_pages,
-                lengths=(pos + 1).astype(jnp.int32),
-                page_indices=page_table.astype(jnp.int32),
-                pages_per_compute_block=_block_pages())
+        from .latent_attention_kernel import head_decode_attention
+        # (a length is at least 1, a dead slot's too: the kernel starts
+        # every slot's first round of copies behind the slot before)
+        return head_decode_attention(q, k_pages, v_pages, page_table,
+                                     jnp.maximum(pos + 1, 1), scale,
+                                     layer=layer)
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        if k_scales is not None:
+            k_scales, v_scales = k_scales[layer], v_scales[layer]
     if path == "pool":
         monitor.stat_add("STAT_paged_attn_pool")  # traces, not calls
         if pool_mask is None:

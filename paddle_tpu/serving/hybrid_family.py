@@ -7,9 +7,10 @@ pool for each (`make_cache`):
     K, V     head pools `[L, Hkv, N, P, D]` in the model's dtype, by PAGES:
              grouped-query attention, so the pools hold the Hkv K/V heads
              (`PagedKVCache(num_kv_heads=...)`, `ops/paged_ops.HeadPoolForm`)
-             and `paged_attention` reads them by its shape rule (JAX's paged
-             kernel for 128-wide heads on a TPU, the gather elsewhere; which
-             one, `stats()["decode_attention"]` says)
+             and `paged_attention` reads them by its shape rule (the
+             head-pool kernel over the whole pools for 128-wide heads on a
+             TPU, the gather elsewhere; which one,
+             `stats()["decode_attention"]` says)
     state    `[L, M, H, P, N]` FLOAT32, by SLOT: the mixer's recurrent state,
              a fixed 4 MB a layer a sequence at the published widths
     window   `[L, K, M, C]` in the model's dtype, by SLOT: the last K
@@ -123,8 +124,8 @@ def hybrid_decode(W, pools, pt, tok, pos, active, cfg, page_size):
 
     def attend_kv(cache, layer, q, pos):
         kp, vp = cache[:2]
-        return paged_attention(q, kp[layer], vp[layer], pt, pos, scale,
-                               kv_heads=cfg.num_key_value_heads)
+        return paged_attention(q, kp, vp, pt, pos, scale,
+                               kv_heads=cfg.num_key_value_heads, layer=layer)
 
     def conv_step(cache, layer, lw, xbc):
         kp, vp, sp, cp = cache

@@ -6,7 +6,7 @@ whose form follows the head width (`ops/paged_ops.HeadPoolForm`, the one
 place that knows where the page axis and the head axis are): 64-wide heads
 take `[L, N, P, H*D]`, a token's heads side by side in one dense row of
 whole 128-lane tiles (so that the device's default layout is row-major);
-128-wide heads keep `[L, H, N, P, D]`, which JAX's paged kernel reads in
+128-wide heads keep `[L, H, N, P, D]`, which the head-pool kernel reads in
 place. With grouped-query attention the pools hold the K/V heads
 (`num_kv_heads`), fewer than the query heads that read them. A family with a
 fixed-size state per SLOT beside its pages (the hybrid family,

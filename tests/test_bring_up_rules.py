@@ -100,16 +100,27 @@ def test_no_interpret_mode_without_the_flag(_no_interpret):
 
 def test_paged_attention_shape_rule():
     ok = paged_ops.paged_kernel_supported
+    bf16 = jnp.bfloat16
     table = (8, 64)
     assert ok((8, 16, 128), (16, 512, 16, 128), table)
     assert ok((8, 16, 256), (16, 512, 32, 256), table)
     assert ok((8, 16, 128), (4, 512, 16, 128), table)     # grouped query
+    assert ok((96, 20, 128), (4, 3456, 16, 128), (96, 96), bf16)  # falcon
     # GPT-2 small / the 768-wide serving model: head dim 64 -> reference
     assert not ok((8, 12, 64), (12, 512, 16, 64), table)
-    assert ok((8, 16, 128), (16, 512, 8, 128), table)       # page size 8
+    # a page is whole sublane tiles of its dtype: 8 rows float32, 16 bf16
+    assert ok((8, 16, 128), (16, 512, 8, 128), table)
+    assert not ok((8, 16, 128), (16, 512, 8, 128), table, bf16)
     assert not ok((8, 16, 128), (16, 512, 12, 128), table)  # page size 12
-    assert not ok((8, 16, 128), (16, 512, 16, 128), (8, 6))  # 6 % 4 pages
     assert not ok((8, 16, 128), (3, 512, 16, 128), table)   # 16 % 3 heads
+    assert not ok((8, 16, 128), (16, 512, 16, 128), table, jnp.int8)
+    # the round is derived (1 MiB of a page of K plus V, a power of two)
+    # and must divide the table: 32 KB pages give 32, not a divisor of 48
+    assert ok((96, 20, 128), (4, 3456, 16, 128), (96, 32), bf16)
+    assert not ok((96, 20, 128), (4, 3456, 16, 128), (96, 48), bf16)
+    # every slot's queries and results in VMEM: 96 slots of 8 ungrouped
+    # heads of 512 float32 would take 50 MB
+    assert not ok((96, 8, 512), (8, 512, 16, 512), (96, 8))
     # off-TPU even an admitted shape takes the reference — counted, by rule
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.standard_normal((2, 4, 128)), jnp.float32)
@@ -126,37 +137,43 @@ def test_paged_attention_shape_rule():
 
 
 def test_paged_rule_matches_what_lowers_for_tpu(monkeypatch):
-    """The rule against the installed kernel, as far as a CPU host can see:
-    exporting for `platforms=["tpu"]` runs the Pallas->Mosaic lowering
-    (Mosaic's own compile, VMEM included, needs the chip). An admitted
-    shape lowers through `paged_attention`'s kernel branch; head dim 64
-    does not lower at all — the reason the rule sends it to the reference.
-    When a newer jax lowers it, this test says so: widen the rule then,
-    with a chip run."""
-    from jax.experimental.pallas.ops.tpu.paged_attention import (
-        paged_attention as kernel)
+    """The rule against the repo's head-pool kernel, as far as a CPU host
+    can see: exporting for `platforms=["tpu"]` runs the Pallas->Mosaic
+    lowering (Mosaic's own compile, VMEM included, needs the chip). The
+    admitted bfloat16 and float32 shapes, and a group of 5 (falcon's 20
+    query heads over 4 K/V heads), lower through `paged_attention`'s kernel
+    branch to ONE custom call named `head_decode_attention`, with whole
+    pools and the layer as a scalar; head dim 64 is not admitted and lowers
+    to no custom call."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-    def args(D, dtype):
-        return (jax.ShapeDtypeStruct((8, 8, D), dtype),
-                jax.ShapeDtypeStruct((8, 72, 16, D), dtype),
-                jax.ShapeDtypeStruct((8, 72, 16, D), dtype),
+    def args(H, Hkv, D, P, dtype):
+        return (jax.ShapeDtypeStruct((8, H, D), dtype),
+                jax.ShapeDtypeStruct((2, Hkv, 72, P, D), dtype),
+                jax.ShapeDtypeStruct((2, Hkv, 72, P, D), dtype),
                 jax.ShapeDtypeStruct((8, 8), jnp.int32),
                 jax.ShapeDtypeStruct((8,), jnp.int32))
 
+    def lowered(*shapes):
+        return jax.export.export(
+            jax.jit(lambda *a: paged_ops.paged_attention(*a, 0.1, layer=1)),
+            platforms=["tpu"])(*shapes).mlir_module()
+
     k0 = stat_get("STAT_paged_attn_kernel")
-    for dtype in (jnp.bfloat16, jnp.float32):
-        exp = jax.export.export(
-            jax.jit(lambda *a: paged_ops.paged_attention(*a, 0.1)),
-            platforms=["tpu"])(*args(128, dtype))
-        assert "tpu_custom_call" in exp.mlir_module()
-    assert stat_get("STAT_paged_attn_kernel") == k0 + 2
-    with pytest.raises(Exception, match="divisible by 8 and 128"):
-        jax.export.export(
-            jax.jit(lambda q, kp, vp, t, pos: kernel(
-                q, kp, vp, lengths=pos + 1, page_indices=t,
-                pages_per_compute_block=4)),
-            platforms=["tpu"])(*args(64, jnp.float32))
+    for H, Hkv, P, dtype in ((8, 8, 16, jnp.bfloat16), (8, 8, 8, jnp.float32),
+                             (20, 4, 16, jnp.bfloat16)):
+        a = args(H, Hkv, 128, P, dtype)
+        assert paged_ops.paged_kernel_supported(
+            a[0].shape, a[1].shape[1:], a[3].shape, dtype)
+        text = lowered(*a)
+        assert text.count("tpu_custom_call") == 1
+        assert "head_decode_attention" in text
+    assert stat_get("STAT_paged_attn_kernel") == k0 + 3
+    a = args(8, 8, 64, 16, jnp.float32)
+    assert not paged_ops.paged_kernel_supported(
+        a[0].shape, a[1].shape[1:], a[3].shape, jnp.float32)
+    assert "tpu_custom_call" not in lowered(*a)
+    assert stat_get("STAT_paged_attn_kernel") == k0 + 3
 
 
 def test_flash_gates_bound_the_vmem_resident_sequence():

@@ -222,13 +222,21 @@ def test_deadline_expiry_mid_decode_cancels_only_that_future(model):
     ids = _prompts()
     t0 = monitor.stat_get("STAT_gen_timeouts")
     e0 = monitor.stat_get("STAT_gen_evictions")
-    with _engine(model, num_pages=64) as eng:
-        fa = eng.submit(ids[0], max_new_tokens=40)          # no deadline
-        fb = eng.submit(ids[1], max_new_tokens=100, timeout_ms=60)
-        with pytest.raises(ExecutionTimeoutError):
-            fb.result(timeout=120)
-        out_a = fa.result(timeout=120)                      # unaffected
-        pages_after = eng.stats()["pages"]["pages_in_use"]
+    # (each step slowed by 5 ms: at the tiny model's ~0.5 ms a token B's
+    # 100 tokens could otherwise finish inside its 60 ms)
+    from paddle_tpu.serving import failpoints
+    paddle.set_flags({"FLAGS_failpoints": "slow_step_ms@every:1:5"})
+    try:
+        with _engine(model, num_pages=64) as eng:
+            fa = eng.submit(ids[0], max_new_tokens=40)      # no deadline
+            fb = eng.submit(ids[1], max_new_tokens=100, timeout_ms=60)
+            with pytest.raises(ExecutionTimeoutError):
+                fb.result(timeout=120)
+            out_a = fa.result(timeout=120)                  # unaffected
+            pages_after = eng.stats()["pages"]["pages_in_use"]
+    finally:
+        paddle.set_flags({"FLAGS_failpoints": ""})
+        failpoints.reset()
     assert out_a.shape == (47,)
     assert pages_after == 0                 # the cancel freed B's pages
     assert monitor.stat_get("STAT_gen_timeouts") > t0
@@ -333,11 +341,19 @@ def test_shutdown_drain_finishes_queued_work(model):
 def test_shutdown_no_drain_fails_fast(model):
     # five long requests: two decode for ~100 steps, three stay queued —
     # both classes must fail fast on drain=False, nothing may hang
+    # (each step slowed by 5 ms: the tiny model decodes ~0.5 ms a token,
+    # so 100 steps could otherwise finish before the shutdown)
+    from paddle_tpu.serving import failpoints
     ids = _prompts(n=5, seed=21)
     eng = _engine(model, num_pages=64, name="gen_nodrain")
-    futs = [eng.submit(p, max_new_tokens=100) for p in ids]
-    time.sleep(0.05)  # let the first admissions happen
-    eng.shutdown(drain=False, timeout_s=120)
+    paddle.set_flags({"FLAGS_failpoints": "slow_step_ms@every:1:5"})
+    try:
+        futs = [eng.submit(p, max_new_tokens=100) for p in ids]
+        time.sleep(0.05)  # let the first admissions happen
+        eng.shutdown(drain=False, timeout_s=120)
+    finally:
+        paddle.set_flags({"FLAGS_failpoints": ""})
+        failpoints.reset()
     for f in futs:
         with pytest.raises(UnavailableError):
             f.result(timeout=5)

@@ -80,6 +80,12 @@ def test_the_shape_rule_and_where_the_axes_are():
     layer = wide.layer_shape((4, 16, 128, 16, 128))
     assert layer == (16, 128, 16, 128)
     assert po.paged_kernel_supported((8, 16, 128), layer, (8, 64))
+    assert po.paged_kernel_supported((8, 16, 128), layer, (8, 64),
+                                     jnp.bfloat16)
+    # a split layer of 8-row pages is whole tiles of float32, not bfloat16
+    assert not po.paged_kernel_supported(
+        (8, 16, 128), wide.layer_shape((4, 16, 128, 8, 128)), (8, 64),
+        jnp.bfloat16)
     assert not po.paged_kernel_supported(
         (16, 25, 64), narrow.layer_shape((48, 128, 16, 1664)), (16, 64))
     # the head axis a tp mesh shards: of a pool, a scale pool, one page

@@ -156,6 +156,11 @@ def test_shape_rule_on_both_sides_of_the_gathers_extent():
     assert not ok((8, 16, 128), (16, 128, 16, 128), table)
     assert paged_ops.paged_kernel_supported((8, 16, 128), (16, 128, 16, 128),
                                             table)
+    # a shape the head-pool kernel refuses (bfloat16 pages of 8 rows are no
+    # whole sublane tile) is the pool path's, one K/V head per query head
+    assert not paged_ops.paged_kernel_supported(
+        (8, 16, 128), (16, 128, 8, 128), table, jnp.bfloat16)
+    assert ok((8, 16, 128), (16, 128, 8, 128), table, jnp.bfloat16)
     # grouped-query pools have no dense reference either
     assert not ok((8, 12, 64), (4, 128, 16, 64), table)
     path = paged_ops.paged_attention_path

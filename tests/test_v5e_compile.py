@@ -365,34 +365,25 @@ def test_the_latent_kernel_compiles_for_the_v5e_at_the_shapes_its_rule_admits(
 HYB_SLOTS, HYB_PAGE, HYB_PAGES, HYB_PP, HYB_L = 96, 16, 3456, 96, 2
 
 
-def test_hybrid_programs_compile_for_the_v5e_without_a_pool_copy(
-        one_chip, uncached, monkeypatch):
-    """The three programs of the hybrid family at the cell's shapes, through
-    the engine's jit boundary. Left to choose, the compiler keeps the
-    DEFAULT layout for all four pools — what the explicit head index of
-    `paged_ops._write_rows` and the K-major convolution window exist to make
-    true (with the head axis left as a scatter window it chose
-    `[L, N, P, H, D]` for K and V, and held to the default it copied both
-    340 MB pools in and out of every program; PR 36) — so no program copies
-    a pool. The state update is the Pallas kernel `ssm_decode_update`, one
-    custom call a layer over the pool in place; attention is JAX's paged
-    kernel over 4 K/V heads under 20 query heads. The rules ask the backend,
-    which is the CPU here: the test answers for it."""
+def hybrid_decode_program(one_chip, monkeypatch, L):
+    """The hybrid family's decode program at the cell's shapes and `L`
+    blocks, through the engine's jit boundary with the pools' layout left
+    to the compiler: (sds, W, pools, fns, lowered, compiled)."""
     import types
 
     from jax.experimental.layout import Format, Layout
 
-    from paddle_tpu.device import layout_name
     from paddle_tpu.models.falcon_h1 import FalconH1Config, fh1_weight_shapes
-    from paddle_tpu.ops import paged_ops, ssm_ops
+    from paddle_tpu.ops import latent_attention_kernel, ssm_ops
     from paddle_tpu.serving.decode_family import ProgramContext
     from paddle_tpu.serving.generation import (GenerationConfig, jit_program,
                                                with_step_inputs)
     from paddle_tpu.serving.hybrid_family import HybridFamily
 
     monkeypatch.setattr(ssm_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(latent_attention_kernel, "_interpret", lambda: False)
     monkeypatch.setattr(ssm_ops.jax, "default_backend", lambda: "tpu")
-    cfg = FalconH1Config(num_hidden_layers=HYB_L)
+    cfg = FalconH1Config(num_hidden_layers=L)
     fam = HybridFamily(types.SimpleNamespace(config=cfg))
     ecfg = GenerationConfig(max_slots=HYB_SLOTS, page_size=HYB_PAGE,
                             num_pages=HYB_PAGES, pages_per_seq=HYB_PP,
@@ -404,7 +395,7 @@ def test_hybrid_programs_compile_for_the_v5e_without_a_pool_copy(
 
     W = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
                                fh1_weight_shapes(cfg))
-    L, M = HYB_L, HYB_SLOTS
+    M = HYB_SLOTS
     pools = (sds((L, 4, HYB_PAGES, HYB_PAGE, 128), "bfloat16"),
              sds((L, 4, HYB_PAGES, HYB_PAGE, 128), "bfloat16"),
              sds((L, M, 32, 128, 256), "float32"),
@@ -414,11 +405,34 @@ def test_hybrid_programs_compile_for_the_v5e_without_a_pool_copy(
     assert fam.describe(ecfg, pools) == {"ssm_decode_path": "kernel"}
     fns = fam.build(ProgramContext(ecfg, 1, None, 4, False, path, W, {}))
     key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
-    decode = jit_program(
+    lowered = jit_program(
         with_step_inputs(fns["decode"]), "decode",
         tuple(Format(Layout.AUTO, one_chip) for _ in pools),
-        counters=True).lower(
-        W, *pools, *step_inputs(sds, M, HYB_PP, key)).compile()
+        counters=True).lower(W, *pools, *step_inputs(sds, M, HYB_PP, key))
+    return sds, W, pools, fns, lowered, lowered.compile()
+
+
+def test_hybrid_programs_compile_for_the_v5e_without_a_pool_copy(
+        one_chip, uncached, monkeypatch):
+    """The three programs of the hybrid family at the cell's shapes, through
+    the engine's jit boundary. Left to choose, the compiler keeps the
+    DEFAULT layout for all four pools — what the explicit head index of
+    `paged_ops._write_rows` and the K-major convolution window exist to make
+    true (with the head axis left as a scatter window it chose
+    `[L, N, P, H, D]` for K and V, and held to the default it copied both
+    340 MB pools in and out of every program; PR 36) — so no program copies
+    a pool. The state update is the Pallas kernel `ssm_decode_update`, one
+    custom call a layer over the pool in place; attention is the repo's
+    head-pool kernel `head_decode_attention` over 4 K/V heads under 20
+    query heads (JAX's paged kernel until PR 37). The rules ask the
+    backend, which is the CPU here: the test answers for it."""
+    from paddle_tpu.device import layout_name
+    from paddle_tpu.serving.generation import jit_program
+
+    L = HYB_L
+    sds, W, pools, fns, _, decode = hybrid_decode_program(one_chip,
+                                                          monkeypatch, L)
+    M = HYB_SLOTS
     fmts = tuple(decode.input_formats[0][1:5])
     assert fmts == tuple(decode.output_formats[:4])
     assert [layout_name(f, p.shape, p.dtype)
@@ -442,7 +456,10 @@ def test_hybrid_programs_compile_for_the_v5e_without_a_pool_copy(
              if "tpu_custom_call" in ln and "ssm_decode_update" in ln]
     assert len(calls) == HYB_L
     assert all("/ssm/state_update" in ln for ln in calls)
-    assert "paged_attn_kernel" in text
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "head_decode_attention" in ln]
+    assert len(calls) == HYB_L
+    assert all("/attn/attend" in ln for ln in calls)
     ptext = prefill.as_text()
     for scope in ("layer_1/ssm/scan", "layer_1/ssm/conv", "state_write",
                   "layer_1/attn/attend"):
@@ -457,3 +474,23 @@ def test_hybrid_programs_compile_for_the_v5e_without_a_pool_copy(
     # 0.21 GB at six layers (the step's activations and a layer's slices)
     assert decode.memory_analysis().temp_size_in_bytes < 256 << 20
     assert prefill.memory_analysis().temp_size_in_bytes < 320 << 20
+
+
+def test_the_head_kernel_is_traced_once_for_the_six_layers(
+        one_chip, uncached, monkeypatch):
+    """The cell's decode program at its six blocks (PR 37): ONE lowered
+    body of `head_decode_attention` (a `jax.jit` of its own, the layer a
+    scalar operand: set-up time is an end-to-end metric), six custom calls
+    over the whole pools in place, no pool copied at the boundary, and
+    temporaries no larger than JAX's paged kernel left them (213.5 MB at
+    the parent; 214.8 MB here, the padded queries and results)."""
+    _, _, _, _, lowered, decode = hybrid_decode_program(one_chip,
+                                                        monkeypatch, 6)
+    assert lowered.as_text().count("head_decode_attention") == 1
+    text = decode.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "head_decode_attention" in ln]
+    assert len(calls) == 6
+    assert not [ln for ln in text.splitlines() if " copy(" in ln
+                and f"{HYB_PAGES},{HYB_PAGE},128]" in ln]
+    assert decode.memory_analysis().temp_size_in_bytes < 216e6
