@@ -87,6 +87,27 @@ oldest overwritten — the same bounding discipline as the trace rings):
                   the step before it was still unread (ISSUE 34): its
                   tokens went from the device's output straight into
                   this step's input, and the host's work ran under it
+    decode_dev_ms / prefill_dev_ms / dev_idle_ms / dev_idle_by
+                  the engine's own timeline of the device
+                  (serving/device_clock.py): each timed program's `enq`
+                  (its dispatch returned) and `done` (the earlier of a
+                  watcher thread's stamp and the read-back's return), on
+                  the clock of these records. A program occupies the
+                  device from the later of its `enq` and the `done` before
+                  to its own `done`, charged to the record that READ it
+                  (decode and verify steps to decode_dev_ms, prefills to
+                  prefill_dev_ms); where its `enq` is later than the
+                  `done` before, the device sat idle in between, charged
+                  to the record of the iteration that launched it, and
+                  dev_idle_by cuts that idle by the step thread's
+                  innermost `generation::` scope (bucket suffix dropped,
+                  `none` outside any; with the trace ring off, by the
+                  engine's host bucket at the launch, `attr_admit_ms` /
+                  `attr_bookkeep_ms`); its rounded values sum to
+                  dev_idle_ms. Over consecutive records the three times
+                  tile the span from the first `enq` to the last `done`.
+                  They read the device, not the host: decode_ms and the
+                  attribution buckets keep their meaning
 
 The fit loop has a record of its own (`FitRecord`, ISSUE 25): one per
 train step into ONE process-wide `FitLog` ring of the same kind, read
@@ -187,7 +208,14 @@ _FIELDS = ("it", "step", "t", "live", "admitted", "completed", "expired",
            # families), and the real prompt tokens of the iteration's
            # prefill programs, counted by the engine for every family —
            # appended, by the same era rule
-           "state_slots", "kv_rows", "prefill_tokens")
+           "state_slots", "kv_rows", "prefill_tokens",
+           # the engine's own timeline of the device
+           # (serving/device_clock.py): the device time of the decode /
+           # verify programs and of the prefill programs the iteration
+           # READ, and the device idle that closed at one of its launches
+           # with that idle by the step thread's scope ({} by default) —
+           # appended, by the same era rule
+           "decode_dev_ms", "prefill_dev_ms", "dev_idle_ms", "dev_idle_by")
 
 _FIT_FIELDS = ("fit", "step", "t", "input_wait_ms", "prep_ms",
                "dispatch_ms", "sync_ms", "callback_ms", "other_ms",
@@ -217,6 +245,11 @@ class StepRecord(_Record):
     """One engine iteration's scheduler state."""
 
     __slots__ = _FIELDS
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        if not self.dev_idle_by:
+            self.dev_idle_by = {}
 
 
 class FitRecord(_Record):

@@ -128,6 +128,9 @@ def _active() -> bool:
     return _profiler_enabled or bool(flag("FLAGS_flight_recorder"))
 
 
+recording = _active     # public: whether scopes are being kept
+
+
 # -- recording -------------------------------------------------------------
 
 def record_complete(name: str, t0: float, t1: float) -> None:
@@ -244,6 +247,31 @@ def tail_events(n: int):
                         r.thread_name))
     out.sort(key=lambda e: e[3])  # by scope end time
     return out[-n:] if n > 0 else out
+
+
+def own_scopes(since: float, prefix: str = "") -> List[Tuple[str, float,
+                                                              float]]:
+    """The CALLING thread's closed scopes named `prefix`… that end at or
+    after `since`, as (name, t0, t1), newest first. A scope is appended
+    when it closes, so the ring is in the order of the scopes' ends and the
+    walk stops at the first one that ended before `since`: cheap where
+    `since` is recent, whatever the ring's size."""
+    r = _local.ring
+    if r is None:
+        return []
+    buf = r.buf
+    n = len(buf)
+    newest = (r.idx if n == r.cap else 0) - 1
+    out = []
+    for k in range(n):
+        name, ph, t0, t1 = buf[(newest - k) % n]
+        if ph != "X":
+            continue
+        if t1 < since:
+            break
+        if name.startswith(prefix):
+            out.append((name, t0, t1))
+    return out
 
 
 def counter_samples(since: Optional[float] = None):

@@ -126,6 +126,7 @@ from ..profiler import (RecordEvent, audit, device_telemetry, exporter,
                         timeseries, trace_context)
 from . import failpoints
 from .decode_family import ProgramContext, family_of
+from .device_clock import DeviceClock
 from .kv_cache import TRASH_PAGE
 from .kv_tier import HostTier
 from .prefix_cache import PrefixCache
@@ -567,13 +568,15 @@ class _Flight:
     and once its end has been observed the same on the host with the
     step's own time."""
 
-    __slots__ = ("owners", "outs", "host", "ahead", "decode_ms", "wait_ms")
+    __slots__ = ("owners", "outs", "host", "ahead", "decode_ms", "wait_ms",
+                 "launch")
 
-    def __init__(self, owners, outs, ahead):
+    def __init__(self, owners, outs, ahead, launch):
         self.owners = owners        # per slot: the request, or None
         self.outs = outs            # (next tokens, poison flags[, counters])
         self.host = None            # the same as numpy, once observed
         self.ahead = ahead          # launched with the step before unread
+        self.launch = launch        # its device-clock stamps, or None
         self.decode_ms = 0.0        # launch (or the end of the program
         #                             before it) to its own observed end
         self.wait_ms = 0.0          # of it, blocked in the read-back
@@ -808,6 +811,9 @@ class GenerationEngine:
         self._cursor = time.perf_counter()
         self._unobserved = 0
         self._phase = "attr_bookkeep_ms"
+        # the device's own timeline (`device_clock.py`): started with the
+        # step thread where the step ring is on, None otherwise
+        self._devclock: Optional[DeviceClock] = None
         # degraded modes (ISSUE 15): detector knobs snapshotted at
         # construction (a runtime flag flip must not flip speculation
         # onto an un-warmed program); the spec-off verdict itself rides
@@ -865,6 +871,8 @@ class GenerationEngine:
                 import gc
                 gc.collect()
                 gc.freeze()
+            if self._step_log is not None:
+                self._devclock = DeviceClock(name)
             self._thread = threading.Thread(
                 target=self._loop, daemon=True, name=f"{name}-genstep")
             self._thread.start()
@@ -878,6 +886,8 @@ class GenerationEngine:
             #                                   for supervised engines
             if self._step_log is not None:
                 step_log.unregister(self._step_log)
+            if self._devclock is not None:
+                self._devclock.stop()
             raise
 
     # -- jitted programs ---------------------------------------------------
@@ -1561,6 +1571,15 @@ class GenerationEngine:
         self._unobserved -= 1
         return ms
 
+    def _dispatched(self, kind: str, out):
+        """A timed program's dispatch has just returned, so it is in the
+        device's queue: the device clock's stamps for it, or None with the
+        clock off. `out` is an output only the host reads (never a donated
+        pool), which the clock's watcher waits on."""
+        clock = self._devclock
+        return None if clock is None else clock.launched(kind, out,
+                                                         self._phase)
+
     def _idle_wait(self, timeout: Optional[float]):
         """Wait on the engine's condition (held) with nothing in flight:
         the wait is the idle bucket's."""
@@ -1631,6 +1650,11 @@ class GenerationEngine:
                 #         handled — no stderr traceback for a recovery
                 #         that worked
             raise
+        finally:
+            # however the loop ends, nothing is launched after it: the
+            # device clock's watcher ends too (`shutdown()` joins it)
+            if self._devclock is not None:
+                self._devclock.stop()
 
     def _record_iteration(self):
         """One compact scheduler record per engine iteration (ISSUE 11):
@@ -1691,6 +1715,13 @@ class GenerationEngine:
                        + it["attr_admit_ms"] + it["attr_bookkeep_ms"], 3)
         a_book = (a_wall - a_idle - a_admit - a_prefill - a_promote
                   - a_decode)
+        # the device's own timeline (`device_clock.py`): the device time of
+        # the programs this iteration READ, and the idle that closed at a
+        # launch of this iteration, by the step thread's scope; the parts
+        # are rounded first and the idle is their sum
+        dev, idle = self._devclock.close()
+        idle_by = {k: round(s * 1000.0, 3) for k, s in idle.items()}
+        idle_by = {k: ms for k, ms in idle_by.items() if ms > 0}
         rec = step_log.StepRecord(
             it=self._iters, step=self._steps_total,
             t=time.perf_counter(), live=live,
@@ -1726,6 +1757,10 @@ class GenerationEngine:
             # step before it still unread (ISSUE 34)
             ahead=it["ahead"],
             prefill_tokens=it["prefill_tokens"],
+            decode_dev_ms=round(dev["decode"] * 1000.0, 3),
+            prefill_dev_ms=round(dev["prefill"] * 1000.0, 3),
+            dev_idle_ms=round(sum(idle_by.values(), 0.0), 3),
+            dev_idle_by=idle_by,
             # what the family's decode program counted on the device
             **{name: it[name] for name in self._family.step_counters})
         self._step_log.record(rec)
@@ -2156,11 +2191,12 @@ class GenerationEngine:
             live.append(req)
         self._queue = live
 
-    def _read_back(self, kind: str, *outs):
+    def _read_back(self, kind: str, launch, *outs):
         """The blocking read of a timed program's host outputs (`kind`:
-        "prefill", or "decode" for the verify step) — with `_observe`,
-        which reads the decode step in flight, the only places the step
-        thread waits for the chip. The blocked time goes to this
+        "prefill", or "decode" for the verify step; `launch`: its device
+        clock stamps, which the read's return completes) — with
+        `_observe`, which reads the decode step in flight, the only places
+        the step thread waits for the chip. The blocked time goes to this
         iteration's `<kind>_wait_ms`, the program's own time (`_program_
         ended`) to `<kind>_ms`, of which the wait is a sub-split. A
         program launched BEHIND a decode step in flight ends after it: that
@@ -2176,6 +2212,8 @@ class GenerationEngine:
         # launches where the chip would wait for the wake-ups
         self._flush_released()
         host = [np.asarray(o) for o in outs]
+        if launch is not None:
+            launch.read = time.perf_counter()
         self._it[f"{kind}_wait_ms"] += _now_ms() - t0
         self._it[f"{kind}_ms"] += self._program_ended()
         return host if len(host) > 1 else host[0]
@@ -2189,6 +2227,8 @@ class GenerationEngine:
         self._flush_released()      # as in `_read_back`: the chip is busy
         with RecordEvent("generation::read"):
             fl.host = [np.asarray(o) for o in fl.outs]
+            if fl.launch is not None:
+                fl.launch.read = time.perf_counter()
         fl.wait_ms = _now_ms() - t0
         fl.decode_ms = self._program_ended()
 
@@ -2198,6 +2238,8 @@ class GenerationEngine:
         fl, self._flight = self._flight, None
         if fl is not None and fl.host is None:
             self._unobserved -= 1
+            if fl.launch is not None:
+                self._devclock.dropped(fl.launch)
 
     def _slot_arg(self, slot) -> tuple:
         """The prefill program's last argument for a family that keeps
@@ -2240,8 +2282,9 @@ class GenerationEngine:
                     out = self._tail_jit(
                         self._W, *self._pools(), req.pt_row, ids,
                         np.int32(tail), np.int32(pfx))
+                launch = self._dispatched("prefill", out[-1])
                 self._set_pools(out[:-1])
-                lg = self._read_back("prefill", out[-1])
+                lg = self._read_back("prefill", launch, out[-1])
         else:
             bucket = self._bucket_for(S)
             ids = np.zeros((1, bucket), np.int32)
@@ -2252,8 +2295,9 @@ class GenerationEngine:
                     out = self._prefill_jit(
                         self._W, *self._pools(), req.pt_row, ids,
                         np.int32(S), *self._slot_arg(req.slot))
+                launch = self._dispatched("prefill", out[-1])
                 self._set_pools(out[:-1])
-                lg = self._read_back("prefill", out[-1])
+                lg = self._read_back("prefill", launch, out[-1])
         # real prompt tokens through a prefill program, whatever the family
         self._it["prefill_tokens"] += tail
         if not np.all(np.isfinite(lg)):
@@ -2427,8 +2471,9 @@ class GenerationEngine:
                 out = self._tail_jit(
                     self._W, *self._pools(), req.pt_row, ids,
                     np.int32(take), np.int32(req.prefill_pos))
+            launch = self._dispatched("prefill", out[-1])
             self._set_pools(out[:-1])
-            lg = self._read_back("prefill", out[-1])
+            lg = self._read_back("prefill", launch, out[-1])
         self._it["prefill_chunks"] += 1
         self._it["prefill_tokens"] += take
         self._chunks_total += 1
@@ -2666,14 +2711,16 @@ class GenerationEngine:
         self._program_launched()
         with RecordEvent(self._step_span):
             out = self._decode_call(self._W, *self._pools(), *args)
-        NP = self._npool
-        # (*pools, next tokens, poison flags[, the family's counters])
+            NP = self._npool
+            # (*pools, next tokens, poison flags[, the family's counters])
+            launch = self._dispatched("decode", out[NP + 1])
         self._set_pools(out[:NP])
         for req in owners:
             if req is not None:
                 req.next_pos += 1
         self._prev = (out[NP], owners)
-        self._flight = fl = _Flight(owners, out[NP:], flight is not None)
+        self._flight = fl = _Flight(owners, out[NP:], flight is not None,
+                                    launch)
         self._steps_total += 1
         monitor.stat_add("STAT_gen_steps")
         if fl.ahead:
@@ -2746,7 +2793,8 @@ class GenerationEngine:
         self._program_launched()
         with RecordEvent(f"generation::verify[k={self._spec_k}]"):
             out = self._verify_call(self._W, *self._pools(), *args)
-            n_acc, nxt, bad = self._read_back("decode", out[-3],
+            launch = self._dispatched("decode", out[-1])
+            n_acc, nxt, bad = self._read_back("decode", launch, out[-3],
                                               out[-2], out[-1])
         if failpoints.fire("decode_poison_nan") is not None:
             bad = self._inject_poison(bad)
@@ -3208,6 +3256,11 @@ class GenerationEngine:
         t = getattr(self, "_thread", None)
         if t is not None:
             t.join(timeout_s)
+        if self._devclock is not None:
+            # the loop stopped it on its way out; told again in case the
+            # loop outlived the join
+            self._devclock.stop()
+            self._devclock.join(timeout_s)
         exporter.unregister_engine(self)
         if self._step_log is not None:
             step_log.unregister(self._step_log)

@@ -111,16 +111,19 @@ def tiny_engine(gpt, name, **kw):
 
 
 def test_new_step_fields_are_appended_after_the_old():
-    # PR 36's three (the hybrid family's two device counters, the engine's
-    # prefill tokens) after PR 34's `ahead` after PR 27's two device counters
-    # after PR 25's three waits, each era appended to the one before
-    assert step_log._FIELDS[-3:] == ("state_slots", "kv_rows",
-                                     "prefill_tokens")
-    assert step_log._FIELDS[-4] == "ahead"
-    assert step_log._FIELDS[-6:-4] == ("experts_hit", "latent_rows")
-    assert step_log._FIELDS[-9:-6] == ("decode_wait_ms", "prefill_wait_ms",
-                                       "admit_wait_ms")
-    assert step_log._FIELDS[-10] == "attr_wall_ms"
+    # the device timeline's four after the hybrid family's three (its two
+    # device counters, the engine's prefill tokens) after `ahead` after the
+    # latent family's two device counters after the three waits, each era
+    # appended to the one before
+    assert step_log._FIELDS[-4:] == ("decode_dev_ms", "prefill_dev_ms",
+                                     "dev_idle_ms", "dev_idle_by")
+    assert step_log._FIELDS[-7:-4] == ("state_slots", "kv_rows",
+                                       "prefill_tokens")
+    assert step_log._FIELDS[-8] == "ahead"
+    assert step_log._FIELDS[-10:-8] == ("experts_hit", "latent_rows")
+    assert step_log._FIELDS[-13:-10] == ("decode_wait_ms", "prefill_wait_ms",
+                                         "admit_wait_ms")
+    assert step_log._FIELDS[-14] == "attr_wall_ms"
     assert list(step_log.StepRecord().to_dict()) == list(step_log._FIELDS)
 
 

@@ -253,6 +253,38 @@ def test_metadata_and_run_ids_come_from_one_pass_over_the_wire_format(
     assert tr.read_wire(str(path), "/device:TPU:9") == ({}, {}, launches)
 
 
+def test_the_await_lag_pairs_each_timed_run_with_the_wait_for_it():
+    """The engine's watcher ends `generation::await` 0.05-2 ms after each
+    run's true end (launch + 8.3 ms on the host's clock); the trace's offset
+    is 0.95 ms where the true one is 1 ms, so each lag reads 0.05 ms long,
+    and the completion callbacks, seen 0.1 ms after the end, read 0.1 ms
+    short. A wait for a run before the trace and an untimed program are
+    paired with nothing."""
+    pl, stacks, ids, stamped = planes(offset_ms=1.0, runs=4)
+    modules = pl[1]["lines"][0]["events"]
+    lags = (0.05, 0.1, 0.15, 2.0)
+    awaits = [(tr.AWAIT, 0.0, 9 * MS)]      # a wait for a run before
+    for (n, s, e), lag in zip(list(modules), lags):
+        awaits.append((tr.AWAIT, s, e + 1 * MS + lag * MS))
+        modules.append(("jit_gen_zero_pages(3)", e, e + 0.1 * MS))
+    pl[0]["lines"].append({"name": "python", "events": awaits})
+    r = tr.reduce(pl, stacks, ids, stamped)
+    a = r["await_lag_ms"]
+    assert set(a) == {"gen_decode"} and a["gen_decode"]["runs"] == 4
+    assert a["gen_decode"]["median"] == pytest.approx(0.175)
+    assert a["gen_decode"]["p95"] == pytest.approx(0.2 + 0.85 * 1.85)
+    assert a["gen_decode"]["complete_median"] == pytest.approx(0.025)
+    text = tr.render(r)
+    assert "jit_gen_decode x4: device median 0.175" in text
+    # the first run's wait began before the trace did: that run is left
+    # out, and the others keep their own waits
+    del awaits[1]
+    a = tr.reduce(pl, stacks, ids, stamped)["await_lag_ms"]["gen_decode"]
+    assert a["runs"] == 3 and a["median"] == pytest.approx(0.2)
+    # a trace without the engine's watcher prints no such table
+    assert tr.reduce(*planes())["await_lag_ms"] == {}
+
+
 def test_a_trace_without_a_chip_is_refused_by_name(tmp_path):
     import jax
     import jax.numpy as jnp

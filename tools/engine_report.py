@@ -28,7 +28,12 @@ records predating the field read as single-chip), and
 prefill-vs-decode wall, and the per-iteration goodput attribution
 (ISSUE 20: idle/wall columns plus a per-incarnation "where did the
 milliseconds go" rollup — admit / prefill / promote / decode /
-bookkeep / idle tile each iteration's wall exactly) — then the audit
+bookkeep / idle tile each iteration's wall exactly), and the engine's
+own timeline of the device (dev/dev_idle columns: device time of the
+programs the iteration read, and device idle that closed at one of its
+launches; a per-incarnation "device idle by span" rollup of
+`dev_idle_by`, which says what the step thread was doing while the chip
+waited) — then the audit
 tail with reason codes (per request: ADMIT_PREFIX_HIT carries
 prefix_tokens, COW_SPLIT the split pages), so "why did this request
 wait/die" reads straight off the artifact. Records predating
@@ -117,7 +122,34 @@ def summarize(records: List[dict]) -> dict:
         "decode_ms_total": round(sum(r.get("decode_ms", 0.0)
                                      for r in records), 3),
         "goodput": goodput(records),
+        "device": device_idle(records),
     }
+
+
+def device_idle(records: List[dict]) -> dict:
+    """Per-incarnation device rollup from the engine's own timeline of the
+    device: device time of the programs read, device idle, and that idle
+    by the step thread's scope at the time (dev_idle_by). {} when no record
+    carries the timeline (older records, or FLAGS_gen_step_log off)."""
+    by_inc: dict = {}
+    for r in records:
+        if "dev_idle_ms" not in r:
+            continue
+        d = by_inc.setdefault(r.get("incarnation", 0),
+                              {"dev_ms": 0.0, "idle_ms": 0.0, "wall_ms": 0.0,
+                               "idle_by": {}})
+        d["dev_ms"] += r.get("decode_dev_ms", 0.0) + r.get("prefill_dev_ms",
+                                                           0.0)
+        d["idle_ms"] += r["dev_idle_ms"]
+        d["wall_ms"] += r.get("attr_wall_ms", 0.0) or 0.0
+        for span, ms in (r.get("dev_idle_by") or {}).items():
+            d["idle_by"][span] = d["idle_by"].get(span, 0.0) + ms
+    for d in by_inc.values():
+        for k in ("dev_ms", "idle_ms", "wall_ms"):
+            d[k] = round(d[k], 3)
+        d["idle_by"] = {k: round(v, 3) for k, v in sorted(
+            d["idle_by"].items(), key=lambda kv: -kv[1])}
+    return by_inc
 
 
 # goodput-attribution buckets (ISSUE 20): label -> StepRecord field.
@@ -222,6 +254,16 @@ def render(name: str, eng: dict, last: int = 0,
                 for label, _ in ATTR_BUCKETS)
             print(f"   goodput inc {inc}: wall "
                   f"{d.get('wall_ms', 0.0):.1f}ms — {pct}", file=out)
+        # the device's own timeline: how long the chip sat idle, and what
+        # the step thread was doing meanwhile
+        for inc, d in sorted(summ.get("device", {}).items()):
+            wall = max(d["wall_ms"], 1e-9)
+            spans = ", ".join(
+                f"{span} {100.0 * ms / max(d['idle_ms'], 1e-9):.1f}%"
+                for span, ms in d["idle_by"].items()) or "-"
+            print(f"   device inc {inc}: busy {d['dev_ms']:.1f}ms, idle "
+                  f"{d['idle_ms']:.1f}ms ({100.0 * d['idle_ms'] / wall:.1f}%"
+                  f" of wall) — device idle by span: {spans}", file=out)
         hdr = (f"   {'inc':>3} {'tp':>2} {'it':>6} {'step':>6} "
                f"{'slots':<10} "
                f"{'adm':>3} "
@@ -230,9 +272,10 @@ def render(name: str, eng: dict, last: int = 0,
                f"{'pfx':>4} {'cow':>3} {'dem':>3} {'pro':>3} "
                f"{'tok':>4} {'acc':>4} "
                f"{'chk':>3} {'prefill':>8} {'decode':>8} "
-               f"{'idle':>8} {'wall':>8}")
+               f"{'idle':>8} {'wall':>8} {'dev':>8} {'dev_idle':>8}")
         print(hdr, file=out)
         for r in records:
+            dev = r.get("decode_dev_ms", 0.0) + r.get("prefill_dev_ms", 0.0)
             print(f"   {r.get('incarnation', 0):>3} "
                   f"{r.get('tp', 0) or 1:>2} "
                   f"{r.get('it', 0):>6} {r.get('step', 0):>6} "
@@ -256,7 +299,9 @@ def render(name: str, eng: dict, last: int = 0,
                   f"{r.get('prefill_ms', 0.0):>7.1f}ms "
                   f"{r.get('decode_ms', 0.0):>7.1f}ms "
                   f"{r.get('attr_idle_ms', 0.0) or 0.0:>7.1f}ms "
-                  f"{r.get('attr_wall_ms', 0.0) or 0.0:>7.1f}ms",
+                  f"{r.get('attr_wall_ms', 0.0) or 0.0:>7.1f}ms "
+                  f"{dev:>7.1f}ms "
+                  f"{r.get('dev_idle_ms', 0.0):>7.1f}ms",
                   file=out)
     audit = eng.get("audit", [])
     if last > 0:

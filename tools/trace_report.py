@@ -24,7 +24,14 @@ For the first chip of the newest trace under <trace_dir> it prints
              innermost program span (`fit::`, `feeder::`, `generation::`)
              that covers each gap's midpoint — a span of the thread that
              launches the programs first, any other thread's second —
-             else `no program span`.
+             else `no program span`;
+  await      by program the engine times (`jit_gen_decode`,
+             `jit_gen_prefill`, ...), the median and p95 of how far the
+             end of each `generation::await` span — the generation
+             engine's own stamp of that program's end — lies after the
+             device's end of the run (plus the clock offset) and after
+             the run's `CompleteCallbacks`: how late the engine's device
+             clock reads (paddle_tpu/serving/device_clock.py).
 
 The device's events read earlier than the host span that launched them
 (1.3 ms in one trace, 5.5 ms in another: PERF.md); no program run starts
@@ -74,6 +81,12 @@ ENQUEUE, COMPLETE = "DoEnqueueProgram", "CompleteCallbacks"
 # one whose spans a gap is laid to first
 LAUNCH_SPANS = ("fit::train_step", "generation::step[",
                 "generation::verify[", "generation::prefill")
+# the engine's watcher waits for each program it times under this span,
+# whose end is the engine's stamp of the program's end
+# (paddle_tpu/serving/device_clock.py)
+AWAIT = "generation::await"
+TIMED = ("gen_decode", "gen_verify", "gen_prefill", "gen_prefill_tail")
+RUN_MATCH_NS = 1000     # a run's start as `load` and the wire pass read it
 _WRAPPER = re.compile(r"^p?jit\((.*)\)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CUSTOM_CALL = re.compile(r"[\]})] custom-call\(")
@@ -213,6 +226,53 @@ def program_of(module_event):
     return name[4:] if name.startswith("jit_") else name
 
 
+def await_lags(modules, awaits, runs, launches, offset):
+    """How late the engine's watcher saw each timed program end: for every
+    run of a program the engine times (TIMED), the end of the
+    `generation::await` span that waited for it less the run's end on the
+    device (shifted by the clock offset, which is a lower bound: the lag is
+    then an upper bound) and less the start of the run's own
+    `CompleteCallbacks` (paired by run_id). The watcher waits for the
+    programs one at a time in launch order and no wait ends before its
+    program has, so a run takes the first wait not yet taken that ends at
+    or after it — and before the next timed run ends: a run whose wait
+    began before the trace did has none, and is left out.
+    {program: {"device": [ms], "complete": [ms]}}."""
+    import bisect
+    starts = sorted((s, rid) for rid, (s, _) in runs.items())
+    complete = {}
+    for n, rid, s, _ in launches:
+        if n == COMPLETE:
+            complete[rid] = min(s, complete.get(rid, s))
+    ends = sorted(e for _, _, e in awaits)
+    timed = sorted((e, s, program_of(n)) for n, s, e in modules
+                   if program_of(n) in TIMED)
+    out, i = {}, 0
+    for k, (e, s, prog) in enumerate(timed):
+        i = bisect.bisect_left(ends, e + offset, i)
+        if i == len(ends):
+            break
+        if k + 1 < len(timed) and ends[i] >= timed[k + 1][0] + offset:
+            continue
+        row = out.setdefault(prog, {"device": [], "complete": []})
+        row["device"].append((ends[i] - e - offset) / 1e6)
+        j = bisect.bisect_left(starts, (s - RUN_MATCH_NS,))
+        if j < len(starts) and abs(starts[j][0] - s) <= RUN_MATCH_NS \
+                and starts[j][1] in complete:
+            row["complete"].append(
+                (ends[i] - complete[starts[j][1]]) / 1e6)
+        i += 1
+    return out
+
+
+def _spread(ms):
+    """(median, p95) of a list of ms, or (None, None)."""
+    if not ms:
+        return None, None
+    import numpy as np
+    return float(np.median(ms)), float(np.percentile(ms, 95))
+
+
 def reduce(planes, metadata, runs, launches):
     """The report's numbers from `load`'s planes and `read_wire`'s three."""
     devices = sorted((p for p in planes
@@ -290,7 +350,15 @@ def reduce(planes, metadata, runs, launches):
         by_span[label] = by_span.get(label, 0.0) + (e - s)
         named += (e - s) if inside else 0.0
     idle_long = sum(e - s for s, e in long_gaps)
+    lags = await_lags(modules, [ev for ev in host if ev[0] == AWAIT],
+                      runs, launches, offset)
     return {
+        "await_lag_ms": {
+            prog: dict(zip(("runs", "median", "p95", "complete_median",
+                            "complete_p95"),
+                           (len(row["device"]), *_spread(row["device"]),
+                            *_spread(row["complete"]))))
+            for prog, row in lags.items()},
         "chip": chip["name"], "window_ms": (hi - lo) / 1e6,
         "busy_ms": busy / 1e6, "idle_share": 1.0 - busy / (hi - lo),
         "clock_offset_ms": offset / 1e6, "offset_pairs": pairs,
@@ -353,6 +421,16 @@ def render(r):
         g["by_span_ms"], g["idle_ms"])
     out.append(f"  under a program span: {100 * g['named_share']:.1f}% of "
                f"the idle time in these gaps")
+    if r["await_lag_ms"]:
+        out += ["", f"`{AWAIT}`: its end after the end of the program run "
+                "it waited for (device end + clock offset), and after the "
+                "run's CompleteCallbacks, ms"]
+        for prog, a in sorted(r["await_lag_ms"].items()):
+            cc = ("no CompleteCallbacks paired" if a["complete_median"]
+                  is None else f"CompleteCallbacks median "
+                  f"{a['complete_median']:.3f} p95 {a['complete_p95']:.3f}")
+            out.append(f"  jit_{prog} x{a['runs']}: device median "
+                       f"{a['median']:.3f} p95 {a['p95']:.3f}; {cc}")
     return "\n".join(out)
 
 
