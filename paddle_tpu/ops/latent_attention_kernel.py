@@ -244,13 +244,13 @@ _HEAD_BLOCK_BYTES = 1 << 20
 
 
 def head_block_pages(page_size, kv_heads, head_dim, itemsize,
-                     table_width) -> int:
+                     table_width, block_bytes=_HEAD_BLOCK_BYTES) -> int:
     """Pages one round of copies moves: what of a page of K plus V fits
-    `_HEAD_BLOCK_BYTES`, rounded down to a power of two, and no more than
-    the table holds. `paged_ops.paged_kernel_supported` asks that it
-    divide the table's width."""
+    `block_bytes`, rounded down to a power of two, and no more than the
+    table holds. `paged_ops.paged_kernel_supported` asks that it divide
+    the table's width."""
     page = 2 * kv_heads * page_size * head_dim * itemsize
-    fit = max(1, _HEAD_BLOCK_BYTES // page)
+    fit = max(1, block_bytes // page)
     return min(1 << (fit.bit_length() - 1), int(table_width))
 
 
@@ -394,3 +394,185 @@ def head_decode_attention(q, k_pool, v_pool, page_table, lengths, scale,
     return _head_call(q, k_pool, v_pool, page_table, lengths,
                       jnp.full((1,), layer, jnp.int32), scale=float(scale),
                       block_pages=int(block_pages), interpret=_interpret())
+
+
+# -- fused head pools ----------------------------------------------------------
+#
+# The same page walk over fused head pools `[L, N, P, row]`: a token's H
+# heads of D lanes side by side in one row of whole 128-lane tiles
+# (`ops/paged_ops.HeadPoolForm`; gpt2-xl: 25 heads of 64 in 1,664 lanes),
+# the decode attention of `paged_ops.paged_attention` where its rule
+# `paged_row_kernel_supported` admits the shapes. Written below the two
+# kernels above so that none of their lines move: a program that holds a
+# Pallas kernel keys its compile cache on the kernel's source lines.
+#
+# A page of K is one contiguous copy for all the heads, and one more that
+# page of V. What differs from the split pools is where a head lies: in the
+# LANES of the row, not on an axis of its own. So a slot's queries enter the
+# matrix unit block-diagonal: row h holds head h's query in head h's D lanes
+# and zeros elsewhere (H rows padded to whole float32 sublane tiles), and
+# [Hp, row] x [row, bk] scores every head against the round's rows at once
+# (a zero lane, past H*D too, adds nothing); p [Hp, bk] x V [bk, row] gives
+# each head's weighted sum in every lane, of which row h keeps head h's
+# lanes, by selection. Both products are float32 at "highest" (bfloat16
+# pages are widened in VMEM): scores, maximum, sum and rescaling are float32
+# and no K, V or q value is rounded below float32. A masked position is
+# dropped by selection in both products, as above.
+#
+# On the v5e, the 48 layers' attention at the gpt2-xl cell's shapes (16
+# slots, 25 x 64 in 1,664 lanes, float32 pages of 16, 320 pages, a 64-entry
+# table; my chip run, PR 39), timed alone: the ring's contexts (227 pages
+# held) at 4 / 8 / 16 pages a round 5.12 / 4.52 / 5.00 ms; a vector-unit
+# form (K * q over the lanes, each head's lanes summed against a 0/1 matrix
+# in three bfloat16 parts) 5.52 / 5.16 / 5.88; pool-dense 23.2. Full tables
+# at 4 pages: 18.6 (vector-unit 19.5); one position a slot 1.85 (2.08).
+# Hence rounds of 2 MiB of K plus V: 8 pages, 128 rows, at those shapes.
+_ROW_BLOCK_BYTES = 2 << 20
+
+__all__ += ["row_block_pages", "row_decode_attention", "row_query_rows"]
+
+
+def row_block_pages(page_size, row, itemsize, table_width) -> int:
+    """Pages a round of the fused-row kernel moves: `head_block_pages` of
+    one row a page at `_ROW_BLOCK_BYTES`.
+    `paged_ops.paged_row_kernel_supported` asks that it divide the table's
+    width."""
+    return head_block_pages(page_size, 1, row, itemsize, table_width,
+                            _ROW_BLOCK_BYTES)
+
+
+def row_query_rows(heads) -> int:
+    """Rows of a slot's block-diagonal queries: its heads, padded to whole
+    float32 sublane tiles."""
+    return -(-heads // 8) * 8
+
+
+def _row_kernel(len_ref, table_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+                kbuf, vbuf, sems, *, table_width, heads, head_dim, scale):
+    B = q_ref.shape[0]
+    _, bp, P, R = kbuf.shape
+    bk = bp * P
+    pools = ((k_ref.at[layer_ref[0]], kbuf), (v_ref.at[layer_ref[0]], vbuf))
+    iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32,
+                             (row_query_rows(heads), R))
+    head, lane = iota(0), iota(1)
+    own = ((lane >= head * head_dim) & (lane < (head + 1) * head_dim)
+           & (head < heads))                      # row h: head h's lanes
+
+    def copies(b, i, slot, go):
+        """Start (`go`) or wait for the copies of round `i` of slot `b` into
+        buffer `slot`: two a page (K, V), each a whole row of every head,
+        as far as the page that holds the slot's last position."""
+        first = b * table_width + i * bp
+
+        def one(j, _):
+            page = table_ref[first + j]
+            for pool, buf in pools:
+                dma = pltpu.make_async_copy(pool.at[page], buf.at[slot, j],
+                                            sems.at[slot])
+                if go:
+                    dma.start()
+                else:
+                    dma.wait()
+            return 0
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(pl.cdiv(len_ref[b], P) - i * bp, bp), one, 0)
+
+    copies(0, 0, 0, True)
+
+    def dot(x, y, contract):
+        return jax.lax.dot_general(x, y, (contract, ((), ())),
+                                   preferred_element_type=jnp.float32,
+                                   precision=jax.lax.Precision.HIGHEST)
+
+    def per_slot(b, slot):
+        length = len_ref[b]
+        rounds = pl.cdiv(length, bk)
+        q = jnp.where(own, q_ref[b].astype(jnp.float32), 0.0)     # [Hp, R]
+
+        def per_round(i, carry):
+            m, l, acc, slot = carry
+            last = i + 1 == rounds
+            nb = jnp.where(last, b + 1, b)
+            ni = jnp.where(last, 0, i + 1)
+
+            @pl.when(nb < B)
+            def _():
+                copies(nb, ni, 1 - slot, True)
+
+            copies(b, i, slot, False)
+            t = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            tv = i * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            k = kbuf[slot].reshape(bk, R).astype(jnp.float32)
+            s = dot(q, k, ((1,), (1,))) * scale                   # [Hp, bk]
+            s = jnp.where(t < length, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            v = jnp.where(tv < length,
+                          vbuf[slot].reshape(bk, R).astype(jnp.float32), 0.0)
+            return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
+                    alpha * acc + dot(p, v, ((1,), (0,))), 1 - slot)
+
+        m0 = jnp.full((q.shape[0], 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((q.shape[0], 1), jnp.float32)
+        _, l, acc, slot = jax.lax.fori_loop(
+            0, rounds, per_round, (m0, l0, jnp.zeros_like(q), slot))
+        o_ref[b] = jnp.sum(jnp.where(own, acc / l, 0.0), axis=0,
+                           keepdims=True).astype(o_ref.dtype)
+        return slot
+
+    jax.lax.fori_loop(0, B, per_slot, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_pages",
+                                             "interpret"))
+def _row_call(q, k_pool, v_pool, page_table, lengths, layer, *, scale,
+              block_pages, interpret):
+    B, H, D = q.shape
+    P, R = k_pool.shape[2:]
+    rows = jnp.pad(q.reshape(B, 1, H * D), ((0, 0), (0, 0), (0, R - H * D)))
+    kernel = functools.partial(_row_kernel, table_width=page_table.shape[1],
+                               heads=H, head_dim=D, scale=scale)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((2, block_pages, P, R), k_pool.dtype)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,      # lengths, the page table, the layer
+            grid=(1,),
+            in_specs=[whole, in_place, in_place],
+            out_specs=whole,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, R), q.dtype),
+        interpret=interpret,
+        name="row_decode_attention",
+    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32).reshape(-1),
+      layer, rows, k_pool, v_pool)
+    return out[:, 0, :H * D].reshape(B, H, D)
+
+
+def row_decode_attention(q, k_pool, v_pool, page_table, lengths, scale,
+                         layer=None, block_pages=None):
+    """q [B, H, D]; k_pool / v_pool ONE fused layer `[N, P, row]` (a
+    token's H heads of D lanes side by side, zero past H*D), or with
+    `layer` the whole `[L, N, P, row]` pools, read in place; page_table
+    [B, PP]; lengths [B] >= 1, the positions each slot attends. Query head
+    i reads lanes i*D .. (i+1)*D of every row. Returns [B, H, D] in q's
+    dtype. Every product and sum is float32 (the comment above
+    `_ROW_BLOCK_BYTES`).
+
+    A round is `row_block_pages`. The layer reaches the kernel as a scalar
+    and the call is a `jax.jit` of its own, so a decode program's layers
+    share one traced kernel, as `head_decode_attention`'s do."""
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    P, R = k_pool.shape[2:]
+    if block_pages is None:
+        block_pages = row_block_pages(P, R, k_pool.dtype.itemsize,
+                                      page_table.shape[1])
+    return _row_call(q, k_pool, v_pool, page_table, lengths,
+                     jnp.full((1,), layer, jnp.int32), scale=float(scale),
+                     block_pages=int(block_pages), interpret=_interpret())

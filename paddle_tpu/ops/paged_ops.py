@@ -10,21 +10,26 @@ partial page, and admission control is exact page arithmetic
 
 Three decode-attention implementations over that layout, one math:
 
-- **kernel** — `head_decode_attention` (`ops/latent_attention_kernel.py`,
-  PR 37): one Pallas program walks each slot's own pages in place as far
-  as `pos`, one copy a page for all K/V heads. Flag-gated by
-  `FLAGS_use_paged_attention`, run where a Pallas kernel runs, and
-  shape-gated by `paged_kernel_supported` (head dim a multiple of 128;
-  the round derived from the page's bytes and the table's width).
+- **kernel** — one Pallas program walks each slot's own pages in place
+  as far as `pos`, one copy a page for all the heads
+  (`ops/latent_attention_kernel.py`): `head_decode_attention` over
+  split pools, shape-gated by `paged_kernel_supported` (head dim a
+  multiple of 128), and `row_decode_attention` over fused pools,
+  whose heads lie in the lanes of one row, shape-gated by
+  `paged_row_kernel_supported` (one K/V head a query head); each round
+  derived from the page's bytes and the table's width. Flag-gated by
+  `FLAGS_use_paged_attention` and run where a Pallas kernel runs.
 - **pool** (pool-dense, `paged_pool_attention`) — no gather: all B
   rows' queries are scored against the layer's WHOLE pool in one
   batched matmul (`[B, D] x [D, N*P]` per head) and a page-ownership
   mask (`paged_pool_mask`, built once a step from the page table and
   `pos` alone) says which pool rows each sequence reads. Every pool
   page is read once per layer, whatever the batch. Shape-gated by
-  `paged_pool_dense_supported`: floating pools, not a kernel shape, and
-  `N <= B*PP` — the pool holds no more pages than the gather would
-  materialize, so it never reads more than the path it replaces.
+  `paged_pool_dense_supported`: floating pools, not a shape of the
+  split pools' kernel, and `N <= B*PP` — the pool holds no more pages
+  than the gather would materialize, so it never reads more than the
+  path it replaces. Where a Pallas kernel runs, the fused rows' kernel
+  is chosen before it; elsewhere (the CPU) such shapes stay here.
 - **reference** (every other shape: a pool larger than `B*PP`, int8
   pools, and the oracle the other two are tested against) — gather the
   page table into a dense `[B, H, T, D]` buffer and run
@@ -92,7 +97,7 @@ __all__ = ["HeadPoolForm", "head_pools_fused",
            "paged_gather_layers", "paged_gather_quantized",
            "latent_pool_width", "paged_latent_attention",
            "paged_latent_kernel_supported", "paged_latent_path",
-           "paged_latent_write",
+           "paged_latent_write", "paged_row_kernel_supported",
            "paged_prefix_attention", "paged_write",
            "paged_write_quantized", "page_rows_for_positions",
            "sharded_paged_attention"]
@@ -580,7 +585,7 @@ def paged_kernel_supported(q_shape, pages_shape, table_shape,
     - floating pages of 2 or 4 bytes, head dim a multiple of 128, a page
       whole sublane tiles (8 rows float32, 16 bfloat16): one copy a page
       moves all the K/V heads' `[Hkv, P, D]` tiles. Head dim 64 (GPT-2)
-      is fused rows and takes the pool-dense path or the gather BY RULE.
+      is fused rows, the shapes of `paged_row_kernel_supported`.
     - query heads a multiple of K/V heads (grouped query included).
     - the kernel walks a slot's table `head_block_pages` entries at a time
       (derived from the bytes of a page of K plus V and the table's width)
@@ -614,7 +619,11 @@ def paged_pool_dense_supported(q_shape, pages_shape, table_shape,
     [H, N, P, D]; table [B, PP].
 
     - floating pools: int8 pools dequantize per page on gather.
-    - not a shape of the Pallas kernel, which reads pages in place.
+    - not a shape of the split pools' kernel (`paged_kernel_supported`),
+      which reads pages in place. The fused rows' kernel
+      (`paged_row_kernel_supported`) is chosen before this path only
+      where a Pallas kernel runs (`paged_attention_path`), so its shapes
+      stay pool-dense on every other backend.
     - one K/V head per query head (the reference's own limit).
     - `N <= B*PP`: the pool holds no more pages than the gather would
       materialize for this batch, so pool-dense never reads more than
@@ -631,15 +640,21 @@ def paged_pool_dense_supported(q_shape, pages_shape, table_shape,
 
 
 def paged_attention_path(q_shape, pages_shape, table_shape,
-                         pages_dtype=jnp.float32) -> str:
+                         pages_dtype=jnp.float32, fused=None) -> str:
     """Which implementation `paged_attention` traces for these shapes:
-    "kernel", "pool" or "reference" (module docstring)."""
+    "kernel", "pool" or "reference" (module docstring). `fused` is the
+    pool's form, by default the one `HeadPoolForm` gives the head width:
+    it says which kernel's rule applies."""
     if not jnp.issubdtype(pages_dtype, jnp.floating):
         return "reference"    # int8 pools dequantize page by page on gather
+    if fused is None:
+        fused = head_pools_fused(q_shape[-1])
+    kernel_supported = (paged_row_kernel_supported if fused
+                        else paged_kernel_supported)
     # lint: allow(flag-in-trace): kernel-vs-reference is a trace-time choice by design (module docstring); the flag picks which program gets built
     if (bool(flag("FLAGS_use_paged_attention")) and _pallas_runs()
-            and paged_kernel_supported(q_shape, pages_shape, table_shape,
-                                       pages_dtype)):
+            and kernel_supported(q_shape, pages_shape, table_shape,
+                                 pages_dtype)):
         return "kernel"
     if paged_pool_dense_supported(q_shape, pages_shape, table_shape,
                                   pages_dtype):
@@ -663,8 +678,10 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
 
     `paged_attention_path` picks the implementation from the shapes: where
     a Pallas kernel runs, shapes `paged_kernel_supported` admits dispatch
-    the repo's head-pool kernel, which reads the pages in place (with
-    `layer`, in the whole pools: no layer is cut out for it); shapes
+    the repo's head-pool kernel and fused layers that
+    `paged_row_kernel_supported` admits its fused-row kernel; both read
+    the pages in place (with `layer`, in the whole pools: no layer is cut
+    out for them); shapes
     `paged_pool_dense_supported` admits score every row against the
     whole pool under `pool_mask` (the `paged_pool_mask` of this table
     and `pos`; a caller with many layers builds it once and passes it,
@@ -685,16 +702,18 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, scale,
         layer_shape = hd[:1] + shape[:2] + (D,)     # a query head
     else:                       # unless `kv_heads` says fewer
         layer_shape = shape
+    fused = len(shape) == 3
     path = paged_attention_path(q.shape, layer_shape, page_table.shape,
-                                k_pages.dtype)
+                                k_pages.dtype, fused)
     if path == "kernel":
         monitor.stat_add("STAT_paged_attn_kernel")  # traces, not calls
-        from .latent_attention_kernel import head_decode_attention
+        from .latent_attention_kernel import (head_decode_attention,
+                                              row_decode_attention)
         # (a length is at least 1, a dead slot's too: the kernel starts
         # every slot's first round of copies behind the slot before)
-        return head_decode_attention(q, k_pages, v_pages, page_table,
-                                     jnp.maximum(pos + 1, 1), scale,
-                                     layer=layer)
+        kernel = row_decode_attention if fused else head_decode_attention
+        return kernel(q, k_pages, v_pages, page_table,
+                      jnp.maximum(pos + 1, 1), scale, layer=layer)
     if layer is not None:
         k_pages, v_pages = k_pages[layer], v_pages[layer]
         if k_scales is not None:
@@ -983,3 +1002,50 @@ def paged_latent_attention(q, pool, page_table, pos, scale, kv_rank,
                          preferred_element_type=jnp.float32)
         bad = jnp.any(valid & ~jnp.all(finite, axis=-1), axis=-1)    # [B]
         return jnp.where(bad[:, None, None], jnp.nan, out)
+
+
+def paged_row_kernel_supported(q_shape, pages_shape, table_shape,
+                               pages_dtype=jnp.float32) -> bool:
+    """Static gate of the fused-row decode kernel
+    (`ops/latent_attention_kernel.row_decode_attention`), by observable
+    shape like `paged_kernel_supported`, which takes the split form. q
+    [B, H, D]; pages ONE layer as the rules read it, [Hkv, N, P, D]
+    (`HeadPoolForm.layer_shape`), of a fused pool `[N, P, row]` whose row
+    is the H*D values in whole 128-lane tiles; table [B, PP].
+
+    - fused rows (`head_pools_fused`: a head width that is no whole number
+      of lane tiles) of floating values of 2 or 4 bytes, a page whole
+      sublane tiles (8 rows float32, 16 bfloat16): one copy a page moves
+      a row of every head.
+    - one K/V head a query head, and a slot's block-diagonal queries
+      (`row_query_rows(H)` x row float32) at most 1 MiB: they and their
+      weighted sums live in VMEM through a slot's rounds.
+    - every slot's query row and result, padded to a sublane tile in
+      float32, sit in VMEM whole: at most 8 MiB together.
+    - the kernel walks a slot's table `row_block_pages` entries at a time
+      (derived from the bytes of a page of K plus V and the table's width)
+      and asks that the round divide the table.
+    Every shape admitted here must compile on the chip. What shows it: the
+    gpt2-xl cell's decode program (16 slots x 25 heads of 64 in 1,664
+    lanes, float32 pages of 16, a 64-entry table, 320 pages) compiles for
+    the described v5e (`tests/test_v5e_compile.py`), and
+    `tests/test_chip_kernels.py::test_row_rule_admits_only_what_compiles`
+    runs the kernel against the gather on the chip at float32 and bfloat16
+    pages, pages of 8 and 16, 64- and 96-wide heads, 6 to 64 heads. The
+    size of the pool does not enter: the kernel reads it in place. Widen
+    the rule only with a chip run that shows it."""
+    from .latent_attention_kernel import row_block_pages, row_query_rows
+    B, H, D = q_shape
+    Hkv, _, P, Dk = pages_shape
+    dtype = jnp.dtype(pages_dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize not in (2, 4):
+        return False
+    if D != Dk or not head_pools_fused(D) or H != Hkv:
+        return False
+    if P % (32 // dtype.itemsize):
+        return False
+    row = latent_pool_width(H * D)
+    if row_query_rows(H) * row * 4 > 1 << 20 or 2 * B * 8 * row * 4 > 8 << 20:
+        return False
+    PP = table_shape[1]
+    return PP % row_block_pages(P, row, dtype.itemsize, PP) == 0

@@ -232,14 +232,11 @@ class GPTFamily:
                     mask)
 
         def attend(cache, layer, q, pos):
+            # the whole pools and the layer: the kernel reads that layer in
+            # place, the other paths cut it out themselves
             pools, pt, mask = cache
-            if quant:
-                kp, vp, ksc, vsc = pools
-                return paged_attention(q, kp[layer], vp[layer], pt, pos,
-                                       scale, ksc[layer], vsc[layer])
-            kp, vp = pools
-            return paged_attention(q, kp[layer], vp[layer], pt, pos, scale,
-                                   pool_mask=mask)
+            return paged_attention(q, pools[0], pools[1], pt, pos, scale,
+                                   *pools[2:], pool_mask=mask, layer=layer)
 
         pool_dense = ctx.decode_attention == "pool"
 

@@ -143,8 +143,10 @@ def test_paged_rule_matches_what_lowers_for_tpu(monkeypatch):
     admitted bfloat16 and float32 shapes, and a group of 5 (falcon's 20
     query heads over 4 K/V heads), lower through `paged_attention`'s kernel
     branch to ONE custom call named `head_decode_attention`, with whole
-    pools and the layer as a scalar; head dim 64 is not admitted and lowers
-    to no custom call."""
+    pools and the layer as a scalar; head dim 64 is not admitted in that
+    split form and lowers to no custom call. In the form the pools give
+    64-wide heads, fused rows, it lowers to ONE custom call named
+    `row_decode_attention`."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def args(H, Hkv, D, P, dtype):
@@ -174,6 +176,13 @@ def test_paged_rule_matches_what_lowers_for_tpu(monkeypatch):
         a[0].shape, a[1].shape[1:], a[3].shape, jnp.float32)
     assert "tpu_custom_call" not in lowered(*a)
     assert stat_get("STAT_paged_attn_kernel") == k0 + 3
+    fused = jax.ShapeDtypeStruct((2, 72, 16, 512), jnp.float32)  # 8 x 64
+    assert paged_ops.paged_row_kernel_supported(
+        a[0].shape, (8, 72, 16, 64), a[3].shape, jnp.float32)
+    text = lowered(a[0], fused, fused, a[3], a[4])
+    assert text.count("tpu_custom_call") == 1
+    assert "row_decode_attention" in text
+    assert stat_get("STAT_paged_attn_kernel") == k0 + 4
 
 
 def test_flash_gates_bound_the_vmem_resident_sequence():

@@ -65,6 +65,49 @@ def test_paged_rule_admits_only_what_compiles(B, H, Hkv, D, P, PP, dtype):
     assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - want))) < 0.02
 
 
+# (sequences, heads, head dim, page size, table pages, pool pages) of the
+# fused-row kernel: the gpt2-xl cell's 16 slots x 25 heads of 64 in
+# 1,664 lanes over 320 pages and a 64-entry table, GPT-2 small's 12 x 64 in
+# 768 lanes, a row of 6 x 96 heads in 640 lanes, and 64 heads of 64, the
+# most the rule's 1 MiB of block-diagonal queries admits at that width
+ROW_SHAPES = [(16, 25, 64, 16, 64, 320), (8, 12, 64, 16, 64, 128),
+              (4, 6, 96, 16, 8, 72), (16, 25, 64, 8, 64, 320),
+              (16, 64, 64, 16, 64, 128)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("B,H,D,P,PP,N", ROW_SHAPES)
+def test_row_rule_admits_only_what_compiles(B, H, D, P, PP, N, dtype):
+    assert jax.default_backend() == "tpu"
+    layer = (H, N, P, D)
+    if dtype == jnp.bfloat16 and P % 16:
+        assert not paged_ops.paged_row_kernel_supported(
+            (B, H, D), layer, (B, PP), dtype)
+        pytest.skip("bfloat16 pages of 8 rows: not the kernel's, by rule")
+    assert paged_ops.paged_row_kernel_supported((B, H, D), layer, (B, PP),
+                                                dtype)
+    rng = np.random.RandomState(0)
+    R = paged_ops.latent_pool_width(H * D)
+
+    def pool():
+        rows = rng.standard_normal((2, N, P, H * D))
+        return jnp.asarray(np.pad(rows, [(0, 0)] * 3 + [(0, R - H * D)]),
+                           dtype)
+    q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
+    kp, vp = pool(), pool()
+    table = jnp.asarray(rng.randint(1, N, size=(B, PP)), jnp.int32)
+    pos = jnp.asarray(rng.randint(0, PP * P, size=(B,)), jnp.int32)
+    scale = 1.0 / D ** 0.5
+    k0 = stat_get("STAT_paged_attn_kernel")
+    out = jax.jit(lambda *a: paged_ops.paged_attention(*a, scale, layer=1))(
+        q, kp, vp, table, pos)
+    assert stat_get("STAT_paged_attn_kernel") == k0 + 1   # not pool-dense
+    kd = paged_ops.paged_gather(kp[1], table, (H, D)).astype(jnp.float32)
+    vd = paged_ops.paged_gather(vp[1], table, (H, D)).astype(jnp.float32)
+    want = paged_ops.cached_attention(q, kd, vd, pos, scale)
+    assert float(jnp.max(jnp.abs(out - want))) < 2e-5
+
+
 def _attend(which, B, H, S, D, dtype):
     rng = np.random.RandomState(1)
     q, k, v = (jnp.asarray(rng.standard_normal((B, H, S, D)), dtype)

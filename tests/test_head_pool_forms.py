@@ -112,9 +112,19 @@ def test_on_a_tpu_backend_wide_heads_still_take_the_kernel(monkeypatch):
     layer = wide.layer_shape(wide.pool_shape(4, 128, 16))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert po.paged_attention_path((8, 16, 128), layer, (8, 64)) == "kernel"
+    # narrow heads take the fused rows' kernel there, at any pool
+    # size; int8 rows stay on the gather, and a page of 8 bfloat16 rows (no
+    # whole sublane tile) stays pool-dense
     narrow = po.HeadPoolForm(25, 64)
-    layer = narrow.layer_shape(narrow.pool_shape(48, 128, 16))
-    assert po.paged_attention_path((16, 25, 64), layer, (16, 64)) == "pool"
+    for pages in (128, 320, 2048):
+        layer = narrow.layer_shape(narrow.pool_shape(48, pages, 16))
+        assert po.paged_attention_path((16, 25, 64), layer,
+                                       (16, 64)) == "kernel"
+    assert po.paged_attention_path((16, 25, 64), layer, (16, 64),
+                                   jnp.int8) == "reference"
+    layer = narrow.layer_shape(narrow.pool_shape(48, 128, 8))
+    assert po.paged_attention_path((16, 25, 64), layer, (16, 128),
+                                   jnp.bfloat16) == "pool"
 
 
 def test_gathers_read_the_same_rows_from_either_form(data):

@@ -83,9 +83,10 @@ LATENT_PLANTS = ("dropped_expert", "float8_cache", "wrong_page",
 
 def test_every_plant_has_a_test_that_shows_it_live():
     from test_falcon_h1 import HYBRID_PLANTS
-    # `wrong_page` and `wrong_table` wrap both families' decode attention
+    from test_row_decode_kernel import GPT_PLANTS
+    # `wrong_page` and `wrong_table` wrap every family's decode attention
     assert set(plant_fault.PLANTS) - {"none"} == \
-        set(LATENT_PLANTS) | set(HYBRID_PLANTS)
+        set(LATENT_PLANTS) | set(HYBRID_PLANTS) | set(GPT_PLANTS)
 
 
 @pytest.mark.parametrize("fault", LATENT_PLANTS)

@@ -225,6 +225,102 @@ def test_gen_decode_fits_the_chip_with_a_larger_pool(
     assert not whole_pool_copies(progs["decode"], shape)
 
 
+# the cell's pool as benchmark/configs/gpt2-xl.json has it: 320 pages
+GEN_CELL_PAGES = 320
+
+
+def xl_kernel_decode(one_chip, monkeypatch, num_pages):
+    """The GPT family's decode program at the cell's shapes with the
+    fused-row kernel's side of the rule taken, as a TPU backend takes it
+    (the rule asks the backend, which is the CPU here: the test answers
+    for it), through the engine's jit boundary with the pools' layout left
+    to the compiler: (lowered, compiled). Built from the family, not an
+    engine, which would compile the kernel for the CPU."""
+    import types
+
+    from jax.experimental.layout import Format, Layout
+
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.ops import latent_attention_kernel, paged_ops
+    from paddle_tpu.serving.decode_family import ProgramContext
+    from paddle_tpu.serving.generation import (GenerationConfig, jit_program,
+                                               with_step_inputs)
+    from paddle_tpu.serving.gpt_family import GPTFamily
+
+    monkeypatch.setattr(paged_ops, "_pallas_runs", lambda: True)
+    monkeypatch.setattr(latent_attention_kernel, "_interpret", lambda: False)
+    cfg = GPTConfig(vocab_size=256, hidden_size=GEN_E, num_heads=GEN_H,
+                    num_layers=1, intermediate_size=4 * GEN_E,
+                    max_position_embeddings=1024, dropout=0.0)
+    W1 = GPTForCausalLM(cfg).decode_weights()
+    fam = GPTFamily(types.SimpleNamespace(gpt=types.SimpleNamespace(
+        config=cfg)))
+    ecfg = GenerationConfig(max_slots=GEN_SLOTS, page_size=GEN_PAGE,
+                            num_pages=num_pages, pages_per_seq=GEN_PP,
+                            prefill_buckets=(128,), warmup=False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def like(a):
+        return sds(np.shape(a), np.asarray(a).dtype)
+
+    W = {"wte": sds((GEN_V, GEN_E), jnp.float32), "wpe": like(W1["wpe"]),
+         "lnf": tuple(like(a) for a in W1["lnf"]),
+         "blocks": [tuple(like(a) for a in W1["blocks"][0])
+                    for _ in range(GEN_L)]}
+    pool = sds((GEN_L, num_pages, GEN_PAGE, 1664), jnp.float32)
+    path = fam.decode_attention(ecfg, 1, (pool, pool))
+    assert path == "kernel"
+    fns = fam.build(ProgramContext(ecfg, 1, None, 2, False, path, W, {}))
+    key = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
+    lowered = jit_program(
+        with_step_inputs(fns["decode"]), "decode",
+        (Format(Layout.AUTO, one_chip),) * 2).lower(
+        W, pool, pool, *step_inputs(sds, GEN_SLOTS, GEN_PP, key))
+    return lowered, lowered.compile()
+
+
+def test_the_row_kernel_is_traced_once_for_the_48_layers(
+        one_chip, uncached, monkeypatch):
+    """gpt2-xl's decode program at the re-based 320 pages with the
+    fused-row kernel: ONE lowered body of `row_decode_attention`
+    (a `jax.jit` of its own, the layer a scalar operand: set-up time is an
+    end-to-end metric), 48 custom calls of that name under the layers'
+    `attn` scopes over the whole pools in place, no ownership mask, no
+    pool copied, the pools in their default layout, and temporaries no
+    larger than the pool-dense program's at the same pool (the CPU
+    backend's side of the rule, compiled here too) but for where the
+    scheduler puts the tied head's transposed `wte`."""
+    from paddle_tpu.device import layout_name
+
+    lowered, decode = xl_kernel_decode(one_chip, monkeypatch, GEN_CELL_PAGES)
+    assert lowered.as_text().count("row_decode_attention") == 1
+    text = decode.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "row_decode_attention" in ln]
+    assert len(calls) == GEN_L
+    assert all(re.search(r"layer_\d+/attn", ln) for ln in calls)
+    assert "kv_mask" not in text
+    shape = (GEN_L, GEN_CELL_PAGES, GEN_PAGE, 1664)
+    assert not whole_pool_copies(decode, shape)
+    pool = jax.ShapeDtypeStruct(shape, jnp.float32)
+    fmts = decode.input_formats[0][1:3]
+    assert [layout_name(f, pool.shape, pool.dtype) for f in fmts] == \
+        ["default"] * 2
+    # the pool-dense program of the same cell takes 385.2 MB, the kernel's
+    # 407.1 MB. Most of either is ONE buffer, the tied head's transposed
+    # `wte` (334.6 MB), and what else is live beside it: the pool-dense
+    # program copies it at its end, after the layers' scores and masks are
+    # gone (2.2 MB beside it); the kernel's, with no such temporaries,
+    # copies it at its top, beside the layers' weight prefetches (22.8 MB)
+    monkeypatch.undo()
+    progs, _, _ = xl_programs(one_chip, GEN_CELL_PAGES, ("decode",))
+    dense = progs["decode"].memory_analysis().temp_size_in_bytes
+    assert decode.memory_analysis().temp_size_in_bytes <= dense + 24e6
+
+
 # -- the latent family's decode program (glm-4.7-flash.reasoning-saturated) --
 # published widths, 64 experts, vocabulary 154,880, bfloat16; 32 slots, pages
 # of 16 tokens, 8,192 pages, 256 table entries

@@ -44,6 +44,15 @@ Faults of the hybrid family (models/falcon_h1.py, serving/hybrid_family.py):
     wrong_group     query head i reads K/V head i % 4 (striped) where the
                     grouped-query map is i // 5 (blocked)
 
+Faults of the GPT family (models/gpt.py, serving/gpt_family.py), whose
+64-wide heads lie side by side in the lanes of one fused K and one fused V
+row a token:
+
+    wrong_page, wrong_table
+                    as above, through `paged_attention` over the fused pools
+    wrong_lanes     query head i reads the 64 lanes of head (i + 1) mod H
+                    in every K and V row
+
 `--dump DIR` writes the reference's per-token shortfalls (`short.npy`) and
 the window's step records (`steps.json`) there; `--drain S` shortens the
 mix's drain for a short `--seconds` (a plant needs finished requests to
@@ -122,6 +131,19 @@ def wrong_group():
                      kv_heads=kv_heads, **kw)
         return out[:, at]
     paged_ops.paged_attention = striped
+
+
+def wrong_lanes():
+    import jax.numpy as jnp
+    from paddle_tpu.ops import paged_ops
+    attend = paged_ops.paged_attention
+
+    def next_heads_lanes(q, *args, **kw):
+        # query head i sits where head i + 1 is read, so that it reads the
+        # (i + 1) mod H-th 64 lanes of every fused K and V row
+        return jnp.roll(attend(jnp.roll(q, 1, axis=1), *args, **kw), -1,
+                        axis=1)
+    paged_ops.paged_attention = next_heads_lanes
 
 
 def bf16_state():
@@ -206,7 +228,7 @@ def float8_window():
 PLANTS = {"none": lambda: None, "float8_cache": float8_cache,
           "dropped_expert": dropped_expert, "wrong_page": wrong_page,
           "wrong_table": wrong_table, "wrong_group": wrong_group,
-          "bf16_state": bf16_state,
+          "wrong_lanes": wrong_lanes, "bf16_state": bf16_state,
           "neighbour_state": neighbour_state, "unmasked_pad": unmasked_pad,
           "no_window": no_window, "float8_window": float8_window}
 
