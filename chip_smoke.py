@@ -45,10 +45,14 @@ launches step n+1 from the tokens the device still holds and only then reads
 step n, so what a step teaches the host — an EOS, a poison flag — is learned
 one step late (the token computed meanwhile is dropped), and whatever needs
 a step's tokens on the host first (speculation, a test hook, an armed step
-failpoint) settles the step in flight before it launches. `serve` and
-`latent` each end a loaded run through the loop and fail unless
-`stats()["lookahead"]["ahead"]` is over 0.9 of that run's decode steps with
-no token dropped, and the tokens still match the phase's reference.
+failpoint) settles the step in flight before it launches. A prefill's first
+token stays on the device the same way: the first-token program writes it
+into the next step's token input, and the prefill is read after that step
+is launched. `serve`, `latent` and `hybrid` each end a loaded run through
+the loop and fail unless `stats()["lookahead"]["ahead"]` is over 0.9 of that
+run's decode steps and `prefills_ahead` over 0.9 of its prefills, with
+nothing settled first and no token dropped, and the tokens still match the
+phase's reference.
 
 `--cpu-rehearsal` runs the same control flow at tiny sizes on the CPU with
 the kernels in the Pallas interpreter, to debug the script itself. It says
@@ -337,15 +341,22 @@ class Smoke:
     def check_ahead(self, phase, stats):
         """The loaded run kept one decode step in flight ahead of the host
         (ISSUE 34): all but a few of its steps were launched before the
-        step before them was read, and no token was dropped."""
+        step before them was read, all but a few of its prefills were read
+        after the next step was launched behind them, and no token was
+        dropped."""
         look, steps = stats["lookahead"], stats["steps"]
-        self.say(f"{phase}: lookahead {look} over {steps} decode steps")
-        self.check(look["ahead"] > 0.9 * steps and not look["settled"]
-                   and look["dropped_tokens"] == 0,
+        prefills = stats["prefills"]
+        self.say(f"{phase}: lookahead {look} over {steps} decode steps "
+                 f"and {prefills} prefills")
+        self.check(look["ahead"] > 0.9 * steps
+                   and look["prefills_ahead"] > 0.9 * prefills
+                   and not look["settled"] and look["dropped_tokens"] == 0,
                    f"{phase}: over 0.9 of the {steps} decode steps were "
                    f"launched ahead of the last one's read-back "
-                   f"({look['ahead']}), none settled first, no token "
-                   f"dropped")
+                   f"({look['ahead']}) and over 0.9 of the {prefills} "
+                   f"prefills read behind the next launch "
+                   f"({look['prefills_ahead']}), none settled first, no "
+                   f"token dropped")
 
     def near_argmax_rate(self, net, outs, prompts):
         """Teacher-forced agreement with the eager forward: every sequence

@@ -71,19 +71,23 @@ and cannot be retracted).
 
 **One decode step in flight (ISSUE 34)**: the step thread launches decode
 step n+1 BEFORE it reads step n. Step n's next-token output is step n+1's
-token input as a device array that no one reads in between (a request
-that joined since brings its first token from the host; the program takes
-both and a per-slot mask), positions, activity and the page table come
-from the host's own count, and the PRNG key is folded inside the program
-from the base key and the step number. Then the thread reads step n,
-delivers, completes, records and admits while the chip runs step n+1. What
-is learned a step late — an EOS, a poison flag — costs one step: the
-token step n+1 computed for that slot is dropped (never staged, never
-counted; its K/V write fell inside the request's own pages, zeroed on
-release as ever). What needs a step's tokens on the host before the next
-launch — speculation, a `_pre_step_hook`, an armed step failpoint — finds
-no step in flight: the one loop settles first, decided from the engine's
-own state (`stats()["lookahead"]`).
+token input as a device array that no one reads in between (the program
+also takes host tokens and a per-slot mask), positions, activity and the
+page table come from the host's own count, and the PRNG key is folded
+inside the program from the base key and the step number. Then the thread
+reads step n, delivers, completes, records and admits while the chip runs
+step n+1. A request admitted meanwhile joins the same way: its prefill is
+followed by the small `gen_first_token` program, which samples the first
+token on the device and writes it into that token input at the request's
+slot, and the prefill is read only after step n+1 is launched behind it.
+What is learned a step late — an EOS, a poison flag, of a decode step or
+of a prefill — costs one step: the token step n+1 computed for that slot
+is dropped (never staged, never counted; its K/V write fell inside the
+request's own pages, zeroed on release as ever). What needs tokens on the
+host before the next launch — speculation, a `_pre_step_hook`, an armed
+step failpoint — finds no step in flight and every prefill read at once:
+the one loop settles first, decided from the engine's own state
+(`stats()["lookahead"]`).
 
 Hardening carries over from the one-shot engine, re-expressed at token
 granularity: bounded intake (`EngineOverloaded`), worst-case page
@@ -125,7 +129,7 @@ from ..profiler import (RecordEvent, audit, device_telemetry, exporter,
                         flight_recorder, slo, spans, step_log,
                         timeseries, trace_context)
 from . import failpoints
-from .decode_family import ProgramContext, family_of
+from .decode_family import ProgramContext, family_of, sample_next
 from .device_clock import DeviceClock
 from .kv_cache import TRASH_PAGE
 from .kv_tier import HostTier
@@ -331,7 +335,7 @@ class _GenRequest:
                  "defer_logged", "stream", "ttft_deadline_ms",
                  "prefix_tokens", "prefill_pos", "pending_digests",
                  "spec_accepted", "claimed", "retries", "skip_stream",
-                 "trace_id")
+                 "trace_id", "unread")
 
     _ids = itertools.count(1)
 
@@ -372,6 +376,8 @@ class _GenRequest:
         self.trace_id = trace_id        # fleet trace id (ISSUE 20) —
         #                                 survives replay so one id
         #                                 spans every incarnation
+        self.unread = False             # its prefill (and first token) is
+        #                                 launched and not yet read
 
 
 class ReplayEntry:
@@ -468,17 +474,18 @@ class _ProgramPack:
     pools' layout asked of the compiler (PR 28); a resurrection adopts
     it, so the rebuilt engine runs the same executable."""
 
-    __slots__ = ("ledger", "execs", "prefill", "tail",
+    __slots__ = ("ledger", "execs", "prefill", "tail", "first",
                  "decode", "verify", "zero", "cow", "npool", "W",
                  "tier_gather", "tier_write", "formats", "preferred")
 
-    def __init__(self, ledger, prefill, tail, decode, verify, zero, cow,
-                 npool, W, execs=None, tier_gather=None,
+    def __init__(self, ledger, prefill, tail, first, decode, verify, zero,
+                 cow, npool, W, execs=None, tier_gather=None,
                  tier_write=None, formats=None, preferred=None):
         self.ledger = ledger
         self.execs = {} if execs is None else execs
         self.prefill = prefill
         self.tail = tail
+        self.first = first
         self.decode = decode
         self.verify = verify
         self.zero = zero
@@ -539,11 +546,11 @@ def with_step_inputs(decode):
     """A family's decode body (serving/decode_family.py) behind the inputs
     the step thread has when it launches a step AHEAD of the last one's
     read-back: `prev`, the next-token output of the step before, still on
-    the device; `tok` and `fresh`, the host's token for the slots whose
-    token `prev` does not hold (a request that joined since: its first
-    token was sampled on the host from the prefill's logits) and their
-    mask; the base key and the step's number, folded here. One compiled
-    program as before, the family's body unchanged inside it:
+    the device, with the first token of each request admitted since
+    written in at its slot (`first_token_program`); `tok` and `fresh`,
+    the host's token for the slots whose token `prev` does not hold, and
+    their mask; the base key and the step's number, folded here. One
+    compiled program as before, the family's body unchanged inside it:
 
         gen_decode(W, *pools, pt, prev, tok, fresh, pos, active, temps,
                    smask, base_key, step) -> what the family's body returns
@@ -558,6 +565,52 @@ def with_step_inputs(decode):
             key = step_key(base_key, step)
         return decode(W, *lead, tok, pos, active, temps, smask, key)
     return gen_decode
+
+
+def first_token_program(top_k):
+    """The body of the program that follows every prefill on the device: the
+    request's first token sampled from the prefill's logits as a decode step
+    samples (`decode_family.sample_next`; greedy is the first maximum, as
+    `np.argmax` gives it), written into the next decode step's token input
+    at the request's slot, so that step can be launched before anyone reads
+    the prefill:
+
+        gen_first_token(logits, prev [M], slot, temp, smask, base_key,
+                        ordinal) -> (prev' [M], tok, bad)
+
+    `bad` is the prefill's poison flag (a logit that is not finite). A
+    sampled request draws from `fold_in(base_key, ordinal)`, its
+    engine-local ordinal: two engines of one seed sample the same first
+    tokens. The engine gives it a base key of its own, split from the
+    decode steps' (`fold_in` of one key by a step's number and by an
+    ordinal would otherwise draw the same noise)."""
+    def gen_first_token(logits, prev, slot, temp, smask, base_key, ordinal):
+        import jax
+        import jax.numpy as jnp
+        with jax.named_scope("first_token"):
+            nxt, bad = sample_next(
+                logits.reshape(1, -1), jnp.ones((1,), bool), temp[None],
+                smask[None], jax.random.fold_in(base_key, ordinal), top_k)
+            return prev.at[slot].set(nxt[0]), nxt[0], bad[0]
+    return gen_first_token
+
+
+class _Prefill:
+    """One prefill launched and not yet read, with the first-token program
+    behind it: the request, the token and poison flag on the device, the
+    prefill's device-clock stamps, its bucket and the prompt's page digests
+    for the prefix cache, and the count of decode steps launched before it
+    (a larger count when it is read: one was launched behind it)."""
+
+    __slots__ = ("req", "outs", "launch", "bucket", "digests", "steps")
+
+    def __init__(self, req, outs, launch, bucket, digests, steps):
+        self.req = req
+        self.outs = outs            # (first token, poison flag)
+        self.launch = launch
+        self.bucket = bucket
+        self.digests = digests
+        self.steps = steps
 
 
 class _Flight:
@@ -796,12 +849,20 @@ class GenerationEngine:
         self._no_prev = np.zeros((M,), np.int32)
         import jax
         # the base key as the decode program takes it (it folds the step's
-        # number in: `step_key`)
+        # number in: `step_key`), and the first-token program's, split from
+        # it (it folds a request's ordinal in)
         self._key_host = np.asarray(jax.random.PRNGKey(self._cfg.seed))
+        self._first_key_host = np.asarray(
+            jax.random.split(self._key_host)[1])
         self._step_span = f"generation::step[m={M}]"
         self._ahead_total = 0          # steps launched ahead of a read-back
         self._settled = {}             # steps that settled first, by reason
+        #                                (prefills read at once: "prefill:")
         self._dropped_tokens = 0       # computed for a request that had left
+        # prefills launched and not yet read, oldest first; how many were
+        # read only after a decode step was launched behind them
+        self._unread: deque = deque()
+        self._prefills_ahead = 0
         # attribution (ISSUE 20 / 34): `_cursor` is how far the step
         # thread's timeline has been charged to a bucket; `_unobserved`
         # counts the timed programs launched whose end has not been
@@ -929,6 +990,7 @@ class GenerationEngine:
             self._npool = pack.npool
             self._prefill_jit = pack.prefill
             self._tail_jit = pack.tail
+            self._first_jit = pack.first
             self._decode_jit = pack.decode
             self._verify_jit = pack.verify
             self._zero_jit = pack.zero
@@ -947,10 +1009,10 @@ class GenerationEngine:
         # the trace-time closures capture the LEDGER and scalars, never
         # the engine object (ProgramContext). The programs' names are
         # fixed on purpose (gen_prefill, gen_prefill_tail, gen_decode,
-        # gen_verify, gen_zero_pages, gen_cow_copy, gen_tier_gather,
-        # gen_tier_write): a profiler trace's `XLA Modules` line reads
-        # `jit_gen_decode(...)`, and tools/trace_report.py sums device
-        # time per program by them
+        # gen_first_token, gen_verify, gen_zero_pages, gen_cow_copy,
+        # gen_tier_gather, gen_tier_write): a profiler trace's `XLA
+        # Modules` line reads `jit_gen_decode(...)`, and
+        # tools/trace_report.py sums device time per program by them
         NP = self._npool = len(self._pool_arrays)
         self._fns = self._family.build(ProgramContext(
             self._cfg, self._tp, self._mesh, NP, self._quant_kv,
@@ -1013,6 +1075,9 @@ class GenerationEngine:
 
         self._prefill_jit = jit("prefill", fmts)
         self._tail_jit = jit("prefill_tail", fmts)
+        # the engine's own, the same for every family (no pool, no weights;
+        # not in the ledger, which counts the family's programs)
+        self._first_jit = jax.jit(first_token_program(self._cfg.top_k))
         # `.lower()` of these two gives the step program's text; the
         # engine itself runs `_execs[step_name]`, compiled above
         self._decode_jit = jit("decode", fmts)
@@ -1027,7 +1092,8 @@ class GenerationEngine:
             if self._tier is not None else None)
         self._pack = _ProgramPack(
             ledger=self._ledger, prefill=self._prefill_jit,
-            tail=self._tail_jit, decode=self._decode_jit,
+            tail=self._tail_jit, first=self._first_jit,
+            decode=self._decode_jit,
             verify=self._verify_jit, zero=self._zero_jit,
             cow=self._cow_jit, npool=self._npool, W=self._W,
             execs=self._execs,
@@ -1085,6 +1151,16 @@ class GenerationEngine:
         executable."""
         with self._dev_ctx():
             return self._execs[f"verify[k={self._spec_k}]"](*args)
+
+    def _first_token_call(self, logits, prev, slot: int,
+                          temperature: float, do_sample: bool, ordinal: int):
+        """One dispatch of the first-token program (`first_token_program`);
+        returns (prev', token, poison flag), all on the device."""
+        with self._dev_ctx():
+            return self._first_jit(logits, prev, np.int32(slot),
+                                   np.float32(temperature),
+                                   np.bool_(do_sample), self._first_key_host,
+                                   np.int32(ordinal))
 
     def _zero_pages(self, pages):
         # chunked to the fixed zero-scatter width: one sequence's free
@@ -1217,9 +1293,10 @@ class GenerationEngine:
             self._enter_phase(was)
 
     def _warmup(self):
-        """Compile every prefill bucket + the decode step (or, with
-        speculation on, the ONE verify[k] program that replaces it) +
-        the zeroing scatter up front: no live request pays a compile,
+        """Compile every prefill bucket + the first-token program + the
+        decode step (or, with speculation on, the ONE verify[k] program
+        that replaces it) + the zeroing scatter up front: no live request
+        pays a compile,
         and the ledger's exactly-once invariant is observable from step
         one. Warmup writes land only in the reserved scratch page."""
         M, PP = self._cfg.max_slots, self._cfg.pages_per_seq
@@ -1233,7 +1310,8 @@ class GenerationEngine:
                                             ids, np.int32(1),
                                             *self._slot_arg(0))
                 self._set_pools(out[:-1])
-                np.asarray(out[-1])
+                lg = out[-1]
+                np.asarray(lg)
                 if self._use_tail:
                     # one tail-prefill compile per bucket too: prefix
                     # hits AND prefill chunks ride these programs, and
@@ -1246,6 +1324,13 @@ class GenerationEngine:
                                              ids, np.int32(1), np.int32(0))
                     self._set_pools(out[:-1])
                     np.asarray(out[-1])
+            # the first-token program, on both forms of the token input it
+            # is given: the host's zeros and a device array (its own output;
+            # a decode step's, below)
+            prev = self._no_prev
+            for _ in range(2):
+                prev = self._first_token_call(lg, prev, 0, 1.0, False, 0)[0]
+            np.asarray(prev)
             if self._prefix is not None:
                 with self._dev_ctx():
                     out = self._cow_jit(*self._pools(),
@@ -1284,6 +1369,8 @@ class GenerationEngine:
                                         *self._step_arrays())
                 np.asarray(out[self._npool])
                 self._set_pools(out[:self._npool])
+                np.asarray(self._first_token_call(
+                    lg, out[self._npool], 0, 1.0, False, 0)[0])
             self._zero_pages([])
         # every program has run once: each returned the pools as it took
         # them (an executable handed back by a compile cache included)
@@ -1601,7 +1688,7 @@ class GenerationEngine:
                     if self._closed and self._abort:
                         # the step in flight is dropped, not read: nothing
                         # of it was staged or counted
-                        self._drop_flight()
+                        self._drop_unread()
                         self._evict_all(UnavailableError(
                             f"{self.name}: engine shut down"))
                         # flush the aborted/freed counts: the ring's
@@ -1631,9 +1718,9 @@ class GenerationEngine:
                     self._audit.flush_sink()
                     # with a step in flight or sequences decoding, the
                     # next iteration reads or launches a program at once
-                    # and `_read_back` hands these out while the chip runs;
-                    # otherwise nothing is certain to follow, so they go
-                    # out now
+                    # and a read-back (`_read`, `_observe`) hands these
+                    # out while the chip runs; otherwise nothing is certain
+                    # to follow, so they go out now
                     self._release_staged()
                     if self._flight is None and not self._decoding():
                         self._flush_released()
@@ -1831,11 +1918,12 @@ class GenerationEngine:
         self._flush_released()
 
     def _die(self, e: BaseException):
-        # a step in flight is dropped, not read (the pools it was launched
-        # on may be what failed): none of its tokens was staged or
-        # counted, so the manifest's `toks` are exactly what was delivered
-        # and a replay derives the dropped token again — once
-        self._drop_flight()
+        # a step in flight and the prefills launched and unread are dropped,
+        # not read (the pools they were launched on may be what failed):
+        # none of their tokens was staged or counted, so the manifest's
+        # `toks` are exactly what was delivered and a replay derives the
+        # dropped tokens again — once
+        self._drop_unread()
         # two INDEPENDENT try blocks: a ring-record failure on a
         # half-broken engine must not also strand the staged
         # resolutions (they carry real results/errors already decided)
@@ -1937,6 +2025,11 @@ class GenerationEngine:
         both free (FIFO, head-of-line blocking — later smaller requests
         never overtake, so admission latency stays predictable)."""
         while True:
+            if (self._prefix is not None and self._unread and self._queue
+                    and None in self._slots):
+                # the next admission's lookup walks the pages that the
+                # prefills before it register when they are read
+                self._settle_prefills("prefix_cache")
             with self._cv:
                 # whole-queue sweep, not just the head: a request queued
                 # BEHIND a page-blocked head must still get its deadline
@@ -2191,20 +2284,27 @@ class GenerationEngine:
             live.append(req)
         self._queue = live
 
-    def _read_back(self, kind: str, launch, *outs):
+    def _read_chunk(self, launch, logits):
+        """Read a prefill chunk's logits at once, with what was launched
+        before it read first, in launch order: the decode step in flight,
+        then the prefills unread (settled, as "chunk")."""
+        self._settle_prefills("chunk")
+        fl = self._flight
+        if fl is not None and fl.host is None:
+            self._observe(fl)
+        return self._read("prefill", launch, logits)
+
+    def _read(self, kind: str, launch, *outs):
         """The blocking read of a timed program's host outputs (`kind`:
         "prefill", or "decode" for the verify step; `launch`: its device
         clock stamps, which the read's return completes) — with
         `_observe`, which reads the decode step in flight, the only places
         the step thread waits for the chip. The blocked time goes to this
         iteration's `<kind>_wait_ms`, the program's own time (`_program_
-        ended`) to `<kind>_ms`, of which the wait is a sub-split. A
-        program launched BEHIND a decode step in flight ends after it: that
-        step's end is observed first, so each gets its own time and no
-        stretch is counted twice."""
-        fl = self._flight
-        if fl is not None and fl.host is None:
-            self._observe(fl)
+        ended`) to `<kind>_ms`, of which the wait is a sub-split. Programs
+        are read in launch order, so a program launched BEHIND another
+        ends after it and that one's end is observed first: each gets its
+        own time and no stretch is counted twice."""
         t0 = _now_ms()
         # the program is launched and the chip busy: the tokens and
         # outcomes the LAST iteration staged (its record has landed) wake
@@ -2224,7 +2324,7 @@ class GenerationEngine:
         and wait ride on the flight, for the record of the iteration that
         delivers it."""
         t0 = _now_ms()
-        self._flush_released()      # as in `_read_back`: the chip is busy
+        self._flush_released()      # as in `_read`: the chip is busy
         with RecordEvent("generation::read"):
             fl.host = [np.asarray(o) for o in fl.outs]
             if fl.launch is not None:
@@ -2232,14 +2332,18 @@ class GenerationEngine:
         fl.wait_ms = _now_ms() - t0
         fl.decode_ms = self._program_ended()
 
-    def _drop_flight(self):
-        """Forget the step in flight unread (abort, death): nothing of it
-        was staged or counted."""
+    def _drop_unread(self):
+        """Forget the step in flight and the prefills launched, unread
+        (abort, death): nothing of them was staged or counted."""
         fl, self._flight = self._flight, None
+        launches = [p.launch for p in self._unread]
+        self._unread.clear()
         if fl is not None and fl.host is None:
+            launches.append(fl.launch)
+        for launch in launches:
             self._unobserved -= 1
-            if fl.launch is not None:
-                self._devclock.dropped(fl.launch)
+            if launch is not None:
+                self._devclock.dropped(launch)
 
     def _slot_arg(self, slot) -> tuple:
         """The prefill program's last argument for a family that keeps
@@ -2253,57 +2357,100 @@ class GenerationEngine:
         return self._cfg.prefill_buckets[-1]
 
     def _do_prefill(self, req: _GenRequest, digests=None):
-        """Run the request's prompt through the bucketed prefill program
-        (writes its K/V pages), sample the first token, and mark the
-        slot live — it joins the very next decode step. A prefix hit
+        """Launch the request's prompt through the bucketed prefill program
+        (writes its K/V pages) and the first-token program behind it
+        (`_first_token`), and mark the slot live — it joins the very next
+        decode step, its token input the device's own. A prefix hit
         (req.prefix_tokens > 0) prefills ONLY the tail through the
         per-bucket tail program — the cached pages are read, never
         written. A poisoned request (non-finite logits — the pools came
         back valid) fails ONLY this request and returns its pages
-        zeroed; an exception from the jitted call itself is
-        engine-fatal, because the pools were DONATED into it and may
-        already be consumed — touching them again (even to zero this
-        request's pages) would dereference deleted buffers (same
-        contract as a decode-step exception)."""
+        zeroed, once the prefill is read; an exception from the jitted
+        call itself is engine-fatal, because the pools were DONATED into
+        it and may already be consumed — touching them again (even to
+        zero this request's pages) would dereference deleted buffers
+        (same contract as a decode-step exception)."""
         failpoints.maybe_raise("prefill_raise")  # engine-fatal, like a
         #                                          real prefill jit error
         S = int(req.prompt.size)
         pfx = req.prefix_tokens
         tail = S - pfx
+        bucket = self._bucket_for(tail)
+        ids = np.zeros((1, bucket), np.int32)
+        ids[0, :tail] = req.prompt[pfx:]
+        if pfx:
+            span = f"generation::prefill_tail[b={bucket}]"
+            prog, last = self._tail_jit, (np.int32(tail), np.int32(pfx))
+        else:
+            span = f"generation::prefill[b={bucket}]"
+            prog, last = self._prefill_jit, (np.int32(S),
+                                             *self._slot_arg(req.slot))
         # (with a decode step in flight the prefill is launched behind it:
         # the chip goes from the step straight into the prefill)
-        if pfx:
-            bucket = self._bucket_for(tail)
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :tail] = req.prompt[pfx:]
-            with RecordEvent(f"generation::prefill_tail[b={bucket}]"):
-                self._program_launched()
-                with self._dev_ctx():
-                    out = self._tail_jit(
-                        self._W, *self._pools(), req.pt_row, ids,
-                        np.int32(tail), np.int32(pfx))
-                launch = self._dispatched("prefill", out[-1])
-                self._set_pools(out[:-1])
-                lg = self._read_back("prefill", launch, out[-1])
-        else:
-            bucket = self._bucket_for(S)
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :S] = req.prompt
-            with RecordEvent(f"generation::prefill[b={bucket}]"):
-                self._program_launched()
-                with self._dev_ctx():
-                    out = self._prefill_jit(
-                        self._W, *self._pools(), req.pt_row, ids,
-                        np.int32(S), *self._slot_arg(req.slot))
-                launch = self._dispatched("prefill", out[-1])
-                self._set_pools(out[:-1])
-                lg = self._read_back("prefill", launch, out[-1])
+        with RecordEvent(span):
+            self._program_launched()
+            with self._dev_ctx():
+                out = prog(self._W, *self._pools(), req.pt_row, ids, *last)
+            launch = self._dispatched("prefill", out[-1])
+            self._set_pools(out[:-1])
         # real prompt tokens through a prefill program, whatever the family
         self._it["prefill_tokens"] += tail
-        if not np.all(np.isfinite(lg)):
-            self._poison_prefill(req, bucket)
+        self._first_token(req, out[-1], launch, bucket, digests)
+
+    def _first_token(self, req: _GenRequest, logits, launch, bucket: int,
+                     digests) -> None:
+        """Behind a launched prefill (whole prompt, prefix tail or final
+        chunk; `logits` its output, `launch` its device-clock stamps):
+        launch the first-token program, which writes the request's first
+        token into the next decode step's token input at its slot, and
+        leave the prefill unread — it is read once the next decode step
+        has been launched behind it (`_step`), or at once where something
+        needs its token on the host first (`_settles_first`)."""
+        M = self._cfg.max_slots
+        prev, owners = self._prev or (self._no_prev, [None] * M)
+        prev, tok, bad = self._first_token_call(
+            logits, prev, req.slot, req.temperature, req.do_sample,
+            req.ordinal)
+        owners = list(owners)
+        owners[req.slot] = req
+        self._prev = (prev, owners)
+        # the step that takes this token writes it at the prompt's end
+        req.next_pos = int(req.prompt.size)
+        req.unread = True
+        self._unread.append(_Prefill(req, (tok, bad), launch, bucket,
+                                     digests, self._steps_total))
+        why = self._settles_first()
+        if why is not None:
+            self._settle_prefills(why)
+
+    def _settle_prefills(self, why: str) -> None:
+        """Read the prefills launched and unread, oldest first, and deliver
+        their first tokens (`_finish_prefill`). The decode step in flight
+        is read first where it was launched before them; where one was
+        launched behind them it stays in flight, and they count as read
+        ahead (`prefills_ahead`). The others count under `settled` as
+        "prefill:<why>": what did not let them wait for a launch."""
+        if not self._unread:
             return
-        self._finish_prefill(req, lg, digests)
+        fl = self._flight
+        if (fl is not None and fl.host is None
+                and self._steps_total == self._unread[0].steps):
+            self._observe(fl)
+        while self._unread:
+            p = self._unread.popleft()
+            with RecordEvent("generation::read_prefill"):
+                tok, bad = self._read("prefill", p.launch, *p.outs)
+            if self._steps_total > p.steps:
+                self._prefills_ahead += 1
+                monitor.stat_add("STAT_gen_prefills_ahead")
+            else:
+                key = f"prefill:{why}"
+                self._settled[key] = self._settled.get(key, 0) + 1
+            p.req.unread = False
+            if bad:
+                self._poison_prefill(p.req, p.bucket)
+            else:
+                self._finish_prefill(p.req, int(tok), p.digests)
 
     def _inject_poison(self, bad: np.ndarray, owners=None) -> np.ndarray:
         """`decode_poison_nan` failpoint: mark the first live slot's
@@ -2421,18 +2568,15 @@ class GenerationEngine:
                               pages=len(freed),
                               free_pages=self._cache.free_pages)
 
-    def _finish_prefill(self, req: _GenRequest, lg: np.ndarray,
-                        digests) -> None:
+    def _finish_prefill(self, req: _GenRequest, tok: int, digests) -> None:
         """Shared tail of every prefill flavor (whole-prompt, prefix
-        tail, final chunk): register cacheable pages, sample the first
-        token, mark the slot decode-live."""
+        tail, final chunk), once it is read: register cacheable pages,
+        deliver the first token the device sampled."""
         self._prefills_total += 1
         monitor.stat_add("STAT_gen_prefills")
         if self._prefix is not None and digests:
             self._register_pages(req, digests)
-        tok = self._sample_host(req, lg)
         req.toks.append(tok)
-        req.next_pos = int(req.prompt.size)
         self._tokens_total += 1
         monitor.stat_add("STAT_gen_tokens")
         self._it["tokens"] += 1
@@ -2473,39 +2617,23 @@ class GenerationEngine:
                     np.int32(take), np.int32(req.prefill_pos))
             launch = self._dispatched("prefill", out[-1])
             self._set_pools(out[:-1])
-            lg = self._read_back("prefill", launch, out[-1])
         self._it["prefill_chunks"] += 1
         self._it["prefill_tokens"] += take
         self._chunks_total += 1
         monitor.stat_add("STAT_gen_prefill_chunks")
+        req.prefill_pos += take
+        if req.prefill_pos == S:
+            # the final chunk: its first token as any prefill's
+            req.prefill_pos = None
+            digests, req.pending_digests = req.pending_digests, None
+            self._first_token(req, out[-1], launch, bucket, digests)
+            return
+        # a chunk before it is read at once: only its poison flag is wanted
+        lg = self._read_chunk(launch, out[-1])
         if not np.all(np.isfinite(lg)):
             req.prefill_pos = None
             req.pending_digests = None
             self._poison_prefill(req, bucket)
-            return
-        req.prefill_pos += take
-        if req.prefill_pos < S:
-            return
-        req.prefill_pos = None
-        digests, req.pending_digests = req.pending_digests, None
-        self._finish_prefill(req, lg, digests)
-
-    def _sample_host(self, req: _GenRequest, logits: np.ndarray) -> int:
-        """First-token sampling on host (prefill returns logits; decode
-        samples in-graph). Greedy is np.argmax — first-max ties, same
-        as jnp.argmax, so greedy parity with generate() holds."""
-        if not req.do_sample:
-            return int(np.argmax(logits))
-        lg = logits / max(req.temperature, 1e-6)
-        if self._cfg.top_k:
-            kth = np.sort(lg)[-int(self._cfg.top_k)]
-            lg = np.where(lg < kth, -1e30, lg)
-        # engine-local ordinal, NOT the process-global rid: two engines
-        # with the same config/seed must sample identical streams
-        r = np.random.RandomState(
-            (self._cfg.seed * 1000003 + req.ordinal) % (2 ** 31))
-        g = -np.log(-np.log(r.uniform(1e-12, 1.0, lg.shape)))
-        return int(np.argmax(lg + g))
 
     # -- decode step -------------------------------------------------------
 
@@ -2522,8 +2650,9 @@ class GenerationEngine:
         Admission reserved each request's pages for prompt + max_new and
         `pt_row` is fixed from then on, `next_pos` advances by one a
         launch, and a request that ends by max_new ends at a step the host
-        knows before it launches it — so no slot runs a step it does not
-        need, except the one step after an EOS the host has not read yet.
+        knows before it launches it (a prefill's unread token counts) — so
+        no slot runs a step it does not need, except the one step after an
+        EOS or a poison flag the host has not read yet.
         The table and the per-slot arrays are patched where a slot's
         request changed, not rebuilt: an inactive slot's row is zero (its
         write lands in the reserved scratch page)."""
@@ -2538,10 +2667,10 @@ class GenerationEngine:
         for i, req in enumerate(self._slots):
             if req is not None and (
                     req.prefill_pos is not None     # still chunk-prefilling
-                    or (len(req.toks)
+                    or (len(req.toks) + req.unread
                         + (flight is not None and flight.owners[i] is req)
-                        >= req.max_new)):           # ends at the step in
-                req = None                          # flight: max_new
+                        >= req.max_new)):           # ends at its prefill or
+                req = None                          # the step in flight
             if req is not rows[i]:
                 rows[i] = req
                 if req is None:
@@ -2622,8 +2751,9 @@ class GenerationEngine:
 
     def _settles_first(self) -> Optional[str]:
         """Why the next decode step may NOT be launched ahead of the last
-        one's read-back — what needs a step's tokens on the host before
-        the next launch — or None. Decided from the engine's own state:
+        one's read-back, nor a prefill be left unread — what needs a step's
+        or a prefill's tokens on the host before the next launch — or None.
+        Decided from the engine's own state:
         speculation (the proposer reads the token history), a
         `_pre_step_hook` (it may look at, or wait for, what was
         delivered), an armed step failpoint (serving/failpoints.py:
@@ -2642,17 +2772,19 @@ class GenerationEngine:
         one step in flight; returns whether anything ran.
 
         With step n in flight: launch step n+1 from what the device holds
-        (`_launch`), THEN read step n and deliver it (`_settle`) while the
-        chip runs n+1 — unless something needs n's tokens on the host
-        first (`_settles_first`), in which case n is read and nothing is
-        launched. With none in flight (the degenerate case: the first
-        step after an idle engine, every step of an engine that settles
-        first): the hook and the failpoints as ever, then a speculative
-        step, or a launch — read at once where a reason to settle
-        stands, left in flight otherwise. Every live sequence advances
-        one token a step through the single compiled decode program
-        (inactive slots are masked into the reserved scratch page), or 1
-        to k+1 through the single compiled verify program.
+        (`_launch`), THEN read step n and deliver it (`_settle`), THEN read
+        the prefills this iteration launched behind n (`_settle_prefills`),
+        all while the chip runs n+1 — unless something needs n's tokens on
+        the host first (`_settles_first`), in which case n is read and
+        nothing is launched. With none in flight (the degenerate case: the
+        first step after an idle engine, every step of an engine that
+        settles first): the hook and the failpoints as ever, then a
+        speculative step, or a launch — read at once where a reason to
+        settle stands, left in flight otherwise — and the prefills read
+        after it. No prefill is left unread past this. Every live sequence
+        advances one token a step through the single compiled decode
+        program (inactive slots are masked into the reserved scratch
+        page), or 1 to k+1 through the single compiled verify program.
 
         Output is the same work as a loop that reads every step before
         the next: greedy streams are token-identical; step k still draws
@@ -2662,13 +2794,16 @@ class GenerationEngine:
         while the next step is already launched), and a step that ran for
         an EOS overshoot alone, with no other slot live, takes a step
         number that loop would not have spent: sampled streams equal its
-        streams only where neither happened."""
+        streams only where neither happened. A request's first token is
+        drawn from `fold_in` of the first-token key by its ordinal, which
+        no launch order moves."""
         fl = self._flight
         if fl is not None:
             why = self._settles_first()
             if why is None:
                 self._launch(fl)
             self._settle(fl)
+            self._settle_prefills(why or "no_decode")
             return True
         if not self._decoding():
             return False
@@ -2688,9 +2823,11 @@ class GenerationEngine:
         if why is not None:
             self._settled[why] = self._settled.get(why, 0) + 1
         if why == "speculation":
+            self._settle_prefills(why)
             self._spec_step()
             return True
         fl = self._launch(None)
+        self._settle_prefills(why or "no_decode")
         if fl is not None and why is not None:
             self._settle(fl)
         return True
@@ -2787,15 +2924,16 @@ class GenerationEngine:
         fewer weight streams."""
         with RecordEvent("generation::prepare"):
             args, drafted = self._spec_arrays()
-        # (never with a step in flight: `_settles_first`; the device's
-        # next tokens of an earlier decode launch are stale after this)
+        # (never with a step in flight or a prefill unread:
+        # `_settles_first`; the device's next tokens of an earlier decode
+        # launch are stale after this)
         self._prev = None
         self._program_launched()
         with RecordEvent(f"generation::verify[k={self._spec_k}]"):
             out = self._verify_call(self._W, *self._pools(), *args)
             launch = self._dispatched("decode", out[-1])
-            n_acc, nxt, bad = self._read_back("decode", launch, out[-3],
-                                              out[-2], out[-1])
+            n_acc, nxt, bad = self._read("decode", launch, out[-3],
+                                         out[-2], out[-1])
         if failpoints.fire("decode_poison_nan") is not None:
             bad = self._inject_poison(bad)
         self._set_pools(out[:-3])
@@ -2852,7 +2990,9 @@ class GenerationEngine:
         a timeout (STAT_gen_timeouts, SLO error)."""
         t = _now_ms()
         for req in list(self._slots):
-            if req is None:
+            if req is None or req.unread:
+                # (a prefill unread is read this iteration, its first token
+                # with it: checked at the next)
                 continue
             deadlines = [req.deadline_ms] if req.deadline_ms else []
             if req.ttft_deadline_ms is not None and not req.toks:
@@ -3073,13 +3213,18 @@ class GenerationEngine:
             "prefill_chunks": self._chunks_total,
             # one decode step in flight (ISSUE 34): decode steps launched
             # while the step before was still unread (`steps` has them
-            # all), steps that settled first by what needed their tokens
-            # on the host ("speculation", "pre_step_hook", "failpoint"),
-            # and tokens the chip computed for a request that had left by
-            # the time they were read (the step after an EOS or a poison
-            # flag learned a step late, an expiry, an eviction): dropped,
-            # never staged or counted
+            # all), prefills read only after a decode step was launched
+            # behind them (`prefills` has them all), steps that settled
+            # first by what needed their tokens on the host
+            # ("speculation", "pre_step_hook", "failpoint") and prefills
+            # read at once ("prefill:" and that, or "no_decode": no step
+            # followed, "chunk": a prefill chunk was read, "prefix_cache":
+            # the next admission's lookup), and tokens the chip computed
+            # for a request that had left by the time they were read (the
+            # step after an EOS or a poison flag learned a step late, an
+            # expiry, an eviction): dropped, never staged or counted
             "lookahead": {"ahead": self._ahead_total,
+                          "prefills_ahead": self._prefills_ahead,
                           "settled": dict(self._settled),
                           "dropped_tokens": self._dropped_tokens},
             # fault tolerance (ISSUE 15): which engine generation this

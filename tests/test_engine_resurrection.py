@@ -327,6 +327,54 @@ def test_death_with_a_step_in_flight_replays_each_token_once(model):
         sup.shutdown()
 
 
+def test_death_with_a_prefill_unread_replays_each_token_once(model):
+    """Three requests admitted in one iteration: the first two prefills are
+    launched with their first tokens on the device and left unread (the
+    decode step that would be launched behind them never is), and the third
+    prefill kills the engine. The unread prefills are dropped, not read:
+    nothing of them was staged, counted or streamed, so every request
+    replays from its prompt on the rebuilt engine and every stream delivers
+    each token once; the ring's sums reconcile over both incarnations."""
+    prompts = _prompts(3, seed=17)
+    with _eng(model, name="resurrect_pref", max_slots=3,
+              max_new_tokens=9) as eng:
+        ref = [eng.submit(p, max_new_tokens=9).result() for p in prompts]
+    k0 = monitor.stat_get("STAT_gen_tokens")
+    c0 = monitor.stat_get("STAT_gen_completions")
+    at_death = []
+    with flags(FLAGS_failpoints="prefill_raise@3",
+               FLAGS_gen_restart_backoff_ms=5.0):
+        sup = _sup(model, name="resurrect_p", max_slots=3, max_new_tokens=9)
+        first = sup.engine
+        real_die = first._die
+
+        def die(e):     # what the dying engine held when it died
+            at_death.append(([p.req.rid for p in first._unread],
+                             [len(r.toks) for r in first._slots
+                              if r is not None]))
+            return real_die(e)
+        first._die = die
+        with first._cv:     # all three admitted by one `_admit`
+            streams = [sup.submit_stream(p, max_new_tokens=9)
+                       for p in prompts]
+        collected = [[int(t) for t in st] for st in streams]
+        assert sup.restarts == 1
+        (unread, generated), = at_death
+        assert len(unread) == 2 and generated == [0, 0, 0]
+        for i, st in enumerate(streams):
+            out = st.result(timeout=30)
+            assert collected[i] == out[len(prompts[i]):].tolist()
+            np.testing.assert_array_equal(out, ref[i])
+        recs = step_log.steps_payload()["engines"]["resurrect_p"]["records"]
+        assert {r["incarnation"] for r in recs} == {0, 1}
+        assert sum(r["completed"] for r in recs) == \
+            monitor.stat_get("STAT_gen_completions") - c0 == 3
+        assert sum(r["tokens"] for r in recs) == \
+            monitor.stat_get("STAT_gen_tokens") - k0 == 3 * 9
+        assert sup.stats()["pages"]["pages_in_use"] == 0
+        sup.shutdown()
+
+
 def test_prefill_fault_restart(model):
     prompts = _prompts(2, seed=9)
     with _eng(model, name="resurrect_pref") as eng:

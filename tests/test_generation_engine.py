@@ -672,7 +672,11 @@ def test_a_poison_failpoint_fails_its_request_alone_and_settles_first(model):
             np.testing.assert_array_equal(o, ref)
     np.testing.assert_array_equal(out_c, refs[2])
     assert armed["lookahead"]["ahead"] == 0
-    assert armed["lookahead"]["settled"] == {"failpoint": armed["steps"]}
+    assert armed["lookahead"]["prefills_ahead"] == 0
+    # every prefill read at once too: each request's (a poisoned one's
+    # count, none are)
+    assert armed["lookahead"]["settled"] == {
+        "failpoint": armed["steps"], "prefill:failpoint": 2}
     assert after["lookahead"]["ahead"] > 0
     assert after["pages"]["pages_in_use"] == 0
 
@@ -682,8 +686,9 @@ def test_a_poison_failpoint_fails_its_request_alone_and_settles_first(model):
 def test_what_needs_the_tokens_on_the_host_finds_no_step_in_flight(
         model, reason):
     """Speculation (the proposer reads the history), a `_pre_step_hook`,
-    an armed `slow_step_ms`: every step is read before the next launch,
-    decided from the engine's own state, and the tokens are the same."""
+    an armed `slow_step_ms`: every step is read before the next launch and
+    every prefill at once, decided from the engine's own state, and the
+    tokens are the same."""
     from paddle_tpu.serving import failpoints
     ids = _prompts(3, seed=4)
     refs = [_alone(model, p, 6) for p in ids]
@@ -707,7 +712,10 @@ def test_what_needs_the_tokens_on_the_host_finds_no_step_in_flight(
         np.testing.assert_array_equal(out, ref)
     look = s["lookahead"]
     assert look["ahead"] == 0 and look["dropped_tokens"] == 0
-    assert look["settled"] == {reason: s["steps"]} and s["steps"] > 0
+    assert look["prefills_ahead"] == 0
+    assert look["settled"] == {reason: s["steps"],
+                               f"prefill:{reason}": len(ids)}
+    assert s["steps"] > 0 and s["prefills"] == len(ids)
     if reason == "pre_step_hook":
         assert seen and all(seen)       # never a step in flight at a hook
 
@@ -806,3 +814,287 @@ def test_a_steps_time_is_never_under_its_programs_and_the_buckets_tile(
             > 0.8 * sum(r["attr_wall_ms"] - r["prefill_ms"]
                         for r in stepped))
     assert sum(r["tokens"] for r in recs) == s["tokens"]
+
+
+# -- the prefill's first token stays on the device ----------------------------
+#
+# Every prefill is followed by the small first-token program, which samples
+# the request's first token on the device and writes it into the next decode
+# step's token input; the engine launches that step before it reads the
+# prefill. A test that holds `eng._cv` while it submits makes the step thread
+# admit all of those requests in ONE iteration, their prefills chained on the
+# device with no read in between.
+
+def _family_net(family):
+    if family == "gpt":
+        paddle.seed(11)
+        net = GPTForCausalLM(GPTConfig.tiny(dropout=0.0))
+    elif family == "latent":
+        from paddle_tpu.models import GlmMoeLiteConfig, GlmMoeLiteForCausalLM
+        paddle.seed(27)
+        net = GlmMoeLiteForCausalLM(GlmMoeLiteConfig.tiny())
+    else:
+        from paddle_tpu.models import FalconH1Config, FalconH1ForCausalLM
+        paddle.seed(36)
+        net = FalconH1ForCausalLM(FalconH1Config.tiny())
+    net.eval()
+    return net
+
+
+_FAMILY_ENGINE = {
+    "gpt": dict(page_size=4, num_pages=64, prefill_buckets=(4, 8)),
+    "latent": dict(page_size=4, num_pages=64, pages_per_seq=16,
+                   prefill_buckets=(16, 32)),
+    "hybrid": dict(page_size=8, num_pages=25, pages_per_seq=8,
+                   prefill_buckets=(16, 32)),
+}
+
+
+def _full_forward_greedy(net, prompt, n, width=48):
+    """Greedy decoding by the Layer's whole causal forward over what there is
+    so far (right-padded to one width, which no real position attends to)."""
+    seq = [int(t) for t in prompt]
+    for _ in range(n):
+        ids = np.zeros((1, width), "int32")
+        ids[0, :len(seq)] = seq
+        lg = net(paddle.to_tensor(ids)).numpy()[0, len(seq) - 1]
+        seq.append(int(lg.argmax()))
+    return np.asarray(seq)
+
+
+@pytest.mark.parametrize("family", ["gpt", "latent", "hybrid"])
+def test_prefills_read_behind_the_next_launch_are_token_identical(family):
+    """Five requests over three slots, the first three admitted in one
+    iteration (three prefills and their first tokens chained on the device,
+    then the first decode step launched behind them, then the three read),
+    the last two admitted as slots free, behind a step in flight: every
+    stream is its prompt's greedy decoding alone, every prefill was read
+    after the next launch, and nothing compiles after the warm-up — the
+    first-token program included."""
+    net = _family_net(family)
+    vocab = 512 if family == "gpt" else 384
+    rs = np.random.RandomState(42)
+    shapes = [(3, 9), (8, 4), (5, 11), (7, 6), (2, 8)]
+    if family != "gpt":
+        shapes = [(S + 8, n) for S, n in shapes]
+    prompts = [rs.randint(0, vocab, size=(S,)).astype("int64")
+               for S, _ in shapes]
+    if family == "gpt":
+        refs = [_alone(net, p, n) for p, (_, n) in zip(prompts, shapes)]
+    else:
+        refs = [_full_forward_greedy(net, p, n)
+                for p, (_, n) in zip(prompts, shapes)]
+    eng = serving.GenerationEngine(
+        net, name=f"first_tok_{family}", max_slots=3, max_new_tokens=12,
+        request_timeout_ms=0, **_FAMILY_ENGINE[family])
+    try:
+        warm = dict(eng.stats()["compiles"])
+        cached = eng._first_jit._cache_size()
+        with eng._cv:
+            streams = [eng.submit_stream(p, max_new_tokens=n)
+                       for p, (_, n) in zip(prompts, shapes)]
+        got = [list(s) for s in streams]
+        outs = [s.result(timeout=120) for s in streams]
+        assert _wait_until(lambda: eng._flight is None)
+        s = eng.stats()
+        recs = eng._step_log.tail(10_000)
+        assert eng._first_jit._cache_size() == cached
+    finally:
+        eng.shutdown()
+    for g, out, ref, p in zip(got, outs, refs, prompts):
+        np.testing.assert_array_equal(out, ref)
+        assert g == out[len(p):].tolist()
+    look = s["lookahead"]
+    assert look["prefills_ahead"] == s["prefills"] == len(prompts)
+    assert look["settled"] == {} and look["dropped_tokens"] == 0
+    assert max(r["admitted"] for r in recs) == 3    # three in one iteration
+    assert s["compiles"] == warm
+    assert s["tokens"] == sum(n for _, n in shapes)
+    assert s["pages"]["pages_in_use"] == 0
+
+
+def test_max_new_one_and_a_first_token_eos_behind_a_step_in_flight(model):
+    """With another request decoding (a step always in flight): a request of
+    one token joins no decode step (its unread token counts against
+    max_new), so nothing of it is dropped; a request whose FIRST token is
+    its EOS has already joined the step launched behind its prefill — that
+    step's token for it is dropped exactly once, neither streamed nor
+    counted. The pools are zero once all is done, and the next request, on
+    the pages they freed, decodes its own tokens."""
+    ids = _prompts(4, seed=9)
+    ref_one = _alone(model, ids[1], 1)
+    ref_eos = _alone(model, ids[2], 1)
+    eos = int(ref_eos[-1])
+    ref_long = _alone(model, ids[0], 30)
+    ref_next = _alone(model, ids[3], 8)
+    with _engine(model, max_new_tokens=30) as eng:
+        long_s = eng.submit_stream(ids[0], max_new_tokens=30)
+        it = iter(long_s)
+        next(it)                        # decoding has begun
+        d0 = eng.stats()["lookahead"]["dropped_tokens"]
+        one = eng.submit_stream(ids[1], max_new_tokens=1)
+        assert list(one) == ref_one[7:].tolist()
+        assert eng.stats()["lookahead"]["dropped_tokens"] == d0
+        stop = eng.submit_stream(ids[2], max_new_tokens=10,
+                                 eos_token_id=eos)
+        assert list(stop) == [eos]
+        np.testing.assert_array_equal(stop.result(timeout=120), ref_eos)
+        np.testing.assert_array_equal(one.result(timeout=120), ref_one)
+        assert _wait_until(
+            lambda: eng.stats()["lookahead"]["dropped_tokens"] == d0 + 1)
+        np.testing.assert_array_equal(long_s.result(timeout=120), ref_long)
+        assert _wait_until(lambda: eng._flight is None)
+        st = eng.stats()
+        # the two short requests' tokens: one each, the dropped one not
+        assert st["tokens"] == 30 + 1 + 1
+        assert st["lookahead"]["dropped_tokens"] == d0 + 1
+        assert st["lookahead"]["prefills_ahead"] == st["prefills"] == 3
+        assert st["pages"]["pages_in_use"] == 0
+        for pool in eng._pools():
+            assert float(np.abs(np.asarray(pool)).max()) == 0.0
+        out_next = eng.generate(ids[3], max_new_tokens=8)
+    np.testing.assert_array_equal(out_next, ref_next)
+
+
+def test_a_poisoned_prefill_read_behind_a_step_fails_its_request_alone(
+        model):
+    """A prefill whose logits come back non-finite (planted here: the
+    marked prompt's logits times NaN) while another request decodes: its
+    first token's poison flag is read after the next step was launched with
+    the request in it; only that request fails, its token of that step is
+    dropped, its pages come back zeroed, and the next request decodes its
+    own tokens."""
+    import jax.numpy as jnp
+    ids = _prompts(3, seed=12)
+    MARK = 511
+    bad_prompt = ids[1].copy()
+    bad_prompt[0] = MARK
+    ref_long = _alone(model, ids[0], 40)
+    ref_next = _alone(model, ids[2], 8)
+    p0 = monitor.stat_get("STAT_gen_poisoned")
+    with _engine(model, max_new_tokens=40) as eng:
+        real, NP = eng._prefill_jit, eng._npool
+
+        def planted(W, *args):
+            out = real(W, *args)
+            if int(args[NP + 1][0, 0]) == MARK:     # the prompt's ids
+                return (*out[:-1], out[-1] * jnp.nan)
+            return out
+        eng._prefill_jit = planted
+        long_s = eng.submit_stream(ids[0], max_new_tokens=40)
+        it = iter(long_s)
+        next(it)                        # decoding has begun
+        bad = eng.submit(bad_prompt, max_new_tokens=8)
+        with pytest.raises(FatalError, match="non-finite prefill"):
+            bad.result(timeout=120)
+        assert _wait_until(
+            lambda: eng.stats()["lookahead"]["dropped_tokens"] == 1)
+        np.testing.assert_array_equal(long_s.result(timeout=120), ref_long)
+        assert _wait_until(lambda: eng._flight is None)
+        st = eng.stats()
+        for pool in eng._pools():
+            assert float(np.abs(np.asarray(pool)).max()) == 0.0
+        out_next = eng.generate(ids[2], max_new_tokens=8)
+    np.testing.assert_array_equal(out_next, ref_next)
+    assert monitor.stat_get("STAT_gen_poisoned") == p0 + 1
+    assert st["lookahead"]["prefills_ahead"] == 2   # the poisoned one too
+    assert st["prefills"] == 1                      # delivered: the long
+    assert st["pages"]["pages_in_use"] == 0
+
+
+def test_two_engines_of_one_seed_sample_the_same_first_tokens(model):
+    """A sampled request's first token is drawn on the device from the
+    first-token key folded with the request's engine-local ordinal: two
+    engines of one seed give the same first tokens whether the requests
+    arrive together (admitted in one iteration) or one at a time, another
+    seed gives others, and the first-token key is not the decode steps'."""
+    ids = _prompts(6, seed=14)
+    kw = dict(max_new_tokens=1, do_sample=True, temperature=0.9)
+
+    def firsts(seed, together):
+        with _engine(model, seed=seed, max_slots=6) as eng:
+            assert not np.array_equal(eng._first_key_host, eng._key_host)
+            if together:
+                with eng._cv:
+                    futs = [eng.submit(p, **kw) for p in ids]
+                outs = [f.result(timeout=120) for f in futs]
+            else:
+                outs = [eng.generate(p, **kw) for p in ids]
+            assert eng.stats()["lookahead"]["prefills_ahead"] == 0
+            assert eng.stats()["lookahead"]["settled"] == {
+                "prefill:no_decode": len(ids)}
+        return [int(o[-1]) for o in outs]
+
+    a = firsts(42, together=True)
+    assert firsts(42, together=False) == a
+    assert firsts(7, together=True) != a
+    greedy = [int(_alone(model, p, 1)[-1]) for p in ids]
+    assert a != greedy
+
+
+def test_prefills_ahead_counts_every_admission_of_a_loaded_run(model):
+    """A loaded run — more requests than slots, of mixed lengths, a step in
+    flight all along: every admission's prefill is read after the next
+    decode step was launched behind it, as the step ring's records count
+    them, and `STAT_gen_prefills_ahead` moves by as many."""
+    rs = np.random.RandomState(17)
+    prompts = [rs.randint(0, 512, size=(int(n),)).astype("int64")
+               for n in rs.randint(2, 9, size=12)]
+    news = [int(n) for n in rs.randint(2, 14, size=12)]
+    c0 = monitor.stat_get("STAT_gen_prefills_ahead")
+    with _engine(model, name="first_tok_loaded", max_slots=4,
+                 prefill_buckets=(4, 8), max_new_tokens=16) as eng:
+        futs = [eng.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, news)]
+        outs = [f.result(timeout=120) for f in futs]
+        assert _wait_until(lambda: eng._flight is None)
+        s = eng.stats()
+        recs = eng._step_log.tail(10_000)
+    for p, n, out in zip(prompts, news, outs):
+        np.testing.assert_array_equal(out, _alone(model, p, n))
+    admitted = sum(r["admitted"] for r in recs)
+    assert admitted == len(prompts)
+    assert s["lookahead"]["prefills_ahead"] == admitted
+    assert monitor.stat_get("STAT_gen_prefills_ahead") - c0 == admitted
+    assert s["lookahead"]["settled"] == {}
+
+
+def test_prefills_read_ahead_keep_the_records_tiling_the_thread(model):
+    """Several admissions an iteration, their prefills read after the decode
+    step launched behind them: every record's six buckets still sum to its
+    wall, a prefill's time never under its blocked read, and the walls of
+    the records between two moments the engine had nothing in flight sum to
+    the step thread's time between them — no stretch counted twice, none
+    lost."""
+    ids = _prompts(6, seed=19)
+    with _engine(model, name="first_tok_tiles", max_slots=3,
+                 max_new_tokens=10) as eng:
+        eng.generate(ids[0], max_new_tokens=3)
+        assert _wait_until(lambda: eng._flight is None and all(
+            r is None for r in eng._slots))
+        time.sleep(0.05)                # the loop is back in its wait
+        n0 = eng._step_log.recorded
+        t_a = eng._step_log.tail(1)[0]["t"]
+        with eng._cv:
+            futs = [eng.submit(p, max_new_tokens=8) for p in ids]
+        for f in futs:
+            f.result(timeout=120)
+        assert _wait_until(lambda: eng._flight is None and all(
+            r is None for r in eng._slots))
+        time.sleep(0.05)
+        recs = eng._step_log.tail(eng._step_log.recorded - n0)
+        s = eng.stats()
+    assert s["lookahead"]["prefills_ahead"] == 1 + len(ids)
+    assert sum(r["admitted"] for r in recs) == len(ids)
+    assert sum(r["prefill_ms"] > 0 for r in recs) >= 2
+    for r in recs:
+        assert 0 <= r["prefill_wait_ms"] <= r["prefill_ms"], r
+        assert 0 <= r["decode_wait_ms"] <= r["decode_ms"], r
+        total = (r["attr_admit_ms"] + r["prefill_ms"]
+                 + r["attr_promote_ms"] + r["decode_ms"]
+                 + r["attr_bookkeep_ms"] + r["attr_idle_ms"])
+        assert abs(total - r["attr_wall_ms"]) < 1e-9, r
+    # (each record's `t` follows the charge that closes its wall by the
+    # record's own bookkeeping, some microseconds, more on a loaded host)
+    walls = sum(r["attr_wall_ms"] for r in recs)
+    assert walls == pytest.approx((recs[-1]["t"] - t_a) * 1e3, abs=5.0)
